@@ -12,7 +12,7 @@ after a fixed number of steps against the uncompressed run.
 
 The wire-dtype change itself is proven at the HLO level in
 tests/test_compression_path.py / tests/test_overlap_compression.py; this
-script puts numbers on it for BASELINE.md. The STATED TOLERANCE for the
+script puts numbers on it. The STATED TOLERANCE for the
 quantized wires: with error feedback the final loss must track the bf16
 path within ``--tolerance`` (default 10% relative) — the acceptance bound
 the bench asserts (``within_tolerance``; exit non-zero on a miss). The

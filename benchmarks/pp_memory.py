@@ -1,4 +1,4 @@
-"""Pipeline-schedule backward-memory comparison (the BASELINE.md 6.7× row).
+"""Pipeline-schedule backward-memory comparison.
 
 Compares XLA's `memory_analysis()` of the compiled gradient computation for
 `PipelinedLM(schedule='gpipe')` (AD-derived backward: the scan stash holds

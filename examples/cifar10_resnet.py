@@ -62,7 +62,7 @@ def main() -> None:
     # ARCH=vit swaps the conv model for the conv-free ViT (models/vit.py)
     # through the identical training path — architecture is a swappable
     # leaf, and the ViT's matmul shapes reach MFU the CIFAR convs can't
-    # (BASELINE.md vit row).
+    # (not measured on this round's chip).
     if os.environ.get("ARCH", "resnet") == "vit":
         module = ViT(
             patch_size=4, d_model=256, n_heads=8, n_layers=6,

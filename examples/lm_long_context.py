@@ -113,7 +113,7 @@ def main() -> None:
             n_experts=int(os.environ.get("N_EXPERTS", 8)),
             # Memory knobs for extreme context (REMAT=1, LOGITS=bf16):
             # together they take one 16 GB chip from OOM to training at
-            # seq 131,072 (BASELINE.md context-envelope row).
+            # seq 131,072 (not measured on this round's chip).
             remat=hvt.runtime.env_flag("REMAT"),
             logits_dtype=jnp.bfloat16
             if os.environ.get("LOGITS", "") == "bf16"

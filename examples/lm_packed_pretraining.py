@@ -8,8 +8,8 @@ fixed [B, T] rows. Everything the path needs is first-class here —
 2. `data.packing.next_token_pairs`: shifted (x, y, loss-weights) whose mask
    stops targets at document boundaries;
 3. `TransformerLM(..., segment_ids=...)`: per-document RoPE restart and the
-   flash kernel's segment-masked attention (block-level early-out — 4.0×
-   over dense-masked at seq 4096, BASELINE.md);
+   flash kernel's segment-masked attention (block-level early-out; its
+   gain over dense-masked is not measured on this round's chip);
 4. a weighted cross-entropy Trainer loss via the callable-loss hook.
 
 The corpus is synthetic (zero-egress environment): each "document" is a

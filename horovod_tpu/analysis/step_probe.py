@@ -124,7 +124,6 @@ def lowered_moe_dispatch_text(d_model: int = 8, capacity: int = 4) -> str:
     import numpy as np
     from jax.sharding import PartitionSpec as P
 
-    from horovod_tpu import compat
     from horovod_tpu.parallel import collectives
 
     devices = jax.devices()
@@ -142,7 +141,7 @@ def lowered_moe_dispatch_text(d_model: int = 8, capacity: int = 4) -> str:
             h, "expert", split_axis=0, concat_axis=0, tiled=True
         )
 
-    fn = compat.shard_map(
+    fn = jax.shard_map(
         stage, mesh=mesh, in_specs=(P("expert"),), out_specs=P("expert")
     )
     x = jnp.zeros((e * e, capacity, d_model), jnp.float32)

@@ -1,90 +1,39 @@
-"""jax version-tolerance shims.
+"""Private-jax helpers for elastic re-initialisation.
 
-The repo targets the current jax spelling of APIs; containers pinned to an
-older jax (< 0.5) lack some of them. Every such difference is absorbed here
-— call sites import from `horovod_tpu.compat` and stay on the modern
-signature.
-
-* ``shard_map``: ``jax.shard_map(..., check_vma=...)`` is the modern form;
-  older releases ship ``jax.experimental.shard_map.shard_map`` whose
-  equivalent knob is spelled ``check_rep``.
-* ``axis_size``: ``jax.lax.axis_size(name)`` is newer; the portable
-  spelling reads the bound axis env directly (a trace-time constant, like
-  the modern call — NOT a ``psum(1)`` collective).
+Resizing the world in-process needs two operations jax has no public API
+for: fully resetting the distributed runtime's global state (so a second
+``jax.distributed.initialize`` is legal) and dropping the live backends
+(whose collectives are compiled against the OLD world size). Both touch
+``jax._src``; they are written against the installed jax (the floor in
+pyproject.toml) and live here so there is one place to re-check on an
+upgrade.
 """
 
 from __future__ import annotations
 
 import jax
-from jax import lax
-
-if hasattr(lax, "axis_size"):
-    axis_size = lax.axis_size
-else:
-
-    def axis_size(axis_name) -> int:
-        """Size of a bound mesh axis (tuple = product), trace-time."""
-        if isinstance(axis_name, (tuple, list)):
-            out = 1
-            for n in axis_name:
-                out *= axis_size(n)
-            return out
-        from jax._src import core as _core  # old jax only: no public API
-
-        return _core.get_axis_env().axis_size(axis_name)
-
-if hasattr(jax, "shard_map"):
-
-    def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = True):
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_vma=check_vma,
-        )
-
-else:  # jax < 0.5: experimental module, check_rep spelling
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = True):
-        return _shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_rep=check_vma,
-        )
-
-
-# --- elastic rescale shims (horovod_tpu.elastic) ---------------------------
-#
-# Resizing the world in-process needs two operations jax has no stable public
-# API for: fully resetting the distributed runtime's global state (so a
-# second `initialize()` is legal) and dropping the live backends (whose
-# collectives are compiled against the OLD world size). Both touch private
-# modules whose spelling drifts across versions — absorbed here.
+from jax._src import distributed
+from jax.extend import backend
 
 
 def reset_distributed_state() -> None:
     """Null out jax's distributed global state so a subsequent
     ``jax.distributed.initialize`` succeeds.
 
-    ``jax.distributed.shutdown()`` forgets ``preemption_sync_manager`` on
-    0.4.x ("Preemption sync manager should only be initialized once" on the
-    next init) and leaves ``coordinator_address``/``process_id`` populated;
-    a rescale must clear everything. Attribute-tolerant: fields that a jax
-    version lacks are skipped."""
-    try:
-        from jax._src import distributed
-    except ImportError:  # pragma: no cover — future jax moved the module
-        return
+    ``State.shutdown()`` clears the client, service and preemption manager
+    only when it runs to the end, and leaves ``coordinator_address`` /
+    ``process_id`` / ``num_processes`` populated; a rescale must clear
+    everything, including after a torn shutdown."""
     state = distributed.global_state
-    for attr in ("client", "service", "preemption_sync_manager",
-                 "coordinator_address"):
-        if hasattr(state, attr):
-            setattr(state, attr, None)
+    state.client = None
+    state.service = None
+    state.preemption_sync_manager = None
+    state.coordinator_address = None
     # Back to the PRISTINE single-process values, not None: backend
     # creation reads process_id/num_processes directly (node_id=None
     # crashes the CPU client constructor).
-    if hasattr(state, "process_id"):
-        state.process_id = 0
-    if hasattr(state, "num_processes"):
-        state.num_processes = 1
+    state.process_id = 0
+    state.num_processes = 1
 
 
 def distributed_shutdown_barrier() -> None:
@@ -99,12 +48,7 @@ def distributed_shutdown_barrier() -> None:
     fatal errors"). After the barrier, leftover fields are reset so
     re-initialization at a new world size is legal."""
     try:
-        from jax._src import distributed
-    except ImportError:  # pragma: no cover
-        return
-    state = distributed.global_state
-    try:
-        state.shutdown()
+        distributed.global_state.shutdown()
     finally:
         reset_distributed_state()
 
@@ -115,17 +59,6 @@ def clear_backends() -> None:
 
     Every live ``jax.Array`` is invalidated — callers must hold host
     (numpy) copies of anything they still need (the ElasticState commit
-    contract). Spelling drift: ``jax.extend.backend.clear_backends`` is the
-    current home; older releases only have the underscored xla_bridge
-    helper."""
+    contract)."""
     jax.clear_caches()
-    try:
-        from jax.extend import backend as _backend
-
-        _backend.clear_backends()
-        return
-    except (ImportError, AttributeError):
-        pass
-    from jax._src import xla_bridge  # pragma: no cover — old jax only
-
-    xla_bridge._clear_backends()
+    backend.clear_backends()

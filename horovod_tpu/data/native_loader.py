@@ -8,9 +8,10 @@ the accelerator runs the previous step.
 
 `NativeBatchLoader` is a drop-in for the training-path `ArrayDataset`
 pipeline (full reshuffle each epoch, repeat-forever, drop-remainder — the
-same semantics `Trainer.fit(x=, y=)` builds). `available()` reports whether
-the shared library could be loaded/built; callers fall back to the Python
-pipeline when it can't, so the framework works without a toolchain.
+same semantics `Trainer.fit(x=, y=)` builds). The shared library is built
+from native/hvt_data.cc at first use (`make`, g++); `available()` reports
+whether that worked, and callers fall back to the Python pipeline — after a
+warning — when it didn't, so the framework works without a toolchain.
 
 By default each yielded array is an owned copy (safe under any lifetime —
 JAX's async device_put may read host buffers after dispatch, and a GC'd
@@ -26,6 +27,7 @@ import ctypes
 import os
 import subprocess
 import threading
+import warnings
 from typing import Sequence
 
 import numpy as np
@@ -43,8 +45,21 @@ _lib_lock = threading.Lock()
 _load_failed = False
 
 
+def _unavailable(why: str) -> None:
+    """Record (once — the failure is cached) that the native engine cannot
+    be used, and say so: the Python assembler that takes over is a
+    different engine, not a silent equivalent."""
+    global _load_failed
+    _load_failed = True
+    warnings.warn(
+        f"native batch-assembly engine unavailable ({why}); "
+        "Trainer.fit(x=, y=) assembles batches in Python instead"
+    )
+
+
 def _load():
-    """Load (building on first use) the shared library; None on failure."""
+    """Load the shared library, building it from native/hvt_data.cc on
+    first use (it is not committed); None — after a warning — on failure."""
     global _lib, _load_failed
     if _lib is not None or _load_failed:
         return _lib
@@ -65,28 +80,23 @@ def _load():
                 capture_output=True,
                 timeout=120,
             )
-        except Exception:
+        except (OSError, subprocess.SubprocessError) as e:
             if not os.path.exists(_LIB_PATH):
-                _load_failed = True
+                _unavailable(f"building {_LIB_PATH} failed: {e!r}")
                 return None
         try:
             lib = ctypes.CDLL(_LIB_PATH)
-        except OSError:
-            _load_failed = True
+        except OSError as e:
+            _unavailable(f"loading {_LIB_PATH} failed: {e!r}")
             return None
         # ABI handshake: a stale prebuilt .so (no compiler to rebuild,
         # make failed above) predating the epoch-anchored stream would
         # silently IGNORE the extra create arguments — the cursors would
         # then describe a stream nobody produces. Missing symbol or
-        # version mismatch → treat the native engine as unavailable and
-        # fall back to the python pipeline (fail-safe, never
-        # fail-different-bytes).
-        try:
-            if lib.hvt_loader_abi_version() != 2:
-                _load_failed = True
-                return None
-        except AttributeError:
-            _load_failed = True
+        # version mismatch → treat the native engine as unavailable
+        # (fail-safe, never fail-different-bytes).
+        if getattr(lib, "hvt_loader_abi_version", lambda: None)() != 2:
+            _unavailable(f"{_LIB_PATH} is a stale build (ABI != 2)")
             return None
         lib.hvt_loader_create.restype = ctypes.c_void_p
         lib.hvt_loader_create.argtypes = [

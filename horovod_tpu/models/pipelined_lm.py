@@ -42,7 +42,6 @@ from __future__ import annotations
 import flax.linen as nn
 import jax
 
-from horovod_tpu import compat
 import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
@@ -359,7 +358,7 @@ class PipelinedLM(nn.Module):
             if extras is not None:
                 args += (extras,)
                 in_specs += ((extra_spec, extra_spec),)
-            out = compat.shard_map(
+            out = jax.shard_map(
                 run,
                 mesh=self.mesh,
                 in_specs=in_specs,

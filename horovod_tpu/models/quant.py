@@ -3,7 +3,7 @@ an int8 COMPUTE path for compute-bound prefill / large-batch decode.
 
 **Weight-only storage** (`quantize_params` + ``quantized=True`` in the
 decode family): autoregressive decode streams every weight once per
-generated token (BASELINE.md decode rows: the step is HBM-bound), so
+generated token (the step is HBM-bound), so
 halving weight bytes is a direct tokens/sec lever. Kernels are stored as
 int8 with per-output-channel f32 scales; the decode loop dequantizes
 INSIDE each scan step, which XLA fuses into the matmul reads — the HBM
@@ -11,8 +11,8 @@ stream stays int8.
 
 **int8 compute** (`int8_dot_general` + ``TransformerLM(int8_compute=
 True)``): the v5e MXU runs int8×int8→int32 at twice its bf16 rate, which
-is the lever for the COMPUTE-bound phase — prompt prefill (1.2–1.44×
-measured at d1024–d2048, BASELINE.md). Every Dense matmul quantizes its
+is the lever for the COMPUTE-bound phase — prompt prefill (not measured
+on this round's chip). Every Dense matmul quantizes its
 activations dynamically (symmetric per-row scales over the contracted
 axes, recomputed per call — no calibration data) and its weights
 per-output-channel, accumulates in int32 on the MXU, and rescales the

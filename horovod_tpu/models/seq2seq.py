@@ -47,7 +47,6 @@ import functools
 import flax.linen as nn
 import jax
 
-from horovod_tpu import compat
 import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
@@ -92,7 +91,7 @@ def _attention(cfg: ShardingConfig, q, k, v, *, causal: bool,
                 q, k, v, axis_name=SEQ_AXIS,
                 q_segment_ids=qi, kv_segment_ids=ki,
             )
-            return compat.shard_map(
+            return jax.shard_map(
                 fn, mesh=cfg.mesh,
                 in_specs=(qspec, qspec, qspec, ids_spec, ids_spec),
                 out_specs=qspec, check_vma=False,
@@ -111,7 +110,7 @@ def _attention(cfg: ShardingConfig, q, k, v, *, causal: bool,
             fn = lambda q, k, v, ids: attention_ops.ring_flash_attention(  # noqa: E731
                 q, k, v, axis_name=SEQ_AXIS, causal=causal, segment_ids=ids
             )
-            return compat.shard_map(
+            return jax.shard_map(
                 fn, mesh=cfg.mesh,
                 in_specs=(qspec, qspec, qspec, ids_spec),
                 out_specs=qspec, check_vma=False,
@@ -119,7 +118,7 @@ def _attention(cfg: ShardingConfig, q, k, v, *, causal: bool,
         fn = lambda q, k, v: attention_ops.ring_flash_attention(  # noqa: E731
             q, k, v, axis_name=SEQ_AXIS, causal=causal
         )
-        return compat.shard_map(
+        return jax.shard_map(
             fn, mesh=cfg.mesh, in_specs=(qspec, qspec, qspec),
             out_specs=qspec, check_vma=False,
         )(q, k, v)
@@ -142,7 +141,7 @@ def _attention(cfg: ShardingConfig, q, k, v, *, causal: bool,
         in_specs = (spec, spec, spec)
         if q_ids is not None:
             in_specs += (P(BATCH_AXES, None), P(BATCH_AXES, None))
-        local = compat.shard_map(
+        local = jax.shard_map(
             local, mesh=cfg.mesh, in_specs=in_specs, out_specs=spec,
             check_vma=False,
         )

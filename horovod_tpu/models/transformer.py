@@ -32,7 +32,6 @@ import functools
 import flax.linen as nn
 import jax
 
-from horovod_tpu import compat
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
@@ -171,7 +170,8 @@ class Block(nn.Module):
     # densely-trained model with (window, attention_sinks, sliding_cache)
     # for generation is the approximate StreamingLLM recipe. Sink-masked
     # forwards run the flash kernel (a pinned sink tile per q block —
-    # O(T·(window+sinks)); dense fallback when the tiling doesn't hold)
+    # O(T·(window+sinks)); dense, with a warning, when the tiling doesn't
+    # hold)
     # and compose with sequence parallelism: the flash ring adds a dense
     # sink contribution on the hop holding global block 0, Ulysses passes
     # them to its local kernel (the dense-block ring refuses).
@@ -290,7 +290,7 @@ class Block(nn.Module):
                 fn = lambda q, k, v, ids: impl(q, k, v, segment_ids=ids)  # noqa: E731
                 args = (q, k, v, segment_ids)
                 in_specs = (spec, spec, spec, P(BATCH_AXES, SEQ_AXIS))
-            out = compat.shard_map(
+            out = jax.shard_map(
                 fn, mesh=cfg.mesh, in_specs=in_specs, out_specs=spec,
                 check_vma=False,
             )(*args)
@@ -301,17 +301,20 @@ class Block(nn.Module):
                 q_segment_ids=segment_ids, kv_segment_ids=segment_ids,
             )
         else:
-            # Local path: the pallas flash kernel (O(T) memory, ~2-3x over
-            # XLA's materialized attention on v5e; falls back to dense when
-            # its tiling doesn't hold, interprets off-TPU). GSPMD cannot
-            # auto-partition a Mosaic custom call, so on a multi-device mesh
-            # it runs in a fully-manual shard_map (batch over data/fsdp,
-            # heads over model — attention mixes neither).
+            # Local path: the pallas flash kernel (O(T) memory; warns and
+            # runs dense when its tiling doesn't hold, interprets off-TPU).
+            # GSPMD cannot auto-partition a Mosaic custom call, so on a
+            # multi-device mesh it runs in a fully-manual shard_map (batch
+            # over data/fsdp, heads over model — attention mixes neither).
+            # That takes the mesh: a model built WITHOUT one compiles on a
+            # single device only, and the Trainer refuses it on a larger
+            # mesh wherever the kernel is compiled
+            # (trainer._require_kernel_mesh).
             from horovod_tpu.ops.flash_attention import flash_attention
 
-            # sinks ride the kernel's pinned sink tile (a no-op at 0;
-            # dense fallback automatic) — one code path for plain, windowed
-            # and global+local local attention.
+            # sinks ride the kernel's pinned sink tile (a no-op at 0) —
+            # one code path for plain, windowed and global+local local
+            # attention.
             def local(q, k, v, ids=None):
                 return flash_attention(
                     q, k, v, causal=True, window=self.window,
@@ -325,7 +328,7 @@ class Block(nn.Module):
                 in_specs = (spec, spec, spec)
                 if segment_ids is not None:
                     in_specs += (P(BATCH_AXES, None),)
-                local = compat.shard_map(
+                local = jax.shard_map(
                     local, mesh=cfg.mesh, in_specs=in_specs, out_specs=spec,
                     check_vma=False,
                 )
@@ -574,14 +577,14 @@ class Block(nn.Module):
             # Same global+local mask as training/decode, computed from the
             # fresh K/V (the ring cache may already have evicted mid-prompt
             # keys an early query needs); sinks ride the kernel's pinned
-            # tile, dense fallback automatic.
+            # tile.
             local = functools.partial(
                 flash_attention, causal=True, window=self.window,
                 sinks=sinks,
             )
             if cfg.mesh is not None and cfg.mesh.size > 1:
                 spec = P(BATCH_AXES, None, MODEL_AXIS, None)
-                local = compat.shard_map(
+                local = jax.shard_map(
                     local, mesh=cfg.mesh, in_specs=(spec, spec, spec),
                     out_specs=spec, check_vma=False,
                 )
@@ -725,7 +728,7 @@ class TransformerLM(nn.Module):
     compute_dtype: jnp.dtype = jnp.float32
     sharding: ShardingConfig = ShardingConfig()
     # Memory knobs for long context (HBM is the binding constraint on one
-    # chip — BASELINE.md context-envelope rows):
+    # chip):
     # * remat: rematerialize each block in the backward pass
     #   (jax.checkpoint) — activations per layer drop to the block inputs;
     # * logits_dtype: bf16 halves the [B, T, vocab] logits + cotangent that

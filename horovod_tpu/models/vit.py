@@ -1,7 +1,7 @@
 """Vision Transformer for CIFAR/MNIST-scale images (Dosovitskiy et al.,
 arXiv:2010.11929) — the TPU-first vision family.
 
-The conv attribution (benchmarks/conv_profile.py, BASELINE.md) proved the
+The conv attribution (benchmarks/conv_profile.py) found the
 CIFAR-scale conv models are *shape-bound*: a 16-channel 3×3 conv fills
 16/128 MXU lanes and no amount of batch fixes it (ResNet-20 plateaus at
 MFU ≈ 0.20). The TPU-first answer is an architecture whose image compute

@@ -26,7 +26,6 @@ from __future__ import annotations
 
 import jax
 
-from horovod_tpu import compat
 import jax.numpy as jnp
 from jax import lax
 
@@ -125,7 +124,7 @@ def ring_attention(q, k, v, *, axis_name: str = "seq", causal: bool = True,
     mask away; the flash-ring variant additionally skips their FLOPs).
     """
     check_window(window, causal)
-    n = compat.axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     my = lax.axis_index(axis_name)
     b, t_local, h, d = q.shape
     scale = d ** -0.5
@@ -214,7 +213,7 @@ def ring_cross_attention(q, k, v, *, axis_name: str = "seq",
             "q_segment_ids and kv_segment_ids come as a pair (the "
             "source-side padding mask needs both sides labelled)"
         )
-    n = compat.axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     b, tq, h, d = q.shape
 
     def hop(k_blk, v_blk, ks_blk):
@@ -300,7 +299,7 @@ def ring_flash_attention(q, k, v, *, axis_name: str = "seq", causal: bool = True
             raise ValueError(
                 "sinks need window set (full causal already sees them)"
             )
-    n = compat.axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     my = lax.axis_index(axis_name)
     b, t_local, h, d = q.shape
     if sinks > t_local:
@@ -442,7 +441,7 @@ def ulysses_attention(q, k, v, *, axis_name: str = "seq", causal: bool = True,
     tiling holds (O(T) memory — without it, the [T, T] score matrix would
     cancel most of what head-swapping buys at long context), with the dense
     path as fallback exactly like `flash_attention` itself."""
-    n = compat.axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     if q.shape[2] % n != 0:
         raise ValueError(
             f"ulysses needs heads ({q.shape[2]}) divisible by the seq axis ({n})"

@@ -13,15 +13,18 @@ Backward is a custom VJP with the standard two-kernel recomputation scheme
 (dq swept over K blocks, dK/dV swept over Q blocks) using the saved
 logsumexp, so residual memory is O(T) as well.
 
-`flash_attention` is shape-checked and falls back to the dense reference
-(`ops.attention.dense_attention`) when the kernel's tiling constraints don't
-hold; `interpret=True` (auto on CPU) runs the same kernel in the pallas
-interpreter, which is how the unit tests validate it off-TPU.
+`flash_attention` is shape-checked: when the kernel's tiling constraints
+don't hold it runs the dense reference (`ops.attention.dense_attention`)
+instead and says so with a `KernelFallbackWarning`, once per shape — a
+changed algorithm is never silent. Off-TPU the same kernel runs in the
+pallas interpreter (`default_interpret`), which is how the unit tests
+validate it without a chip.
 """
 
 from __future__ import annotations
 
 import functools
+import warnings
 
 import jax
 import jax.numpy as jnp
@@ -32,15 +35,14 @@ from jax.experimental.pallas import tpu as pltpu
 from horovod_tpu.ops.attention import check_window, dense_attention
 
 _BIG_NEG = -1e30
-# 1024-square tiles won the measured block sweep on v5e (benchmarks/
-# fa_tune.py): vs 512² they are 1.23x at T=1024 and 1.27-1.4x at T=8192
-# (fwd and fwd+bwd), because each K/V block amortizes the per-block
-# online-softmax statistics (max/renormalize) over 4x the scores. The
-# [bq, bk] f32 score tile is 4 MB — fine for VMEM at D ≤ 128; for wider
-# heads `flash_attention` drops to 512 to keep the working set bounded.
-# Tuned for v5e-class VMEM (16 MiB): on a smaller-VMEM TPU generation an
-# oversized tile fails LOUDLY at Mosaic compile time (not silent wrong
-# results) — pass block_q/block_k=512 there.
+# 1024-square tiles: each K/V block amortizes the per-block online-softmax
+# statistics (max/renormalize) over 4x the scores of a 512² tile (the gain
+# is not measured on this round's chip). The [bq, bk] f32 score tile is
+# 4 MB, and the backward kernels keep several of them live, which puts them
+# near v5e's 16 MiB scoped-VMEM limit: `pick_blocks` drops to 512 wherever
+# the chip's compiler was seen to refuse 1024². An oversized tile fails
+# LOUDLY at Mosaic compile time (not silent wrong results) — pass
+# block_q/block_k=512 there.
 DEFAULT_BLOCK_Q = 1024
 DEFAULT_BLOCK_K = 1024
 
@@ -765,6 +767,33 @@ def _check_segment_shapes(q, k, q_segment_ids, kv_segment_ids):
         )
 
 
+class KernelFallbackWarning(UserWarning):
+    """A flash-attention call ran the dense reference because the kernel's
+    tiling does not hold for its shape."""
+
+
+def _warn_dense_fallback(q, k, block_q, block_k) -> None:
+    # The shape rides the message, so Python's default filter shows it
+    # once per shape and call site.
+    warnings.warn(
+        f"flash_attention: q{tuple(q.shape)} k{tuple(k.shape)} "
+        f"{jnp.dtype(q.dtype).name} does not tile at blocks "
+        f"({block_q}, {block_k}) — running the dense reference, not the "
+        "kernel (see `supported`)",
+        KernelFallbackWarning,
+        stacklevel=3,
+    )
+
+
+def default_interpret() -> bool:
+    """Whether kernel calls run in the Pallas interpreter — the ONE place
+    it is decided when a caller passes ``interpret=None``: everywhere but
+    on TPU devices, where Mosaic compiles the kernel. The interpreted
+    kernel is ordinary JAX (it proves the algebra, not the codegen, and
+    GSPMD partitions it freely); the compiled one is a custom call."""
+    return jax.devices()[0].platform != "tpu"
+
+
 def flash_attention_with_lse(
     q, k, v, *,
     causal: bool = True,
@@ -792,13 +821,14 @@ def flash_attention_with_lse(
         q.shape, block_q, block_k, k_shape=k.shape, dtype=q.dtype,
         segmented=segmented,
     ):
+        _warn_dense_fallback(q, k, block_q, block_k)
         return _dense_with_lse(
             q, k, v, causal=causal,
             q_segment_ids=q_segment_ids, kv_segment_ids=kv_segment_ids,
             window=window, q_offset=q_offset,
         )
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = default_interpret()
     return _flash_lse(
         q, k, v, q_segment_ids, kv_segment_ids, causal, window, 0, q_offset,
         block_q, block_k, interpret,
@@ -851,6 +881,14 @@ def pick_blocks(t: int, d: int, dtype, bq: int = DEFAULT_BLOCK_Q,
     instead of regressing to the dense fallback just because
     1536 % 1024 != 0."""
     t_k = t if t_k is None else t_k
+    if jnp.dtype(dtype).itemsize >= 4:
+        # 4-byte inputs double the q/k/v/dO block bytes, and the dK/dV
+        # backward (the fullest kernel: four live [bq, bk] f32 tiles, two
+        # accumulators, two outputs) no longer fits: v5e's compiler refuses
+        # f32 at 1024² from T=2048 on (16.20M against the 16.00M scoped
+        # limit at B4·H8·D64; tests/test_chip_compile.py). 512² compiles
+        # there up to T=32k.
+        bq, bk = min(bq, 512), min(bk, 512)
     if d > 128 or max(t, t_k) >= 32768:
         # Wide heads: a 1024² f32 score tile + wide q/k/v blocks would
         # crowd VMEM. Very long grids overflow v5e's 16 MB scoped-VMEM
@@ -897,9 +935,10 @@ def flash_attention(
     q_offset: int | None = None,
     interpret: bool | None = None,
 ):
-    """[B,Tq,H,D] attention via the pallas kernel; dense fallback when the
-    tiling doesn't hold. ``interpret=None`` auto-selects the pallas
-    interpreter off-TPU so tests/CPU paths run the same kernel code.
+    """[B,Tq,H,D] attention via the pallas kernel; when the tiling doesn't
+    hold, the dense reference with a `KernelFallbackWarning`.
+    ``interpret=None`` takes `default_interpret()` (the pallas interpreter
+    off-TPU, so tests/CPU paths run the same kernel code).
 
     ``q_segment_ids``/``kv_segment_ids`` ([B,Tq]/[B,Tk] ints) restrict
     attention to equal-id pairs — the packed-sequence pretraining mask
@@ -938,6 +977,7 @@ def flash_attention(
         segmented=segmented,
     ) and (sinks == 0 or (sinks <= block_k and q_offset is None))
     if not kernel_ok:
+        _warn_dense_fallback(q, k, block_q, block_k)
         if segmented or k.shape[1] != q.shape[1] or window is not None \
                 or q_offset is not None:
             out, _ = _dense_with_lse(
@@ -948,7 +988,7 @@ def flash_attention(
             return out
         return dense_attention(q, k, v, causal=causal)
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = default_interpret()
     return _flash(
         q, k, v, q_segment_ids, kv_segment_ids, causal, window, sinks,
         q_offset, block_q, block_k, interpret,

@@ -2,8 +2,8 @@
 
 The standard LM loss path materializes ``[B, T, vocab]`` logits twice — once
 in the forward pass and once as the backward cotangent — and at long context
-those two arrays dominate HBM (BASELINE.md context-envelope rows: at seq
-131k they are the OOM driver the ``logits_dtype=bf16`` knob only halves).
+those two arrays dominate HBM (at seq 131k they are the OOM driver the
+``logits_dtype=bf16`` knob only halves).
 This op computes ``cross_entropy(h @ W, labels)`` without ever building the
 full logits array: a `lax.scan` over row-chunks computes each chunk's
 ``[C, vocab]`` logits tile on the fly — forward for the logsumexp, again in

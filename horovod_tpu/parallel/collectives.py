@@ -30,7 +30,7 @@ from typing import Any, Sequence
 
 import jax
 
-from horovod_tpu import compat, flight
+from horovod_tpu import flight
 import jax.numpy as jnp
 from jax import lax
 from jax.experimental import multihost_utils
@@ -126,7 +126,7 @@ def broadcast(x, root: int = 0, axis_name=None):
         names = _axis_names(axis_name)
         idx = lax.axis_index(names[0])
         for name in names[1:]:
-            idx = idx * compat.axis_size(name) + lax.axis_index(name)
+            idx = idx * lax.axis_size(name) + lax.axis_index(name)
         mask = (idx == root).astype(x.dtype)
         return lax.psum(x * mask, axis_name)
     if jax.process_count() == 1:
@@ -856,7 +856,7 @@ def _scatter_reduce_bucket(b, axis_name, dcn: int, wire_dtype, extra_axes,
     # is what `hvt-audit` reads, and a singleton-group all-reduce there
     # would read as full-payload gradient traffic that the compiled
     # program never performs.
-    extra = tuple(a for a in extra_axes if compat.axis_size(a) > 1)
+    extra = tuple(a for a in extra_axes if lax.axis_size(a) > 1)
     if extra:
         b = lax.psum(b, extra)
     if residual is not None:
@@ -873,7 +873,7 @@ def _scatter_reduce_bucket(b, axis_name, dcn: int, wire_dtype, extra_axes,
         if residual is not None:
             err = jnp.zeros(b.shape, jnp.float32)
         return out, err
-    n = compat.axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     ici = n // dcn
     ici_groups, dcn_groups = _hier_groups(n, dcn)
     cols = b.size // n
@@ -973,7 +973,7 @@ def _composite_axis_index(axis_name):
     names = _axis_names(axis_name)
     idx = lax.axis_index(names[0])
     for name in names[1:]:
-        idx = idx * compat.axis_size(name) + lax.axis_index(name)
+        idx = idx * lax.axis_size(name) + lax.axis_index(name)
     return idx
 
 
@@ -982,7 +982,7 @@ def _group_size(axis_name, axis_index_groups) -> int:
         return len(axis_index_groups[0])
     n = 1
     for name in _axis_names(axis_name):
-        n *= compat.axis_size(name)
+        n *= lax.axis_size(name)
     return n
 
 
@@ -1145,7 +1145,7 @@ def _hierarchical_psum_err(x, axis_name, dcn: int, *, extra_axes=(),
     remainder (the per-hop telescoping contract). A residual with no
     quantized hop anywhere is flushed: transmitted in full, zero error
     back."""
-    n = compat.axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     if n % dcn != 0:
         raise ValueError(
             f"dcn factor {dcn} does not divide axis {axis_name!r} size {n}"

@@ -12,12 +12,12 @@ FLOPs come from XLA's own cost model on the *compiled* step
 (`Compiled.cost_analysis()`), so the count covers exactly what runs —
 forward, backward, optimizer, collectives — for any model, with no
 per-architecture analytic bookkeeping to drift out of date. MFU is that
-count against the chip's peak; "match or beat" needs this denominator
-(VERDICT round 1)."""
+count against the chip's peak; "match or beat" needs this denominator."""
 
 from __future__ import annotations
 
 import contextlib
+import functools
 import json
 import os
 import threading
@@ -91,8 +91,7 @@ def flash_attention_flops(batch: int, seq_q: int, seq_k: int, heads: int,
                           backward: bool = True,
                           window: int | None = None) -> float:
     """Matmul FLOPs one flash-attention call actually executes — the part
-    XLA's cost model cannot see (a Mosaic custom call is opaque to it;
-    BASELINE.md footnote 1).
+    XLA's cost model cannot see (a Mosaic custom call is opaque to it).
 
     Counted from the kernel's own structure (ops/flash_attention.py): the
     forward runs 2 block dots per (q, k) tile pair (scores, P·V); the
@@ -132,27 +131,43 @@ def fused_ce_flops(n_tokens: int, d_model: int, vocab: int,
 def resolve_peak_flops(calibrate: bool = True) -> tuple:
     """(per-chip peak FLOP/s, source) for any MFU denominator — shared by
     bench.py (`_resolve_peak_flops` delegates here) and the live trainer
-    MFU gauge, so no surface reports ``mfu: null``.
+    MFU gauge.
 
-    Resolution order: the explicit ``HVT_PEAK_FLOPS`` override, the
-    built-in TPU peak table (`device_peak_flops`), and — with
-    ``calibrate=True`` — a measured matmul calibration on THIS host
-    (best-of-3 chained f32 matmuls), the honest trend denominator for
-    device kinds with no published peak (the CPU CI topology). The
-    calibrated value is exported back into ``HVT_PEAK_FLOPS`` so every
-    later resolution in the process divides by the same number.
-    ``calibrate=False`` returns ``(None, "unknown")`` instead of paying
-    the ~second of matmuls."""
-    import jax.numpy as jnp
-
+    Resolution order: the explicit ``HVT_PEAK_FLOPS`` override, then the
+    built-in peak table keyed by ``device_kind`` (`device_peak_flops`). An
+    accelerator the table does not know RAISES: a utilization against a
+    guessed peak is worse than none. Only the ``cpu`` platform (the CI
+    topology, which has no published peak) goes on — with
+    ``calibrate=True`` to a matmul measured on this host
+    (`_host_matmul_flops`, source ``"calibrated"``), else to
+    ``(None, "unknown")``. The calibrated value is returned to the caller
+    and nowhere else; pass it on (`mfu(..., peak=)`) rather than through
+    the environment."""
     if registry.get_raw("HVT_PEAK_FLOPS") is not None:
         return float(registry.get_float("HVT_PEAK_FLOPS")), "override"
-    peak = device_peak_flops()
+    device = jax.devices()[0]
+    peak = device_peak_flops(device)
     if peak:
         return peak, "table"
+    if device.platform != "cpu":
+        raise ValueError(
+            f"no published peak FLOP/s for device kind "
+            f"{device.device_kind!r}: add it to trace._PEAK_FLOPS with its "
+            "source, or set HVT_PEAK_FLOPS"
+        )
     if not calibrate:
         return None, "unknown"
     n = int(os.environ.get("BENCH_PEAK_CALIB_N", 1024))
+    return _host_matmul_flops(n), "calibrated"
+
+
+@functools.lru_cache(maxsize=None)
+def _host_matmul_flops(n: int) -> float:
+    """Best-of-3 chained ``n``³ f32 matmul rate on the CPU backend — the
+    trend denominator for CPU CI rows. Measured once per process so every
+    leg of a run divides by the same number."""
+    import jax.numpy as jnp
+
     a = jnp.ones((n, n), jnp.float32)
     b = jnp.ones((n, n), jnp.float32)
     f = jax.jit(lambda a, b: (a @ b).sum())
@@ -171,15 +186,15 @@ def resolve_peak_flops(calibrate: bool = True) -> tuple:
         chain()
         dt = (time.perf_counter() - t0) / reps
         best = dt if best is None else min(best, dt)
-    peak = 2.0 * n ** 3 / best
-    os.environ["HVT_PEAK_FLOPS"] = f"{peak:.6g}"
-    return peak, "calibrated"
+    return 2.0 * n ** 3 / best
 
 
 def mfu(flops_per_step: float | None, step_time_s: float, n_chips: int = 1,
-        device=None) -> float | None:
-    """Model FLOPs utilization: achieved FLOP/s ÷ fleet peak FLOP/s."""
-    peak = device_peak_flops(device)
+        device=None, peak: float | None = None) -> float | None:
+    """Model FLOPs utilization: achieved FLOP/s ÷ fleet peak FLOP/s.
+    ``peak`` is a per-chip peak already resolved by `resolve_peak_flops`;
+    without it the table (or override) is consulted for ``device``."""
+    peak = peak or device_peak_flops(device)
     if not peak or not flops_per_step or step_time_s <= 0:
         return None
     return flops_per_step / step_time_s / (peak * n_chips)

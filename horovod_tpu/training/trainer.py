@@ -19,12 +19,12 @@ from __future__ import annotations
 
 import os
 import time
+import warnings
 from typing import Any, Callable, Sequence
 
 import flax.struct
 import jax
 
-from horovod_tpu import compat
 from horovod_tpu.analysis import registry
 import jax.numpy as jnp
 import numpy as np
@@ -92,6 +92,35 @@ def _adapt_ef_residual(host_state, built_state):
     )
 
 
+def _require_kernel_mesh(module, mesh) -> None:
+    """Refuse, with the remedy, a model that would put a Mosaic-compiled
+    flash kernel under GSPMD's automatic partitioning: the chip's compiler
+    rejects that step ("Mosaic kernels cannot be automatically
+    partitioned"), and only a model that holds the mesh can wrap the call
+    in the `shard_map` that avoids it (models/transformer.py). Acts only
+    where the kernel is compiled: interpreted (off-TPU) it is ordinary
+    JAX and partitions freely."""
+    from horovod_tpu.models.transformer import ShardingConfig
+    from horovod_tpu.ops import flash_attention
+
+    cfg = getattr(module, "sharding", None)
+    if (
+        mesh.size > 1
+        and isinstance(cfg, ShardingConfig)
+        and cfg.mesh is None
+        and cfg.attn != "dense"
+        and not flash_attention.default_interpret()
+    ):
+        raise ValueError(
+            f"{type(module).__name__} runs the compiled flash-attention "
+            f"kernel but was built without a mesh, and this Trainer's mesh "
+            f"has {mesh.size} devices: XLA cannot partition a Mosaic "
+            "kernel automatically. Build the model with "
+            "sharding=ShardingConfig(mesh=<the Trainer's mesh>) so the "
+            "kernel runs inside a shard_map."
+        )
+
+
 class Trainer:
     """compile+fit+evaluate+predict for a flax module over a device mesh.
 
@@ -127,6 +156,7 @@ class Trainer:
         self.loss_fn = _resolve_loss(loss)
         self._module_loss = loss == "module"
         self.mesh = mesh if mesh is not None else mesh_lib.data_parallel_mesh()
+        _require_kernel_mesh(module, self.mesh)
         self.seed = seed
         # param_specs: callable (params, mesh) -> PartitionSpec pytree, or a
         # spec pytree — TP/FSDP parameter layout (e.g.
@@ -577,7 +607,7 @@ class Trainer:
                 )
             else:
                 grads_spec = P()
-            return compat.shard_map(
+            return jax.shard_map(
                 local,
                 mesh=self.mesh,
                 in_specs=(P(), P(), stacked, stacked, sharded0),
@@ -1023,7 +1053,7 @@ class Trainer:
                 )
             return t
 
-        f = jax.jit(compat.shard_map(
+        f = jax.jit(jax.shard_map(
             red, mesh=self.mesh, in_specs=(jax.sharding.PartitionSpec(),),
             out_specs=P(), check_vma=False,
         ))
@@ -1262,6 +1292,9 @@ class StepPhaseSampler:
         from horovod_tpu import trace as trace_lib
 
         self._peak = trace_lib.resolve_peak_flops(calibrate=True)
+        # The two probes below are attribution only: a failure costs a
+        # gauge (comm reads 0 so compute == total; hvt_mfu is absent),
+        # never the training run — but it is said, not swallowed.
         try:
             f, grads, _text = self.trainer.reduction_program(state.params)
             jax.block_until_ready(f(grads))  # compile + settle
@@ -1269,17 +1302,24 @@ class StepPhaseSampler:
             t0 = time.perf_counter()
             jax.block_until_ready(f(grads))
             self._comm_s = time.perf_counter() - t0  # warm cache
-        except Exception:
-            self._comm = None  # attribution degrades to comm=0, loudly
-            # visible as compute==total; never kills training.
+        except Exception as e:
+            self._comm = None
+            warnings.warn(
+                f"StepPhaseSampler: isolated reduction probe failed, "
+                f"hvt_step_phase_ms{{comm}} will read 0: {e!r}"
+            )
         if self._step_shapes is not None:
             try:
                 compiled = self._run.lower(*self._step_shapes).compile()
+            except Exception as e:
+                warnings.warn(
+                    f"StepPhaseSampler: cost-model compile of the step "
+                    f"failed, hvt_mfu will be absent: {e!r}"
+                )
+            else:
                 flops = trace_lib.compiled_cost_flops(compiled)
                 if flops:
                     self._flops = flops / self._steps_per_exec
-            except Exception:
-                self._flops = None
 
     def _timed_comm(self) -> float:
         if self._comm is None:

@@ -308,12 +308,10 @@ class TestHierarchicalReduction:
     the 8-device test mesh's data axis factored (dcn outer, ici inner)."""
 
     def _run(self, fn, x):
-        from horovod_tpu import compat
-
         mesh = mesh_lib.data_parallel_mesh()
         P = jax.sharding.PartitionSpec
         return jax.jit(
-            compat.shard_map(
+            jax.shard_map(
                 fn, mesh=mesh,
                 in_specs=(P(("data", "fsdp")),),
                 out_specs=P(("data", "fsdp")),
@@ -352,7 +350,6 @@ class TestHierarchicalReduction:
         lowered text shows exactly one bf16 all_reduce (the DCN hop) and
         one non-bf16 (the ICI hop)."""
         hvt.init()
-        from horovod_tpu import compat
 
         mesh = mesh_lib.data_parallel_mesh()
         P = jax.sharding.PartitionSpec
@@ -363,7 +360,7 @@ class TestHierarchicalReduction:
                 wire_dtype=jnp.bfloat16,
             )
 
-        f = jax.jit(compat.shard_map(
+        f = jax.jit(jax.shard_map(
             hier, mesh=mesh, in_specs=(P(("data", "fsdp")),),
             out_specs=P(("data", "fsdp")), check_vma=False,
         ))

@@ -9,7 +9,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 from jax.sharding import Mesh, PartitionSpec as P
-from horovod_tpu.compat import shard_map
+from jax import shard_map
 
 from horovod_tpu.ops.attention import (
     dense_attention,

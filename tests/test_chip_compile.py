@@ -1,0 +1,194 @@
+"""Compiles for a DESCRIBED TPU v5e — the chip's own compiler, no chip.
+
+The CPU suite runs the flash kernel in the Pallas interpreter, which is
+ordinary JAX: it proves the algebra and cannot see what Mosaic and XLA:TPU
+refuse — a tile that overflows scoped VMEM, a kernel GSPMD is asked to
+partition, a step that does not fit HBM. libtpu is installed here and
+compiles for a topology that is described and not attached, so the main
+path's kernels and one whole train step are compiled at real widths in
+this ONE file (libtpu belongs to one process: a second file could land on
+another xdist worker and find it taken). Nothing runs, so nothing here
+says anything about results or times.
+
+The topology is described inside a module-scoped fixture — never at
+import, so every worker collects the same tests — and the persistent
+compilation cache is off around these compiles (an executable compiled
+for a described device can be written to it but not read back).
+"""
+
+import re
+
+import jax
+import jax.numpy as jnp
+import optax
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+import horovod_tpu as hvt
+from horovod_tpu.models.transformer import ShardingConfig, TransformerLM
+from horovod_tpu.ops import flash_attention as fa
+from horovod_tpu.parallel import mesh as mesh_lib
+from horovod_tpu.parallel import sharding as sharding_lib
+from horovod_tpu.training.train_state import TrainState
+
+SDS = jax.ShapeDtypeStruct
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    env = pytest.MonkeyPatch()
+    env.setenv("TPU_LOG_DIR", "disabled")  # or the compiler logs under /tmp
+    try:
+        desc = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:
+        env.undo()
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    cache_was_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", cache_was_on)
+    compilation_cache.reset_cache()
+    env.undo()
+
+
+@pytest.fixture
+def compiled_kernel(monkeypatch):
+    """Steer the program's one interpret decision to what it takes on the
+    chip. It asks the attached devices, which are CPUs here."""
+    monkeypatch.setattr(fa, "default_interpret", lambda: False)
+
+
+def kernel_calls(compiled) -> list[str]:
+    return [
+        line for line in compiled.as_text().splitlines()
+        if 'custom_call_target="tpu_custom_call"' in line
+    ]
+
+
+@pytest.mark.parametrize(
+    # [B, T, H, D], dtype, flash kwargs, segmented, Mosaic calls fwd+bwd
+    "shape,dtype,kwargs,segmented,n_calls",
+    [
+        pytest.param((4, 1024, 16, 128), jnp.bfloat16, {}, False, 3,
+                     id="smoke-bf16-T1024"),
+        pytest.param((2, 4096, 16, 128), jnp.bfloat16, {}, True, 3,
+                     id="segments-T4096"),
+        pytest.param((2, 4096, 16, 128), jnp.bfloat16,
+                     {"window": 1024, "sinks": 64}, False, 4,
+                     id="window-sinks-T4096"),
+        # Refused at 1024² tiles (16.20M of 16.00M scoped VMEM in the dK/dV
+        # pass); `pick_blocks` takes 512² for 4-byte inputs.
+        pytest.param((4, 2048, 8, 64), jnp.float32, {}, False, 3,
+                     id="f32-D64-T2048"),
+    ],
+)
+def test_flash_fwd_bwd_compiles_for_v5e(topo, shape, dtype, kwargs,
+                                        segmented, n_calls):
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    qkv = SDS(shape, dtype, sharding=one_chip)
+    args = [qkv, qkv, qkv]
+    if segmented:
+        args.append(SDS(shape[:2], jnp.int32, sharding=one_chip))
+
+    def loss(q, k, v, ids=None):
+        out = fa.flash_attention(
+            q, k, v, causal=True, interpret=False,
+            q_segment_ids=ids, kv_segment_ids=ids, **kwargs,
+        )
+        return out.astype(jnp.float32).sum()
+
+    compiled = jax.jit(
+        jax.value_and_grad(loss, argnums=(0, 1, 2))
+    ).lower(*args).compile()
+    assert len(kernel_calls(compiled)) == n_calls
+
+
+# --- one whole train step on the four described chips ----------------------
+
+D_MODEL, HEADS, SEQ, VOCAB, GLOBAL_BATCH = 2048, 16, 1024, 8192, 8
+
+
+def lm_trainer(mesh, sharding):
+    model = TransformerLM(
+        vocab_size=VOCAB, d_model=D_MODEL, n_heads=HEADS, n_layers=2,
+        dropout=0.0, compute_dtype=jnp.bfloat16, fused_head_chunks=8,
+        sharding=sharding,
+    )
+    return hvt.Trainer(
+        model, hvt.DistributedOptimizer(optax.adamw(1e-4)),
+        loss="module", mesh=mesh,
+    )
+
+
+def abstract_step_args(trainer):
+    """`train_step`'s arguments as shapes with shardings: a described
+    device cannot hold an array, so nothing is built or placed."""
+    mesh = trainer.mesh
+    rep = sharding_lib.replicated(mesh)
+    tokens = SDS(
+        (GLOBAL_BATCH, SEQ), jnp.int32,
+        sharding=sharding_lib.batch_sharding(mesh, 2),
+    )
+    x0 = jnp.zeros((trainer.dp_size, SEQ), jnp.int32)
+    key = jax.random.PRNGKey(0)
+    params = jax.eval_shape(
+        lambda: trainer.module.init(
+            {"params": key, "dropout": key}, x0, train=False, labels=x0
+        )
+    )["params"]
+    state = TrainState(
+        step=SDS((), jnp.int32),
+        params=params,
+        opt_state=jax.eval_shape(trainer.tx.init, params),
+        rng=SDS((2,), jnp.uint32),
+        model_state=None,
+    )
+    state = jax.tree.map(
+        lambda l: SDS(l.shape, l.dtype, sharding=rep), state
+    )
+    scalar = SDS((), jnp.float32, sharding=rep)
+    return state, (tokens, tokens), scalar, {
+        name: scalar for name in trainer.metric_names
+    }
+
+
+@pytest.fixture
+def four_chip_mesh(topo):
+    return mesh_lib.build_mesh(
+        mesh_lib.MeshSpec(data=4), devices=topo.devices
+    )
+
+
+def test_train_step_compiles_data_parallel_on_four_chips(
+        four_chip_mesh, compiled_kernel):
+    trainer = lm_trainer(four_chip_mesh, ShardingConfig(mesh=four_chip_mesh))
+    compiled = trainer._train_step.lower(
+        *abstract_step_args(trainer)
+    ).compile()
+    calls = kernel_calls(compiled)
+    assert len(calls) == 2 * 3  # layers x fwd/dq/dkv
+    # The shard_map hands each chip's kernel its quarter of the batch.
+    kernel_batches = {
+        int(b) for line in calls
+        for b in re.findall(rf"bf16\[(\d+),{HEADS},{SEQ},128\]", line)
+    }
+    assert kernel_batches == {GLOBAL_BATCH // 4}
+    assert "all-reduce" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16e9
+
+
+def test_meshless_model_on_four_chips_is_refused_with_the_remedy(
+        four_chip_mesh, compiled_kernel):
+    """Without the mesh the model cannot wrap the kernel in a shard_map,
+    and the chip's compiler refuses the step ("Mosaic kernels cannot be
+    automatically partitioned"). The program says so first, and how to
+    fix it."""
+    with pytest.raises(ValueError, match=r"ShardingConfig\(mesh="):
+        lm_trainer(four_chip_mesh, ShardingConfig())

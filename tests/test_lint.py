@@ -1314,12 +1314,13 @@ class TestHVT009MetricRegistryDiscipline:
 
     def test_obs_call_inside_shard_map_and_scan_flagged(self):
         found = findings_of(MetricRegistryDiscipline, """
-            from horovod_tpu import compat, obs
+            import jax
+            from horovod_tpu import obs
             from jax import lax
             def local(x):
                 obs.gauge("hvt_mfu", 0.5)
                 return x
-            f = compat.shard_map(local, mesh=None, in_specs=(), out_specs=())
+            f = jax.shard_map(local, mesh=None, in_specs=(), out_specs=())
             def body(c, t):
                 obs.gauge("hvt_mfu", 0.5)
                 return c, t

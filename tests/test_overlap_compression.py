@@ -27,7 +27,7 @@ import optax
 import pytest
 
 import horovod_tpu as hvt
-from horovod_tpu import checkpoint, compat
+from horovod_tpu import checkpoint
 from horovod_tpu.analysis import hlo_audit, registry
 from horovod_tpu.analysis.step_probe import lowered_step_text
 from horovod_tpu.parallel import collectives, mesh as mesh_lib
@@ -198,7 +198,7 @@ class TestQuantizedWire:
             )
             return out["g"], new_r["g"]
 
-        f = jax.jit(compat.shard_map(
+        f = jax.jit(jax.shard_map(
             one_round, mesh=mesh,
             in_specs=(P(("data", "fsdp")), P(("data", "fsdp"))),
             out_specs=(P(("data", "fsdp")), P(("data", "fsdp"))),

@@ -8,7 +8,6 @@ stack computes — the schedule is an execution detail, not a model change.
 
 import jax
 
-from horovod_tpu import compat
 import jax.numpy as jnp
 import numpy as np
 import optax
@@ -50,7 +49,7 @@ class TestSchedule:
 
             return spmd_pipeline(stage, xm)
 
-        out = compat.shard_map(
+        out = jax.shard_map(
             run,
             mesh=mesh,
             in_specs=(P("pipe", None), P("pipe", None), P(None, None, None)),

@@ -128,8 +128,8 @@ class TestSpeedup:
     def test_trained_copy_model_accepts_drafts(self):
         """On a model that has actually learned the copy task, the ngram
         draft proposes the true continuation and the target accepts ~gamma
-        tokens per round — the mechanism behind the measured speedup
-        (BASELINE.md). Exactness still holds, and the round count must be
+        tokens per round — the mechanism behind speculation's speedup.
+        Exactness still holds, and the round count must be
         WELL under one-per-token."""
         from horovod_tpu.parallel import mesh as mesh_lib
 
