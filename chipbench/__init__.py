@@ -1,0 +1,7 @@
+"""chipbench — the repository's benchmark on the chip (see README.md here).
+
+Everything the yardstick needs lives under this directory: traffic
+generation, the reduction from profiler traces to metrics, the table of
+peaks, the FLOP and byte counts, the plain float32 reference and the
+comparison that decides ``correct``. From the program it takes only the
+system under test (`horovod_tpu.Trainer`, `TransformerLM`)."""
