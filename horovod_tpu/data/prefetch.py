@@ -21,6 +21,8 @@ import queue
 import threading
 from typing import Callable, Iterator
 
+from horovod_tpu import trace
+
 
 class DevicePrefetcher:
     """Iterate device-resident items staged ahead by a background thread.
@@ -58,12 +60,24 @@ class DevicePrefetcher:
                 continue
 
     def _produce(self, host_iter, put):
+        # Three spans a batch, on this thread's line of the profiler's
+        # trace: the input engine's assembly (`next(host_iter)`: the C++
+        # producer or the Python loader), the host→device staging, and the
+        # wait for room in the queue — where a producer that is ahead of
+        # the training loop spends its time.
+        host_iter = iter(host_iter)
         try:
-            for item in host_iter:
-                if self._stop.is_set():
-                    return
-                self._enqueue(put(item))
-            self._enqueue(self._DONE)
+            while True:
+                with trace.span("input.assemble"):
+                    item = next(host_iter, self._DONE)
+                if item is self._DONE or self._stop.is_set():
+                    break
+                with trace.span("input.place"):
+                    item = put(item)
+                with trace.span("input.queue_full"):
+                    self._enqueue(item)
+            if not self._stop.is_set():
+                self._enqueue(self._DONE)
         except BaseException as e:  # noqa: BLE001 — delivered to consumer
             self._enqueue(e)
             # Then terminate the stream: a consumer that catches the error

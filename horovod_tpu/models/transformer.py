@@ -679,6 +679,7 @@ class LMHead(nn.Module):
             (self.d_model, self.vocab_size),
         )
 
+    @jax.named_scope(fused_ce.SCOPE)
     def __call__(self, x):
         if self.int8_compute:
             from horovod_tpu.models.quant import int8_dot_general

@@ -303,8 +303,14 @@ METRICS: dict[str, MetricSpec] = _decl([
                "Gradient-accumulation factor K of the running trainer.",
                "training"),
     MetricSpec("hvt_optimizer_steps_total", "counter",
-               "Optimizer steps completed by this process's fits.",
+               "Optimizer steps this process's fit loops have handed to "
+               "the device (counted in the loop, exporter on or off).",
                "training"),
+    MetricSpec("hvt_input_wait_seconds_total", "counter",
+               "Host seconds the streamed fit loop spent blocked on "
+               "next(prefetcher) — the `hvt.input_wait` span's time, "
+               "always on. Its rate against wall time is the share of "
+               "the loop the input engine holds up.", "training"),
     MetricSpec("hvt_step_samples_total", "counter",
                "Times the step-phase sampler ran (one per "
                "HVT_METRICS_EVERY window).", "training"),

@@ -46,6 +46,17 @@ _BIG_NEG = -1e30
 DEFAULT_BLOCK_Q = 1024
 DEFAULT_BLOCK_K = 1024
 
+# The kernels' names (`pallas_call(name=)`): the innermost name-stack entry,
+# so the compiled HLO instruction and the profiler's device event carry them
+# (`%hvt_flash_fwd.3 = ... custom-call(...)`) whatever flax scope, `jvp`,
+# `transpose` or `shard_map` the call sits in. The benchmark's per-kernel
+# metrics key on them (chipbench/spans.py): keep them stable, and never end
+# one in a digit (the reduction strips an instruction's trailing number).
+# The sink-only dK/dV pass is dK/dV work and shares its name.
+KERNEL_FWD = "hvt_flash_fwd"
+KERNEL_DQ = "hvt_flash_dq"
+KERNEL_DKV = "hvt_flash_dkv"
+
 
 # Segment-id operand layout (Mosaic-friendly, no in-kernel transposes):
 # q ids ride the SUBLANE axis as [B, Tq, LANES] (value broadcast across the
@@ -501,6 +512,7 @@ def _flash_fwd_impl(q, k, v, q_seg, kv_seg, causal, window, sinks, q_offset,
             pltpu.VMEM((bq, 1), jnp.float32),
         ],
         interpret=interpret,
+        name=KERNEL_FWD,
     )(*operands)
     return jnp.transpose(out, (0, 2, 1, 3)), lse
 
@@ -580,6 +592,7 @@ def _flash_bwd_core(causal, window, sinks, q_offset, bq, bk, interpret, res,
         out_shape=jax.ShapeDtypeStruct(qt.shape, q.dtype),
         scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
         interpret=interpret,
+        name=KERNEL_DQ,
     )(qt, kt, vt, gt, lse, delta, *seg_ops)
 
     dkv_in_specs = [
@@ -614,6 +627,7 @@ def _flash_bwd_core(causal, window, sinks, q_offset, bq, bk, interpret, res,
             pltpu.VMEM((bk, d), jnp.float32),
         ],
         interpret=interpret,
+        name=KERNEL_DKV,
     )(qt, kt, vt, gt, lse, delta, *seg_ops)
     if banded and sinks:
         # Sink contributions to dK/dV of k block 0: every q block sees the
@@ -651,6 +665,7 @@ def _flash_bwd_core(causal, window, sinks, q_offset, bq, bk, interpret, res,
                 pltpu.VMEM((bk, d), jnp.float32),
             ],
             interpret=interpret,
+            name=KERNEL_DKV,
         )(qt, kt[:, :, :bk], vt[:, :, :bk], gt, lse, delta, *seg_ops)
         dk = dk.at[:, :, :bk].add(dk0)
         dv = dv.at[:, :, :bk].add(dv0)

@@ -34,6 +34,14 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+# The head's name in the compiled step: every op of the forward scan and of
+# the backward scan carries it in its metadata (`jax.named_scope` adds to the
+# op's name stack and changes no instruction), so a profiler trace can sum
+# the head + CE by name (chipbench/spans.py `head_ce_ms_per_step`). Entered
+# in BOTH rules of the custom_vjp: the backward rule is traced apart from the
+# forward and would not inherit a scope opened inside it.
+SCOPE = "hvt.head_ce"
+
 
 def _chunk_logits(hc, w, compute_dtype):
     """One chunk's logits tile ``[C, V]`` with f32 MXU accumulation."""
@@ -83,6 +91,7 @@ def _split(x, n_chunks):
     return x.reshape((n_chunks, c) + x.shape[1:]), n
 
 
+@jax.named_scope(SCOPE)
 def _fwd(h, w, labels, n_chunks):
     lead = labels.shape
     compute_dtype = h.dtype
@@ -110,6 +119,7 @@ def _fwd_vjp(h, w, labels, n_chunks):
     return (loss, correct), res
 
 
+@jax.named_scope(SCOPE)
 def _bwd_vjp(n_chunks, res, cts):
     h, w, labels = res
     g_loss, _ = cts  # `correct` is piecewise constant — cotangent discarded

@@ -6,7 +6,11 @@ strictly more than Horovod's Chrome-trace Timeline — viewable in
 TensorBoard/perfetto. Primary-process-gated like every writer in the
 framework. `HVT_PROFILE=<dir>` turns tracing on in `Trainer.fit` and
 `bench.py` without code changes (the `HOROVOD_TIMELINE=<file>` env-var
-contract, SURVEY.md §2.3 Timeline row).
+contract, SURVEY.md §2.3 Timeline row). What the program says about itself
+lands in the same trace: `span` puts host events named ``hvt.<name>`` on
+the profiler's clock, and the compiled step carries the scopes
+``hvt.head_ce`` / ``hvt.optimizer`` and the kernel names ``hvt_flash_*``
+(README "Observability" has the table).
 
 FLOPs come from XLA's own cost model on the *compiled* step
 (`Compiled.cost_analysis()`), so the count covers exactly what runs —
@@ -229,18 +233,23 @@ def trace(log_dir: str, primary_only: bool = True):
             jax.profiler.stop_trace()
 
 
-# --- structured trace spans (HVT_TRACE_DIR) ---------------------------------
+# --- spans: one API, two sinks ----------------------------------------------
 #
-# Nestable JSONL span records around the framework's operational
-# boundaries — step, reduction, commit, rescale, checkpoint-save — one
-# rank-tagged file per process, so a fleet's spans can be merged by
-# (rank, ts) into a timeline without a collector. Each record:
+# `span` wraps the framework's host-side boundaries — input_wait, step,
+# callbacks, the prefetch thread's input.assemble / input.place /
+# input.queue_full, reduction, commit, rescale, checkpoint-save, the
+# serving tier's request tree. Every span is a `jax.profiler`
+# TraceAnnotation named "hvt.<name>" (so it lands in any open profiler
+# session beside the device's events, on their clock), and, with
+# HVT_TRACE_DIR set, also a nestable JSONL record in one rank-tagged file
+# per process, so a fleet's spans can be merged by (rank, ts) into a
+# timeline without a collector. Each record:
 #
 #   {"name", "ts" (epoch seconds, span START), "dur_s", "rank", "pid",
 #    "id", "parent" (enclosing span id or null), "depth", ...attrs}
 #
-# Off (zero overhead beyond one registry read) unless HVT_TRACE_DIR is
-# set. Writes are per-record appends with a flush — span cadence is the
+# The file sink is off (one registry read) unless HVT_TRACE_DIR is set.
+# Writes are per-record appends with a flush — span cadence is the
 # optimizer step at its finest, never per-microbatch. Span emission must
 # never take training down: write failures are swallowed after the
 # first (the writer disables itself) — but never SILENTLY: every span a
@@ -249,6 +258,9 @@ def trace(log_dir: str, primary_only: bool = True):
 # trace dir reads as a climbing counter on /metrics instead of a
 # mysteriously empty timeline. Records carry the writing HOST so
 # `hvt-trace` (obs/timeline.py) knows which ranks share a clock.
+
+# What `span` puts before a span's name in the profiler's trace.
+PROFILER_PREFIX = "hvt."
 
 
 def span_dir() -> str | None:
@@ -375,55 +387,43 @@ def emit_span(name: str, ts: float, dur_s: float, **attrs) -> None:
 
 @contextlib.contextmanager
 def span(name: str, **attrs):
-    """``with trace.span('commit', epoch=3): ...`` — one JSONL span
-    record on exit, nesting tracked per thread. No-op (and attr kwargs
-    unevaluated only if the caller guards — they're cheap scalars at
-    every call site) when ``HVT_TRACE_DIR`` is unset."""
-    if not span_dir():
-        yield
-        return
-    stack = _span_writer._stack()
-    sid = _span_writer.next_id()
-    parent = stack[-1] if stack else None
-    stack.append(sid)
-    t0 = time.time()
-    p0 = time.perf_counter()
-    try:
-        yield
-    finally:
-        stack.pop()
-        # Core fields LAST — see emit_span.
-        _span_writer.write({
-            **attrs,
-            "name": name,
-            "ts": t0,
-            "dur_s": time.perf_counter() - p0,
-            "rank": runtime.process_rank(),
-            "pid": os.getpid(),
-            "host": _host(),
-            "id": sid,
-            "parent": parent,
-            "depth": len(stack),
-        })
+    """``with trace.span('commit', epoch=3): ...`` — the framework's one
+    span API, with two sinks on two clocks:
 
+    * always, a `jax.profiler.TraceAnnotation` named ``"hvt." + name``
+      (attrs as its stats): a host event on the calling thread's line of
+      whatever profiler session is open (`trace()`, ``HVT_PROFILE``,
+      ``POST /profile``, the benchmark's traced run), on the device
+      events' own clock. With no session open it costs a flag test;
+    * when ``HVT_TRACE_DIR`` is set, one JSONL span record on exit,
+      nesting tracked per thread, on the wall clock (what `hvt-trace`
+      merges across ranks).
 
-class StepTimer:
-    """Wall-clock step/throughput accounting feeding the bench harness."""
-
-    def __init__(self):
-        self.times: list[float] = []
-        self._t0 = None
-
-    def __enter__(self):
-        self._t0 = time.perf_counter()
-        return self
-
-    def __exit__(self, *exc):
-        self.times.append(time.perf_counter() - self._t0)
-
-    @property
-    def mean_s(self) -> float:
-        return sum(self.times) / max(1, len(self.times))
-
-    def throughput(self, items_per_step: int) -> float:
-        return items_per_step / self.mean_s if self.times else 0.0
+    Host-side only: never enter one inside a traced body (HVT009)."""
+    with jax.profiler.TraceAnnotation(PROFILER_PREFIX + name, **attrs):
+        if not span_dir():
+            yield
+            return
+        stack = _span_writer._stack()
+        sid = _span_writer.next_id()
+        parent = stack[-1] if stack else None
+        stack.append(sid)
+        t0 = time.time()
+        p0 = time.perf_counter()
+        try:
+            yield
+        finally:
+            stack.pop()
+            # Core fields LAST — see emit_span.
+            _span_writer.write({
+                **attrs,
+                "name": name,
+                "ts": t0,
+                "dur_s": time.perf_counter() - p0,
+                "rank": runtime.process_rank(),
+                "pid": os.getpid(),
+                "host": _host(),
+                "id": sid,
+                "parent": parent,
+                "depth": len(stack),
+            })

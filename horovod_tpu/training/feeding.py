@@ -485,6 +485,7 @@ def _maybe_step_sampler(trainer):
 def fit_epochs(trainer, it, pending, zero_acc, epochs, initial_epoch, steps_per_epoch,
     callbacks, validation_data, batch_size, verbose, initial_step=0,
 ):
+    from horovod_tpu import obs
     from horovod_tpu import trace as trace_lib
     from horovod_tpu.data.prefetch import DevicePrefetcher
 
@@ -582,10 +583,17 @@ def fit_epochs(trainer, it, pending, zero_acc, epochs, initial_epoch, steps_per_
             start = initial_step if epoch == initial_epoch else 0
             step = start
             for k in plan_for(epoch):
-                if sampler is not None:
-                    t_in = time.perf_counter()
+                # Always timed, on both clocks: the profiler's (the span)
+                # and /metrics' (the counter). With the producer ahead
+                # this is a queue pop; when it grows, the input engine is
+                # on the critical path.
+                t_in = time.perf_counter()
+                with trace_lib.span("input_wait"):
                     chunk = next(prefetcher)
-                    sampler.add_input_wait(time.perf_counter() - t_in)
+                waited = time.perf_counter() - t_in
+                obs.counter("hvt_input_wait_seconds_total", waited)
+                if sampler is not None:
+                    sampler.add_input_wait(waited)
                     if sampler._step_shapes is None:
                         # First chunk: derive examples per OPTIMIZER step
                         # from the placed shapes ([spe?, K?, G, ...]) and
@@ -607,14 +615,19 @@ def fit_epochs(trainer, it, pending, zero_acc, epochs, initial_epoch, steps_per_
                             run, (trainer.state, chunk, scale, metric_acc),
                             k,
                         )
-                else:
-                    chunk = next(prefetcher)
                 t_run = time.perf_counter() if sampler is not None else 0.0
+                # The HOST's call into the step program, not the step:
+                # it returns at enqueue while fewer programs are in
+                # flight than the runtime allows, and blocks for a
+                # retiring step otherwise. The step's device time is the
+                # device's `XLA Modules` event; the n-th `hvt.step` span
+                # of a trace belongs to the n-th of them.
                 with trace_lib.span("step", epoch=epoch, step=step,
                                     steps=k):
                     trainer.state, metrics, metric_acc = run(
                         trainer.state, chunk, scale, metric_acc
                     )
+                obs.counter("hvt_optimizer_steps_total", k)
                 if sampler is not None:
                     # Step-call host time feeds the SkewProbe's blocked
                     # signal (sync-dispatch backends block HERE, not in
@@ -624,8 +637,9 @@ def fit_epochs(trainer, it, pending, zero_acc, epochs, initial_epoch, steps_per_
                 step += k
                 # Once per execution, with the last step's metrics —
                 # Keras's steps_per_execution callback semantics.
-                for cb in callbacks:
-                    cb.on_batch_end(step - 1, metrics)
+                with trace_lib.span("callbacks"):
+                    for cb in callbacks:
+                        cb.on_batch_end(step - 1, metrics)
             finish_epoch(trainer,
                 epoch, epochs, metric_acc, steps_per_epoch - start, t0,
                 callbacks, validation_data, batch_size, verbose,
@@ -636,6 +650,7 @@ def fit_epochs(trainer, it, pending, zero_acc, epochs, initial_epoch, steps_per_
 def fit_device_cached(trainer, x, y, batch_size, epochs, initial_epoch, steps_per_epoch,
     callbacks, validation_data, verbose, initial_step=0,
 ):
+    from horovod_tpu import obs
     from horovod_tpu import trace as trace_lib
 
     data, per_shard = stage_device_dataset(trainer, x, y)
@@ -723,6 +738,7 @@ def fit_device_cached(trainer, x, y, batch_size, epochs, initial_epoch, steps_pe
                                 scale, metric_acc, n, batch_size, at,
                             )
                         )
+                    obs.counter("hvt_optimizer_steps_total", n)
                     if sampler is not None:
                         sampler.add_step_time(time.perf_counter() - t_run)
                         sampler.maybe_sample(trainer.state, n)
@@ -730,8 +746,9 @@ def fit_device_cached(trainer, x, y, batch_size, epochs, initial_epoch, steps_pe
                     # Once per chunk, with the chunk's last step metrics
                     # and the TRUE within-epoch step index — the
                     # steps_per_execution callback contract.
-                    for cb in callbacks:
-                        cb.on_batch_end(at - 1, metrics)
+                    with trace_lib.span("callbacks"):
+                        for cb in callbacks:
+                            cb.on_batch_end(at - 1, metrics)
                 finish_epoch(trainer,
                     epoch, epochs, metric_acc, steps - start, t0, callbacks,
                     validation_data, batch_size, verbose,

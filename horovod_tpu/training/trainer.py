@@ -43,6 +43,8 @@ from horovod_tpu.training.optimizer import (
 )
 
 PyTree = Any
+# The optimizer update's name in the compiled step (see `train_step`).
+OPTIMIZER_SCOPE = "hvt.optimizer"
 
 from horovod_tpu.training import build as build_lib
 from horovod_tpu.training import feeding
@@ -658,39 +660,47 @@ class Trainer:
                 (loss, (acc, model_state, sown_metrics)), grads = (
                     jax.value_and_grad(loss_of, has_aux=True)(state.params)
                 )
-            updates, opt_state = self.tx.update(grads, state.opt_state, state.params)
-            if self._ef:
-                # Install the boundary reduction's new untransmitted
-                # remainder (the EF wrapper's update passed the old one
-                # through untouched).
-                opt_state = opt_state.replace(ef_residual=new_residual)
-            updates = jax.tree.map(lambda u: u * update_scale, updates)
-            if self._scatter > 1 and self._explicit_step:
-                # Composed ZeRO-1 path: pin the zero1 layout on the
-                # updates so the replication boundary is the param
-                # all-gather AFTER the sharded optimizer math —
-                # propagation must not re-replicate the scattered
-                # gradients and optimizer mirrors instead. The optimizer
-                # math itself is per-leaf elementwise dataflow over the
-                # scattered gradients, so with leaf-aligned buckets each
-                # bucket's shard-local apply (and its param all-gather
-                # below) is schedulable the moment THAT bucket's scatter
-                # lands — the fused per-shard apply of the weight-update
-                # -sharding end state (arXiv:2004.13336), as compiled
-                # structure.
-                updates = jax.lax.with_sharding_constraint(
-                    updates,
-                    jax.tree.map(
-                        lambda p: jax.sharding.NamedSharding(
-                            self.mesh,
-                            collectives.zero1_partition_spec(
-                                jnp.shape(p), self._scatter
-                            ),
-                        ),
-                        state.params,
-                    ),
+            # Named so that a profiler trace can sum the update by name
+            # (chipbench/spans.py `optimizer_ms_per_step`): the scope adds to
+            # each op's metadata and changes no instruction. Where XLA fuses
+            # the update into the weight-gradient matmul, the fused op is
+            # counted where ITS metadata puts it.
+            with jax.named_scope(OPTIMIZER_SCOPE):
+                updates, opt_state = self.tx.update(
+                    grads, state.opt_state, state.params
                 )
-            params = optax.apply_updates(state.params, updates)
+                if self._ef:
+                    # Install the boundary reduction's new untransmitted
+                    # remainder (the EF wrapper's update passed the old one
+                    # through untouched).
+                    opt_state = opt_state.replace(ef_residual=new_residual)
+                updates = jax.tree.map(lambda u: u * update_scale, updates)
+                if self._scatter > 1 and self._explicit_step:
+                    # Composed ZeRO-1 path: pin the zero1 layout on the
+                    # updates so the replication boundary is the param
+                    # all-gather AFTER the sharded optimizer math —
+                    # propagation must not re-replicate the scattered
+                    # gradients and optimizer mirrors instead. The optimizer
+                    # math itself is per-leaf elementwise dataflow over the
+                    # scattered gradients, so with leaf-aligned buckets each
+                    # bucket's shard-local apply (and its param all-gather
+                    # below) is schedulable the moment THAT bucket's scatter
+                    # lands — the fused per-shard apply of the weight-update
+                    # -sharding end state (arXiv:2004.13336), as compiled
+                    # structure.
+                    updates = jax.lax.with_sharding_constraint(
+                        updates,
+                        jax.tree.map(
+                            lambda p: jax.sharding.NamedSharding(
+                                self.mesh,
+                                collectives.zero1_partition_spec(
+                                    jnp.shape(p), self._scatter
+                                ),
+                            ),
+                            state.params,
+                        ),
+                    )
+                params = optax.apply_updates(state.params, updates)
             if self._scatter > 1:
                 # ZeRO-1 (implicit or composed): the updated params must
                 # come back REPLICATED. Left to propagation, XLA keeps
@@ -1228,7 +1238,6 @@ class StepPhaseSampler:
         steps; at the cadence boundary, drain and publish."""
         from horovod_tpu import obs
 
-        obs.counter("hvt_optimizer_steps_total", steps)
         self._steps += steps
         if self._window_t0 is not None and self._steps < self.every:
             return

@@ -71,6 +71,18 @@ def kernel_calls(compiled) -> list[str]:
     ]
 
 
+def kernel_names(compiled) -> list[str]:
+    """The Mosaic calls' HLO instruction names without their numbers
+    (what the benchmark's reduction calls an op family), sorted."""
+    return sorted(
+        re.sub(r"[.\d]+$", "", re.match(r"\s*(?:ROOT )?%(\S+) =", line)[1])
+        for line in kernel_calls(compiled)
+    )
+
+
+FLASH_KERNELS = sorted([fa.KERNEL_DKV, fa.KERNEL_DQ, fa.KERNEL_FWD])
+
+
 @pytest.mark.parametrize(
     # [B, T, H, D], dtype, flash kwargs, segmented, Mosaic calls fwd+bwd
     "shape,dtype,kwargs,segmented,n_calls",
@@ -97,16 +109,50 @@ def test_flash_fwd_bwd_compiles_for_v5e(topo, shape, dtype, kwargs,
         args.append(SDS(shape[:2], jnp.int32, sharding=one_chip))
 
     def loss(q, k, v, ids=None):
-        out = fa.flash_attention(
-            q, k, v, causal=True, interpret=False,
-            q_segment_ids=ids, kv_segment_ids=ids, **kwargs,
-        )
+        # A caller's scope, as a flax module gives one: called bare, the
+        # kernel's name is the outermost entry of the name stack and takes
+        # the transform's wrapper (`jvp_hvt_flash_fwd_`).
+        with jax.named_scope("attention"):
+            out = fa.flash_attention(
+                q, k, v, causal=True, interpret=False,
+                q_segment_ids=ids, kv_segment_ids=ids, **kwargs,
+            )
         return out.astype(jnp.float32).sum()
 
     compiled = jax.jit(
         jax.value_and_grad(loss, argnums=(0, 1, 2))
     ).lower(*args).compile()
     assert len(kernel_calls(compiled)) == n_calls
+    # The instruction names the profiler's device events carry; the
+    # sink-only dK/dV pass (the fourth call) is dK/dV work by name too.
+    assert kernel_names(compiled) == sorted(
+        FLASH_KERNELS + [fa.KERNEL_DKV] * (n_calls - 3))
+
+
+def test_flash_kernels_keep_their_names_under_a_shard_map(topo):
+    """On a mesh the model wraps the kernel in a `shard_map`, whose own
+    name used to be the innermost and so the instruction's."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    mesh = mesh_lib.build_mesh(
+        mesh_lib.MeshSpec(data=4), devices=topo.devices)
+    spec = P(mesh_lib.DATA_AXIS)
+    qkv = SDS((8, 1024, 16, 128), jnp.bfloat16,
+              sharding=NamedSharding(mesh, spec))
+
+    def loss(q, k, v):
+        out = jax.shard_map(
+            lambda q, k, v: fa.flash_attention(
+                q, k, v, causal=True, interpret=False),
+            mesh=mesh, in_specs=(spec,) * 3, out_specs=spec,
+            check_vma=False,
+        )(q, k, v)
+        return out.astype(jnp.float32).sum()
+
+    compiled = jax.jit(
+        jax.value_and_grad(loss, argnums=(0, 1, 2))
+    ).lower(qkv, qkv, qkv).compile()
+    assert kernel_names(compiled) == FLASH_KERNELS
 
 
 # --- one whole train step on the four described chips ----------------------
@@ -173,6 +219,7 @@ def test_train_step_compiles_data_parallel_on_four_chips(
     ).compile()
     calls = kernel_calls(compiled)
     assert len(calls) == 2 * 3  # layers x fwd/dq/dkv
+    assert kernel_names(compiled) == sorted(FLASH_KERNELS * 2)
     # The shard_map hands each chip's kernel its quarter of the batch.
     kernel_batches = {
         int(b) for line in calls
