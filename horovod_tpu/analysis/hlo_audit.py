@@ -60,6 +60,7 @@ __all__ = [
     "op_bytes_by_kind",
     "payload_alltoalls",
     "scatter_reductions",
+    "while_bodies",
     "while_count",
     "wire_dtype",
 ]
@@ -314,15 +315,49 @@ def op_bytes_by_kind(ops) -> dict:
     return out
 
 
+_HLO_WHILE_RE = re.compile(r"=\s*[^=]*\bwhile\(")
+
+
 def while_count(text: str) -> int:
     """Loop (scan) ops in the program — the overlap peel's structural
     witness (PR 7: the peeled K=2 step has strictly fewer)."""
     if "stablehlo." in text:
         return text.count("stablehlo.while")
     return sum(
-        1 for line in text.splitlines()
-        if re.search(r"=\s*[^=]*\bwhile\(", line)
+        1 for line in text.splitlines() if _HLO_WHILE_RE.search(line)
     )
+
+
+_HLO_COMPUTATION_RE = re.compile(
+    r"^(?:ENTRY )?%?([\w.\-]+) [^\n]*\{\n(.*?)^\}", re.M | re.S
+)
+_HLO_CALLEE_RE = re.compile(
+    r"(?:body|condition|calls|to_apply)=%?([\w.\-]+)"
+)
+
+
+def while_bodies(text: str, scope: str = "") -> list[str]:
+    """Compiled HLO: the text of each loop's body whose `while`
+    instruction names ``scope`` in its metadata (every loop when empty),
+    with every computation the body calls — fusions, nested loops,
+    reducers — appended. What "nothing crosses the chips inside the
+    loop" has to read (`collective_ops(body)`)."""
+    computations = dict(_HLO_COMPUTATION_RE.findall(text))
+
+    def with_callees(name: str, seen: set) -> str:
+        if name in seen or name not in computations:
+            return ""
+        seen.add(name)
+        body = computations[name]
+        return body + "".join(
+            with_callees(c, seen) for c in _HLO_CALLEE_RE.findall(body)
+        )
+
+    return [
+        with_callees(re.search(r"body=%?([\w.\-]+)", line)[1], set())
+        for line in text.splitlines()
+        if _HLO_WHILE_RE.search(line) and scope in line
+    ]
 
 
 # Donation: lowered StableHLO marks donated args with `tf.aliasing_output`

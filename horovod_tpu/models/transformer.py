@@ -45,6 +45,8 @@ from horovod_tpu.parallel.mesh import (
 )
 
 BATCH_AXES = (DATA_AXIS, FSDP_AXIS)
+# The axes a [B, T] token's row is split over: batch (data, fsdp) and seq.
+ROW_AXES = BATCH_AXES + (SEQ_AXIS,)
 
 
 def _rope(x, positions, *, base: float = 10000.0):
@@ -671,6 +673,7 @@ class LMHead(nn.Module):
     compute_dtype: jnp.dtype = jnp.float32
     logits_dtype: jnp.dtype = jnp.float32
     int8_compute: bool = False
+    sharding: ShardingConfig = ShardingConfig()
 
     def setup(self):
         self.kernel = self.param(
@@ -697,11 +700,43 @@ class LMHead(nn.Module):
         return logits.astype(self.logits_dtype)
 
     def fused_loss(self, x, labels, n_chunks: int):
-        """(per-token loss, per-token correct) without full logits."""
-        return fused_ce.fused_linear_cross_entropy(
-            x.astype(self.compute_dtype), self.kernel, labels,
-            max(1, n_chunks),
-        )
+        """(per-token loss, per-token correct) without full logits.
+
+        Where the mesh splits the rows of ``x`` ``[B, T, D]`` (live
+        ``data``/``fsdp``/``seq`` axes that divide B and T), each chip runs
+        the chunked head on its own ``B/dp x T/sp`` rows: a `shard_map`
+        over the row axes with the kernel replicated over them, so neither
+        scan holds a collective and the transpose of the replicated kernel
+        is the ONE cross-chip sum of dW, after the backward loop. Left to
+        the partitioner, the scans walk the flattened (sharded) row axis
+        and every chip gathers and computes every chunk. ``model`` stays
+        the partitioner's (the region is manual over the row axes only)."""
+        def head(x, kernel, labels):
+            return fused_ce.fused_linear_cross_entropy(
+                x, kernel, labels, max(1, n_chunks))
+
+        mesh = self.sharding.mesh
+        if mesh is not None and _rows_split(mesh, *labels.shape):
+            rows = P(BATCH_AXES, SEQ_AXIS)
+            # jitted: op by op (`Trainer.build`'s init) JAX refuses a
+            # region that is manual over some of the mesh's axes only.
+            # The scope around the region names the dW sum for the trace
+            # too: it is born in the region's transpose, outside both rules.
+            head = jax.named_scope(fused_ce.SCOPE)(jax.jit(jax.shard_map(
+                head, mesh=mesh,
+                in_specs=(P(BATCH_AXES, SEQ_AXIS, None), P(), rows),
+                out_specs=(rows, rows),
+                axis_names=frozenset(ROW_AXES), check_vma=False,
+            )))
+        return head(x.astype(self.compute_dtype), self.kernel, labels)
+
+
+def _rows_split(mesh: Mesh, b: int, t: int) -> bool:
+    """Whether ``mesh`` splits a ``[b, t]`` batch's rows over more than one
+    device, evenly (a `shard_map` takes no ragged shard)."""
+    dp = mesh.shape.get(DATA_AXIS, 1) * mesh.shape.get(FSDP_AXIS, 1)
+    sp = mesh.shape.get(SEQ_AXIS, 1)
+    return dp * sp > 1 and b % dp == 0 and t % sp == 0
 
 
 class TransformerLM(nn.Module):
@@ -764,9 +799,14 @@ class TransformerLM(nn.Module):
     # StreamingLLM attention sinks (decode-time; see Block.attention_sinks).
     attention_sinks: int = 0
     # Row-chunk count for the fused linear-CE head when ``labels`` are fed
-    # through ``__call__`` (loss='module'): peak head memory is
-    # ceil(B·T/chunks)·vocab floats instead of the full [B, T, vocab] logits
-    # + cotangent. 0 → a single chunk (dense-equivalent memory, same math).
+    # through ``__call__`` (loss='module'): chunks of the CHIP'S OWN rows
+    # (B/dp · T/sp of them on a mesh that ``sharding`` holds, see
+    # LMHead.fused_loss), so peak head memory is
+    # ceil(the chip's rows / chunks) · vocab floats instead of the full
+    # [B, T, vocab] logits + cotangent. 0 → a single chunk (dense-equivalent
+    # memory, same math). A model built without the mesh under a
+    # multi-device Trainer (attn='dense') keeps the replicated head: chunks
+    # of all B·T rows, computed on every chip.
     fused_head_chunks: int = 0
 
     @nn.compact
@@ -854,6 +894,7 @@ class TransformerLM(nn.Module):
             compute_dtype=self.compute_dtype,
             logits_dtype=self.logits_dtype,
             int8_compute=self.int8_compute,
+            sharding=cfg,
             name="lm_head",
         )
         if labels is not None:

@@ -24,6 +24,24 @@ this matters; this op exists for the framework's long-context flagship,
 where the head is the memory-binding layer.
 
 Used by ``TransformerLM(fused_head_chunks=n)`` + ``Trainer(loss='module')``.
+
+On a mesh. This op knows no mesh: it chunks the rows it is handed. Left to
+the partitioner that is wrong twice over — `_split` cuts the flattened
+``B·T`` axis, so a batch split over ``data`` becomes the axis the scans
+walk, every chip gathers every chunk and computes all of them (the dp4
+step's head took 4.05x the one-chip head's time, PERF.md PR 26), and a dW
+carry that is a partial sum cannot cross a `while` without an all-reduce
+per chunk. So the caller that holds the mesh (`LMHead.fused_loss`,
+models/transformer.py) calls this op inside a `shard_map` over the row
+axes (``data``, ``fsdp``, ``seq``) with the kernel replicated over them:
+each chip flattens and chunks its OWN ``B/dp x T/sp`` rows (``n_chunks``
+counts chunks of those), neither scan holds a collective, and the
+transpose of the replicated kernel is the one cross-chip sum of dW, after
+the backward loop, in the kernel's dtype (float32 parameters: the float32
+the rule accumulates in). ``model`` (the kernel's vocabulary dimension)
+stays the partitioner's. A model built
+without a mesh and run under a multi-device Trainer (``attn='dense'``)
+cannot know the mesh and keeps the replicated head.
 """
 
 from __future__ import annotations
@@ -64,7 +82,8 @@ def fused_linear_cross_entropy(h, w, labels, n_chunks: int = 8):
       labels: integer ``[...]`` matching ``h``'s leading shape.
       n_chunks: static number of row-chunks the flattened ``B·T`` rows are
         scanned in; peak logits memory is ``ceil(B·T / n_chunks) · V`` floats
-        (per forward or backward scan step).
+        (per forward or backward scan step). Inside a `shard_map` the rows
+        are the chip's own (see the module docstring).
 
     Returns:
       ``(loss, correct)`` — per-token f32 loss ``lse - logit[label]`` and a

@@ -25,8 +25,10 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 import horovod_tpu as hvt
+from horovod_tpu.analysis import hlo_audit
 from horovod_tpu.models.transformer import ShardingConfig, TransformerLM
 from horovod_tpu.ops import flash_attention as fa
+from horovod_tpu.ops import fused_ce
 from horovod_tpu.parallel import mesh as mesh_lib
 from horovod_tpu.parallel import sharding as sharding_lib
 from horovod_tpu.training.train_state import TrainState
@@ -158,12 +160,14 @@ def test_flash_kernels_keep_their_names_under_a_shard_map(topo):
 # --- one whole train step on the four described chips ----------------------
 
 D_MODEL, HEADS, SEQ, VOCAB, GLOBAL_BATCH = 2048, 16, 1024, 8192, 8
+HEAD_CHUNKS = 8
 
 
 def lm_trainer(mesh, sharding):
     model = TransformerLM(
         vocab_size=VOCAB, d_model=D_MODEL, n_heads=HEADS, n_layers=2,
-        dropout=0.0, compute_dtype=jnp.bfloat16, fused_head_chunks=8,
+        dropout=0.0, compute_dtype=jnp.bfloat16,
+        fused_head_chunks=HEAD_CHUNKS,
         sharding=sharding,
     )
     return hvt.Trainer(
@@ -217,6 +221,18 @@ def test_train_step_compiles_data_parallel_on_four_chips(
     compiled = trainer._train_step.lower(
         *abstract_step_args(trainer)
     ).compile()
+    # The chunked head + CE runs on each chip's own rows: its logits tile
+    # is a chunk of the chip's quarter, not of the global batch, and no
+    # chip gathers rows inside the loops. (Elsewhere the compiler may still
+    # split an all-reduce into a reduce-scatter and a gather.)
+    hlo = compiled.as_text()
+    bodies = hlo_audit.while_bodies(hlo, fused_ce.SCOPE)
+    assert len(bodies) == 2  # the forward scan and the backward scan
+    local_rows = GLOBAL_BATCH // 4 * SEQ // HEAD_CHUNKS
+    for body in bodies:
+        assert not hlo_audit.collective_ops(body)
+        assert f"f32[{local_rows},{VOCAB}]" in body
+    assert f"f32[{4 * local_rows},{VOCAB}]" not in hlo
     calls = kernel_calls(compiled)
     assert len(calls) == 2 * 3  # layers x fwd/dq/dkv
     assert kernel_names(compiled) == sorted(FLASH_KERNELS * 2)
@@ -226,7 +242,7 @@ def test_train_step_compiles_data_parallel_on_four_chips(
         for b in re.findall(rf"bf16\[(\d+),{HEADS},{SEQ},128\]", line)
     }
     assert kernel_batches == {GLOBAL_BATCH // 4}
-    assert "all-reduce" in compiled.as_text()
+    assert "all-reduce" in hlo
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16e9
 

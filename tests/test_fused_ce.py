@@ -1,7 +1,11 @@
 """Fused chunked linear-CE (ops/fused_ce.py) and the Trainer loss='module'
 contract: math parity with the dense logits path, gradient parity through
-the custom VJP, and the memory claim (no full [B·T, vocab] logits array)
-verified against XLA's own memory analysis."""
+the custom VJP, the memory claim (no full [B·T, vocab] logits array)
+verified against XLA's own memory analysis, and the head on a mesh: each
+chip chunks its own rows, with one sum of dW after the backward loop."""
+
+import math
+import re
 
 import flax.linen as nn
 import jax
@@ -11,8 +15,18 @@ import optax
 import pytest
 
 import horovod_tpu as hvt
-from horovod_tpu.models.transformer import TransformerLM
+from horovod_tpu.analysis import hlo_audit
+from horovod_tpu.models.transformer import (
+    BATCH_AXES,
+    SEQ_AXIS,
+    LMHead,
+    ShardingConfig,
+    TransformerLM,
+    param_specs,
+)
+from horovod_tpu.ops import fused_ce
 from horovod_tpu.ops.fused_ce import fused_linear_cross_entropy
+from horovod_tpu.parallel import sharding as sharding_lib
 
 
 def _dense_loss(h, w, labels):
@@ -116,13 +130,16 @@ class TestFusedLinearCrossEntropy:
 class TestModuleLossTrainer:
     """TransformerLM(fused_head_chunks=...) + Trainer(loss='module')."""
 
-    def _fit(self, loss, fused_chunks, steps=6, **model_kw):
+    def _fit(self, loss, fused_chunks, steps=6, mesh=None, **model_kw):
+        if mesh is not None:
+            model_kw["sharding"] = ShardingConfig(mesh=mesh)
         model = TransformerLM(
             vocab_size=64, d_model=32, n_heads=4, n_layers=2, dropout=0.0,
             fused_head_chunks=fused_chunks, **model_kw,
         )
         trainer = hvt.Trainer(
-            model, hvt.DistributedOptimizer(optax.adam(1e-2)), loss=loss
+            model, hvt.DistributedOptimizer(optax.adam(1e-2)), loss=loss,
+            mesh=mesh,
         )
         rng = np.random.RandomState(0)
         x = rng.randint(1, 64, size=(16, 12)).astype(np.int32)
@@ -191,6 +208,187 @@ class TestModuleLossTrainer:
             jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32)
         )["params"]
         assert params["lm_head"]["kernel"].shape == (32, 64)
+
+
+# --- the head on a mesh: each chip's own rows ------------------------------
+
+def mesh_of(**axes):
+    return hvt.build_mesh(
+        hvt.MeshSpec(**axes),
+        devices=jax.devices()[:math.prod(axes.values())],
+    )
+
+
+def on_mesh(mesh, tree, specs):
+    return jax.tree.map(
+        lambda a, spec: jax.device_put(
+            a, jax.sharding.NamedSharding(mesh, spec)),
+        tree, specs,
+    )
+
+
+ROWS = jax.sharding.PartitionSpec(BATCH_AXES, SEQ_AXIS)
+
+
+class TestHeadOnEachChipsOwnRows:
+    # 4 x 10 rows in 3 chunks: 40 rows pad to 42 without a mesh, and a
+    # chip's 10 (or 20 on data=2 x model=2) pad to 12 (21) on one.
+    B, T, D, V, CHUNKS = 4, 10, 32, 64, 3
+
+    def _lm(self, sharding):
+        return TransformerLM(
+            vocab_size=self.V, d_model=self.D, n_heads=4, n_layers=1,
+            dropout=0.0, fused_head_chunks=self.CHUNKS, sharding=sharding,
+        )
+
+    def _tokens(self):
+        rng = np.random.RandomState(0)
+        x = rng.randint(1, self.V, size=(self.B, self.T)).astype(np.int32)
+        return x, np.roll(x, -1, axis=1)
+
+    @staticmethod
+    def _weighted(loss):
+        # Every token's cotangent differs: a row that lands on the wrong
+        # chip, or a padded one that counts, shows in the gradients.
+        return (loss * jnp.linspace(0.5, 1.5, loss.size).reshape(
+            loss.shape)).mean()
+
+    @pytest.mark.parametrize(
+        "axes",
+        [dict(data=4), dict(data=2, fsdp=2), dict(data=2, seq=2),
+         dict(data=2, model=2)],
+        ids=["data4", "data2-fsdp2", "data2-seq2", "data2-model2"],
+    )
+    def test_matches_the_meshless_single_device_values(self, axes):
+        x, y = self._tokens()
+        mesh = mesh_of(**axes)
+        on_seq = "seq" in axes
+
+        # The head alone: loss, correct, dh, dW.
+        rng = np.random.RandomState(1)
+        h = jnp.asarray(rng.randn(self.B, self.T, self.D), jnp.float32)
+        w = jnp.asarray(rng.randn(self.D, self.V) / 6.0, jnp.float32)
+
+        def head_values(sharding, h, w, labels):
+            head = LMHead(self.D, self.V, sharding=sharding)
+
+            def f(h, w):
+                loss, correct = head.apply(
+                    {"params": {"kernel": w}}, h, labels, self.CHUNKS,
+                    method="fused_loss")
+                return self._weighted(loss), (loss, correct)
+
+            return jax.jit(jax.value_and_grad(f, argnums=(0, 1), has_aux=True))(
+                h, w)
+
+        (_, (loss0, correct0)), (dh0, dw0) = head_values(
+            ShardingConfig(), h, w, y)
+        head_spec = param_specs({"lm_head": {"kernel": w}}, mesh)
+        (_, (loss1, correct1)), (dh1, dw1) = head_values(
+            ShardingConfig(mesh=mesh),
+            *on_mesh(mesh, (h, w, y), (
+                jax.sharding.PartitionSpec(BATCH_AXES, SEQ_AXIS, None),
+                head_spec["lm_head"]["kernel"], ROWS)),
+        )
+        # Per-token values come back split as the rows went in.
+        assert loss1.sharding.is_equivalent_to(
+            jax.sharding.NamedSharding(mesh, ROWS), 2)
+        np.testing.assert_allclose(loss1, loss0, rtol=1e-5)
+        np.testing.assert_array_equal(correct1, correct0)
+        np.testing.assert_allclose(dh1, dh0, rtol=1e-5, atol=1e-8)
+        np.testing.assert_allclose(dw1, dw0, rtol=1e-5, atol=1e-8)
+
+        # Through the model: the same values, and the rest of the
+        # gradients, which dh feeds.
+        def lm_values(model, params, x, y):
+            def f(params):
+                loss, correct = model.apply({"params": params}, x, labels=y)
+                return self._weighted(loss), (loss, correct)
+
+            return jax.jit(jax.value_and_grad(f, has_aux=True))(params)
+
+        plain = self._lm(ShardingConfig(attn="dense"))
+        params = plain.init(jax.random.PRNGKey(0), x, labels=y)["params"]
+        (_, (loss0, correct0)), g0 = lm_values(plain, params, x, y)
+        sharded = self._lm(ShardingConfig(
+            mesh=mesh, attn="ring_dense" if on_seq else "dense"))
+        (_, (loss1, correct1)), g1 = lm_values(
+            sharded, on_mesh(mesh, params, param_specs(params, mesh)),
+            *on_mesh(mesh, (x, y), (ROWS, ROWS)))
+        np.testing.assert_allclose(loss1, loss0, rtol=1e-5)
+        np.testing.assert_array_equal(correct1, correct0)
+        np.testing.assert_allclose(
+            g1["lm_head"]["kernel"], g0["lm_head"]["kernel"],
+            rtol=1e-5, atol=1e-7)
+        jax.tree.map(
+            lambda a, b: np.testing.assert_allclose(
+                a, b, rtol=1e-4, atol=1e-6),
+            g1, g0,
+        )
+
+    @pytest.mark.parametrize(
+        "axes,region",
+        [(None, False), (dict(data=1), False), (dict(data=4), True)],
+        ids=["no-mesh", "one-device-mesh", "data4"],
+    )
+    def test_only_a_mesh_that_splits_rows_wraps_the_head(self, axes, region):
+        # One device (or no mesh): the plain call, today's program.
+        x, y = self._tokens()
+        model = self._lm(ShardingConfig(
+            mesh=None if axes is None else mesh_of(**axes), attn="dense"))
+        params = jax.eval_shape(
+            lambda: model.init(jax.random.PRNGKey(0), x, labels=y)
+        )["params"]
+        jaxpr = str(jax.make_jaxpr(
+            lambda p: model.apply({"params": p}, x, labels=y))(params))
+        assert ("shard_map" in jaxpr) == region
+
+    def test_trainer_on_a_data_mesh_follows_the_meshless_run(self):
+        fit = TestModuleLossTrainer()._fit
+        _, state_m, losses_m, _, acc_m = fit(
+            "module", 4, steps=3, mesh=mesh_of(data=4))
+        _, state_p, losses_p, _, acc_p = fit("module", 4, steps=3)
+        np.testing.assert_allclose(losses_m, losses_p, rtol=1e-4)
+        np.testing.assert_allclose(acc_m, acc_p, rtol=1e-4)
+        jax.tree.map(
+            lambda a, b: np.testing.assert_allclose(a, b, rtol=1e-3, atol=1e-5),
+            state_m.params, state_p.params,
+        )
+
+
+def test_compiled_data_parallel_step_keeps_the_head_local():
+    """The compiled data=4 step (CPU's partitioner is the chip's GSPMD):
+    no collective inside the head's loops, tiles of the chip's own rows,
+    and dW crossing the chips once."""
+    d, v, seq, per_chip, chunks = 32, 96, 16, 2, 2
+    mesh = mesh_of(data=4)
+    model = TransformerLM(
+        vocab_size=v, d_model=d, n_heads=4, n_layers=1, dropout=0.0,
+        fused_head_chunks=chunks, sharding=ShardingConfig(mesh=mesh),
+    )
+    trainer = hvt.Trainer(
+        model, hvt.DistributedOptimizer(optax.adamw(1e-3)), loss="module",
+        mesh=mesh,
+    )
+    x = (np.arange(4 * per_chip * seq, dtype=np.int32) % v).reshape(-1, seq)
+    state = trainer.build(x[:4], x[:4])
+    hlo = trainer._train_step.lower(
+        state, trainer._shard((x, x)), jnp.asarray(1.0, jnp.float32),
+        sharding_lib.replicate(trainer.zero_metrics(), mesh),
+    ).compile().as_text()
+
+    bodies = hlo_audit.while_bodies(hlo, fused_ce.SCOPE)
+    assert len(bodies) == 2  # the forward scan and the backward scan
+    local_tile = f"f32[{per_chip * seq // chunks},{v}]"
+    global_tile = f"f32[{4 * per_chip * seq // chunks},{v}]"
+    for body in bodies:
+        assert not hlo_audit.collective_ops(body)
+        assert local_tile in body and global_tile not in body
+    # dW [D, V] float32 crosses the chips once (alone, or as one operand of
+    # a combined all-reduce), after the loops.
+    reduced = re.findall(
+        r"= (.*?) (?:all-reduce|reduce-scatter)(?:-start)?\(", hlo)
+    assert sum(types.count(f"f32[{d},{v}]") for types in reduced) == 1
 
 
 class TestBuildTracesFusedPath:

@@ -112,6 +112,39 @@ class TestParsers:
         assert hlo_audit.while_count(STABLEHLO_SAMPLE) == 1
         assert hlo_audit.while_count(HLO_SAMPLE) == 1
 
+    def test_while_bodies_follow_callees_and_filter_by_scope(self):
+        text = textwrap.dedent("""\
+            HloModule jit_step
+
+            %add (x: f32[], y: f32[]) -> f32[] {
+              ROOT %s = f32[] add(f32[] %x, f32[] %y)
+            }
+
+            %fused (p: f32[8,4]) -> f32[8,4] {
+              ROOT %n = f32[8,4]{1,0} negate(f32[8,4]{1,0} %p)
+            }
+
+            %head_body (c: (s32[], f32[8,4])) -> (s32[], f32[8,4]) {
+              %f = f32[8,4]{1,0} fusion(f32[8,4]{1,0} %g), kind=kLoop, calls=%fused
+            }
+
+            %other_body (c: (s32[], f32[8])) -> (s32[], f32[8]) {
+              %ar = f32[8]{0} all-reduce(f32[8]{0} %g), channel_id=1, to_apply=%add
+            }
+
+            ENTRY %main {
+              %w.1 = (s32[], f32[8,4]{1,0}) while(%t), condition=%c, body=%head_body, metadata={op_name="jit(step)/hvt.head_ce/while"}
+              %w.2 = (s32[], f32[8]{0}) while(%u), condition=%c, body=%other_body, metadata={op_name="jit(step)/scan/while"}
+            }
+        """)
+        head, = hlo_audit.while_bodies(text, "hvt.head_ce")
+        assert "calls=%fused" in head and "negate" in head  # the callee too
+        assert not hlo_audit.collective_ops(head)
+        both = hlo_audit.while_bodies(text)
+        assert len(both) == 2
+        assert [o.kind for o in hlo_audit.collective_ops(both[1])] == [
+            "all-reduce"]
+
     def test_donated_args_hlo_header(self):
         assert hlo_audit.donated_args(HLO_SAMPLE) == [0, 2]
 
