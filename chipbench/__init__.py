@@ -2,6 +2,7 @@
 
 Everything the yardstick needs lives under this directory: traffic
 generation, the reduction from profiler traces to metrics, the table of
-peaks, the FLOP and byte counts, the plain float32 reference and the
-comparison that decides ``correct``. From the program it takes only the
-system under test (`horovod_tpu.Trainer`, `TransformerLM`)."""
+peaks, each model family's FLOP and byte counts and plain float32 reference
+(families/), and the comparison that decides ``correct``. From the program
+it takes only the system under test (`horovod_tpu.Trainer` and the model a
+family builds)."""

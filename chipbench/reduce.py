@@ -35,8 +35,12 @@ MODULES, OPS, ASYNC_OPS = "XLA Modules", "XLA Ops", "Async XLA Ops"
 MIN_HOST_EVENT_NS = 10_000
 MIN_OP_NS = 10
 KERNEL_MARK = 'custom_call_target="tpu_custom_call"'
+# A collective is told by the opcode in its HLO line, not by the
+# instruction's name: the all-reduce that a shard_map's psum makes is named
+# ``%psum.N``.
 COLLECTIVE = re.compile(
-    r"^(all-reduce|reduce-scatter|all-gather|all-to-all|collective-permute)")
+    r" (all-reduce|reduce-scatter|all-gather|all-to-all|collective-permute)"
+    r"(-start|-done)?\(")
 
 
 # --- the adapter -----------------------------------------------------------
@@ -180,12 +184,13 @@ def kernel_ms_per_step(chip: Chip):
 def collective_ms_per_step(chip: Chip):
     """(milliseconds per step in which a collective is in flight, the part
     of them in which no compute op runs). A collective is any event of
-    ``XLA Ops`` or ``Async XLA Ops`` whose instruction is an all-reduce,
-    reduce-scatter, all-gather, all-to-all or collective-permute (``-start``
-    and ``-done`` halves included); the intervals are united, so that an
-    async pair and its halves count once. Compute is every other leaf op."""
+    ``XLA Ops`` or ``Async XLA Ops`` whose HLO line holds the opcode
+    all-reduce, reduce-scatter, all-gather, all-to-all or collective-permute
+    (``-start`` and ``-done`` halves included); the intervals are united,
+    so that an async pair and its halves count once. Compute is every other
+    leaf op."""
     def is_collective(name):
-        return bool(COLLECTIVE.match(op_name(name)))
+        return bool(COLLECTIVE.search(name))
 
     span = clip(((s, s + d) for n, s, d in chip.ops + chip.async_ops
                  if is_collective(n)), chip.t0, chip.t1)
@@ -238,8 +243,11 @@ def idle_gaps(chip: Chip, rows, top: int = 10) -> list[list]:
 
 
 # --- readers of the per-layer metrics --------------------------------------
-# ctx: {"chips": [Chip], "rows", "model", "seq_len", "per_chip_batch",
-#       "n_chips", "device_kind", "tokens_per_s", "step_temp_bytes", "say"}
+# ctx: {"chips": [Chip], "rows", "model", "config", "family",
+#       "required_flops_per_token", "kernel_work", "seq_len",
+#       "per_chip_batch", "n_chips", "device_kind", "tokens_per_s",
+#       "step_temp_bytes", "say"}; the two counts are the family's
+#       (families/<family>.py) at the cell's shapes.
 
 def _worst(ctx, fn):
     values = [fn(chip) for chip in ctx["chips"]]
@@ -266,18 +274,23 @@ def step_temp_gb(ctx):
 def mfu(ctx):
     if ctx["tokens_per_s"] is None:
         return None
-    required = flops.required_flops_per_token(ctx["model"], ctx["seq_len"])
+    required = ctx["required_flops_per_token"]
     peak = flops.peaks(ctx["device_kind"])["flops_per_s"]
     return 100.0 * required * ctx["tokens_per_s"] / (ctx["n_chips"] * peak)
 
 
 def flash_ms_per_step(ctx):
-    """None unless every step holds three kernels a layer (forward, dQ,
+    """None unless every step holds as many Mosaic kernels as the family
+    counts flash calls (for the dense LM three a layer: forward, dQ,
     dK/dV): a count that is off means the events are not what this reader
     takes them for."""
+    if "flash" not in ctx["kernel_work"]:
+        return None
+    calls = ctx["kernel_work"]["flash"][2]
+
     def one(chip):
         ms, count = kernel_ms_per_step(chip)
-        return ms if count == 3 * ctx["model"]["n_layers"] else None
+        return ms if count == calls else None
 
     values = [one(chip) for chip in ctx["chips"]]
     if not values or None in values:
@@ -289,10 +302,9 @@ def flash_roofline(ctx):
     ms = flash_ms_per_step(ctx)
     if ms is None:
         return None
-    model, t, b = ctx["model"], ctx["seq_len"], ctx["per_chip_batch"]
+    executed, nbytes, _calls = ctx["kernel_work"]["flash"]
     least_s, bound = flops.roofline_seconds(
-        flops.flash_executed_flops_per_step(model, t, b),
-        flops.flash_bytes_per_step(model, t, b), ctx["device_kind"])
+        executed, nbytes, ctx["device_kind"])
     ctx["say"](flash_roofline_bound=bound, flash_least_ms=least_s * 1e3)
     return 100.0 * least_s * 1e3 / ms
 

@@ -4,16 +4,20 @@
 
 One process: it loads the cell's files, builds the model at its published
 widths with parameters made on the device from ``--seed``, checks it against
-the float32 reference, warms the one shape the cell uses, measures a window
-of about ``--seconds`` through `Trainer.fit(cache=None)` and prints its
-phases as JSON lines. The LAST line is the result (``correct``,
-``attempted``, ``failed``, ``metrics``, ``device``, and ``breakdown`` when
-traced): the cell's end-to-end metrics with ``--trace 0``, its per-layer
-metrics with ``--trace 1``. It needs a TPU with as many chips as the cell
-asks for and exits non-zero, with no result line, otherwise.
+its family's float32 reference, warms the one shape the cell uses, measures
+a window of about ``--seconds`` through `Trainer.fit(cache=None)` and prints
+its phases as JSON lines. The LAST line is the result (``correct``,
+``attempted``, ``failed``, ``metrics``, ``device``, ``breakdown`` when
+traced, and ``compared``: every number behind ``correct`` beside its limit,
+which are also the last lines on standard error): the cell's end-to-end
+metrics with ``--trace 0``, its per-layer metrics with ``--trace 1``. It
+needs a TPU with as many chips as the cell asks for and exits non-zero, with
+no result line, otherwise.
 
-No cell, configuration, traffic or metric is named in this file: each is a
-file found by the name `BENCHMARK.json` gives it (see README.md).
+No cell, configuration, traffic, metric or model is named in this file: each
+is a file found by the name `BENCHMARK.json` gives it, and the model, its
+reference and its counts by the ``family`` the configuration names (see
+README.md).
 """
 
 import time
@@ -40,15 +44,15 @@ from jax.sharding import PartitionSpec as P  # noqa: E402
 
 import horovod_tpu as hvt  # noqa: E402
 from chipbench import reduce, reference  # noqa: E402
-from horovod_tpu.models.transformer import (  # noqa: E402
-    ShardingConfig,
-    TransformerLM,
-)
 from horovod_tpu.parallel import sharding as sharding_lib  # noqa: E402
 from horovod_tpu.training.train_state import TrainState  # noqa: E402
 
 ROOT = pathlib.Path(__file__).resolve().parent.parent
 WARMUP_STEPS = 6  # the first compiles; the last four give the step time
+
+# What a family's file provides (README.md, "A family").
+FAMILY_API = ("sizes", "build", "per_token_loss", "required_flops_per_token",
+              "kernel_work")
 
 # The system's per-token losses against the float32 reference's, on one
 # seeded sequence at the published widths (chipbench/reference.py
@@ -68,17 +72,47 @@ def load_json(path):
         return json.load(f)
 
 
-def load_attr(path: pathlib.Path, attr: str):
-    """``attr`` of the Python file at ``path``, imported under a name of its
-    own (so a file a later PR adds is found without being a module that
-    anything here imports)."""
+def load_module(path: pathlib.Path):
+    """The Python file at ``path``, imported under a name of its own (so a
+    file a later PR adds is found without being a module that anything
+    here imports)."""
     name = "chipbench_file_" + re.sub(r"\W", "_", str(path))
     if name not in sys.modules:
         spec = importlib.util.spec_from_file_location(name, path)
         module = importlib.util.module_from_spec(spec)
         sys.modules[name] = module
-        spec.loader.exec_module(module)
-    return getattr(sys.modules[name], attr)
+        try:
+            spec.loader.exec_module(module)
+        except BaseException:
+            del sys.modules[name]
+            raise
+    return sys.modules[name]
+
+
+def load_attr(path: pathlib.Path, attr: str):
+    return getattr(load_module(path), attr)
+
+
+def load_family(root: pathlib.Path, config: dict, config_name: str):
+    """The module ``chipbench/families/<family>.py`` that the
+    configuration's ``family`` key names: the program's model at the
+    configuration's sizes, its plain reference and its counts."""
+    family = config.get("family")
+    if not family:
+        raise KeyError(
+            f'configuration {config_name!r} has no "family" key: it names '
+            "the file chipbench/families/<family>.py that builds and counts "
+            "the model, and there is no default")
+    path = root / "chipbench" / "families" / f"{family}.py"
+    if not path.is_file():
+        raise FileNotFoundError(
+            f'configuration {config_name!r} says "family": {family!r}, and '
+            f"{path} is not there")
+    module = load_module(path)
+    missing = [f for f in FAMILY_API if not callable(getattr(module, f, None))]
+    if missing:
+        raise AttributeError(f"family {family!r} ({path}) lacks {missing}")
+    return module
 
 
 def named(entries, name, what):
@@ -108,46 +142,20 @@ def load_cell(root: pathlib.Path, name: str) -> dict:
 
     return {
         "name": name, "workload": workload, "config": config,
+        "family": load_family(root, config, entry["config"]),
         "traffic": traffic, "chips": entry["chips"],
         "end_to_end": [m for m in bench["end_to_end"] if reported(m)],
         "per_layer": [m for m in bench["per_layer"] if reported(m)],
     }
 
 
-def model_sizes(config: dict) -> dict:
-    """The configuration's published keys under the names the repository's
-    LM takes, through the file's own ``maps_to``."""
-    def get(key):
-        source = config["maps_to"].get(key)
-        return None if source is None else config[source]
-
-    sizes = {key: get(key) for key in (
-        "vocab_size", "d_model", "n_heads", "n_kv_heads", "n_layers",
-        "d_ff", "window", "max_positions")}
-    if sizes["n_kv_heads"] == sizes["n_heads"]:
-        sizes["n_kv_heads"] = None
-    if sizes["d_ff"] != 4 * sizes["d_model"]:
-        raise ValueError(
-            "the repository's block has an MLP of 4x width; this "
-            f"configuration asks for {sizes['d_ff']} at d_model "
-            f"{sizes['d_model']}, and no width is ever changed")
-    return sizes
-
-
 def build_trainer(cell: dict, devices, seed: int):
-    """The system under test: the repository's LM at the configuration's
+    """The system under test: the family's model at the configuration's
     sizes under the repository's Trainer, on a mesh over ``devices``."""
-    sizes, spec = model_sizes(cell["config"]), cell["workload"]["trainer"]
+    spec = cell["workload"]["trainer"]
     mesh = hvt.build_mesh(
         hvt.MeshSpec(**cell["workload"]["mesh"]), devices=devices)
-    model = TransformerLM(
-        vocab_size=sizes["vocab_size"], d_model=sizes["d_model"],
-        n_heads=sizes["n_heads"], n_kv_heads=sizes["n_kv_heads"],
-        window=sizes["window"], n_layers=sizes["n_layers"], dropout=0.0,
-        compute_dtype=jnp.dtype(spec["compute_dtype"]),
-        fused_head_chunks=spec["fused_head_chunks"],
-        sharding=ShardingConfig(mesh=mesh),
-    )
+    model = cell["family"].build(cell["config"], spec, mesh)
     optimizer = getattr(optax, spec["optimizer"])(spec["learning_rate"])
     return hvt.Trainer(
         model,
@@ -161,18 +169,29 @@ def init_state(trainer, seq_len: int):
     """Parameters and optimizer state made on the device from the trainer's
     seed in ONE jitted program (which the compile cache keeps), replicated
     over the mesh, and handed to the trainer: `Trainer.build` would make
-    them op by op, uncached."""
+    them op by op, uncached. The initialised variables are treated as
+    `Trainer.build` treats them: parameters kept; what a layer sows anew
+    in every step dropped (``losses``, and ``metrics`` after their names
+    are noted for the step's accumulator); a collection that carries state
+    from step to step refused, because this init does not carry it."""
     tokens = jnp.zeros((trainer.dp_size, seq_len), jnp.int32)
+    sown_metrics = set()  # filled while `init` is traced, by name only
 
     def init(key):
         init_rng, dropout_rng, state_rng = jax.random.split(key, 3)
         variables = trainer.module.init(
             {"params": init_rng, "dropout": dropout_rng}, tokens,
             train=False, labels=tokens)
-        if set(variables) != {"params"}:
+        carried = sorted(set(variables) - {"params", "losses", "metrics"})
+        if carried:
             raise ValueError(
-                f"the model carries {sorted(variables)}: this init knows "
-                "parameters only")
+                f"the model carries {carried} from step to step: this init "
+                "knows parameters and sown losses and metrics only")
+        for path, _ in jax.tree_util.tree_flatten_with_path(
+                variables.get("metrics", {}))[0]:
+            names = [p.key for p in path
+                     if isinstance(p, jax.tree_util.DictKey)]
+            sown_metrics.update(names[-1:])
         params = variables["params"]
         return TrainState(
             step=jnp.zeros((), jnp.int32), params=params,
@@ -182,11 +201,12 @@ def init_state(trainer, seq_len: int):
     trainer.state = jax.jit(
         init, out_shardings=sharding_lib.replicated(trainer.mesh)
     )(jax.random.PRNGKey(trainer.seed))
+    trainer._metric_names = tuple(sorted(sown_metrics))
     return jax.block_until_ready(trainer.state)
 
 
-def reference_check(trainer, sizes, x, y, row: int) -> dict:
-    """The system's forward pass against chipbench/reference.py on sequence
+def reference_check(trainer, cell, x, y, row: int) -> dict:
+    """The system's forward pass against its family's reference on sequence
     ``row`` (the system needs a row per chip, the reference takes one)."""
     rows = [(row + i) % len(x) for i in range(trainer.dp_size)]
 
@@ -197,8 +217,7 @@ def reference_check(trainer, sizes, x, y, row: int) -> dict:
 
     got = jax.jit(model_loss)(trainer.state.params, x[rows], y[rows])
     want = jax.jit(functools.partial(
-        reference.per_token_loss, n_layers=sizes["n_layers"],
-        window=sizes["window"],
+        cell["family"].per_token_loss, config=cell["config"],
     ))(trainer.state.params, x[row], y[row])
     report = reference.compare(got, want)
     report["ok"] = (report["rel_rms"] <= REL_RMS_TOL
@@ -229,16 +248,20 @@ def replicas_agree(trainer) -> bool:
     return bool(same)
 
 
+def lowered_step(trainer, x, y, batch: int):
+    """The train step as `Trainer.fit` calls it, lowered for the trainer's
+    state and ``batch`` rows a chip."""
+    n = batch * trainer.dp_size
+    return trainer._train_step_donated.lower(
+        trainer.state, trainer._shard((x[:n], y[:n])),
+        jnp.asarray(1.0, jnp.float32),
+        sharding_lib.replicate(trainer.zero_metrics(), trainer.mesh))
+
+
 def step_temp_bytes(trainer, x, y, batch: int) -> int:
     """Temporaries of the compiled train step, which this runtime's
     ``peak_bytes_in_use`` leaves out."""
-    n = batch * trainer.dp_size
-    args = (
-        trainer.state, trainer._shard((x[:n], y[:n])),
-        jnp.asarray(1.0, jnp.float32),
-        sharding_lib.replicate(trainer.zero_metrics(), trainer.mesh),
-    )
-    compiled = trainer._train_step_donated.lower(*args).compile()
+    compiled = lowered_step(trainer, x, y, batch).compile()
     return int(compiled.memory_analysis().temp_size_in_bytes)
 
 
@@ -247,8 +270,6 @@ class CompileWatch:
     ``counting`` is set. (JAX keeps its listeners for the life of the
     process, so one watch serves a process.)"""
     def __init__(self):
-        import jax.monitoring
-
         self.counting = False
         self.seen: list[str] = []
         jax.monitoring.register_event_duration_secs_listener(self._on)
@@ -257,10 +278,6 @@ class CompileWatch:
         if self.counting and ("compile" in event or "compilation" in event):
             self.seen.append(event)
 
-
-def make_callbacks(hvt):
-    """The benchmark's two `Trainer.fit` callbacks (made here because the
-    base class is the program's)."""
 
 class Warmup(hvt.callbacks.Callback):
     """Waits for the first step (which compiles), the second and the
@@ -311,18 +328,25 @@ class Window(hvt.callbacks.Callback):
 
 def traced_context(cell, sizes, trace_dir, device_kind, temp_bytes, say):
     """What the per-layer readers get: the trace's rows, the chips' steady
-    stretches, and the sizes the FLOP counts need. ``tokens_per_s`` is the
-    device's own rate over the stretch (steps start to start), because the
-    traced window also holds the profiler's start and stop."""
+    stretches, the configuration with its family, and the family's counts
+    at the cell's shapes. ``tokens_per_s`` is the device's own rate over the
+    stretch (steps start to start), because the traced window also holds
+    the profiler's start and stop."""
     path, = glob.glob(f"{trace_dir}/plugins/profile/*/*.xplane.pb")
     rows = reduce.rows_from_xplane(path)
     chips = reduce.chips_from_rows(rows)
     workload, traffic = cell["workload"], cell["traffic"]
     data = workload["mesh"].get("data", 1)
+    config, family = cell["config"], cell["family"]
+    seq_len = traffic["seq_len"]
+    per_chip_batch = traffic["global_batch"] // data
     ctx = {
         "rows": rows, "chips": chips, "model": sizes,
-        "seq_len": traffic["seq_len"],
-        "per_chip_batch": traffic["global_batch"] // data,
+        "config": config, "family": family,
+        "required_flops_per_token": family.required_flops_per_token(
+            config, seq_len),
+        "kernel_work": family.kernel_work(config, seq_len, per_chip_batch),
+        "seq_len": seq_len, "per_chip_batch": per_chip_batch,
         "n_chips": cell["chips"], "device_kind": device_kind,
         "step_temp_bytes": temp_bytes, "say": say, "tokens_per_s": None,
     }
@@ -374,7 +398,7 @@ def main(argv=None, *, root: pathlib.Path = ROOT, require_tpu=True) -> int:
     say(workload=cell["name"], seed=args.seed, seconds=args.seconds,
         trace=args.trace, compilation_cache_dir=cache_dir)
     watch = CompileWatch()
-    sizes, traffic = model_sizes(cell["config"]), cell["traffic"]
+    sizes, traffic = cell["family"].sizes(cell["config"]), cell["traffic"]
     seq_len, global_batch = traffic["seq_len"], traffic["global_batch"]
     if sizes["max_positions"] and seq_len > sizes["max_positions"]:
         raise ValueError(f"seq_len {seq_len} is beyond the configuration's "
@@ -392,9 +416,9 @@ def main(argv=None, *, root: pathlib.Path = ROOT, require_tpu=True) -> int:
     make = load_attr(
         root / "chipbench" / "traffic" / f"{traffic['kind']}.py", "make")
     x, y = make(args.seed, traffic, sizes["vocab_size"])
-    reference = reference_check(
-        trainer, sizes, x, y, row=args.seed % len(x))
-    say(phase="reference", reference_s=since(t), **reference,
+    agreement = reference_check(
+        trainer, cell, x, y, row=args.seed % len(x))
+    say(phase="reference", reference_s=since(t), **agreement,
         rel_rms_tol=REL_RMS_TOL, mean_abs_tol=MEAN_ABS_TOL,
         bias_tol=BIAS_TOL)
 
@@ -432,22 +456,38 @@ def main(argv=None, *, root: pathlib.Path = ROOT, require_tpu=True) -> int:
     stats = [d.memory_stats() or {} for d in devices]
     peak_bytes = max(s.get("peak_bytes_in_use", 0) for s in stats) + temp_bytes
     median_ms = statistics.median(intervals)
+    longest = max(range(len(intervals)), key=intervals.__getitem__)
     say(phase="window", steps=n_steps, window_s=window_s,
         step_ms_samples=len(intervals), step_ms_median=median_ms,
         # stamps made while the host ran ahead of the device
         step_ms_under_half_median=sum(i < median_ms / 2 for i in intervals),
+        # where a window that reads long lost its time: before the first
+        # stamp, in one interval, or draining the steps still in flight
+        first_stamp_ms=(window.stamps[0] - window.t_open) * 1e3,
+        step_ms_max=intervals[longest], step_ms_max_at=longest + 1,
+        drain_ms=(window.t_close - window.stamps[-1]) * 1e3,
         peak_bytes_in_use=[s.get("peak_bytes_in_use") for s in stats],
         bytes_limit=[s.get("bytes_limit") for s in stats],
         compiles_in_window=watch.seen)
     say(losses=losses)
     gates = {
-        "reference_agrees": reference["ok"],
+        "reference_agrees": agreement["ok"],
         "ran_every_step": len(losses) == n_steps,
         "every_loss_finite": failed == 0,
         "no_compile_in_window": not watch.seen,
         "replicas_agree": replicas_agree(trainer),
     }
     say(gates=gates)
+    # Each number the gates compared, beside its limit.
+    compared = {
+        "rel_rms": [agreement["rel_rms"], REL_RMS_TOL],
+        "mean_abs_diff": [agreement["mean_abs_diff"], MEAN_ABS_TOL],
+        "bias": [agreement["bias"], BIAS_TOL],
+        "steps_not_run": [n_steps - len(losses), 0],
+        "losses_not_finite": [failed, 0],
+        "compiles_in_window": [len(watch.seen), 0],
+        "replicas_differ": [int(not gates["replicas_agree"]), 0],
+    }
 
     run = {"n_steps": n_steps, "tokens_per_step": global_batch * seq_len,
            "window_s": window_s, "intervals_ms": intervals,
@@ -456,8 +496,6 @@ def main(argv=None, *, root: pathlib.Path = ROOT, require_tpu=True) -> int:
               "failed": failed}
     device["memory_peak_bytes"] = peak_bytes
     if args.trace:
-        from chipbench import reduce
-
         ctx = traced_context(cell, sizes, trace_dir, device["kind"],
                              temp_bytes, say)
         chips = ctx["chips"]
@@ -490,7 +528,10 @@ def main(argv=None, *, root: pathlib.Path = ROOT, require_tpu=True) -> int:
                         "unit": m["unit"]}
             for m in cell["end_to_end"]}
     result["metrics"], result["device"] = metrics, device
+    result["compared"] = compared
     print(json.dumps(result), flush=True)
+    for name, (value, limit) in compared.items():
+        print(f"compared {name} {value!r} limit {limit!r}", file=sys.stderr)
     return 0
 
 

@@ -289,7 +289,7 @@ def phase_of(hlo_line: str, scopes: dict) -> str:
         return "head + CE"
     if OPTIMIZER_SCOPE in op_name:
         return "optimizer"
-    if reduce.COLLECTIVE.match(reduce.op_name(hlo_line)):
+    if reduce.COLLECTIVE.search(hlo_line):
         return "collectives"
     path = scope_path(op_name)
     if not path:
@@ -410,10 +410,15 @@ def optimizer_ms_per_step(ctx):
 
 
 def _kernel_metric(ctx, which):
-    """None unless the kernel is there once a layer a step."""
+    """None unless the kernel is there as often a step as the family
+    counts its calls (for the dense LM once a layer)."""
+    work = ctx["kernel_work"].get(f"flash_{which}")
+    if work is None:
+        return None
+
     def one(chip):
         ms, count = kernel_ms_per_step(chip, which)
-        return ms if count == ctx["model"]["n_layers"] else None
+        return ms if count == work[2] else None
 
     return _worst(ctx, one)
 

@@ -26,7 +26,9 @@ ROOT = pathlib.Path(__file__).resolve().parents[2]
 HERE = pathlib.Path(__file__).resolve().parent
 BENCH = json.loads((ROOT / "BENCHMARK.json").read_text())
 CELLS = [w["name"] for w in BENCH["workloads"]]
-RESULT_KEYS = {"correct", "attempted", "failed", "metrics", "device"}
+RESULT_KEYS = {"correct", "attempted", "failed", "metrics", "device",
+               "compared"}
+DENSE_LM = run.load_module(ROOT / "chipbench" / "families" / "dense_lm.py")
 
 
 def rewrite(path, change):
@@ -79,8 +81,9 @@ def test_runner_end_to_end_at_toy_width(toy_root, capsys, cell):
     assert [l["phase"] for l in lines if "phase" in l] == [
         "build", "reference", "warmup", "window"]
     result = lines[-1]
-    assert set(result) == RESULT_KEYS
+    assert set(result) == RESULT_KEYS and list(result)[-1] == "compared"
     assert result["correct"] is True and result["failed"] == 0
+    assert all(value <= limit for value, limit in result["compared"].values())
     assert result["attempted"] == len(lines[-3]["losses"]) >= 4
     assert set(result["metrics"]) == {m["name"] for m in BENCH["end_to_end"]}
     assert all(m["value"] > 0 for m in result["metrics"].values())
@@ -142,6 +145,285 @@ def test_additions_are_found_by_name_with_no_edit(toy_root, capsys):
                for p, digest in before.items())
 
 
+# A family the harness has never seen, as a later PR would bring it: a small
+# flax LM the program does not have (RMSNorm, a gated SiLU MLP, heads of 24
+# on a width of 64, an auxiliary loss and a metric sown in every layer), its
+# plain reference and its own counts, all in one new file.
+TOY_FAMILY = '''
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+
+
+def sizes(config):
+    return {"vocab_size": config["vocab_size"],
+            "max_positions": config["max_position_embeddings"],
+            "attention_layers": config["num_hidden_layers"]}
+
+
+class GatedLM(nn.Module):
+    vocab: int
+    width: int
+    heads: int
+    head_dim: int
+    layers: int
+    mlp: int
+
+    @nn.compact
+    def __call__(self, tokens, train=False, labels=None):
+        x = nn.Embed(self.vocab, self.width, name="embed")(tokens)
+        t = tokens.shape[1]
+        seen = jnp.tril(jnp.ones((t, t), bool))
+        for n in range(self.layers):
+            h = nn.RMSNorm(name=f"norm_attn_{n}")(x)
+            q, k, v = (nn.DenseGeneral((self.heads, self.head_dim),
+                                       use_bias=False, name=f"{w}_{n}")(h)
+                       for w in "qkv")
+            scores = jnp.einsum("bthd,bshd->bhts", q, k) / jnp.sqrt(
+                float(self.head_dim))
+            probs = jax.nn.softmax(jnp.where(seen, scores, -jnp.inf), -1)
+            out = jnp.einsum("bhts,bshd->bthd", probs, v)
+            x = x + nn.DenseGeneral(self.width, axis=(-2, -1),
+                                    use_bias=False, name=f"o_{n}")(out)
+            h = nn.RMSNorm(name=f"norm_mlp_{n}")(x)
+            gate = nn.Dense(self.mlp, use_bias=False, name=f"gate_{n}")(h)
+            up = nn.Dense(self.mlp, use_bias=False, name=f"up_{n}")(h)
+            x = x + nn.Dense(self.width, use_bias=False,
+                             name=f"down_{n}")(nn.silu(gate) * up)
+            self.sow("losses", f"gate_penalty_{n}", 1e-3 * jnp.mean(gate ** 2))
+            self.sow("metrics", "gate_rms", jnp.sqrt(jnp.mean(gate ** 2)))
+        logits = nn.Dense(self.vocab, use_bias=False, name="head")(
+            nn.RMSNorm(name="norm_out")(x))
+        if labels is None:
+            return logits
+        picked = jnp.take_along_axis(logits, labels[..., None], -1)[..., 0]
+        loss = jax.nn.logsumexp(logits, -1) - picked
+        return loss, (jnp.argmax(logits, -1) == labels).astype(jnp.float32)
+
+
+def build(config, trainer_spec, mesh):
+    return GatedLM(
+        vocab=config["vocab_size"], width=config["hidden_size"],
+        heads=config["num_attention_heads"], head_dim=config["head_dim"],
+        layers=config["num_hidden_layers"], mlp=config["intermediate_size"])
+
+
+def _rms_norm(x, scale):
+    return x / jnp.sqrt((x ** 2).mean(-1, keepdims=True) + 1e-6) * scale
+
+
+def per_token_loss(params, tokens, labels, config):
+    with jax.default_matmul_precision("highest"):
+        p = jax.tree.map(lambda a: a.astype(jnp.float32), params)
+        x = p["embed"]["embedding"][tokens]
+        t = tokens.shape[0]
+        seen = jnp.arange(t)[None, :] <= jnp.arange(t)[:, None]
+        for n in range(config["num_hidden_layers"]):
+            h = _rms_norm(x, p[f"norm_attn_{n}"]["scale"])
+            q, k, v = (jnp.einsum("td,dhe->the", h, p[f"{w}_{n}"]["kernel"])
+                       for w in "qkv")
+            scores = jnp.einsum("the,she->hts", q, k) / jnp.sqrt(
+                float(config["head_dim"]))
+            probs = jax.nn.softmax(jnp.where(seen, scores, -jnp.inf), -1)
+            out = jnp.einsum("hts,she->the", probs, v)
+            x = x + jnp.einsum("the,hed->td", out, p[f"o_{n}"]["kernel"])
+            h = _rms_norm(x, p[f"norm_mlp_{n}"]["scale"])
+            gate = h @ p[f"gate_{n}"]["kernel"]
+            x = x + (jax.nn.silu(gate) * (h @ p[f"up_{n}"]["kernel"])
+                     ) @ p[f"down_{n}"]["kernel"]
+        logits = _rms_norm(x, p["norm_out"]["scale"]) @ p["head"]["kernel"]
+        picked = jnp.take_along_axis(logits, labels[:, None], -1)[:, 0]
+        return jax.nn.logsumexp(logits, -1) - picked
+
+
+def required_flops_per_token(config, seq_len):
+    d, width = config["hidden_size"], (
+        config["num_attention_heads"] * config["head_dim"])
+    layer = 4 * d * width + 3 * d * config["intermediate_size"]
+    params = config["num_hidden_layers"] * layer + d * config["vocab_size"]
+    pairs = seq_len * (seq_len + 1) // 2
+    dots = 6 * 2 * pairs * width * config["num_hidden_layers"]
+    return 6.0 * params + dots / seq_len
+
+
+def kernel_work(config, seq_len, per_chip_batch):
+    return {}  # dense attention: no kernel of its own
+'''
+TOY_CONFIG = {
+    "source": "a test", "family": "gated_toy", "vocab_size": 96,
+    "hidden_size": 64, "num_attention_heads": 2, "head_dim": 24,
+    "num_hidden_layers": 2, "intermediate_size": 160,
+    "max_position_embeddings": 64, "reduced": {}}
+# 2 layers of (q, k, v, o: 4·64·48; gate, up, down: 3·64·160) and the head
+# 64·96; 6 dots · 2 · pairs · (2 heads · 24) · 2 layers over the sequence
+TOY_MATMUL_PARAMS = 2 * (4 * 64 * 48 + 3 * 64 * 160) + 64 * 96
+TOY_REQUIRED = 6 * TOY_MATMUL_PARAMS + 6 * 2 * (64 * 65 // 2) * 48 * 2 / 64
+
+
+def add_toy_configuration(toy_root, config=TOY_CONFIG):
+    """The new files and `BENCHMARK.json` entries of a cell `toy-gated.seq64`
+    of a configuration of another family, and of a per-layer metric that
+    reads the family's count from ``ctx``."""
+    here = toy_root / "chipbench"
+    (here / "families" / "gated_toy.py").write_text(TOY_FAMILY)
+    (here / "configs" / "toy-gated.json").write_text(json.dumps(config))
+    workload = json.loads((here / "workloads" / f"{CELLS[0]}.json").read_text())
+    workload["config"] = "toy-gated"
+    (here / "workloads" / "toy-gated.seq64.json").write_text(
+        json.dumps(workload))
+    (here / "layer_metrics" / "required_kflops_per_token.json").write_text(
+        json.dumps({"name": "required_kflops_per_token", "unit": "kFLOP",
+                    "reader": "layer_metrics/required_kflops_per_token.py:read",
+                    "what": "the family's own count, from the published keys"}))
+    (here / "layer_metrics" / "required_kflops_per_token.py").write_text(
+        "def read(ctx):\n"
+        "    own = ctx['family'].required_flops_per_token(\n"
+        "        ctx['config'], ctx['seq_len'])\n"
+        "    assert own == ctx['required_flops_per_token']\n"
+        "    assert ctx['kernel_work'] == {}\n"
+        "    return own / 1e3\n")
+
+    def add(bench):
+        bench["configs"].append(
+            {"name": "toy-gated", "source": "a test",
+             "file": "chipbench/configs/toy-gated.json", "reduced": [],
+             "why": "test"})
+        bench["workloads"].append(
+            {"name": "toy-gated.seq64", "config": "toy-gated",
+             "traffic": workload["traffic"], "chips": 1, "why": "test"})
+        bench["per_layer"].append(
+            {"name": "required_kflops_per_token", "unit": "kFLOP",
+             "better": "lower", "source": "program_counter",
+             "layer": "model", "moves": "tokens_per_s",
+             "workloads": ["toy-gated.seq64"]})
+
+    rewrite(toy_root / "BENCHMARK.json", add)
+
+
+@pytest.mark.parametrize("trace", [0, 1])
+def test_a_configuration_of_a_new_family_arrives_as_files(
+        toy_root, capsys, trace):
+    """The builder, the reference and the counts of an architecture the
+    harness has never seen are one new file that the configuration names;
+    no file that was there changes."""
+    before = {p: hashlib.sha256(p.read_bytes()).hexdigest()
+              for p in (toy_root / "chipbench").rglob("*") if p.is_file()}
+    add_toy_configuration(toy_root)
+    rc, lines = run_cell(toy_root, "toy-gated.seq64", trace, capsys,
+                         require_tpu=False)
+    assert rc == 0
+    result = lines[-1]
+    assert result["correct"] is True and result["failed"] == 0
+    agreement, = (l for l in lines if l.get("phase") == "reference")
+    # float32 against float32, on the family's own reference
+    assert agreement["rel_rms"] < 1e-4 and agreement["ok"] is True
+    norm_scales = (2 * 2 + 1) * 64
+    assert lines[1]["n_params"] == TOY_MATMUL_PARAMS + 96 * 64 + norm_scales
+    if trace:
+        assert result["metrics"]["required_kflops_per_token"]["value"] == (
+            pytest.approx(TOY_REQUIRED / 1e3))
+        # No kernel of the family's and no TPU plane: nothing to read.
+        assert not {"flash_ms_per_step", "flash_roofline", "mfu",
+                    "flash_fwd_ms_per_step"} & set(result["metrics"])
+    else:
+        assert set(result["metrics"]) == {
+            m["name"] for m in BENCH["end_to_end"]}
+    assert all(hashlib.sha256(p.read_bytes()).hexdigest() == digest
+               for p, digest in before.items())
+
+
+def test_mfu_divides_the_familys_own_count():
+    ctx = {"tokens_per_s": 2e6, "required_flops_per_token": TOY_REQUIRED,
+           "n_chips": 4, "device_kind": "TPU v5 lite"}
+    assert reduce.mfu(ctx) == pytest.approx(
+        100 * TOY_REQUIRED * 2e6 / (4 * 197e12))
+    assert reduce.mfu(ctx | {"tokens_per_s": None}) is None
+    assert reduce.flash_ms_per_step({"kernel_work": {}, "chips": []}) is None
+    assert reduce.flash_roofline({"kernel_work": {}, "chips": []}) is None
+
+
+@pytest.mark.parametrize("family,error,says", [
+    (None, KeyError, '"family" key'),
+    ("", KeyError, '"family" key'),
+    ("no_such_family", FileNotFoundError, "no_such_family.py is not there"),
+])
+def test_a_configuration_names_a_family_that_is_there(
+        toy_root, capsys, family, error, says):
+    config = {k: v for k, v in TOY_CONFIG.items() if k != "family"}
+    if family is not None:
+        config["family"] = family
+    add_toy_configuration(toy_root, config)
+    with pytest.raises(error, match=says) as raised:
+        run_cell(toy_root, "toy-gated.seq64", 0, capsys, require_tpu=False)
+    assert "toy-gated" in str(raised.value)
+
+
+def test_a_family_lacking_a_function_is_refused_by_name(toy_root):
+    path = toy_root / "chipbench" / "families" / "half.py"
+    path.write_text("def sizes(config):\n    return {}\nbuild = sizes\n")
+    with pytest.raises(AttributeError, match="kernel_work"):
+        run.load_family(toy_root, {"family": "half"}, "x")
+
+
+def test_init_refuses_a_collection_carried_from_step_to_step():
+    import types
+
+    import flax.linen as nn
+
+    class Counts(nn.Module):
+        @nn.compact
+        def __call__(self, tokens, train=False, labels=None):
+            seen = self.variable("counters", "seen", jnp.zeros, ())
+            seen.value += 1
+            return nn.Embed(8, 4)(tokens).sum(-1), tokens
+
+    import horovod_tpu as hvt
+
+    mesh = hvt.build_mesh(hvt.MeshSpec(data=1), devices=jax.devices()[:1])
+    trainer = types.SimpleNamespace(
+        dp_size=1, module=Counts(), seed=0, tx=None, mesh=mesh)
+    with pytest.raises(ValueError, match="carries .'counters'. from step"):
+        run.init_state(trainer, seq_len=4)
+
+
+@pytest.mark.parametrize("cell", CELLS)
+def test_dense_lm_builds_the_step_program_the_parent_spelled_out(
+        toy_root, cell):
+    """`run.build_trainer` of PR 27 constructed `TransformerLM` itself, with
+    these ten arguments; through the family the module is equal to it and
+    the train step lowers to the same text."""
+    import types
+
+    from horovod_tpu.models.transformer import ShardingConfig, TransformerLM
+
+    loaded = run.load_cell(toy_root, cell)
+    family, config = loaded["family"], loaded["config"]
+    sizes = family.sizes(config)
+
+    def as_the_parent_did(config, spec, mesh):
+        return TransformerLM(
+            vocab_size=sizes["vocab_size"], d_model=sizes["d_model"],
+            n_heads=sizes["n_heads"], n_kv_heads=sizes["n_kv_heads"],
+            window=sizes["window"], n_layers=sizes["n_layers"], dropout=0.0,
+            compute_dtype=jnp.dtype(spec["compute_dtype"]),
+            fused_head_chunks=spec["fused_head_chunks"],
+            sharding=ShardingConfig(mesh=mesh))
+
+    traffic = loaded["traffic"]
+    x, y = copy_task.make(5, traffic, sizes["vocab_size"])
+    texts, modules = [], []
+    for build in (family.build, as_the_parent_did):
+        trainer = run.build_trainer(
+            loaded | {"family": types.SimpleNamespace(build=build)},
+            jax.devices()[:loaded["chips"]], seed=5)
+        run.init_state(trainer, traffic["seq_len"])
+        batch = traffic["global_batch"] // trainer.dp_size
+        texts.append(run.lowered_step(trainer, x, y, batch).as_text())
+        modules.append(trainer.module)
+    assert modules[0] == modules[1]
+    assert texts[0] == texts[1] and "stablehlo" in texts[0]
+
+
 def test_replicas_agree_sees_one_chip_off_by_one_bit():
     import types
 
@@ -200,10 +482,13 @@ def test_reduction_of_the_recorded_cut(cut):
 
 
 def test_leaves_and_exposed_collective_by_hand():
-    """Four ops on one chip between two steps' edges: a matmul 0-60, an
+    """Five ops on one chip between two steps' edges: a matmul 0-60, an
     async all-reduce 40-100 (its start and done halves on the op line, the
-    pair on the async line), a fusion 70-80. The collective is in flight
-    for 60; compute covers 40-60 and 70-80 of it; 30 is exposed."""
+    pair on the async line), a fusion 70-80, and at 80-90 the all-reduce
+    that a shard_map's psum makes, which XLA names after the JAX primitive
+    and which is told by its opcode. A collective is in flight for 60;
+    compute covers 40-60 and 70-80 of it; 30 is exposed (20, were the psum
+    taken for compute by its name, as the fusion that only reads it is)."""
     dev, mod = "/device:TPU:0", "jit_step(1)"
     rows = [(dev, reduce.MODULES, mod, s, 100.0)
             for s in (-100.0, 0.0, 100.0, 200.0)]
@@ -215,20 +500,23 @@ def test_leaves_and_exposed_collective_by_hand():
             (dev, reduce.OPS, "%marker.1 = () custom-call()", base, 0.0),
             (dev, reduce.OPS, "%all-reduce-start.1 = () all-reduce-start()",
              base + 40.0, 0.0),
-            (dev, reduce.ASYNC_OPS, "%all-reduce-start.1 = ()",
+            (dev, reduce.ASYNC_OPS,
+             "%all-reduce-start.1 = () all-reduce-start()",
              base + 40.0, 60.0),
-            (dev, reduce.OPS, "%fusion.2 = f32[] fusion(), kind=kLoop",
-             base + 70.0, 10.0),
+            (dev, reduce.OPS, "%fusion.2 = f32[] fusion(f32[8]{0} %psum.1, "
+             "f32[] %all-reduce-done.1), kind=kLoop", base + 70.0, 10.0),
+            (dev, reduce.OPS, "%psum.1 = f32[8]{0} all-reduce(f32[8]{0} "
+             "%dot.1), to_apply=%add", base + 80.0, 10.0),
             (dev, reduce.OPS, "%all-reduce-done.1 = () all-reduce-done()",
              base + 90.0, 10.0),
         ]
     chip, = reduce.chips_from_rows(rows)
-    assert [reduce.op_name(n) for n, _, _ in chip.ops[:3]] == [
-        "dot.1", "fusion.2", "all-reduce-done.1"]  # no while, no marker
+    assert [reduce.op_name(n) for n, _, _ in chip.ops[:4]] == [
+        "dot.1", "fusion.2", "psum.1", "all-reduce-done.1"]  # no while, marker
     total, exposed = reduce.collective_ms_per_step(chip)
     assert total * 1e6 == pytest.approx(60.0)
     assert exposed * 1e6 == pytest.approx(30.0)
-    assert chip.busy_ns() / chip.stretch_ns == pytest.approx(0.8)
+    assert chip.busy_ns() / chip.stretch_ns == pytest.approx(0.9)
     assert chip.gaps_ns() == [0.0]
     assert reduce.union_ns([(0, 2), (1, 3), (5, 6)]) == 4
 
@@ -247,15 +535,25 @@ def test_leaves_and_exposed_collective_by_hand():
 ])
 def test_flops_against_hand_counts(name, seq_len, params, attn6, pairs):
     entry = run.named(BENCH["configs"], name, "config")
-    model = run.model_sizes(json.loads((ROOT / entry["file"]).read_text()))
+    config = json.loads((ROOT / entry["file"]).read_text())
+    model = DENSE_LM.sizes(config)
     assert flops.visible_pairs(seq_len, model["window"]) == pairs
-    assert flops.matmul_params(model) == params
-    required = flops.required_flops_per_token(model, seq_len)
+    assert DENSE_LM.matmul_params(config) == params
+    required = DENSE_LM.required_flops_per_token(config, seq_len)
     assert required == pytest.approx(6 * params + attn6 / seq_len)
-    executed = flops.flash_executed_flops_per_step(model, seq_len, batch=1)
+    work = DENSE_LM.kernel_work(config, seq_len, per_chip_batch=1)
+    executed, nbytes, calls = work["flash"]
     assert executed == pytest.approx(attn6 * 9 / 6)  # 9 dots, not 6
-    assert flops.head_flops_per_step(model, 10, executed=True) == (
-        pytest.approx(flops.head_flops_per_step(model, 10, executed=False)
+    # 17 [B, T, H·D] bf16 arrays a layer; three kernels a layer
+    layers, width = model["n_layers"], model["d_model"]
+    assert nbytes == 17 * seq_len * width * 2 * layers
+    assert calls == 3 * layers == 3 * model["attention_layers"]
+    parts = [work[f"flash_{k}"] for k in ("fwd", "dq", "dkv")]
+    assert [sum(p[i] for p in parts) for i in range(3)] == [
+        pytest.approx(executed), nbytes, calls]
+    assert [p[0] / parts[0][0] for p in parts] == [1.0, 1.5, 2.0]
+    assert DENSE_LM.head_flops_per_step(config, 10, executed=True) == (
+        pytest.approx(DENSE_LM.head_flops_per_step(config, 10, executed=False)
                       * 8 / 6))
     # A window shorter than the sequence: rows see 1, 2, 3, 3, 3 keys.
     assert flops.visible_pairs(5, 3) == 12
@@ -268,27 +566,29 @@ def test_flops_against_hand_counts(name, seq_len, params, attn6, pairs):
 @pytest.mark.parametrize("n_kv_heads,window", [(None, None), (2, 8)],
                          ids=["mha", "gqa-window"])
 def test_reference_matches_the_system_at_toy_width(n_kv_heads, window):
-    from horovod_tpu.models.transformer import TransformerLM
-
-    model = TransformerLM(
-        vocab_size=64, d_model=32, n_heads=4, n_kv_heads=n_kv_heads,
-        window=window, n_layers=2, dropout=0.0, fused_head_chunks=2)
+    config = {"v": 64, "d": 32, "h": 4, "kv": n_kv_heads or 4, "l": 2,
+              "ff": 128, "w": window, "maps_to": {
+                  "vocab_size": "v", "d_model": "d", "n_heads": "h",
+                  "n_kv_heads": "kv", "n_layers": "l", "d_ff": "ff",
+                  "window": "w" if window else None, "max_positions": None}}
+    model = DENSE_LM.build(
+        config, {"compute_dtype": "float32", "fused_head_chunks": 2}, None)
+    assert (model.n_kv_heads, model.window) == (n_kv_heads, window)
     x, y = (jnp.asarray(a) for a in copy_task.make(
         3, {"seq_len": 32, "n_sequences": 1}, 64))
     key = jax.random.PRNGKey(0)
     variables = model.init({"params": key, "dropout": key}, x, train=False,
                            labels=y)
     got, _ = model.apply(variables, x, train=False, labels=y)
-    want = reference.per_token_loss(
-        variables["params"], x[0], y[0], n_layers=2, window=window)
+    want = DENSE_LM.per_token_loss(variables["params"], x[0], y[0], config)
     # float32 against float32: rounding in another order, nothing more.
     np.testing.assert_allclose(got[0], want, atol=2e-5)
     report = reference.compare(got[0], want)
     assert report["rel_rms"] < 1e-4 and report["mean_abs_diff"] < 1e-5
     # ... and the comparison sees a model that drops the mask's window.
     if window:
-        unmasked = reference.per_token_loss(
-            variables["params"], x[0], y[0], n_layers=2, window=None)
+        unmasked = DENSE_LM.per_token_loss(
+            variables["params"], x[0], y[0], config | {"w": None})
         assert reference.compare(unmasked, want)["rel_rms"] > run.REL_RMS_TOL
 
 
@@ -329,7 +629,7 @@ def test_benchmark_json_is_consistent_with_the_files():
         data = json.loads((ROOT / config["file"]).read_text())
         assert set(config["reduced"]) == set(data["reduced"])
         assert all(name_ok.match(k) and k in data for k in config["reduced"])
-        run.model_sizes(data)
+        run.load_family(ROOT, data, config["name"]).sizes(data)
     for cell in CELLS:
         loaded = run.load_cell(ROOT, cell)
         assert name_ok.match(loaded["workload"]["traffic"])

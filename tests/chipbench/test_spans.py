@@ -37,7 +37,10 @@ def context(cut, **changes):
     said = []
     ctx = {
         "rows": rows, "chips": reduce.chips_from_rows(rows),
-        "model": {"n_layers": cut["n_layers"]},
+        "kernel_work": {
+            "flash": (0.0, 0.0, 3 * cut["n_layers"]),
+            **{f"flash_{k}": (0.0, 0.0, cut["n_layers"])
+               for k in ("fwd", "dq", "dkv")}},
         "say": lambda **fields: said.append(fields), "said": said,
         "spans": {"scopes": dict(cut["scopes"]),
                   "host": [tuple(s) for s in cut["host"]]},
@@ -90,7 +93,10 @@ def test_kernel_reader_on_the_recorded_cut(cut, which):
     assert spans.kernel_ms_per_step(chip, which)[1] == (
         cut["expected"]["kernels_per_step"][which])
     # A model of another depth: the events are not what they are taken for.
-    ctx["model"] = {"n_layers": cut["n_layers"] + 1}
+    ctx["kernel_work"][f"flash_{which}"] = (0.0, 0.0, cut["n_layers"] + 1)
+    assert read_metric(f"flash_{which}_ms_per_step", ctx) is None
+    # A family that counts no such kernel: nothing to read.
+    del ctx["kernel_work"][f"flash_{which}"]
     assert read_metric(f"flash_{which}_ms_per_step", ctx) is None
 
 
@@ -240,6 +246,13 @@ def test_phase_precedence_by_hand():
     # that no scope of the program claims is a collective.
     assert spans.phase_of(gather, {gather: head}) == "head + CE"
     assert spans.phase_of(gather, {}) == "collectives"
+    # ... told by its opcode: a shard_map's psum is an all-reduce by another
+    # name, and an op that only reads a collective's result is not one.
+    psum = "%psum.1 = f32[8]{0} all-reduce(f32[8]{0} %p), to_apply=%add"
+    reader = "%fusion.2 = f32[8]{0} fusion(f32[8]{0} %all-reduce.3), kind=kLoop"
+    assert spans.phase_of(psum, {psum: head}) == "head + CE"
+    assert spans.phase_of(psum, {}) == "collectives"
+    assert spans.phase_of(reader, {}) == "unattributed"
     assert spans.phase_of(kernel, {kernel: head}) == "flash"
     assert spans.kernel_of(kernel) == spans.kernel_of(bare) == "dq"
     assert spans.kernel_of(gather) is None
