@@ -35,6 +35,11 @@ MODULES, OPS, ASYNC_OPS = "XLA Modules", "XLA Ops", "Async XLA Ops"
 MIN_HOST_EVENT_NS = 10_000
 MIN_OP_NS = 10
 KERNEL_MARK = 'custom_call_target="tpu_custom_call"'
+# The flash kernels, by the name ops/flash_attention.py gives `pallas_call`,
+# which the compiled HLO instruction takes (``%hvt_flash_fwd.3 = ...``). A
+# Mosaic call of any other name is another kernel's, with a reader of its own.
+FLASH_KERNELS = {"fwd": "hvt_flash_fwd", "dq": "hvt_flash_dq",
+                 "dkv": "hvt_flash_dkv"}
 # A collective is told by the opcode in its HLO line, not by the
 # instruction's name: the all-reduce that a shard_map's psum makes is named
 # ``%psum.N``.
@@ -175,9 +180,27 @@ def chips_from_rows(rows) -> list[Chip]:
     return chips
 
 
-def kernel_ms_per_step(chip: Chip):
-    """(milliseconds per step in Mosaic kernels, their count per step)."""
-    hits = [d for n, _, d in chip.ops if KERNEL_MARK in n]
+def flash_kernel_of(hlo_line: str):
+    """Which flash kernel an event is, by its instruction's name: "fwd",
+    "dq", "dkv", or None (no Mosaic call, or one of another name). The name
+    is the kernel's own (``hvt_flash_fwd.3``) or, for a call that no scope
+    of the caller wraps, the kernel's under the transformations' prefixes
+    (``transpose_jvp_hvt_flash_dq__.1``); a kernel whose name only begins
+    like one of the three (``hvt_flash_fwd_ring``) is another kernel."""
+    if KERNEL_MARK not in hlo_line:
+        return None
+    name = re.sub(r"(\.\d+)+$", "", op_name(hlo_line)).rstrip("_")
+    for key, kernel in FLASH_KERNELS.items():
+        if name == kernel or name.endswith("_" + kernel):
+            return key
+    return None
+
+
+def flash_kernel_ms_per_step(chip: Chip, which: str | None = None):
+    """(milliseconds a step in the flash kernel ``which``, events a step);
+    without ``which``, in the three flash kernels together."""
+    hits = [d for n, _, d in chip.ops
+            if flash_kernel_of(n) in ((which,) if which else FLASH_KERNELS)]
     return sum(hits) / 1e6 / len(chip.steps), len(hits) / len(chip.steps)
 
 
@@ -280,16 +303,17 @@ def mfu(ctx):
 
 
 def flash_ms_per_step(ctx):
-    """None unless every step holds as many Mosaic kernels as the family
-    counts flash calls (for the dense LM three a layer: forward, dQ,
-    dK/dV): a count that is off means the events are not what this reader
-    takes them for."""
+    """The three flash kernels together, told by their names. None unless
+    every step holds as many of them as the family counts flash calls (for
+    the dense LM three a layer: forward, dQ, dK/dV): a count that is off
+    means the events are not what this reader takes them for. A Mosaic call
+    of another name is not counted and does not void the reading."""
     if "flash" not in ctx["kernel_work"]:
         return None
     calls = ctx["kernel_work"]["flash"][2]
 
     def one(chip):
-        ms, count = kernel_ms_per_step(chip)
+        ms, count = flash_kernel_ms_per_step(chip)
         return ms if count == calls else None
 
     values = [one(chip) for chip in ctx["chips"]]

@@ -50,7 +50,8 @@ from horovod_tpu.training.train_state import TrainState  # noqa: E402
 ROOT = pathlib.Path(__file__).resolve().parent.parent
 WARMUP_STEPS = 6  # the first compiles; the last four give the step time
 
-# What a family's file provides (README.md, "A family").
+# What a family's file provides (README.md, "A family"); it may also state
+# ``LIMITS`` and ``FAR_OFF`` (see `limits_of`).
 FAMILY_API = ("sizes", "build", "per_token_loss", "required_flops_per_token",
               "kernel_work")
 
@@ -65,6 +66,14 @@ FAMILY_API = ("sizes", "build", "per_token_loss", "required_flops_per_token",
 REL_RMS_TOL = 0.04
 MEAN_ABS_TOL = 0.03
 BIAS_TOL = 1e-3
+# ... which hold for every family that states no ``LIMITS`` of its own.
+DEFAULT_LIMITS = {"rel_rms": REL_RMS_TOL, "mean_abs_diff": MEAN_ABS_TOL,
+                  "bias": BIAS_TOL}
+# Of what `reference.compare` reports, the numbers that every token moves
+# or that half of them do: a ``LIMITS`` holds its family to one at least.
+# `far_off_share` alone would pass a fault that lifts no token past the
+# family's threshold, `bias` alone one whose errors cancel.
+WHOLE_SEQUENCE = ("rel_rms", "mean_abs_diff", "median_abs_diff")
 
 
 def load_json(path):
@@ -115,6 +124,38 @@ def load_family(root: pathlib.Path, config: dict, config_name: str):
     return module
 
 
+def limits_of(family) -> dict:
+    """{report name: limit} that `reference.compare`'s report of a cell of
+    ``family`` is held to: `DEFAULT_LIMITS` where the family's file states
+    no ``LIMITS``. Where it states one (an arithmetic the three constants
+    were not measured on, such as a routed model's, sets its own from its
+    own seeds and control, each with its measurement written beside it),
+    that, with `BIAS_TOL` on ``bias`` unless it states another: the family
+    file comes from the PR whose `correct` it decides, so what it may not
+    do is refused here by name, before anything runs (`load_cell` asks): a
+    key that `compare` does not report; a ``LIMITS`` with none of
+    `WHOLE_SEQUENCE`; ``far_off_share`` with no ``FAR_OFF`` (the
+    difference, in the loss's units, beyond which a token counts as far
+    off)."""
+    limits = getattr(family, "LIMITS", None)
+    if limits is None:
+        return dict(DEFAULT_LIMITS)
+    unknown = sorted(set(limits) - set(reference.REPORTED))
+    if unknown:
+        fault = (f"names {unknown}, which chipbench/reference.py `compare` "
+                 f"does not report; it reports {list(reference.REPORTED)}")
+    elif not set(limits) & set(WHOLE_SEQUENCE):
+        fault = (f"names {sorted(limits)} and none of {list(WHOLE_SEQUENCE)}"
+                 ", one of which a lower-precision control has to fail")
+    elif "far_off_share" in limits and not hasattr(family, "FAR_OFF"):
+        fault = ("names 'far_off_share', and the file states no FAR_OFF "
+                 "(how far off a token's loss is far off)")
+    else:
+        return {"bias": BIAS_TOL} | {
+            name: float(limit) for name, limit in limits.items()}
+    raise ValueError(f"{family.__file__}: LIMITS {fault}")
+
+
 def named(entries, name, what):
     for entry in entries:
         if entry["name"] == name:
@@ -140,9 +181,10 @@ def load_cell(root: pathlib.Path, name: str) -> dict:
     def reported(metric):
         return name in metric.get("workloads", [name])
 
+    family = load_family(root, config, entry["config"])
     return {
         "name": name, "workload": workload, "config": config,
-        "family": load_family(root, config, entry["config"]),
+        "family": family, "limits": limits_of(family),
         "traffic": traffic, "chips": entry["chips"],
         "end_to_end": [m for m in bench["end_to_end"] if reported(m)],
         "per_layer": [m for m in bench["per_layer"] if reported(m)],
@@ -219,10 +261,11 @@ def reference_check(trainer, cell, x, y, row: int) -> dict:
     want = jax.jit(functools.partial(
         cell["family"].per_token_loss, config=cell["config"],
     ))(trainer.state.params, x[row], y[row])
-    report = reference.compare(got, want)
-    report["ok"] = (report["rel_rms"] <= REL_RMS_TOL
-                    and report["mean_abs_diff"] <= MEAN_ABS_TOL
-                    and report["bias"] <= BIAS_TOL)
+    report = reference.compare(
+        got, want, far_off=getattr(cell["family"], "FAR_OFF", None))
+    report["limits"] = cell["limits"]
+    report["ok"] = all(
+        report[name] <= limit for name, limit in cell["limits"].items())
     return report
 
 
@@ -368,7 +411,6 @@ def main(argv=None, *, root: pathlib.Path = ROOT, require_tpu=True) -> int:
     args = parser.parse_args(argv)
     cell = load_cell(root, args.workload)
 
-
     cache_dir = hvt.runtime.use_compilation_cache()
     # Keep every program, however quick its compile, so that a second run
     # finds all of them.
@@ -418,9 +460,7 @@ def main(argv=None, *, root: pathlib.Path = ROOT, require_tpu=True) -> int:
     x, y = make(args.seed, traffic, sizes["vocab_size"])
     agreement = reference_check(
         trainer, cell, x, y, row=args.seed % len(x))
-    say(phase="reference", reference_s=since(t), **agreement,
-        rel_rms_tol=REL_RMS_TOL, mean_abs_tol=MEAN_ABS_TOL,
-        bias_tol=BIAS_TOL)
+    say(phase="reference", reference_s=since(t), **agreement)
 
     t = time.perf_counter()
     fit = functools.partial(
@@ -480,9 +520,8 @@ def main(argv=None, *, root: pathlib.Path = ROOT, require_tpu=True) -> int:
     say(gates=gates)
     # Each number the gates compared, beside its limit.
     compared = {
-        "rel_rms": [agreement["rel_rms"], REL_RMS_TOL],
-        "mean_abs_diff": [agreement["mean_abs_diff"], MEAN_ABS_TOL],
-        "bias": [agreement["bias"], BIAS_TOL],
+        **{name: [agreement[name], limit]
+           for name, limit in agreement["limits"].items()},
         "steps_not_run": [n_steps - len(losses), 0],
         "losses_not_finite": [failed, 0],
         "compiles_in_window": [len(watch.seen), 0],

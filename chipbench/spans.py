@@ -14,7 +14,10 @@ same trace and on the same clock:
   training/trainer.py `train_step`);
 * kernel names, `pallas_call(name=)`: ``hvt_flash_fwd``, ``hvt_flash_dq``,
   ``hvt_flash_dkv`` (ops/flash_attention.py), which the compiled HLO
-  instruction takes (``%hvt_flash_fwd.3 = ... custom-call(...)``).
+  instruction takes (``%hvt_flash_fwd.3 = ... custom-call(...)``;
+  `reduce.flash_kernel_of` tells them). A Mosaic call of another name is
+  one of the phase "other kernels" until a reader of its own asks for it by
+  that name.
 
 Where this runtime puts them (seen by hand in PR 25's v5e traces and in
 PR 26's first traced run): a host span is an event of a thread's line of
@@ -53,11 +56,10 @@ from chipbench import reduce
 ROOT = pathlib.Path(__file__).resolve().parent.parent
 SPAN_PREFIX = "hvt."
 HEAD_SCOPE, OPTIMIZER_SCOPE = "hvt.head_ce", "hvt.optimizer"
-KERNELS = {"fwd": "hvt_flash_fwd", "dq": "hvt_flash_dq",
-           "dkv": "hvt_flash_dkv"}
 LOOP_SPANS = ("hvt.input_wait", "hvt.step", "hvt.callbacks")
-PHASES = ("blocks forward", "blocks backward", "flash", "head + CE",
-          "optimizer", "collectives", "other named", "unattributed")
+PHASES = ("blocks forward", "blocks backward", "flash", "other kernels",
+          "head + CE", "optimizer", "collectives", "other named",
+          "unattributed")
 
 
 # --- the adapter -----------------------------------------------------------
@@ -262,28 +264,17 @@ def scope_path(op_name: str) -> str:
     return "/".join(parts[:-1])
 
 
-def kernel_of(hlo_line: str):
-    """Which flash kernel an event is, by its instruction's name: "fwd",
-    "dq", "dkv" or None."""
-    if reduce.KERNEL_MARK not in hlo_line:
-        return None
-    name = reduce.op_name(hlo_line)
-    for key, kernel in KERNELS.items():
-        if kernel in name:
-            return key
-    return None
-
-
 def phase_of(hlo_line: str, scopes: dict) -> str:
     """The one phase a leaf op belongs to, by what its own metadata names
-    (for a fusion, whatever the compiler kept): a Mosaic kernel is flash;
-    then the program's scopes; then a collective instruction no scope
-    claims; then the flax scopes of the blocks, backward where the path
-    holds ``transpose(``; then whatever else has a scope (embedding,
-    final norm, the loss's mean); and "unattributed" for an op with no
-    scope path at all."""
+    (for a fusion, whatever the compiler kept): a Mosaic kernel is flash
+    where it has a flash kernel's name and one of the "other kernels"
+    where it has not; then the program's scopes; then a collective
+    instruction no scope claims; then the flax scopes of the blocks,
+    backward where the path holds ``transpose(``; then whatever else has a
+    scope (embedding, final norm, the loss's mean); and "unattributed" for
+    an op with no scope path at all."""
     if reduce.KERNEL_MARK in hlo_line:
-        return "flash"
+        return "flash" if reduce.flash_kernel_of(hlo_line) else "other kernels"
     op_name = scopes.get(hlo_line, "")
     if HEAD_SCOPE in op_name:
         return "head + CE"
@@ -333,6 +324,8 @@ def scope_table(chip, scopes, floor_ms: float = 0.3):
             sub = "backward" if "transpose(" in op_name else "forward"
         elif phase.startswith("blocks") or phase == "other named":
             sub = sub_scope(op_name)
+        elif phase == "other kernels":  # which: the `pallas_call`'s name
+            sub = re.sub(r"[.\d]+$", "", reduce.op_name(name))
         else:
             sub = ""
         key = (phase, sub)
@@ -358,12 +351,6 @@ def unattributed_families(chip, scopes, top: int = 8):
             total[family] = total.get(family, 0.0) + dur
     ranked = sorted(total, key=total.get, reverse=True)[:top]
     return [[f, total[f] / 1e6 / len(chip.steps)] for f in ranked]
-
-
-def kernel_ms_per_step(chip, which: str):
-    """(milliseconds a step in the flash kernel ``which``, events a step)."""
-    hits = [d for n, _, d in chip.ops if kernel_of(n) == which]
-    return sum(hits) / 1e6 / len(chip.steps), len(hits) / len(chip.steps)
 
 
 # --- readers of the per-layer metrics --------------------------------------
@@ -417,7 +404,7 @@ def _kernel_metric(ctx, which):
         return None
 
     def one(chip):
-        ms, count = kernel_ms_per_step(chip, which)
+        ms, count = reduce.flash_kernel_ms_per_step(chip, which)
         return ms if count == work[2] else None
 
     return _worst(ctx, one)

@@ -24,11 +24,45 @@ from chipbench.traffic import copy_task
 
 ROOT = pathlib.Path(__file__).resolve().parents[2]
 HERE = pathlib.Path(__file__).resolve().parent
-BENCH = json.loads((ROOT / "BENCHMARK.json").read_text())
-CELLS = [w["name"] for w in BENCH["workloads"]]
 RESULT_KEYS = {"correct", "attempted", "failed", "metrics", "device",
                "compared"}
+GATES = {"steps_not_run", "losses_not_finite", "compiles_in_window",
+         "replicas_differ"}
 DENSE_LM = run.load_module(ROOT / "chipbench" / "families" / "dense_lm.py")
+
+
+def bench_of(root):
+    return json.loads((root / "BENCHMARK.json").read_text())
+
+
+def cells_of(root, family=None):
+    """The names of the cells in ``root``'s `BENCHMARK.json`; with
+    ``family``, of those whose configuration's file names it."""
+    bench = bench_of(root)
+
+    def family_of(cell):
+        entry = run.named(bench["configs"], cell["config"], "config")
+        return json.loads((root / entry["file"]).read_text()).get("family")
+
+    return [w["name"] for w in bench["workloads"]
+            if family is None or family_of(w) == family]
+
+
+BENCH = bench_of(ROOT)
+CELLS = cells_of(ROOT)
+DENSE_CELLS = cells_of(ROOT, "dense_lm")
+
+
+# What the two dense configurations shrink to: what these tests have always
+# run them at (`shrink_config` of PRs 25-28 wrote the same).
+DENSE_TOYS = {
+    "cerebras-gpt-1.3b": {
+        "n_embd": 64, "n_inner": 256, "n_layer": 2, "vocab_size": 128,
+        "n_head": 4},
+    "starcoder2-3b": {
+        "hidden_size": 64, "intermediate_size": 256, "num_hidden_layers": 2,
+        "vocab_size": 128, "num_attention_heads": 4,
+        "num_key_value_heads": 2, "sliding_window": 64}}
 
 
 def rewrite(path, change):
@@ -37,59 +71,89 @@ def rewrite(path, change):
     path.write_text(json.dumps(data))
 
 
-@pytest.fixture
-def toy_root(tmp_path):
-    """A copy of the benchmark with every configuration at toy width (64
-    wide, 2 layers, 128 tokens of vocabulary), sequences of 64, float32
-    compute (the reference tolerance is set for the published widths)."""
-    shutil.copytree(ROOT / "chipbench", tmp_path / "chipbench")
-    shutil.copy(ROOT / "BENCHMARK.json", tmp_path)
-
-    def shrink_config(c):
-        m = c["maps_to"]
-        c[m["d_model"]], c[m["d_ff"]], c[m["n_layers"]] = 64, 256, 2
-        c[m["vocab_size"]], c[m["n_heads"]] = 128, 4
-        if m["n_kv_heads"] != m["n_heads"]:
-            c[m["n_kv_heads"]] = 2
-        if m["window"]:
-            c[m["window"]] = 64
-
-    for path in (tmp_path / "chipbench" / "configs").glob("*.json"):
-        rewrite(path, shrink_config)
-    for path in (tmp_path / "chipbench" / "traffic").glob("*.json"):
+def shrink_to_toy(root):
+    """Every configuration under ``root`` at the toy sizes its own file
+    gives under ``toy`` (published key -> toy value: for the two dense
+    ones 64 wide, FFN 256, 2 layers, 128 rows of vocabulary, 4 heads, 2 KV
+    heads and a window of 64 where they have them), sequences of 64,
+    float32 compute (the reference tolerance is set for the published
+    widths). A configuration's family knows which of its keys are sizes,
+    these tests do not: a file without ``toy`` is an error."""
+    for path in sorted((root / "chipbench" / "configs").glob("*.json")):
+        config = json.loads(path.read_text())
+        if "toy" not in config:
+            raise ValueError(
+                f'{path} has no "toy" key: the tests run every '
+                "configuration at toy sizes on the CPU, and the file says "
+                "which of its published keys shrink, and to what")
+        unknown = sorted(set(config["toy"]) - set(config))
+        if unknown:
+            raise ValueError(f'{path}: "toy" names {unknown}, which the file '
+                             "does not have")
+        path.write_text(json.dumps(config | config["toy"]))
+    for path in (root / "chipbench" / "traffic").glob("*.json"):
         rewrite(path, lambda t: t.update(
             seq_len=64, n_sequences=4 * t["global_batch"]))
-    for path in (tmp_path / "chipbench" / "workloads").glob("*.json"):
+    for path in (root / "chipbench" / "workloads").glob("*.json"):
         rewrite(path, lambda w: w["trainer"].update(compute_dtype="float32"))
+
+
+@pytest.fixture
+def bench_copy(tmp_path):
+    """A copy of the benchmark as it is committed."""
+    shutil.copytree(ROOT / "chipbench", tmp_path / "chipbench")
+    shutil.copy(ROOT / "BENCHMARK.json", tmp_path)
     floor = jax.config.jax_persistent_cache_min_compile_time_secs
     yield tmp_path
     jax.config.update("jax_persistent_cache_min_compile_time_secs", floor)
 
 
-def run_cell(root, cell, trace, capsys, **kwargs):
+@pytest.fixture
+def toy_root(bench_copy):
+    """... with every configuration at toy width (`shrink_to_toy`)."""
+    shrink_to_toy(bench_copy)
+    return bench_copy
+
+
+def hashes(root):
+    return {p: hashlib.sha256(p.read_bytes()).hexdigest()
+            for p in (root / "chipbench").rglob("*") if p.is_file()}
+
+
+def run_cell(root, cell, trace, capsys, stderr=None, **kwargs):
+    """(exit code, the JSON lines of standard output); the lines of
+    standard error are appended to ``stderr`` where a list is given."""
     rc = run.main(["--workload", cell, "--seed", "2147483659", "--seconds",
                    "0.2", "--trace", str(trace)], root=root, **kwargs)
-    lines = [json.loads(line)
-             for line in capsys.readouterr().out.splitlines() if line]
-    return rc, lines
+    captured = capsys.readouterr()
+    if stderr is not None:
+        stderr += captured.err.splitlines()
+    return rc, [json.loads(line) for line in captured.out.splitlines() if line]
 
 
-@pytest.mark.parametrize("cell", CELLS)
-def test_runner_end_to_end_at_toy_width(toy_root, capsys, cell):
-    rc, lines = run_cell(toy_root, cell, 0, capsys, require_tpu=False)
+def check_runner_end_to_end(root, cell, capsys):
+    rc, lines = run_cell(root, cell, 0, capsys, require_tpu=False)
     assert rc == 0
     assert [l["phase"] for l in lines if "phase" in l] == [
         "build", "reference", "warmup", "window"]
     result = lines[-1]
     assert set(result) == RESULT_KEYS and list(result)[-1] == "compared"
     assert result["correct"] is True and result["failed"] == 0
+    loaded = run.load_cell(root, cell)
+    assert {name: limit for name, (_, limit) in result["compared"].items()
+            } == loaded["limits"] | dict.fromkeys(GATES, 0)
     assert all(value <= limit for value, limit in result["compared"].values())
     assert result["attempted"] == len(lines[-3]["losses"]) >= 4
-    assert set(result["metrics"]) == {m["name"] for m in BENCH["end_to_end"]}
+    assert set(result["metrics"]) == {m["name"] for m in loaded["end_to_end"]}
     assert all(m["value"] > 0 for m in result["metrics"].values())
     assert set(result["device"]) == {
         "platform", "kind", "count", "memory_peak_bytes"}
     assert result["device"]["platform"] == "cpu"  # and so not a device number
+
+
+@pytest.mark.parametrize("cell", CELLS)
+def test_runner_end_to_end_at_toy_width(toy_root, capsys, cell):
+    check_runner_end_to_end(toy_root, cell, capsys)
 
 
 def test_runner_refuses_a_cpu(capsys):
@@ -102,8 +166,7 @@ def test_additions_are_found_by_name_with_no_edit(toy_root, capsys):
     """A cell, a traffic kind and a per-layer metric arrive as new files
     and `BENCHMARK.json` entries; no file that was there changes."""
     here = toy_root / "chipbench"
-    before = {p: hashlib.sha256(p.read_bytes()).hexdigest()
-              for p in here.rglob("*") if p.is_file()}
+    before = hashes(toy_root)
     (here / "traffic" / "ramp.py").write_text(
         "import numpy as np\n"
         "def make(seed, params, vocab_size):\n"
@@ -141,119 +204,24 @@ def test_additions_are_found_by_name_with_no_edit(toy_root, capsys):
     # A CPU trace has no TPU plane: readers that find nothing say nothing.
     assert "step_device_ms" not in result["metrics"]
     assert "step_temp_gb" in result["metrics"]
-    assert all(hashlib.sha256(p.read_bytes()).hexdigest() == digest
-               for p, digest in before.items())
+    assert before.items() <= hashes(toy_root).items()
 
 
-# A family the harness has never seen, as a later PR would bring it: a small
-# flax LM the program does not have (RMSNorm, a gated SiLU MLP, heads of 24
-# on a width of 64, an auxiliary loss and a metric sown in every layer), its
-# plain reference and its own counts, all in one new file.
-TOY_FAMILY = '''
-import flax.linen as nn
-import jax
-import jax.numpy as jnp
-
-
-def sizes(config):
-    return {"vocab_size": config["vocab_size"],
-            "max_positions": config["max_position_embeddings"],
-            "attention_layers": config["num_hidden_layers"]}
-
-
-class GatedLM(nn.Module):
-    vocab: int
-    width: int
-    heads: int
-    head_dim: int
-    layers: int
-    mlp: int
-
-    @nn.compact
-    def __call__(self, tokens, train=False, labels=None):
-        x = nn.Embed(self.vocab, self.width, name="embed")(tokens)
-        t = tokens.shape[1]
-        seen = jnp.tril(jnp.ones((t, t), bool))
-        for n in range(self.layers):
-            h = nn.RMSNorm(name=f"norm_attn_{n}")(x)
-            q, k, v = (nn.DenseGeneral((self.heads, self.head_dim),
-                                       use_bias=False, name=f"{w}_{n}")(h)
-                       for w in "qkv")
-            scores = jnp.einsum("bthd,bshd->bhts", q, k) / jnp.sqrt(
-                float(self.head_dim))
-            probs = jax.nn.softmax(jnp.where(seen, scores, -jnp.inf), -1)
-            out = jnp.einsum("bhts,bshd->bthd", probs, v)
-            x = x + nn.DenseGeneral(self.width, axis=(-2, -1),
-                                    use_bias=False, name=f"o_{n}")(out)
-            h = nn.RMSNorm(name=f"norm_mlp_{n}")(x)
-            gate = nn.Dense(self.mlp, use_bias=False, name=f"gate_{n}")(h)
-            up = nn.Dense(self.mlp, use_bias=False, name=f"up_{n}")(h)
-            x = x + nn.Dense(self.width, use_bias=False,
-                             name=f"down_{n}")(nn.silu(gate) * up)
-            self.sow("losses", f"gate_penalty_{n}", 1e-3 * jnp.mean(gate ** 2))
-            self.sow("metrics", "gate_rms", jnp.sqrt(jnp.mean(gate ** 2)))
-        logits = nn.Dense(self.vocab, use_bias=False, name="head")(
-            nn.RMSNorm(name="norm_out")(x))
-        if labels is None:
-            return logits
-        picked = jnp.take_along_axis(logits, labels[..., None], -1)[..., 0]
-        loss = jax.nn.logsumexp(logits, -1) - picked
-        return loss, (jnp.argmax(logits, -1) == labels).astype(jnp.float32)
-
-
-def build(config, trainer_spec, mesh):
-    return GatedLM(
-        vocab=config["vocab_size"], width=config["hidden_size"],
-        heads=config["num_attention_heads"], head_dim=config["head_dim"],
-        layers=config["num_hidden_layers"], mlp=config["intermediate_size"])
-
-
-def _rms_norm(x, scale):
-    return x / jnp.sqrt((x ** 2).mean(-1, keepdims=True) + 1e-6) * scale
-
-
-def per_token_loss(params, tokens, labels, config):
-    with jax.default_matmul_precision("highest"):
-        p = jax.tree.map(lambda a: a.astype(jnp.float32), params)
-        x = p["embed"]["embedding"][tokens]
-        t = tokens.shape[0]
-        seen = jnp.arange(t)[None, :] <= jnp.arange(t)[:, None]
-        for n in range(config["num_hidden_layers"]):
-            h = _rms_norm(x, p[f"norm_attn_{n}"]["scale"])
-            q, k, v = (jnp.einsum("td,dhe->the", h, p[f"{w}_{n}"]["kernel"])
-                       for w in "qkv")
-            scores = jnp.einsum("the,she->hts", q, k) / jnp.sqrt(
-                float(config["head_dim"]))
-            probs = jax.nn.softmax(jnp.where(seen, scores, -jnp.inf), -1)
-            out = jnp.einsum("hts,she->the", probs, v)
-            x = x + jnp.einsum("the,hed->td", out, p[f"o_{n}"]["kernel"])
-            h = _rms_norm(x, p[f"norm_mlp_{n}"]["scale"])
-            gate = h @ p[f"gate_{n}"]["kernel"]
-            x = x + (jax.nn.silu(gate) * (h @ p[f"up_{n}"]["kernel"])
-                     ) @ p[f"down_{n}"]["kernel"]
-        logits = _rms_norm(x, p["norm_out"]["scale"]) @ p["head"]["kernel"]
-        picked = jnp.take_along_axis(logits, labels[:, None], -1)[:, 0]
-        return jax.nn.logsumexp(logits, -1) - picked
-
-
-def required_flops_per_token(config, seq_len):
-    d, width = config["hidden_size"], (
-        config["num_attention_heads"] * config["head_dim"])
-    layer = 4 * d * width + 3 * d * config["intermediate_size"]
-    params = config["num_hidden_layers"] * layer + d * config["vocab_size"]
-    pairs = seq_len * (seq_len + 1) // 2
-    dots = 6 * 2 * pairs * width * config["num_hidden_layers"]
-    return 6.0 * params + dots / seq_len
-
-
-def kernel_work(config, seq_len, per_chip_batch):
-    return {}  # dense attention: no kernel of its own
-'''
-TOY_CONFIG = {
-    "source": "a test", "family": "gated_toy", "vocab_size": 96,
-    "hidden_size": 64, "num_attention_heads": 2, "head_dim": 24,
-    "num_hidden_layers": 2, "intermediate_size": 160,
-    "max_position_embeddings": 64, "reduced": {}}
+# A family the harness has never seen, as a later PR would bring it
+# (gated_toy.py beside this file), and a configuration of it: the keys as
+# "published", and under ``toy`` what the tests run.
+TOY_PUBLISHED = {
+    "source": "a test", "family": "gated_toy", "vocab_size": 4096,
+    "hidden_size": 512, "num_attention_heads": 8, "head_dim": 64,
+    "num_hidden_layers": 4, "intermediate_size": 1280,
+    "max_position_embeddings": 2048, "reduced": {},
+    "toy": {"vocab_size": 96, "hidden_size": 64, "num_attention_heads": 2,
+            "head_dim": 24, "num_hidden_layers": 2, "intermediate_size": 160,
+            "max_position_embeddings": 64}}
+TOY_CONFIG = TOY_PUBLISHED | TOY_PUBLISHED["toy"]  # as `shrink_to_toy` leaves it
+GATED_TOY = run.load_module(HERE / "gated_toy.py")
+# ... held to its own limits and, stating none on the bias, to run.py's.
+TOY_LIMITS = {"bias": run.BIAS_TOL} | GATED_TOY.LIMITS
 # 2 layers of (q, k, v, o: 4·64·48; gate, up, down: 3·64·160) and the head
 # 64·96; 6 dots · 2 · pairs · (2 heads · 24) · 2 layers over the sequence
 TOY_MATMUL_PARAMS = 2 * (4 * 64 * 48 + 3 * 64 * 160) + 64 * 96
@@ -265,9 +233,10 @@ def add_toy_configuration(toy_root, config=TOY_CONFIG):
     of a configuration of another family, and of a per-layer metric that
     reads the family's count from ``ctx``."""
     here = toy_root / "chipbench"
-    (here / "families" / "gated_toy.py").write_text(TOY_FAMILY)
+    shutil.copy(HERE / "gated_toy.py", here / "families")
     (here / "configs" / "toy-gated.json").write_text(json.dumps(config))
-    workload = json.loads((here / "workloads" / f"{CELLS[0]}.json").read_text())
+    workload = json.loads(
+        (here / "workloads" / f"{DENSE_CELLS[0]}.json").read_text())
     workload["config"] = "toy-gated"
     (here / "workloads" / "toy-gated.seq64.json").write_text(
         json.dumps(workload))
@@ -306,8 +275,7 @@ def test_a_configuration_of_a_new_family_arrives_as_files(
     """The builder, the reference and the counts of an architecture the
     harness has never seen are one new file that the configuration names;
     no file that was there changes."""
-    before = {p: hashlib.sha256(p.read_bytes()).hexdigest()
-              for p in (toy_root / "chipbench").rglob("*") if p.is_file()}
+    before = hashes(toy_root)
     add_toy_configuration(toy_root)
     rc, lines = run_cell(toy_root, "toy-gated.seq64", trace, capsys,
                          require_tpu=False)
@@ -315,8 +283,11 @@ def test_a_configuration_of_a_new_family_arrives_as_files(
     result = lines[-1]
     assert result["correct"] is True and result["failed"] == 0
     agreement, = (l for l in lines if l.get("phase") == "reference")
-    # float32 against float32, on the family's own reference
+    # float32 against float32, on the family's own reference and held to
+    # the family's own limits
     assert agreement["rel_rms"] < 1e-4 and agreement["ok"] is True
+    assert agreement["limits"] == TOY_LIMITS
+    assert set(result["compared"]) == set(TOY_LIMITS) | GATES
     norm_scales = (2 * 2 + 1) * 64
     assert lines[1]["n_params"] == TOY_MATMUL_PARAMS + 96 * 64 + norm_scales
     if trace:
@@ -328,8 +299,124 @@ def test_a_configuration_of_a_new_family_arrives_as_files(
     else:
         assert set(result["metrics"]) == {
             m["name"] for m in BENCH["end_to_end"]}
-    assert all(hashlib.sha256(p.read_bytes()).hexdigest() == digest
-               for p, digest in before.items())
+    assert before.items() <= hashes(toy_root).items()
+
+
+def test_a_cell_of_another_family_needs_no_edit_to_these_tests(
+        bench_copy, capsys):
+    """What the next `model_config` PR does, in a copy of the benchmark as
+    it is committed: a family, a configuration with its ``toy`` sizes, a
+    workload and a per-layer metric arrive as new files and
+    `BENCHMARK.json` entries BEFORE anything is shrunk. Every test of this
+    file that runs over the cells then holds for the new cell too, or
+    leaves it alone because its configuration names another family."""
+    before = hashes(bench_copy)
+    add_toy_configuration(bench_copy, TOY_PUBLISHED)
+    assert before.items() <= hashes(bench_copy).items()
+    shrink_to_toy(bench_copy)
+    assert json.loads((bench_copy / "chipbench" / "configs" /
+                       "toy-gated.json").read_text()) == TOY_CONFIG
+    assert cells_of(bench_copy) == CELLS + ["toy-gated.seq64"]
+    assert cells_of(bench_copy, "dense_lm") == DENSE_CELLS
+    check_benchmark_json_is_consistent(bench_copy)
+    check_runner_end_to_end(bench_copy, "toy-gated.seq64", capsys)
+    # ... and the cells that were there run as before beside it.
+    check_dense_lm_builds_the_parents_step_program(bench_copy, DENSE_CELLS[0])
+
+
+@pytest.mark.parametrize("toy,says", [
+    (None, 'no "toy" key'),
+    ({"hidden_size": 64, "n_embd": 64}, r"names \['n_embd'\], which the file does not have"),
+], ids=["missing", "names-a-key-the-file-lacks"])
+def test_a_configuration_says_how_it_shrinks(bench_copy, toy, says):
+    config = {k: v for k, v in TOY_PUBLISHED.items() if k != "toy"}
+    if toy is not None:
+        config["toy"] = toy
+    add_toy_configuration(bench_copy, config)
+    with pytest.raises(ValueError, match=says) as raised:
+        shrink_to_toy(bench_copy)
+    assert "toy-gated.json" in str(raised.value)
+
+
+# The reference's mask without its diagonal (row 0 keeps its one key): in
+# every row, which moves every token and so the median; in the last eight
+# rows of 64, which leaves the 56 tokens before them where they were: the
+# median does not see it, the far-off share counts it (and the bias sees it
+# on the seeds where the eight do not cancel: 10 of 14).
+MASK = "jnp.arange(t)[None, :] <= jnp.arange(t)[:, None]"
+MASK_FAULTS = {
+    "every-row": (MASK.replace(
+        "jnp.arange(t)[:, None]",
+        "jnp.maximum(jnp.arange(t)[:, None] - 1, 0)"), "median_abs_diff"),
+    "last-eight-rows": (
+        MASK + " - (jnp.arange(t)[:, None] >= t - 8)", "far_off_share"),
+}
+
+
+@pytest.mark.parametrize("fault", MASK_FAULTS)
+def test_a_familys_own_limits_catch_a_fault_planted_in_it(
+        toy_root, capsys, fault):
+    add_toy_configuration(toy_root)
+    path = toy_root / "chipbench" / "families" / "gated_toy.py"
+    broken_mask, catches = MASK_FAULTS[fault]
+    assert path.read_text().count(MASK) == 1
+    path.write_text(path.read_text().replace(MASK, broken_mask))
+    err = []
+    rc, lines = run_cell(toy_root, "toy-gated.seq64", 0, capsys, err,
+                         require_tpu=False)
+    result = lines[-1]
+    assert rc == 0 and result["correct"] is False
+    over = {name for name, (value, limit) in result["compared"].items()
+            if not value <= limit}
+    assert catches in over and over <= set(TOY_LIMITS)
+    if fault == "last-eight-rows":
+        # 8 of 64 tokens, each at least seven times `FAR_OFF` off, the
+        # other 56 at a fortieth of it at most; the median unmoved
+        assert result["compared"]["far_off_share"][0] == 0.125
+        assert "median_abs_diff" not in over
+    value, limit = result["compared"][catches]
+    assert f"compared {catches} {value!r} limit {limit!r}" in err[-6:]
+
+
+@pytest.mark.parametrize("states,says", [
+    ('LIMITS = {"rel_rmss": 0.1, "bias": 1e-3}',
+     r"names \['rel_rmss'\], which .* does not report; it reports "
+     r"\['bias', .*'far_off_share'\]"),
+    ("LIMITS = {}", r"names \[\] and none of \['rel_rms', 'mean_abs_diff', "
+                    r"'median_abs_diff'\]"),
+    # The family file comes from the PR whose `correct` it decides: a share
+    # of far-off tokens and the bias alone hold it to too little.
+    ('LIMITS = {"far_off_share": 0.5, "bias": 1.0}',
+     r"names \['bias', 'far_off_share'\] and none of "),
+    ("del FAR_OFF", "names 'far_off_share', and the file states no FAR_OFF"),
+], ids=["a-key-compare-does-not-report", "empty",
+        "nothing-that-every-token-moves", "far-off-with-no-threshold"])
+def test_a_limits_that_holds_a_family_to_too_little_is_refused_by_name(
+        toy_root, states, says):
+    add_toy_configuration(toy_root)
+    with open(toy_root / "chipbench" / "families" / "gated_toy.py", "a") as f:
+        f.write(f"\n{states}\n")
+    with pytest.raises(ValueError, match=says) as raised:
+        run.load_cell(toy_root, "toy-gated.seq64")
+    assert "gated_toy.py: LIMITS" in str(raised.value)
+
+
+def test_dense_lm_states_no_limits_and_is_held_to_the_three_constants():
+    assert not hasattr(DENSE_LM, "LIMITS")
+    assert run.limits_of(DENSE_LM) == {
+        "rel_rms": 0.04, "mean_abs_diff": 0.03, "bias": 1e-3}
+    # A family's own limits leave the bias under its constant unless they
+    # state another.
+    assert "bias" not in GATED_TOY.LIMITS
+    assert run.limits_of(GATED_TOY) == TOY_LIMITS == {
+        "bias": 1e-3, "median_abs_diff": 2e-5, "far_off_share": 0.05}
+    import types
+
+    stated = types.SimpleNamespace(LIMITS={"rel_rms": 0.2, "bias": 5e-3})
+    assert run.limits_of(stated) == {"rel_rms": 0.2, "bias": 5e-3}
+    assert set(run.DEFAULT_LIMITS) | set(GATED_TOY.LIMITS) == set(
+        reference.REPORTED)
+    assert set(run.WHOLE_SEQUENCE) < set(reference.REPORTED)
 
 
 def test_mfu_divides_the_familys_own_count():
@@ -386,17 +473,12 @@ def test_init_refuses_a_collection_carried_from_step_to_step():
         run.init_state(trainer, seq_len=4)
 
 
-@pytest.mark.parametrize("cell", CELLS)
-def test_dense_lm_builds_the_step_program_the_parent_spelled_out(
-        toy_root, cell):
-    """`run.build_trainer` of PR 27 constructed `TransformerLM` itself, with
-    these ten arguments; through the family the module is equal to it and
-    the train step lowers to the same text."""
+def check_dense_lm_builds_the_parents_step_program(root, cell):
     import types
 
     from horovod_tpu.models.transformer import ShardingConfig, TransformerLM
 
-    loaded = run.load_cell(toy_root, cell)
+    loaded = run.load_cell(root, cell)
     family, config = loaded["family"], loaded["config"]
     sizes = family.sizes(config)
 
@@ -422,6 +504,17 @@ def test_dense_lm_builds_the_step_program_the_parent_spelled_out(
         modules.append(trainer.module)
     assert modules[0] == modules[1]
     assert texts[0] == texts[1] and "stablehlo" in texts[0]
+
+
+@pytest.mark.parametrize("cell", DENSE_CELLS)
+def test_dense_lm_builds_the_step_program_the_parent_spelled_out(
+        toy_root, cell):
+    """`run.build_trainer` of PR 27 constructed `TransformerLM` itself, with
+    these ten arguments; through the family the module is equal to it and
+    the train step lowers to the same text. For the cells whose
+    configuration says ``"family": "dense_lm"``: another family's module
+    is its own."""
+    check_dense_lm_builds_the_parents_step_program(toy_root, cell)
 
 
 def test_replicas_agree_sees_one_chip_off_by_one_bit():
@@ -454,6 +547,14 @@ def cut():
     return json.loads((HERE / "trace_cut.json").read_text())
 
 
+def mosaic_ms_per_step(chip):
+    """(milliseconds a step in Mosaic kernels of any name, their count a
+    step): all that this cut can tell, recorded before the kernels had
+    names; the readers go by name (`reduce.flash_kernel_ms_per_step`)."""
+    hits = [d for n, _, d in chip.ops if reduce.KERNEL_MARK in n]
+    return sum(hits) / 1e6 / len(chip.steps), len(hits) / len(chip.steps)
+
+
 def test_reduction_of_the_recorded_cut(cut):
     """Two steady steps of the first traced run of cell 1 (v5e), with the
     ops of a microsecond or more; the expected numbers were worked out from
@@ -466,7 +567,8 @@ def test_reduction_of_the_recorded_cut(cut):
     assert chip.gaps_ns() == pytest.approx(want["gaps_ns"])
     assert chip.busy_ns() / chip.stretch_ns == pytest.approx(
         want["busy_share"], abs=1e-9)
-    ms, count = reduce.kernel_ms_per_step(chip)
+    ms, count = mosaic_ms_per_step(chip)
+    assert reduce.flash_kernel_ms_per_step(chip) == (0.0, 0.0)  # no names
     assert count == want["kernels_per_step"]
     assert ms == pytest.approx(want["kernel_ms_per_step"])
     # The containers are in the cut and would double the count if summed.
@@ -592,6 +694,58 @@ def test_reference_matches_the_system_at_toy_width(n_kv_heads, window):
         assert reference.compare(unmasked, want)["rel_rms"] > run.REL_RMS_TOL
 
 
+def test_compare_reports_what_a_minority_of_far_off_tokens_cannot_move():
+    """200 tokens whose losses differ from the reference's by rounding
+    (+-0.004 to +-0.012), then the same with every tenth token 0.3 further
+    off, as a routed model's tokens are whose last expert differs between
+    two precisions: the mean of squares reads six times as much, the median
+    stays, and the far-off share (beyond 0.08, which the family would
+    state: ten of its medians) counts the minority."""
+    rng = np.random.default_rng(0)
+    want = rng.normal(5.0, 1.0, 200).astype(np.float32)
+    noise = rng.choice([-1.0, 1.0], 200) * rng.uniform(0.004, 0.012, 200)
+    flipped = noise.copy()
+    flipped[::10] += 0.3
+    even = reference.compare(want + noise.astype(np.float32), want, 0.08)
+    routed = reference.compare(want + flipped.astype(np.float32), want, 0.08)
+    assert set(reference.REPORTED) <= set(even)
+    assert even["far_off_share"] == 0.0
+    assert even["median_abs_diff"] == pytest.approx(0.008, rel=0.1)
+    assert routed["rel_rms"] > 6 * even["rel_rms"] > 0.04
+    assert routed["mean_abs_diff"] > 4 * even["mean_abs_diff"]
+    assert routed["median_abs_diff"] < 1.2 * even["median_abs_diff"]
+    assert routed["far_off_share"] == pytest.approx(0.1)
+    # By hand: nine tokens 0.01 off and one 0.5 off.
+    ten = jnp.linspace(1.0, 2.0, 10)
+    moved = ten + jnp.asarray([0.01, -0.01, 0.01] * 3 + [0.5])
+    by_hand = reference.compare(moved, ten, far_off=0.1)
+    assert by_hand["median_abs_diff"] == pytest.approx(0.01, rel=1e-4)
+    assert by_hand["mean_abs_diff"] == pytest.approx(0.059, rel=1e-4)
+    assert by_hand["far_off_share"] == pytest.approx(0.1)
+    # Where "far off" begins is the family's to state: with none stated
+    # there is no share to report (and none to set a limit on).
+    assert set(reference.compare(moved, ten)) == (
+        set(by_hand) - {"far_off_share"})
+
+
+def test_far_off_share_where_most_tokens_agree_to_the_bit():
+    """float32 against float32 on another CPU: six tokens of ten equal to
+    the bit, four one or two float32 steps off. The median is 0, and the
+    share is taken against the family's threshold, not against a multiple
+    of that median, which would count all four."""
+    want = jnp.linspace(4.0, 5.0, 10)
+    step = float(np.spacing(np.float32(4.5)))
+    got = want + jnp.asarray([0, 0, step, 0, 0, 2 * step, 0, -step, 0, step])
+    report = reference.compare(got, want, far_off=1e-4)
+    assert report["median_abs_diff"] == 0.0
+    assert 0 < report["mean_abs_diff"] < 2 * step
+    assert report["far_off_share"] == 0.0
+    assert reference.compare(want, want, far_off=1e-4)["far_off_share"] == 0.0
+    lifted = reference.compare(got.at[7].add(0.01), want, far_off=1e-4)
+    assert lifted["far_off_share"] == pytest.approx(0.1)
+    assert lifted["median_abs_diff"] == 0.0
+
+
 def test_traffic_and_end_to_end_arithmetic():
     params = {"seq_len": 16, "n_sequences": 3}
     x, y = copy_task.make(2 ** 31 + 11, params, 50257)
@@ -610,36 +764,49 @@ def test_traffic_and_end_to_end_arithmetic():
     assert end_to_end.setup_s(readings) == 15.5
 
 
-def test_benchmark_json_is_consistent_with_the_files():
+def check_benchmark_json_is_consistent(root):
+    bench = bench_of(root)
     name_ok = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
     unit_ok = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
-    assert set(BENCH) == {"command", "paths", "run_seconds", "configs",
+    assert set(bench) == {"command", "paths", "run_seconds", "configs",
                           "workloads", "end_to_end", "per_layer"}
-    metrics = BENCH["end_to_end"] + BENCH["per_layer"]
-    names = [e["name"] for e in metrics + BENCH["workloads"] + BENCH["configs"]]
+    metrics = bench["end_to_end"] + bench["per_layer"]
+    names = [e["name"] for e in metrics + bench["workloads"] + bench["configs"]]
     assert all(name_ok.match(n) for n in names)
     assert len(set(names)) == len(names)
     assert all(unit_ok.match(m["unit"]) for m in metrics)
     assert all(m["better"] in ("lower", "higher") for m in metrics)
-    assert "setup_s" in {m["name"] for m in BENCH["end_to_end"]}
-    assert all(0.01 <= m["bound"] <= 0.1 for m in BENCH["end_to_end"])
-    four = sum(w["chips"] == 4 for w in BENCH["workloads"])
-    assert four <= max(1, len(BENCH["workloads"]) // 4)
-    for config in BENCH["configs"]:
-        data = json.loads((ROOT / config["file"]).read_text())
+    assert "setup_s" in {m["name"] for m in bench["end_to_end"]}
+    assert all(0.01 <= m["bound"] <= 0.1 for m in bench["end_to_end"])
+    four = sum(w["chips"] == 4 for w in bench["workloads"])
+    assert four <= max(1, len(bench["workloads"]) // 4)
+    toys = {}
+    for config in bench["configs"]:
+        data = json.loads((root / config["file"]).read_text())
+        toys[config["name"]] = data["toy"]
         assert set(config["reduced"]) == set(data["reduced"])
         assert all(name_ok.match(k) and k in data for k in config["reduced"])
-        run.load_family(ROOT, data, config["name"]).sizes(data)
-    for cell in CELLS:
-        loaded = run.load_cell(ROOT, cell)
+        # how the tests shrink it: keys the file has, and nothing else
+        assert data["toy"] and set(data["toy"]) <= set(data)
+        family = run.load_family(root, data, config["name"])
+        family.sizes(data)
+        family.sizes(data | data["toy"])
+    # The two dense ones by their names, among however many there are.
+    assert DENSE_TOYS.items() <= toys.items()
+    for cell in cells_of(root):
+        loaded = run.load_cell(root, cell)
         assert name_ok.match(loaded["workload"]["traffic"])
         reported = {m["name"] for m in loaded["end_to_end"]}
         assert "setup_s" in reported and len(reported) >= 2
         assert loaded["per_layer"]
         for metric in loaded["per_layer"]:
             assert metric["moves"] in reported
-            spec = json.loads((ROOT / "chipbench" / "layer_metrics"
+            spec = json.loads((root / "chipbench" / "layer_metrics"
                                / f"{metric['name']}.json").read_text())
             assert spec["unit"] == metric["unit"]
             path, _, attr = spec["reader"].partition(":")
-            assert callable(run.load_attr(ROOT / "chipbench" / path, attr))
+            assert callable(run.load_attr(root / "chipbench" / path, attr))
+
+
+def test_benchmark_json_is_consistent_with_the_files():
+    check_benchmark_json_is_consistent(ROOT)

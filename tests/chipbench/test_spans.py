@@ -90,7 +90,7 @@ def test_kernel_reader_on_the_recorded_cut(cut, which):
     assert read_metric(f"flash_{which}_ms_per_step", ctx) == (
         pytest.approx(want))
     chip, = ctx["chips"]
-    assert spans.kernel_ms_per_step(chip, which)[1] == (
+    assert reduce.flash_kernel_ms_per_step(chip, which)[1] == (
         cut["expected"]["kernels_per_step"][which])
     # A model of another depth: the events are not what they are taken for.
     ctx["kernel_work"][f"flash_{which}"] = (0.0, 0.0, cut["n_layers"] + 1)
@@ -105,6 +105,53 @@ def test_the_three_kernels_are_the_flash_metric_that_was_there(cut):
     three = sum(read_metric(f"flash_{k}_ms_per_step", ctx)
                 for k in ("fwd", "dq", "dkv"))
     assert three == pytest.approx(reduce.flash_ms_per_step(ctx))
+
+
+def test_a_mosaic_call_of_another_name_is_not_flash(cut):
+    """An expert layer's grouped matmul, say: one leaf op of every step of
+    the cut (the cast of the head's kernel, ``%copy.628``, 0.96 ms) takes
+    the name and target a `pallas_call(name="hvt_grouped_matmul")` would
+    have. It lands in "other kernels" with all of its time, the flash
+    readings stay where they were (the readers that counted every Mosaic
+    call would have found 37 a step for 36 and read nothing), and the
+    phases still sum to the busy time."""
+    old, = {r[2] for r in cut["rows"] if r[2].startswith("%copy.628 = ")}
+    new = ("%hvt_grouped_matmul.1 = bf16[2048,50257]{1,0} custom-call("
+           "f32[2048,50257]{0,1} %p), " + reduce.KERNEL_MARK)
+    rows = [tuple(new if v == old else v for v in r) for r in cut["rows"]]
+    planted = context(cut, rows=rows, chips=reduce.chips_from_rows(rows))
+    planted["spans"]["scopes"] = {
+        new if line == old else line: path
+        for line, path in cut["scopes"].items()}
+    clean = context(cut)
+    chip, = planted["chips"]
+    mosaic_calls = sum(reduce.KERNEL_MARK in n for n, _, _ in chip.ops)
+    assert mosaic_calls / len(chip.steps) == 3 * cut["n_layers"] + 1
+    flash = ["flash_ms_per_step", "flash_roofline"]
+    kernels = [f"flash_{k}_ms_per_step" for k in ("fwd", "dq", "dkv")]
+    for ctx in (clean, planted):  # a roofline needs work and a device
+        ctx["kernel_work"]["flash"] = (1e12, 1e9, 3 * cut["n_layers"])
+        ctx["device_kind"] = "TPU v5 lite"
+    for name in flash:
+        assert getattr(reduce, name)(planted) == getattr(reduce, name)(clean)
+    for name in kernels:
+        assert read_metric(name, planted) == read_metric(name, clean)
+    assert reduce.flash_ms_per_step(planted) == pytest.approx(
+        cut["expected"]["phase_ms"]["flash"])
+    before = spans.phase_ms(clean["chips"][0], clean["spans"]["scopes"])
+    after = spans.phase_ms(chip, planted["spans"]["scopes"])
+    moved = after["other kernels"]
+    assert before["other kernels"] == 0.0 and moved == pytest.approx(
+        sum(r[4] for r in cut["rows"] if r[2] == old) / 1e6 / 4, rel=0.01)
+    was = spans.phase_of(old, cut["scopes"])
+    assert after == pytest.approx(
+        before | {"other kernels": moved, was: before[was] - moved})
+    assert sum(after.values()) == pytest.approx(
+        chip.busy_ns() / 1e6 / len(chip.steps), rel=1e-9)
+    assert chip.busy_ns() == clean["chips"][0].busy_ns()
+    spans.unattributed_device_share(planted)
+    by_scope = planted["said"][-1]["by_scope"]
+    assert ["other kernels", "hvt_grouped_matmul"] in [r[:2] for r in by_scope]
 
 
 def test_phase_table_sums_to_the_busy_time_and_is_printed(cut):
@@ -254,9 +301,25 @@ def test_phase_precedence_by_hand():
     assert spans.phase_of(psum, {}) == "collectives"
     assert spans.phase_of(reader, {}) == "unattributed"
     assert spans.phase_of(kernel, {kernel: head}) == "flash"
-    assert spans.kernel_of(kernel) == spans.kernel_of(bare) == "dq"
-    assert spans.kernel_of(gather) is None
-    assert spans.kernel_of("%hvt_flash_dq.4 = fusion()") is None
+    assert reduce.flash_kernel_of(kernel) == reduce.flash_kernel_of(bare) == "dq"
+    assert reduce.flash_kernel_of(gather) is None
+    assert reduce.flash_kernel_of("%hvt_flash_dq.4 = fusion()") is None
+    # A Mosaic call of another name is a kernel, and not flash.
+    other = kernel.replace("%hvt_flash_dq.4", "%hvt_grouped_matmul.4")
+    assert reduce.flash_kernel_of(other) is None
+    # ... and so is one whose name only begins, or ends, like a flash
+    # kernel's: the name is matched whole, under the transformations'
+    # prefixes and without the instruction's number.
+    for name in ("%hvt_flash_dq_ring.4", "%hvt_flash_dq2.4", "%hvt_flash_d.4",
+                 "%ring_hvt_flash_dqx.4"):
+        assert reduce.flash_kernel_of(
+            kernel.replace("%hvt_flash_dq.4", name)) is None, name
+    for name in ("%hvt_flash_dq", "%hvt_flash_dq.4.1", "%jvp_hvt_flash_dq_.7"):
+        assert reduce.flash_kernel_of(
+            kernel.replace("%hvt_flash_dq.4", name)) == "dq", name
+    assert reduce.flash_kernel_of(
+        kernel.replace("%hvt_flash_dq.4", "%hvt_flash_dkv.4")) == "dkv"
+    assert spans.phase_of(other, {other: head}) == "other kernels"
 
 
 def test_adapter_agrees_with_profile_data_on_a_trace_taken_here(tmp_path):
