@@ -51,7 +51,9 @@ __all__ = [
     "CollectiveOp",
     "ProgramAuditError",
     "ProgramExpectation",
+    "ScheduledReduction",
     "assert_program",
+    "asynchronous_share",
     "audit",
     "collective_ops",
     "donated_args",
@@ -59,6 +61,7 @@ __all__ = [
     "op_bytes",
     "op_bytes_by_kind",
     "payload_alltoalls",
+    "reduction_schedule",
     "scatter_reductions",
     "while_bodies",
     "while_count",
@@ -290,10 +293,14 @@ def op_bytes(op: CollectiveOp) -> int:
     """Payload bytes of one collective's RESULT (elements x element
     size) — the structural bytes-on-wire accounting the bench reports.
     Unknown element types count 4 bytes (the f32 default)."""
+    return _nbytes(op.dtype, op.shape)
+
+
+def _nbytes(dtype: str, shape: tuple) -> int:
     n = 1
-    for d in op.shape:
+    for d in shape:
         n *= d
-    return n * _DTYPE_BYTES.get(op.dtype, 4)
+    return n * _DTYPE_BYTES.get(dtype, 4)
 
 
 def op_bytes_by_kind(ops) -> dict:
@@ -358,6 +365,80 @@ def while_bodies(text: str, scope: str = "") -> list[str]:
         for line in text.splitlines()
         if _HLO_WHILE_RE.search(line) and scope in line
     ]
+
+
+@dataclasses.dataclass(frozen=True)
+class ScheduledReduction:
+    """One cross-chip reduction of a compiled program and how it runs.
+    ``dtype`` and ``shape`` are its first result's (a combined all-reduce
+    is tuple-shaped), ``nbytes`` every result's."""
+
+    kind: str             # "all-reduce" | "reduce-scatter"
+    dtype: str
+    shape: tuple
+    nbytes: int
+    asynchronous: bool
+
+
+_HLO_REDUCTION_RE = re.compile(
+    r"=\s*(\([^=]*?\)|\S+)\s+(all-reduce|reduce-scatter)(-start)?\("
+)
+_HLO_CHANNEL_RE = re.compile(r"channel_id=(\d+)")
+# XLA:TPU runs an asynchronous collective as fusions of the entry
+# computation: `async-collective-start`, steps that ride inside compute
+# fusions (computations named `async_collective_fusion.N`), and
+# `async-collective-done`. Each of their computations restates the
+# collective under its one channel id.
+_ASYNC_FUSION = "async_collective_fusion"
+_HLO_ASYNC_EDGE_RE = re.compile(
+    r"%async-collective-(?:start|done)[\w.\-]* = [^\n]*?calls=%?([\w.\-]+)"
+)
+
+
+def reduction_schedule(text: str) -> list[ScheduledReduction]:
+    """Compiled HLO: every non-scalar all-reduce and reduce-scatter, once,
+    told apart by how the compiler scheduled it. Asynchronous: a
+    ``-start`` / ``-done`` pair, or a collective inside the fusions of an
+    asynchronous collective (the scheduler may then put compute between
+    its start and its done). Synchronous: the plain instruction, during
+    which the chip's compute waits. (Scalars are the metric means, as in
+    `gradient_reductions`.)"""
+    edges = set(_HLO_ASYNC_EDGE_RE.findall(text))
+    found, in_flight = [], set()
+    for name, body in _HLO_COMPUTATION_RE.findall(text):
+        fused = name.startswith(_ASYNC_FUSION) or name in edges
+        for line in body.splitlines():
+            m = _HLO_REDUCTION_RE.search(line)
+            if not m:
+                continue
+            results = [
+                (_canon_dtype(dtype),
+                 tuple(int(d) for d in dims.split(",") if d))
+                for dtype, dims in _HLO_TYPE_RE.findall(m.group(1))
+            ]
+            if not any(shape for _, shape in results):
+                continue
+            if fused:
+                channel = _HLO_CHANNEL_RE.search(line)
+                if channel and channel.group(1) in in_flight:
+                    continue  # the same sum, restated by a later fusion
+                if channel:
+                    in_flight.add(channel.group(1))
+            found.append(ScheduledReduction(
+                m.group(2), *results[0],
+                nbytes=sum(_nbytes(*result) for result in results),
+                asynchronous=fused or bool(m.group(3)),
+            ))
+    return found
+
+
+def asynchronous_share(reductions) -> float | None:
+    """Bytes of the asynchronous reductions over the bytes of all of
+    them; None for a program that reduces nothing across chips."""
+    total = sum(r.nbytes for r in reductions)
+    if not total:
+        return None
+    return sum(r.nbytes for r in reductions if r.asynchronous) / total
 
 
 # Donation: lowered StableHLO marks donated args with `tf.aliasing_output`

@@ -302,6 +302,18 @@ METRICS: dict[str, MetricSpec] = _decl([
     MetricSpec("hvt_accum_k", "gauge",
                "Gradient-accumulation factor K of the running trainer.",
                "training"),
+    MetricSpec("hvt_reduction_bytes", "gauge",
+               "Bytes the compiled step program sums across chips, by how "
+               "the compiler scheduled each sum: asynchronous (compute may "
+               "run between its start and its done) or synchronous (the "
+               "chip's compute waits). Read from the program's text at "
+               "the sampler's one compile.", "training",
+               labels=("schedule",)),
+    MetricSpec("hvt_reduction_async_share", "gauge",
+               "Share of hvt_reduction_bytes that is asynchronous: how "
+               "far the overlapped-reduction compile options engaged "
+               "(0 off TPU and wherever the compiler took none).",
+               "training"),
     MetricSpec("hvt_optimizer_steps_total", "counter",
                "Optimizer steps this process's fit loops have handed to "
                "the device (counted in the loop, exporter on or off).",

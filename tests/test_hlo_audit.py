@@ -166,6 +166,97 @@ class TestParsers:
             hlo_audit.wire_dtype("int4")
 
 
+# How XLA:TPU writes one cross-chip sum, cut to what tells the three ways
+# apart (from compiles of the data=4 step for a described v5e:2x2, PR 30).
+_SUM = ("all-reduce(%p), channel_id=7, replica_groups=[1,4]<=[4], "
+        "to_apply=%add")
+_SCALAR = "%loss = f32[] all-reduce(%l), channel_id=2, to_apply=%add"
+
+PLAIN_ALL_REDUCE = f"""\
+HloModule jit_train_step, is_scheduled=true
+
+ENTRY %main.1_spmd (p: bf16[2048,8192]) -> bf16[2048,8192] {{
+  %p = bf16[2048,8192]{{1,0}} parameter(0)
+  {_SCALAR}
+  %all-reduce.30 = (bf16[2048,8192]{{1,0:T(8,128)(2,1)}}, f32[2048]{{0:T(1024)}}) {_SUM}
+  ROOT %use = bf16[2048,8192]{{1,0}} fusion(%all-reduce.30), kind=kLoop, calls=%fc
+}}
+"""
+
+START_DONE_PAIR = f"""\
+HloModule jit_train_step, is_scheduled=true
+
+ENTRY %main.1_spmd (p: bf16[2048,8192]) -> bf16[2048,8192] {{
+  %p = bf16[2048,8192]{{1,0}} parameter(0)
+  %all-reduce-start.1 = bf16[2048,8192]{{1,0}} all-reduce-start(%p), channel_id=7, to_apply=%add
+  %between = bf16[2,2048,8192]{{2,1,0}} fusion(%q), kind=kOutput, calls=%fc
+  ROOT %all-reduce-done.1 = bf16[2048,8192]{{1,0}} all-reduce-done(%all-reduce-start.1)
+}}
+"""
+
+ASYNC_COLLECTIVE_FUSION = f"""\
+HloModule jit_train_step, is_scheduled=true
+
+%fused_computation.865 (param_0.1: bf16[2048,8192]) -> (bf16[2048,8192], u32[]) {{
+  %param_0.1 = bf16[2048,8192]{{1,0}} parameter(0)
+  %all-reduce.147 = bf16[2048,8192]{{1,0:T(8,128)(2,1)}} {_SUM}
+}}
+
+%async_collective_fusion.668 (param_0.2: bf16[2048,8192], param_1.2: bf16[2,2048,2048]) -> (bf16[16,128,2048], bf16[2048,8192]) {{
+  %convolution.495 = bf16[16,128,2048,1]{{2,1,0,3}} convolution(%a, %b), window={{size=2x1}}
+  %all-reduce.149 = bf16[2048,8192]{{1,0:T(8,128)(2,1)S(1)}} {_SUM}
+}}
+
+%async_collective_fusion.669 (param_0.3: bf16[2048,8192]) -> (bf16[16,128,2048], bf16[2048,8192]) {{
+  %all-reduce.150 = bf16[2048,8192]{{1,0:T(8,128)(2,1)S(1)}} {_SUM}
+}}
+
+%fused_computation.867 (param_0.4: bf16[2048,8192]) -> bf16[2048,8192] {{
+  %all-reduce.151 = bf16[2048,8192]{{1,0:T(8,128)(2,1)}} {_SUM}
+}}
+
+ENTRY %main.1_spmd (p: bf16[2048,8192]) -> bf16[2048,8192] {{
+  %p = bf16[2048,8192]{{1,0}} parameter(0)
+  %async-collective-start.2 = (bf16[2048,8192]{{1,0}}, u32[]) fusion(%p), kind=kCustom, calls=%fused_computation.865
+  %fusion.668 = (bf16[16,128,2048]{{2,1,0}}, bf16[2048,8192]{{1,0}}) fusion(%gte.1, %x), kind=kOutput, calls=%async_collective_fusion.668
+  %fusion.669 = (bf16[16,128,2048]{{2,1,0}}, bf16[2048,8192]{{1,0}}) fusion(%gte.2), kind=kOutput, calls=%async_collective_fusion.669
+  %async-collective-done.2 = bf16[2048,8192]{{1,0}} fusion(%gte.3), kind=kCustom, calls=%fused_computation.867
+  %psum.7 = f32[2048,50257]{{0,1:T(8,128)}} all-reduce(%dw), channel_id=1, to_apply=%add
+  ROOT %out = bf16[2048,8192]{{1,0}} fusion(%async-collective-done.2, %psum.7), kind=kLoop, calls=%fc
+}}
+"""
+
+
+class TestReductionSchedule:
+    @pytest.mark.parametrize("text,expected,share", [
+        pytest.param(  # one combined sum: every byte of the tuple counts
+            PLAIN_ALL_REDUCE,
+            [("bf16", 2048 * 8192 * 2 + 2048 * 4, False)], 0.0,
+            id="plain-all-reduce"),
+        pytest.param(
+            START_DONE_PAIR, [("bf16", 2048 * 8192 * 2, True)], 1.0,
+            id="start-done-pair"),
+        pytest.param(  # start, two steps and done restate ONE sum
+            ASYNC_COLLECTIVE_FUSION,
+            [("bf16", 2048 * 8192 * 2, True),
+             ("f32", 2048 * 50257 * 4, False)],
+            2048 * 8192 * 2 / (2048 * 8192 * 2 + 2048 * 50257 * 4),
+            id="async-collective-fusion"),
+    ])
+    def test_asynchronous_and_synchronous_sums_are_told_apart(
+            self, text, expected, share):
+        found = hlo_audit.reduction_schedule(text)
+        assert [
+            (r.dtype, r.nbytes, r.asynchronous) for r in found
+        ] == expected
+        assert hlo_audit.asynchronous_share(found) == pytest.approx(share)
+
+    def test_a_program_that_sums_nothing_has_no_share(self):
+        assert hlo_audit.reduction_schedule(HLO_SAMPLE.replace(
+            "all-reduce", "add")) == []
+        assert hlo_audit.asynchronous_share([]) is None
+
+
 class TestExpectations:
     def test_parse_grammar(self):
         e = hlo_audit.ProgramExpectation.parse(

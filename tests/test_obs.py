@@ -695,6 +695,11 @@ class TestTrainerExporter:
         assert values["hvt_peak_flops_per_chip"] == 1e12
         assert values["hvt_examples_per_sec"] > 0
         assert values["hvt_accum_k"] == 1
+        # The compiled step's cross-chip sums, by how they were scheduled
+        # (the CPU backend is given no option and schedules none async).
+        assert values['hvt_reduction_bytes{schedule="synchronous"}'] > 0
+        assert values['hvt_reduction_bytes{schedule="asynchronous"}'] == 0
+        assert values["hvt_reduction_async_share"] == 0
         import jax
 
         steps_per_epoch = len(x) // (8 * jax.device_count())

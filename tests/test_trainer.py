@@ -254,3 +254,37 @@ class TestModuleLossBuildHint:
         y = np.zeros(4, np.int64)
         state = trainer.build(x, y)
         assert state is trainer.state
+
+
+@pytest.mark.parametrize("n_devices", [8, 1])
+def test_no_compile_option_off_tpu_or_on_one_device(n_devices):
+    """The overlapped-reduction options are for a multi-chip TPU mesh; the
+    CPU backend would refuse an ``xla_tpu_*`` option, and one device sums
+    nothing across chips."""
+    import jax
+
+    from horovod_tpu.training import trainer as trainer_lib
+
+    mesh = hvt.build_mesh(
+        hvt.MeshSpec(data=n_devices), devices=jax.devices()[:n_devices])
+    assert trainer_lib.training_compiler_options(mesh) == {}
+
+
+def test_compile_options_on_a_multi_chip_tpu_mesh_only():
+    """Chosen from the devices' platform and the mesh's size, nothing
+    else: four TPUs get the table, one TPU and a mixed mesh do not."""
+    import types
+
+    from horovod_tpu.training import trainer as trainer_lib
+
+    def mesh_of(*platforms):
+        chips = np.empty(len(platforms), dtype=object)
+        chips[:] = [types.SimpleNamespace(platform=p) for p in platforms]
+        return types.SimpleNamespace(devices=chips)
+
+    options = trainer_lib.training_compiler_options(mesh_of(*["tpu"] * 4))
+    assert options == trainer_lib.OVERLAPPED_REDUCTION_OPTIONS
+    assert options is not trainer_lib.OVERLAPPED_REDUCTION_OPTIONS
+    assert trainer_lib.training_compiler_options(mesh_of("tpu")) == {}
+    assert trainer_lib.training_compiler_options(
+        mesh_of("tpu", "cpu")) == {}
