@@ -151,8 +151,8 @@ def main():
         f"speculative: {rounds} target passes for {n_new} tokens "
         f"({n_new / rounds:.1f} tok/pass), outputs identical: {agree}, "
         f"wall {t_plain * 1e3:.0f} -> {t_spec * 1e3:.0f} ms (single-call "
-        f"timings include the host round-trip; BENCH_MODEL=spec measures "
-        f"the honest chained speedup)"
+        f"timings include the host round-trip and say nothing of the "
+        f"decode loop's speed)"
     )
     assert agree, "speculative output diverged from plain greedy"
 

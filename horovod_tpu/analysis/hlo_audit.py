@@ -4,15 +4,14 @@ layer 2).
 Every compiled-program invariant the framework actually relies on —
 exactly one gradient reduction per optimizer step (PR 4), wire dtype on
 the DCN hop (PR 7), donation aliasing, the overlap peel — used to live
-as copy-pasted HLO-text greps in three test files and ``bench.py``. This
+as copy-pasted HLO-text greps in three test files. This
 module is the single implementation: a small parser over the two text
 dialects jax emits (lowered StableHLO from ``.lower().as_text()``,
 post-optimization HLO from ``.compile().as_text()``) exposing the ops as
 data, plus an `assert_program` API whose failures print a structured
 diff instead of a regex mismatch.
 
-The load-bearing discrimination, shared verbatim with the bench
-(previously private as ``bench._reduction_calls``): cross-worker
+The load-bearing discrimination: cross-worker
 GRADIENT traffic is
 
 * any non-scalar all-reduce — scalar all-reduces are the loss/accuracy
@@ -291,7 +290,7 @@ _DTYPE_BYTES = {
 
 def op_bytes(op: CollectiveOp) -> int:
     """Payload bytes of one collective's RESULT (elements x element
-    size) — the structural bytes-on-wire accounting the bench reports.
+    size) — the structural bytes-on-wire accounting.
     Unknown element types count 4 bytes (the f32 default)."""
     return _nbytes(op.dtype, op.shape)
 

@@ -340,15 +340,14 @@ KNOBS: dict[str, Knob] = _decl([
          "fed rank."),
     # --- observability ------------------------------------------------------
     Knob("HVT_PROFILE", "path", None, "observability",
-         "Capture a jax.profiler trace of fit()/bench into this dir — the "
+         "Capture a jax.profiler trace of fit() into this dir — the "
          "HOROVOD_TIMELINE contract, primary-process-gated."),
     Knob("HVT_PEAK_FLOPS", "float", None, "observability",
          "Per-chip peak FLOP/s override for the MFU denominator — set it "
          "when the device kind is missing from the built-in peak table "
          "(a new TPU generation: unset, an unknown accelerator is an "
-         "error). Unset on the CPU platform, bench.py and the live MFU "
-         "gauge calibrate a host matmul as a CI trend denominator; "
-         "bench.py exits 2 on an unparseable override."),
+         "error). Unset on the CPU platform, the live MFU gauge is not "
+         "published; an unparseable override raises ValueError."),
     Knob("HVT_METRICS_DIR", "path", None, "observability",
          "Metrics-stream directory (default: $PS_MODEL_PATH, else "
          "./models)."),
@@ -361,8 +360,8 @@ KNOBS: dict[str, Knob] = _decl([
          "Step-phase sampling cadence in optimizer steps for the "
          "trainer exporter: every N steps the fit loop drains the "
          "pipeline once and refreshes the step_ms{total,compute,comm,"
-         "input} / examples-per-sec / MFU gauges (bench A/B-gates the "
-         "overhead at <= 2% of step time)."),
+         "input} / examples-per-sec / MFU gauges (one drain of the "
+         "pipeline per window is the sampler's recurring cost)."),
     Knob("HVT_FLIGHT_RECORD", "path", None, "observability",
          "Collective flight recorder: set to a DIRECTORY and every "
          "collectives.py submission site appends a bounded per-process "
@@ -429,19 +428,19 @@ KNOBS: dict[str, Knob] = _decl([
          "Inject N deterministic TRANSIENT read faults (OSError) into "
          "the dataset-read retry path (data.stream.read_with_retries) — "
          "the chaos hook for exercising HVT_DATA_RETRIES."),
-    # --- examples / bench (read by entry scripts, not the package) ----------
+    # --- examples (read by the example entry scripts, not the package) ------
     Knob("HVT_BACKWARD_PASSES", "int", 1, "examples",
          "Gradient-accumulation factor K for the example entry scripts "
          "(DistributedOptimizer backward_passes_per_step).",
          tunable=Tunable("int", lo=1, hi=8, scale="log")),
     Knob("HVT_COMPRESSION", "str", "none", "examples",
-         "Gradient wire compression for the example/bench entry scripts "
+         "Gradient wire compression for the example entry scripts "
          "(none/bf16/fp16/int8/fp8 — DistributedOptimizer(compression=); "
          "int8/fp8 carry error-feedback residuals by default).",
          tunable=Tunable("choice",
                          choices=("none", "bf16", "fp16", "int8", "fp8"))),
     Knob("HVT_COMPRESSION_ICI", "str", "none", "examples",
-         "ICI-hop gradient wire for the example/bench entry scripts "
+         "ICI-hop gradient wire for the example entry scripts "
          "(none/bf16/fp16/int8/fp8 — DistributedOptimizer("
          "compression_ici=): the hierarchical two-hop reduction's "
          "intra-slice hop, error-feedback-charged per hop for int8/fp8; "
@@ -449,9 +448,11 @@ KNOBS: dict[str, Knob] = _decl([
          tunable=Tunable("choice",
                          choices=("none", "bf16", "fp16", "int8", "fp8"))),
     Knob("HVT_DEVICE_CACHE", "flag", False, "examples",
-         "Examples: stage the dataset into HBM once (`cache='device'`)."),
+         "Example entry scripts: stage the dataset into HBM once "
+         "(`cache='device'`)."),
     Knob("HVT_EXPORT_FORMAT", "str", "stablehlo", "examples",
-         "Examples: serving-bundle export format (stablehlo/savedmodel)."),
+         "Example entry scripts: serving-bundle export format "
+         "(stablehlo/savedmodel)."),
 ])
 
 

@@ -36,8 +36,8 @@ clients never see the static-shape constraint.
 Concurrency: one device worker drains a **coalescing queue** — rows from
 concurrent requests are packed together into the compiled batch shape, so
 N simultaneous single-row clients cost ~ceil(N/batch) device dispatches
-instead of N (measured ~batch× requests/sec at saturation; bench.py's
-serve row). Handler threads only enqueue and wait; the device callable
+instead of N (a count; no serving cell has timed it on the chip yet).
+Handler threads only enqueue and wait; the device callable
 never runs re-entrantly. Sampled generation bundles (temperature > 0) are
 the exception: each request owns its rng seed for the whole compiled
 call, so they serialize per-request through the worker instead of mixing
@@ -203,8 +203,8 @@ class _ModelApp:
         self.row_shape = tuple(int(d) for d in spec["shape"][1:])
         self.dtype = np.dtype(spec["dtype"])
         self.stats = {"device_calls": 0, "rows": 0}
-        # coalesce=False keeps the legacy serialize-whole-requests path —
-        # the bench's before/after baseline (bench.py serve row).
+        # coalesce=False keeps the legacy serialize-whole-requests path
+        # (one lock, one request at a time: ROADMAP D3).
         self._lock = None if coalesce else threading.Lock()
         self._batcher = (
             _Batcher(self._run_rows, self.batch, self.stats)
@@ -505,7 +505,7 @@ def make_server(bundle_dir: str, port: int = 0, host: str = "127.0.0.1",
                 continuous: bool = False, allow_reload: bool = False):
     """Build (but don't start) the HTTP server; ``server.server_address``
     carries the bound port when ``port=0``. ``coalesce=False`` keeps the
-    legacy serialize-whole-requests path (the bench baseline);
+    legacy serialize-whole-requests path;
     ``continuous=True`` routes /v1/generate through the per-decode-step
     scheduler (streaming bundles only; full admissions answer 429).
     ``allow_reload=True`` mounts ``POST /admin/reload`` (the fleet's

@@ -1,10 +1,10 @@
 """Vision Transformer for CIFAR/MNIST-scale images (Dosovitskiy et al.,
 arXiv:2010.11929) — the TPU-first vision family.
 
-The conv attribution (benchmarks/conv_profile.py) found the
-CIFAR-scale conv models are *shape-bound*: a 16-channel 3×3 conv fills
-16/128 MXU lanes and no amount of batch fixes it (ResNet-20 plateaus at
-MFU ≈ 0.20). The TPU-first answer is an architecture whose image compute
+The CIFAR-scale conv models are *shape-bound*: a 16-channel 3×3 conv
+fills 16 of the MXU's 128 lanes and no amount of batch fixes it (a fact
+of the shapes; no benchmark cell runs a conv model, so no utilisation is
+stated here). The TPU-first answer is an architecture whose image compute
 IS matmuls at MXU-friendly widths: patchify (one reshape + one Dense),
 then d_model-wide transformer encoder blocks. Same Trainer / optimizer /
 callback path as the CNNs (the capability the reference exercises,

@@ -3,8 +3,8 @@ exported metric series, the way `analysis/registry.py` is for ``HVT_*``
 knobs.
 
 The framework grew five disjoint slices of operational truth (restart
-journal, supervisor ``/status``, elastic generation state, bench JSON
-rows, serving ``/healthz``). This module unifies their *export surface*:
+journal, supervisor ``/status``, elastic generation state, metrics
+streams, serving ``/healthz``). This module unifies their *export surface*:
 every series any process exposes over ``GET /metrics`` is declared here
 as a `MetricSpec` (kind, help text, labels, histogram bucket edges), and
 the instruments refuse undeclared names — a new series cannot ship
@@ -281,8 +281,8 @@ METRICS: dict[str, MetricSpec] = _decl([
     MetricSpec("hvt_step_phase_ms", "gauge",
                "Live per-step phase attribution in ms (labels: total / "
                "compute / comm / input), sampled every HVT_METRICS_EVERY "
-               "optimizer steps with the same isolated-reduction-program "
-               "attribution bench.py uses.", "training",
+               "optimizer steps; comm is the isolated reduction program "
+               "(Trainer.reduction_program), timed alone.", "training",
                labels=("phase",)),
     MetricSpec("hvt_step_seconds", "histogram",
                "Sampled mean optimizer-step wall time over each "
@@ -293,11 +293,11 @@ METRICS: dict[str, MetricSpec] = _decl([
     MetricSpec("hvt_mfu", "gauge",
                "Live model-FLOPs utilization vs the resolved per-chip "
                "peak (XLA cost-model flops; custom-call kernels "
-               "under-count — bench rows stay the calibrated source).",
+               "under-count — the benchmark's mfu is the one to quote).",
                "training"),
     MetricSpec("hvt_peak_flops_per_chip", "gauge",
                "The per-chip peak FLOP/s the MFU gauge divides by "
-               "(HVT_PEAK_FLOPS override, TPU table, or calibrated).",
+               "(HVT_PEAK_FLOPS override, else the TPU table).",
                "training"),
     MetricSpec("hvt_accum_k", "gauge",
                "Gradient-accumulation factor K of the running trainer.",

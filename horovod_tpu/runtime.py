@@ -141,8 +141,8 @@ def init(
         jax.config.update("jax_num_cpu_devices", n_cpu)
     if env_flag("HVT_FAST_RNG"):
         # TPU hardware RNG for dropout/init keys: threefry (the reproducible
-        # default) costs real step time when dropout is on (~12% on the LM
-        # bench); 'rbg' makes it free. Opt-in — rbg streams are not
+        # default) computes its bits on the vector units when dropout is
+        # on; 'rbg' uses the chip's generator. Opt-in — rbg streams are not
         # bit-reproducible across topologies the way threefry is.
         jax.config.update("jax_default_prng_impl", "rbg")
 
@@ -171,7 +171,7 @@ def init(
 def use_compilation_cache() -> str:
     """Point jax's persistent compilation cache somewhere that survives
     the process, and return the directory. Entry scripts (`chip_smoke.py`,
-    `bench.py`) call this before their first compile.
+    `chipbench/run.py`) call this before their first compile.
 
     ``JAX_COMPILATION_CACHE_DIR`` set: jax already reads it — nothing is
     set in code. Unset: ``<checkout>/.jax_cache``, a FIXED path derived

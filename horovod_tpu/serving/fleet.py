@@ -89,8 +89,8 @@ class ServeFleet:
     """Coordinator + router + N replica subprocesses, one handle.
 
     ``log_path``: the restart-journal path (None journals nowhere);
-    ``continuous=False`` runs the legacy coalescing replicas (the bench
-    baseline). ``ready_timeout`` bounds each replica's boot (bundle
+    ``continuous=False`` runs the legacy coalescing replicas.
+    ``ready_timeout`` bounds each replica's boot (bundle
     deserialization + first jit can dominate).
     """
 
@@ -459,7 +459,7 @@ def main(argv=None) -> int:
                    help="restart-journal path (membership + swap events; "
                    "metrics.prom lands beside it at stop)")
     p.add_argument("--coalesce", action="store_true",
-                   help="legacy coalescing replicas (the bench baseline) "
+                   help="legacy coalescing replicas "
                    "instead of the continuous engine")
     p.add_argument("--demo", action="store_true",
                    help="self-export a tiny streaming bundle and serve it "

@@ -4,9 +4,9 @@ TPU-native (§5.1).
 `jax.profiler` traces capture XLA op timing *and* ICI collective phases —
 strictly more than Horovod's Chrome-trace Timeline — viewable in
 TensorBoard/perfetto. Primary-process-gated like every writer in the
-framework. `HVT_PROFILE=<dir>` turns tracing on in `Trainer.fit` and
-`bench.py` without code changes (the `HOROVOD_TIMELINE=<file>` env-var
-contract, SURVEY.md §2.3 Timeline row). What the program says about itself
+framework. `HVT_PROFILE=<dir>` turns tracing on in `Trainer.fit` without code
+changes (the `HOROVOD_TIMELINE=<file>` env-var contract, SURVEY.md §2.3
+Timeline row). What the program says about itself
 lands in the same trace: `span` puts host events named ``hvt.<name>`` on
 the profiler's clock, and the compiled step carries the scopes
 ``hvt.head_ce`` / ``hvt.optimizer`` and the kernel names ``hvt_flash_*``
@@ -21,14 +21,12 @@ count against the chip's peak; "match or beat" needs this denominator."""
 from __future__ import annotations
 
 import contextlib
-import functools
 import json
 import os
 import threading
 import time
 
 import jax
-import numpy as np
 
 from horovod_tpu import runtime
 from horovod_tpu.analysis import registry
@@ -56,8 +54,7 @@ def device_peak_flops(device=None) -> float | None:
     ``HVT_PEAK_FLOPS`` overrides the table — the explicit per-chip peak
     for device kinds the table doesn't know (CPU CI topologies, new TPU
     generations), so MFU can be a real trend number everywhere. An
-    unparseable override raises ``ValueError`` (bench.py exits 2 on
-    it)."""
+    unparseable override raises ``ValueError``."""
     override = registry.get_float("HVT_PEAK_FLOPS")
     if override:
         return float(override)
@@ -90,63 +87,16 @@ def compiled_cost_flops(compiled) -> float | None:
         return None
 
 
-def flash_attention_flops(batch: int, seq_q: int, seq_k: int, heads: int,
-                          head_dim: int, *, causal: bool = True,
-                          backward: bool = True,
-                          window: int | None = None) -> float:
-    """Matmul FLOPs one flash-attention call actually executes — the part
-    XLA's cost model cannot see (a Mosaic custom call is opaque to it).
-
-    Counted from the kernel's own structure (ops/flash_attention.py): the
-    forward runs 2 block dots per (q, k) tile pair (scores, P·V); the
-    backward runs 7 (dq pass: recomputed scores, dP, dQ; dkv pass:
-    recomputed scores, dV, dP, dK). Each full-sequence dot is
-    ``2·B·H·Tq·Tk·D`` FLOPs; causal block-skipping halves the executed
-    tiles, and a sliding ``window`` shrinks them to the band area
-    W·T − W(W−1)/2 (self-attention; element-granularity approximation of
-    the tile-granular skip). Training callers add this per flash call (per
-    layer, per step) to the XLA cost-model count."""
-    per_dot = 2.0 * batch * heads * seq_q * seq_k * head_dim
-    dots = 9 if backward else 2
-    if causal and window is not None:
-        # Executed score entries: query row i sees min(w, i + Tk − Tq + 1)
-        # keys (end-aligned causal band, clamped at 0 for rows before the
-        # first key when Tk < Tq) — summed over rows, never negative.
-        w = min(window, seq_k)
-        rows = np.arange(seq_q, dtype=np.float64)
-        visible = np.clip(rows + (seq_k - seq_q) + 1, 0.0, float(w))
-        frac = float(visible.sum()) / (seq_q * seq_k)
-        return dots * per_dot * frac
-    return dots * per_dot * (0.5 if causal else 1.0)
-
-
-def fused_ce_flops(n_tokens: int, d_model: int, vocab: int,
-                   n_chunks: int) -> float:
-    """Matmul FLOPs the fused chunked-CE head (ops/fused_ce.py) executes
-    beyond what XLA's cost model counts. The head's forward and backward
-    each run inside a ``lax.scan`` whose body the cost model counts ONCE
-    but which executes ``n_chunks`` times. Executed per step over all
-    N = B·T tokens: forward logits 2·N·D·V, backward recompute 2·N·D·V +
-    dh 2·N·D·V + dW 2·N·D·V = 8·N·D·V total; counted = that / n_chunks —
-    so the uncounted remainder is 8·N·D·V·(1 − 1/n_chunks)."""
-    return 8.0 * n_tokens * d_model * vocab * (1.0 - 1.0 / max(1, n_chunks))
-
-
-def resolve_peak_flops(calibrate: bool = True) -> tuple:
-    """(per-chip peak FLOP/s, source) for any MFU denominator — shared by
-    bench.py (`_resolve_peak_flops` delegates here) and the live trainer
-    MFU gauge.
+def resolve_peak_flops() -> tuple:
+    """(per-chip peak FLOP/s, source) for the live trainer's MFU gauge.
 
     Resolution order: the explicit ``HVT_PEAK_FLOPS`` override, then the
     built-in peak table keyed by ``device_kind`` (`device_peak_flops`). An
     accelerator the table does not know RAISES: a utilization against a
     guessed peak is worse than none. Only the ``cpu`` platform (the CI
-    topology, which has no published peak) goes on — with
-    ``calibrate=True`` to a matmul measured on this host
-    (`_host_matmul_flops`, source ``"calibrated"``), else to
-    ``(None, "unknown")``. The calibrated value is returned to the caller
-    and nowhere else; pass it on (`mfu(..., peak=)`) rather than through
-    the environment."""
+    topology, which has no published peak) goes on, to
+    ``(None, "unknown")``: a CPU run without the override publishes no
+    utilization at all."""
     if registry.get_raw("HVT_PEAK_FLOPS") is not None:
         return float(registry.get_float("HVT_PEAK_FLOPS")), "override"
     device = jax.devices()[0]
@@ -159,38 +109,7 @@ def resolve_peak_flops(calibrate: bool = True) -> tuple:
             f"{device.device_kind!r}: add it to trace._PEAK_FLOPS with its "
             "source, or set HVT_PEAK_FLOPS"
         )
-    if not calibrate:
-        return None, "unknown"
-    n = int(os.environ.get("BENCH_PEAK_CALIB_N", 1024))
-    return _host_matmul_flops(n), "calibrated"
-
-
-@functools.lru_cache(maxsize=None)
-def _host_matmul_flops(n: int) -> float:
-    """Best-of-3 chained ``n``³ f32 matmul rate on the CPU backend — the
-    trend denominator for CPU CI rows. Measured once per process so every
-    leg of a run divides by the same number."""
-    import jax.numpy as jnp
-
-    a = jnp.ones((n, n), jnp.float32)
-    b = jnp.ones((n, n), jnp.float32)
-    f = jax.jit(lambda a, b: (a @ b).sum())
-    float(jax.device_get(f(a, b)))  # compile + settle
-    reps = 8
-
-    def chain():
-        t = jnp.float32(0)
-        for _ in range(reps):
-            t = t + f(a, b)
-        return float(jax.device_get(t))
-
-    best = None
-    for _ in range(3):
-        t0 = time.perf_counter()
-        chain()
-        dt = (time.perf_counter() - t0) / reps
-        best = dt if best is None else min(best, dt)
-    return 2.0 * n ** 3 / best
+    return None, "unknown"
 
 
 def mfu(flops_per_step: float | None, step_time_s: float, n_chips: int = 1,

@@ -834,10 +834,10 @@ class Trainer:
             # amortized over every step), so the per-step read is a
             # contiguous dynamic slice — random per-step row gathers are
             # latency-bound on TPU and were the e2e step's input cost
-            # (0.68 ms/step at CIFAR shapes vs ~0 after; round 2 measured
-            # them at 31% of the MNIST step). The gather runs over FLATTENED
-            # trailing dims (~9x a multi-dim-trailing gather,
-            # benchmarks/conv_profile.py). HBM cost: a second copy of the
+            # (no benchmark cell runs this device-cached path, so what
+            # they cost is not stated here). The gather runs over FLATTENED
+            # trailing dims (one row index instead of a multi-dimensional
+            # trailing gather). HBM cost: a second copy of the
             # CONSUMED prefix (the full dataset when steps cover the epoch),
             # live alongside `data` for the epoch — the device-cached path
             # trades HBM for zero per-step host/latency cost by design; use
@@ -984,7 +984,7 @@ class Trainer:
         # chunk is consumed exactly once, so its transfer buffer returns to
         # the allocator at dispatch — with the double-buffered prefetcher
         # (data/prefetch.py) two batch-sized buffers alternate instead of
-        # accumulating. Bench/tests reuse batches across calls and must
+        # accumulating. Tests reuse batches across calls and must
         # keep the non-donating forms above.
         self._train_step_donated = train_jit(
             train_step, donate_argnums=state_donate + (1,)
@@ -1084,10 +1084,10 @@ class Trainer:
         `collectives.reduce_gradients` program the explicit step embeds
         (bucketing, order, dcn two-hop, wire dtypes, ZeRO-1 scatter, all
         from the trainer's config). The single attribution source for
-        "how much of a step is comm": bench.py's step_ms.comm legs and
-        the live `StepPhaseSampler` both time exactly this program, so
-        offline BENCH_* rows and the live ``hvt_step_phase_ms{comm}``
-        gauge are the same measurement at different cadences."""
+        "how much of a step is comm" on the host's clock: the live
+        `StepPhaseSampler` times exactly this program for its
+        ``hvt_step_phase_ms{comm}`` gauge, and `hvt-audit` reads its
+        lowered text for the collectives' counts and wire dtypes."""
         import jax.numpy as jnp
 
         P = jax.sharding.PartitionSpec
@@ -1108,8 +1108,8 @@ class Trainer:
                 reverse=self._bucket_reverse,
                 scatter=scatter if scatter > 1 else None,
             )
-            # Scalar data-dependency on every reduced bucket (honest
-            # fetch; see bench._timed).
+            # Scalar data-dependency on every reduced bucket: fetching
+            # it waits for all of them.
             t = sum(
                 jnp.sum(l.astype(jnp.float32)) for l in jax.tree.leaves(out)
             )
@@ -1192,39 +1192,39 @@ class StepPhaseSampler:
     (``HVT_METRICS_PORT``): every ``HVT_METRICS_EVERY`` optimizer steps,
     refresh the ``hvt_step_phase_ms{total,compute,comm,input}``,
     ``hvt_examples_per_sec``, ``hvt_mfu`` and ``hvt_step_seconds``
-    series from a drained measurement window — the bench-time
-    ``step_ms`` accounting (PR 7/12), live.
+    series from a drained measurement window on the host's clock (the
+    device's own times are the benchmark's: `chipbench`, `PERF.md`).
 
-    Measurement contract, matching bench.py's discipline exactly:
+    Measurement contract:
 
     * **total** — wall-clock across the window, blocked at BOTH edges
       (`jax.block_until_ready` on the newest state): with async dispatch
       the python loop runs ahead of the device, so only a drained window
       is an honest mean step time. The drain is the sampler's only
-      recurring pipeline cost — one bubble per window, which the bench
-      overhead A/B gates at <= 2% of ``step_ms.total``
-      (``BENCH_MODEL=zero1``).
+      recurring pipeline cost — one bubble per window; what it costs
+      a step on the chip is not measured (no benchmark cell turns the
+      sampler on).
     * **comm** — the isolated boundary-reduction program
-      (`Trainer.reduction_program` — the SAME attribution bench trusts),
+      (`Trainer.reduction_program`),
       compiled once at the first sample, then re-timed every
       ``comm_refresh`` samples (default 8) and CACHED in between: the
       comm split is structural (buckets, wires, topology) and drifts at
       network-degradation timescales, while re-timing it every window
       was the dominant recurring sampler cost (a full isolated
-      reduction per window blew the 2% overhead budget on comm-heavy
+      reduction per window on comm-heavy
       steps). The published comm gauge therefore refreshes every
       ``comm_refresh x every`` optimizer steps.
     * **input** — host time the fit loop spent blocked on the prefetcher
       (`add_input_wait`), amortized per step.
     * **compute** — the remainder, clamped >= 0; phases are clamped to
-      sum to total (the PR 7 coherence rule — bench exits non-zero on
-      phase > total, the live gauges clamp instead: an observability
+      sum to total (the PR 7 coherence rule: no phase exceeds total;
+      the live gauges clamp rather than raise: an observability
       surface must not kill training over a scheduling blip).
     * **mfu** — XLA cost-model FLOPs of the compiled step executable
       (per optimizer step) against `trace.resolve_peak_flops` x chips.
       Custom-call kernels (flash attention, fused CE) are opaque to the
       cost model, so this gauge UNDER-counts for those models — a live
-      trend signal; the calibrated BENCH_* rows stay the MFU headline.
+      trend signal; the benchmark's per-layer ``mfu`` is the headline.
 
     The first ``maybe_sample`` call only opens the window (and pays the
     one-time warmups: reduction-program compile, step-flops cost
@@ -1296,6 +1296,7 @@ class StepPhaseSampler:
         """After each execution's dispatch: account ``steps`` optimizer
         steps; at the cadence boundary, drain and publish."""
         from horovod_tpu import obs
+        from horovod_tpu import trace as trace_lib
 
         self._steps += steps
         if self._window_t0 is not None and self._steps < self.every:
@@ -1331,15 +1332,16 @@ class StepPhaseSampler:
             n_chips = int(self.trainer.mesh.devices.size)
             obs.gauge("hvt_peak_flops_per_chip", peak)
             obs.gauge(
-                "hvt_mfu", self._flops / total_s / (peak * n_chips)
+                "hvt_mfu",
+                trace_lib.mfu(self._flops, total_s, n_chips, peak=peak),
             )
         obs.counter("hvt_step_samples_total")
         self.samples += 1
         if self.skew_probe is not None:
             # One tiny allgather of host timings per sample window —
             # OUTSIDE the published window (the re-edge below restarts
-            # the clock after it), its cost charged to the sampler and
-            # covered by the bench sampler-overhead A/B gate. The
+            # the clock after it), its cost charged to the sampler,
+            # not to the step time it publishes. The
             # signal is per-step BLOCKED time: host seconds inside the
             # step calls plus the drain, covering both dispatch regimes
             # (SkewProbe docstring).
@@ -1347,8 +1349,8 @@ class StepPhaseSampler:
                 (self._step_call_s + drain_s) / self._steps
             )
         # Re-edge AFTER the sampling work: the published step time
-        # measures training, not the sampler; the sampler's own cost is
-        # what the bench overhead A/B measures.
+        # measures training, not the sampler; the sampler's own cost
+        # falls between two windows.
         self._window_t0 = time.perf_counter()
         self._steps = 0
         self._input_s = 0.0
@@ -1359,7 +1361,7 @@ class StepPhaseSampler:
     def _warmup(self, state) -> None:
         from horovod_tpu import trace as trace_lib
 
-        self._peak = trace_lib.resolve_peak_flops(calibrate=True)
+        self._peak = trace_lib.resolve_peak_flops()
         # The two probes below are attribution only: a failure costs a
         # gauge (comm reads 0 so compute == total; hvt_mfu is absent),
         # never the training run — but it is said, not swallowed.
@@ -1463,8 +1465,8 @@ class SkewProbe:
     the trainer exporter is on (the probe only exists inside the
     sampler) AND the run is multi-process; ``HVT_SKEW_PROBE=0`` is the
     kill switch. Cost: one object allgather per sample window, outside
-    the published timing window, charged to the sampler overhead the
-    bench A/B gates."""
+    the published timing window, charged to the sampler and not to
+    the step time it publishes."""
 
     def __init__(self):
         self.rank = runtime.process_rank()

@@ -3,13 +3,15 @@
 The repo already records everything an analytic tuner needs, it just
 records it in four places. This module is the funnel:
 
-* **BENCH_* rows** (``{"n", "cmd", "rc", "tail"}`` with a JSON tail
-  printed by bench.py) — per-bucket ``step_ms.comm_buckets`` timings,
-  the step decomposition, the serialized-vs-overlapped pair, the
-  structural ``wire_bytes_per_opt_step``. Rows stamped with a
+* **BENCH_* rows** (files named ``BENCH_*.json`` in the evidence
+  directory: ``{"n", "cmd", "rc", "tail"}`` with a JSON tail, the
+  format of a measurement script that is gone; nothing in the tree
+  writes them now, ROADMAP D6) — per-bucket ``step_ms.comm_buckets``
+  timings, the step decomposition, the serialized-vs-overlapped pair,
+  the structural ``wire_bytes_per_opt_step``. Rows stamped with a
   ``config:`` block (PR 19) are self-describing; LEGACY rows without
-  one get their tunable values inferred from the row keys bench has
-  always emitted (``bucket_bytes``, ``k``, ``compression`` ...).
+  one get their tunable values inferred from the row's own keys
+  (``bucket_bytes``, ``k``, ``compression`` ...).
 * **hvt-trace spans** (``HVT_TRACE_DIR`` JSONL) — per-phase wall-time
   attribution via `obs.timeline.phase_attribution`, used to
   cross-check the input/compute split.
@@ -49,7 +51,7 @@ def wire_ratio(name: str) -> float:
 def load_rows(evidence_dir: str) -> list[dict]:
     """Parse every BENCH_*.json under ``evidence_dir`` into tail dicts.
 
-    Each returned dict is the bench tail with bookkeeping keys added:
+    Each returned dict is the row's tail with bookkeeping keys added:
     ``_source`` (filename) and ``_cmd`` (the recorded command line).
     Unparseable files are skipped — stale evidence must not brick the
     tuner. Sorted by filename, so the NEWEST row (highest r-number)
@@ -78,7 +80,7 @@ def config_of(row: dict) -> dict:
     """The tunable-knob values a row ran under.
 
     Rows since PR 19 carry an explicit ``config:`` block; legacy rows
-    are inferred from the measurement keys bench always emitted, with
+    are inferred from the measurement keys every row carries, with
     registry defaults filling the gaps.
     """
     cfg = dict(space.default_config())
@@ -87,7 +89,7 @@ def config_of(row: dict) -> dict:
         "HVT_BACKWARD_PASSES": row.get("k"),
         "HVT_COMPRESSION": row.get("compression"),
         "HVT_COMPRESSION_ICI": row.get("compression_ici"),
-        # bench's zero1 headline leg has always been the overlapped one
+        # a row's headline leg has always been the overlapped one
         # (serialized is the B leg) — a row reporting overlap_fraction
         # measured with the overlap on.
         "HVT_OVERLAP_REDUCTION": (True if "overlap_fraction" in row
@@ -139,7 +141,7 @@ def load_trace(trace_dir: str | None) -> dict:
     """Per-phase wall-time attribution from hvt-trace spans, or {}.
 
     Imported lazily: the obs layer is optional evidence, and the tuner
-    must work from bench rows alone."""
+    must work from recorded rows alone."""
     if not trace_dir or not os.path.isdir(trace_dir):
         return {}
     try:

@@ -15,7 +15,7 @@ block (the launcher's ``--tune`` flag builds the same block):
 model's shortlist and races each candidate against the config the job
 would otherwise run — a few REAL steps apiece in a subprocess (the
 launcher process must never initialize jax), decided by the same
-paired-leg discipline as every bench gate (`tune.probe`).
+paired-leg discipline (`tune.probe`).
 
 The winner is written into the resolved env (spec-pinned env still
 wins: an operator's explicit knob is a decision, not a suggestion) and
@@ -213,8 +213,8 @@ def resolve(block: dict, env: dict, *, workdir: str | None = None,
 def build_probe_step(config: dict, *, hidden: int = 1024,
                      per_chip_batch: int = 16, steps: int = 3):
     """Compile one candidate config into a zero-arg timed leg: ``steps``
-    real ZeRO-1 optimizer steps at the bench MLP shape, fused into one
-    program with an honest data-dependent fetch (see bench._timed).
+    real ZeRO-1 optimizer steps of a two-layer MLP, fused into one
+    program whose fetched scalar depends on every step's result.
 
     jax-heavy — only the probe subprocess calls this."""
     import jax
@@ -264,8 +264,8 @@ def build_probe_step(config: dict, *, hidden: int = 1024,
 
     def step_batch():
         # One optimizer step's feed: [G, F] for k=1, a [k, G, F]
-        # microbatch stack for the accumulating step (bench.measure's
-        # shape contract for _train_chunk).
+        # microbatch stack for the accumulating step (`_train_chunk`'s
+        # shape contract).
         if k == 1:
             return draw()
         micro = [draw() for _ in range(k)]

@@ -147,13 +147,13 @@ def fit(rows: list[dict], trace: dict | None = None) -> CostModel:
 
     ``trace``, when given (`evidence.load_trace`), cross-checks the
     input attribution: if the traced input phase is slower than the
-    bench row's input column, trust the trace (bench hides staged input
-    behind the prefetch queue; the trace sees the drain)."""
+    row's input column, trust the trace (a row's timing hides staged
+    input behind the prefetch queue; the trace sees the drain)."""
     anchor = evidence_lib.anchor_row(rows)
     if anchor is None:
         raise FitError(
-            "no usable evidence: need at least one BENCH_* row with "
-            "step_ms.comm_buckets (run BENCH_MODEL=zero1 python bench.py)"
+            "no usable evidence: need at least one BENCH_*.json row with "
+            "step_ms.comm_buckets in the evidence directory"
         )
     points = evidence_lib.comm_points(rows)
     if not points:

@@ -1,6 +1,5 @@
-"""Paired-leg A/B measurement: the discipline every timed comparison
-in this repo uses, extracted from bench.py (PR 13) so the autotuner can
-point it at itself.
+"""Paired-leg A/B measurement: the discipline of the autotuner's
+in-situ probe race (`tune.insitu`).
 
 A naive A/B on a shared noisy host crowns fake winners two ways:
 monotone machine drift (thermal, cache warming) systematically favors
@@ -30,7 +29,7 @@ __all__ = ["PairedResult", "paired_compare", "median"]
 
 
 def median(xs) -> float:
-    """Upper median — matches the bench gate's sorted()[n // 2]."""
+    """Upper median: sorted()[n // 2]."""
     xs = sorted(xs)
     if not xs:
         raise ValueError("median of empty sequence")

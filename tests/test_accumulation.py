@@ -85,7 +85,7 @@ def _trainer(module, k=1, compression="none", bucket_bytes=None, seed=3,
 
 # The lowered-step plumbing and the gradient-traffic discrimination are
 # `analysis.step_probe.lowered_step_text` + `analysis.hlo_audit` since
-# PR 9 — one implementation, shared with bench.py and `hvt-audit`.
+# PR 9 — one implementation, shared with `hvt-audit`.
 
 
 class TestTrajectoryEquivalence:

@@ -395,7 +395,7 @@ class TestBuildTracesFusedPath:
     def test_init_receives_labels_under_module_loss(self):
         # build() must init with dummy labels so the module traces the
         # fused-CE branch — the dense [B, T, vocab] branch at init is the
-        # OOM point at long-context scale (ADVICE r3, trainer.py build).
+        # OOM point at long-context scale (an earlier review's finding, trainer.py build).
         seen = []
 
         class Rec(nn.Module):

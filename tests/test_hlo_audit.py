@@ -4,7 +4,7 @@
 Parser units run over handcrafted fixtures of BOTH text dialects jax
 emits (lowered StableHLO, post-optimization HLO), then the integration
 tests audit real lowered trainer steps through `analysis.step_probe` —
-the same plumbing bench.py and the migrated perf-path tests ride. The
+the same plumbing the migrated perf-path tests ride. The
 CLI subprocess tests pin the exit-code contract (0 clean / 1 violation
 / 2 usage) and are the tier-1 gate for the canonical K=4 + int8 step:
 `hvt-audit step` must fail loudly when the HVT_OVERLAP_REDUCTION or
@@ -94,7 +94,7 @@ class TestParsers:
         ]
 
     def test_gradient_discrimination(self):
-        """The shared bench discrimination: scalar all-reduces (metric
+        """The shared discrimination: scalar all-reduces (metric
         means) and rank-1 gathers (quantized-wire per-bucket scales) are
         NOT gradient traffic; non-scalar all-reduces and rank>=2 payload
         gathers are."""

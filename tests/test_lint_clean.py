@@ -12,6 +12,7 @@ Three drift directions are closed here:
   read is drift too, just in the other direction).
 """
 
+import ast
 import os
 import re
 import subprocess
@@ -140,11 +141,8 @@ class TestEnvvarsDoc:
         """Reverse drift: a registered knob nothing references anymore
         should be deleted from the registry (and thus from the doc)."""
         referenced = set()
-        roots = [PACKAGE, os.path.join(REPO, "examples"),
-                 os.path.join(REPO, "benchmarks"),
-                 os.path.join(REPO, "bench.py")]
-        for path in core.iter_python_files(p for p in roots
-                                           if os.path.exists(p)):
+        roots = [PACKAGE, os.path.join(REPO, "examples")]
+        for path in core.iter_python_files(roots):
             if os.path.abspath(path).startswith(
                 os.path.join(PACKAGE, "analysis") + os.sep
             ):
@@ -156,6 +154,33 @@ class TestEnvvarsDoc:
             f"registered knobs referenced nowhere: {unused} — remove the "
             "Knob rows and regenerate docs/ENVVARS.md"
         )
+
+    def test_unregistered_environment_reads_are_the_two_contracts(self):
+        """HVT004 only knows the ``HVT_`` prefix, so a literal
+        ``os.environ`` read of any other name passes the lint unseen (a
+        measuring script's switch once lived in `trace.py` that way). The
+        package reads two such names, both contracts with its
+        surroundings: the platform's export directory and jax's own
+        cache directory."""
+        read = set()
+        for path in core.iter_python_files([PACKAGE]):
+            with open(path, encoding="utf-8") as f:
+                tree = ast.parse(f.read(), filename=path)
+            for node in ast.walk(tree):
+                if isinstance(node, ast.Call) and node.args:
+                    fn, key = ast.unparse(node.func), node.args[0]
+                    if fn not in ("os.environ.get", "os.getenv"):
+                        continue
+                elif (isinstance(node, ast.Subscript)
+                      and ast.unparse(node.value) == "os.environ"):
+                    key = node.slice
+                else:
+                    continue
+                if isinstance(key, ast.Constant) and isinstance(key.value, str):
+                    read.add(key.value)
+        assert read - set(registry.KNOBS) == {
+            "PS_MODEL_PATH", "JAX_COMPILATION_CACHE_DIR",
+        }
 
     def test_readme_links_envvars_doc(self):
         with open(os.path.join(REPO, "README.md")) as f:

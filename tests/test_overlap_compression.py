@@ -16,7 +16,6 @@ PR 7's proof obligations:
 * The error-feedback residual lives in opt_state (`ErrorFeedbackState`),
   survives a checkpoint save/restore roundtrip, and an elastic reshard
   re-cuts it mass-conserving.
-* bench.py's phase guard rejects any phase exceeding step_ms.total.
 """
 
 import flax.linen as nn
@@ -72,7 +71,7 @@ def _fit_params(tr, x, y, k, steps=4):
 
 # Lowered-step plumbing + the gradient-traffic discrimination live in
 # `analysis.step_probe` / `analysis.hlo_audit` since PR 9 (one
-# implementation, shared with bench.py and `hvt-audit`).
+# implementation, shared with `hvt-audit`).
 
 
 class TestOverlapEquivalence:
@@ -337,34 +336,6 @@ class TestResidualStateSurfaces:
         jax.tree.map(
             lambda u, v: np.testing.assert_array_equal(u, v), want, got
         )
-
-
-class TestBenchPhaseGuard:
-    def _guard(self):
-        import bench
-
-        return bench._phase_overruns
-
-    def test_consistent_breakdown_passes(self):
-        assert self._guard()(
-            {"total": 1.0, "compute": 0.5, "comm": 0.2, "input": 0.3}
-        ) == []
-
-    def test_phase_exceeding_total_flagged(self):
-        # the r04 regression shape: compute 0.281 > total 0.256
-        bad = self._guard()(
-            {"total": 0.256, "compute": 0.281, "input": 0.0}
-        )
-        assert "compute" in bad
-
-    def test_phases_summing_past_total_flagged(self):
-        bad = self._guard()(
-            {"total": 1.0, "compute": 0.7, "comm": 0.2, "input": 0.3}
-        )
-        assert "sum(phases)" in bad
-
-    def test_missing_breakdown_is_not_an_error(self):
-        assert self._guard()({}) == []
 
 
 class TestKnobRegistry:

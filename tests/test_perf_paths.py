@@ -234,6 +234,29 @@ class TestTraceAccounting:
     def test_peak_flops_none_on_cpu(self):
         assert trace.device_peak_flops(jax.devices()[0]) is None
 
+    def test_resolved_peak_on_cpu_is_unknown_and_measures_nothing(
+            self, monkeypatch):
+        """No override, CPU platform: no peak — and no computation is
+        run to make one up (a host matmul once stood in for it)."""
+        monkeypatch.delenv("HVT_PEAK_FLOPS", raising=False)
+
+        def refuse(*a, **k):
+            raise AssertionError("resolve_peak_flops compiled something")
+
+        monkeypatch.setattr(jax, "jit", refuse)
+        assert trace.resolve_peak_flops() == (None, "unknown")
+
+    def test_resolved_peak_refuses_an_unknown_accelerator(self,
+                                                          monkeypatch):
+        class FakeDev:
+            device_kind = "NPU 9000"
+            platform = "npu"
+
+        monkeypatch.delenv("HVT_PEAK_FLOPS", raising=False)
+        monkeypatch.setattr(jax, "devices", lambda *a: [FakeDev()])
+        with pytest.raises(ValueError, match="NPU 9000"):
+            trace.resolve_peak_flops()
+
     def test_mfu_math(self):
         class FakeDev:
             device_kind = "TPU v5 lite"

@@ -854,7 +854,7 @@ def test_slow_fault_e2e_straggler_named_and_fleet_scraped(tmp_path, capfd):
         "HVT_METRICS_PORT": str(base),
         "HVT_METRICS_EVERY": "1",   # drain every step: max skew signal
         "HVT_FLEET_POLL_S": "0.5",  # cache member scrapes fast
-        "HVT_PEAK_FLOPS": "1e12",   # skip the matmul calibration
+        "HVT_PEAK_FLOPS": "1e12",   # the CPU has no published peak
         "JAX_ENABLE_COMPILATION_CACHE": "0",
         "JAX_COMPILATION_CACHE_DIR": "",
     }

@@ -212,7 +212,7 @@ class TestShardUpdate:
 
 
 class TestModuleLossBuildHint:
-    """Regression for the ADVICE build() fallback: with loss='module' and no
+    """Regression for an earlier review's finding, the build() fallback: with loss='module' and no
     sample_y, labels are synthesized as zeros_like(sample_x) (the LM-family
     contract); a module whose labels differ in dtype/shape fails deep inside
     init — the re-raise must name the fix (pass sample_y)."""

@@ -619,7 +619,7 @@ class TestGlobalLocalOnMesh:
 
 
 class TestLoRASpecExemption:
-    """Regression for the ADVICE is_lora tightening: the TP/EP exemption is
+    """Regression for an earlier review's finding, the is_lora tightening: the TP/EP exemption is
     for LoRAModel *adapter* leaves (a 'lora' subtree with 'a'/'b' leaves) —
     a user submodule merely NAMED 'lora' must still get its kernels
     TP-sharded, or it silently trains unsharded."""

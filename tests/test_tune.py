@@ -15,10 +15,15 @@ Covers the pieces in isolation and the seams between them:
 * in-situ `resolve`: selection, the persisted store, restart REUSE
   (the prober must not run twice), journal event shapes;
 * the `tune:` job-spec surface (validate_spec, the shipped YAML);
-* the `hvt-tune offline --check` tier-1 gate over the repo's own
-  recorded evidence, end to end through the real CLI;
+* the `hvt-tune offline --check` tier-1 gate over the fixture rows in
+  ``tests/fixtures/tune_evidence/``, end to end through the real CLI;
 * slow: predicted ranking matches the MEASURED A/B ranking on three
   real candidate configs (the offline acceptance gate).
+
+``BENCH_*.json`` is the file-name pattern `evidence.load_rows` globs in an
+evidence directory, and the rows' ``"cmd"`` strings are what the fixture
+rows recorded: neither names a script in the tree (the one that wrote the
+fixture's rows was deleted in PR 31).
 """
 
 import json
@@ -33,6 +38,7 @@ from horovod_tpu.analysis import registry
 from horovod_tpu.tune import evidence, insitu, model, offline, probe, space
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+EVIDENCE = os.path.join(REPO, "tests", "fixtures", "tune_evidence")
 
 MB = 1 << 20
 
@@ -605,18 +611,19 @@ class TestJobSpecTune:
         assert validate_spec(spec) == []
 
 
-# --- tier-1 gate: the tuner is trustworthy on the repo's own evidence -------
+# --- tier-1 gate: the tuner is trustworthy on the fixture's evidence --------
 
 
 class TestOfflineCheckClean:
-    """`hvt-tune offline --check` over the committed BENCH_* rows — the
-    recorded evidence loads, the model reproduces the measured anchor,
+    """`hvt-tune offline --check` over the fixture's BENCH_* rows (three,
+    recorded on 8 virtual CPU devices: the report's shape, no speed of
+    the system) — the recorded evidence loads, the model reproduces the measured anchor,
     and the search beats its own anchor (ISSUE 19's --check gate)."""
 
     def test_check_exits_zero_on_repo_evidence(self):
         proc = subprocess.run(
             [sys.executable, "-m", "horovod_tpu.tune", "offline",
-             "--check", "--evidence", REPO],
+             "--check", "--evidence", EVIDENCE],
             cwd=REPO, capture_output=True, text=True, timeout=120,
         )
         assert proc.returncode == 0, proc.stdout + proc.stderr
@@ -625,7 +632,7 @@ class TestOfflineCheckClean:
     def test_offline_report_runs_end_to_end(self):
         proc = subprocess.run(
             [sys.executable, "-m", "horovod_tpu.tune", "offline",
-             "--evidence", REPO, "--top", "5"],
+             "--evidence", EVIDENCE, "--top", "5"],
             cwd=REPO, capture_output=True, text=True, timeout=120,
         )
         assert proc.returncode == 0, proc.stdout + proc.stderr

@@ -760,56 +760,17 @@ class TestQuantizedTwoShot:
             )
 
 
-class TestBenchZero1Gates:
-    """Pure-function units for the new bench gates (the wall-clock
-    overlap gate and MFU-denominator guard run in bench.py's main;
-    their decision logic is unit-tested here)."""
-
-    def _bench(self):
-        import importlib.util
-        import os
-
-        path = os.path.join(
-            os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-            "bench.py",
-        )
-        spec = importlib.util.spec_from_file_location("_bench_mod", path)
-        mod = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(mod)
-        return mod
-
-    def test_flops_guard_accepts_peel_structure(self):
-        bench = self._bench()
-        micro = 1e9
-        # K=4 overlap on: first microbatch + peeled last + scan body =
-        # 3 statically counted microbatches; the compiled count sits in
-        # [2.5, 3.5] x micro.
-        g = bench._flops_guard(4, True, micro, 2.9e9)
-        assert g["ok"] and g["counted_microbatches"] == 3
-        # K=4 overlap off: first + scan body = 2.
-        g2 = bench._flops_guard(4, False, micro, 2.1e9)
-        assert g2["ok"] and g2["counted_microbatches"] == 2
-
-    def test_flops_guard_catches_structure_drift(self):
-        bench = self._bench()
-        micro = 1e9
-        # Peel silently gone: the program statically counts one less
-        # microbatch than the overlap-on structure implies.
-        assert not bench._flops_guard(4, True, micro, 1.9e9)["ok"]
-        # Scan silently unrolled: every microbatch counted.
-        assert not bench._flops_guard(4, True, micro, 4.2e9)["ok"]
-
-    def test_flops_guard_skips_without_cost_model(self):
-        bench = self._bench()
-        g = bench._flops_guard(4, True, None, None)
-        assert g["ok"] and g["skipped"]
-        assert bench._flops_guard(1, True, 1e9, 1e9)["skipped"]
+class TestPeakFlopsOverride:
+    """`HVT_PEAK_FLOPS`, the MFU gauge's denominator where the table has
+    no entry: resolved, refused when unparseable, and what `trace.mfu`
+    divides by."""
 
     def test_peak_flops_override_resolves_without_calibration(self,
                                                               monkeypatch):
-        bench = self._bench()
+        from horovod_tpu import trace
+
         monkeypatch.setenv("HVT_PEAK_FLOPS", "1.5e12")
-        peak, src = bench._resolve_peak_flops()
+        peak, src = trace.resolve_peak_flops()
         assert peak == 1.5e12 and src == "override"
 
     def test_unparseable_peak_override_is_loud(self, monkeypatch):
