@@ -710,13 +710,27 @@ class LMHead(nn.Module):
         is the ONE cross-chip sum of dW, after the backward loop. Left to
         the partitioner, the scans walk the flattened (sharded) row axis
         and every chip gathers and computes every chunk. ``model`` stays
-        the partitioner's (the region is manual over the row axes only)."""
+        the partitioner's (the region is manual over the row axes only),
+        and where it is live the op is told so: scanning the vocabulary
+        there makes the partitioner gather the kernel and every chip of a
+        ``model`` group compute every slice, so the rows are scanned."""
+        mesh = self.sharding.mesh
+        vocab_split = mesh is not None and mesh.shape.get(MODEL_AXIS, 1) > 1
+        shards = 1 if mesh is None else _row_shards(mesh, *labels.shape)
+        # Trace time, from the chip's own rows: the backward rule's choice
+        # is static, so this says which program was built.
+        from horovod_tpu import obs
+
+        by_vocab = fused_ce.scans_vocab(
+            labels.size // shards, self.vocab_size, vocab_split)
+        obs.gauge("hvt_head_ce_scan", float(by_vocab), axis="vocab")
+        obs.gauge("hvt_head_ce_scan", float(not by_vocab), axis="rows")
+
         def head(x, kernel, labels):
             return fused_ce.fused_linear_cross_entropy(
-                x, kernel, labels, max(1, n_chunks))
+                x, kernel, labels, max(1, n_chunks), vocab_split)
 
-        mesh = self.sharding.mesh
-        if mesh is not None and _rows_split(mesh, *labels.shape):
+        if shards > 1:
             rows = P(BATCH_AXES, SEQ_AXIS)
             # jitted: op by op (`Trainer.build`'s init) JAX refuses a
             # region that is manual over some of the mesh's axes only.
@@ -731,12 +745,13 @@ class LMHead(nn.Module):
         return head(x.astype(self.compute_dtype), self.kernel, labels)
 
 
-def _rows_split(mesh: Mesh, b: int, t: int) -> bool:
-    """Whether ``mesh`` splits a ``[b, t]`` batch's rows over more than one
-    device, evenly (a `shard_map` takes no ragged shard)."""
+def _row_shards(mesh: Mesh, b: int, t: int) -> int:
+    """Over how many devices ``mesh`` splits a ``[b, t]`` batch's rows; 1
+    where it does not, or not evenly (a `shard_map` takes no ragged
+    shard)."""
     dp = mesh.shape.get(DATA_AXIS, 1) * mesh.shape.get(FSDP_AXIS, 1)
     sp = mesh.shape.get(SEQ_AXIS, 1)
-    return dp * sp > 1 and b % dp == 0 and t % sp == 0
+    return dp * sp if b % dp == 0 and t % sp == 0 else 1
 
 
 class TransformerLM(nn.Module):
@@ -744,7 +759,7 @@ class TransformerLM(nn.Module):
 
     With ``labels=...`` passed to ``__call__`` the model instead returns
     ``(per_token_loss, per_token_correct)`` computed by the fused chunked-CE
-    head (``fused_head_chunks`` row-chunks; see ops/fused_ce.py) — the
+    head (``fused_head_chunks`` chunks; see ops/fused_ce.py) — the
     ``Trainer(loss='module')`` contract. Without labels the full-logits path
     is unchanged (predict/decode/export)."""
 

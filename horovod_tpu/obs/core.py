@@ -314,6 +314,15 @@ METRICS: dict[str, MetricSpec] = _decl([
                "far the overlapped-reduction compile options engaged "
                "(0 off TPU and wherever the compiler took none).",
                "training"),
+    MetricSpec("hvt_head_ce_scan", "gauge",
+               "Which axis the chunked head + CE's backward rule scans "
+               "(ops/fused_ce.py): 1 on the axis of the last program "
+               "traced, 0 on the other. `vocab` where a chip's rows are "
+               "fewer than the vocabulary (the running float32 sum is dh "
+               "[rows, D]), `rows` otherwise (it is dW [D, V]). Set by "
+               "`LMHead.fused_loss` at trace time: the choice is static "
+               "per program.",
+               "training", labels=("axis",)),
     MetricSpec("hvt_optimizer_steps_total", "counter",
                "Optimizer steps this process's fit loops have handed to "
                "the device (counted in the loop, exporter on or off).",

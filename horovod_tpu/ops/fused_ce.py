@@ -5,13 +5,38 @@ in the forward pass and once as the backward cotangent — and at long context
 those two arrays dominate HBM (at seq 131k they are the OOM driver the
 ``logits_dtype=bf16`` knob only halves).
 This op computes ``cross_entropy(h @ W, labels)`` without ever building the
-full logits array: a `lax.scan` over row-chunks computes each chunk's
-``[C, vocab]`` logits tile on the fly — forward for the logsumexp, again in
-the backward for the softmax — so peak extra memory is
-O(chunk · vocab) instead of O(B · T · vocab), trading one extra head matmul
-(recompute) for the two big arrays. The per-chunk matmuls stay MXU-shaped
-(``[C, D] @ [D, V]`` with f32 accumulation), so the recompute rides the
-systolic array rather than fighting it.
+full logits array: a loop computes one ``n_chunks``-th of the logits at a
+time as a tile on the fly — forward for the logsumexp, again in the
+backward for the softmax — so peak extra memory is O(B · T · vocab /
+n_chunks) instead of O(B · T · vocab), trading one extra head matmul
+(recompute) for the two big arrays. The per-tile matmuls stay MXU-shaped
+with f32 accumulation, so the recompute rides the systolic array rather
+than fighting it.
+
+Which axis the backward scans. The forward is a `lax.scan` over row-chunks
+(tiles ``[⌈N/n⌉, V]``; it carries nothing) and saves each row's logsumexp.
+The backward has two gradients and can finish only one of them per tile:
+the other is a running float32 sum that is read and written once a tile,
+and that traffic bounds the matmul that feeds it (PERF.md, PR 32: the
+``[D, V]`` sum of a 50k vocabulary, 0.4–0.6 GB eight times a step, held the
+dW matmul at 43 % of the MXU's peak and 85 % of HBM bandwidth). So the rule
+scans the axis that leaves the sum on the smaller side, chosen from the two
+sizes it is traced with (`scans_vocab`):
+
+* rows N ≥ vocabulary V (long context on one chip; a small vocabulary): a
+  scan over row-chunks. The sum is dW ``[D, V]``; each chunk's dh is final.
+* N < V (an LM's training step: 4,096 rows a chip against 50k entries): a
+  loop over ``n_chunks`` lane-aligned slices of the vocabulary, tiles
+  ``[N, ⌈V/n⌉]`` of the same size. The softmax is ``exp(logits − lse)``
+  with the forward's own logsumexp, each dW slice ``[D, ⌈V/n⌉]`` is final
+  and written once, in place, and the sum is dh ``[N, D]``, V/N times
+  smaller, cast to ``h.dtype`` once after the loop.
+
+Same operands (``compute_dtype``), same float32 accumulation, the same
+8·N·D·V of work either way. The sub-scope of the backward loop
+(``hvt.head_ce/vocab_scan`` or ``hvt.head_ce/row_scan``) says which was
+built, and so does the gauge ``hvt_head_ce_scan{axis=}`` that the caller,
+``LMHead.fused_loss``, sets from `scans_vocab` as it traces the head.
 
 This is the moral equivalent of the "fused linear cross-entropy" kernels in
 GPU land, expressed TPU-natively: `lax.scan` + `jax.custom_vjp` and XLA's
@@ -39,7 +64,10 @@ counts chunks of those), neither scan holds a collective, and the
 transpose of the replicated kernel is the one cross-chip sum of dW, after
 the backward loop, in the kernel's dtype (float32 parameters: the float32
 the rule accumulates in). ``model`` (the kernel's vocabulary dimension)
-stays the partitioner's. A model built
+stays the partitioner's, and there the caller passes ``vocab_split``: the
+backward scans the rows whatever the sizes, because a loop over slices of
+a dimension the partitioner has split makes it gather the kernel first
+and every chip of a ``model`` group compute every slice. A model built
 without a mesh and run under a multi-device Trainer (``attn='dense'``)
 cannot know the mesh and keeps the replicated head.
 """
@@ -59,6 +87,12 @@ from jax import lax
 # in BOTH rules of the custom_vjp: the backward rule is traced apart from the
 # forward and would not inherit a scope opened inside it.
 SCOPE = "hvt.head_ce"
+# Under it, the backward rule's loop by the axis it scans (`scans_vocab`):
+# a trace's op names say which program was built.
+ROW_SCAN, VOCAB_SCAN = "row_scan", "vocab_scan"
+# A vocabulary slice's width is a multiple of the TPU's 128 lanes, so that
+# every slice of the kernel and of dW starts on a tile boundary.
+LANES = 128
 
 
 def _chunk_logits(hc, w, compute_dtype):
@@ -71,8 +105,9 @@ def _chunk_logits(hc, w, compute_dtype):
     )
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def fused_linear_cross_entropy(h, w, labels, n_chunks: int = 8):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def fused_linear_cross_entropy(h, w, labels, n_chunks: int = 8,
+                               vocab_split: bool = False):
     """Per-token CE loss of ``h @ w`` against integer ``labels``, chunked.
 
     Args:
@@ -80,10 +115,16 @@ def fused_linear_cross_entropy(h, w, labels, n_chunks: int = 8):
         ``[B, T, D]``), f32 or bf16.
       w: ``[D, V]`` head kernel (the LM head's ``lm_head/kernel`` param).
       labels: integer ``[...]`` matching ``h``'s leading shape.
-      n_chunks: static number of row-chunks the flattened ``B·T`` rows are
-        scanned in; peak logits memory is ``ceil(B·T / n_chunks) · V`` floats
-        (per forward or backward scan step). Inside a `shard_map` the rows
-        are the chip's own (see the module docstring).
+      n_chunks: static number of chunks: of the flattened ``B·T`` rows in
+        the forward scan and in a backward that scans rows (a tile of
+        ``ceil(B·T / n_chunks) · V`` floats a step), of the vocabulary in
+        a backward that scans it (``B·T · ceil(V / n_chunks)``, the slice
+        rounded up to whole lanes). Inside a `shard_map` the rows are the
+        chip's own (see the module docstring).
+      vocab_split: static; what the caller that holds the mesh sees: the
+        partitioner splits ``w``'s vocabulary dimension over chips (a live
+        ``model`` axis). The backward rule then scans the rows whatever
+        the sizes (see "Which axis the backward scans").
 
     Returns:
       ``(loss, correct)`` — per-token f32 loss ``lse - logit[label]`` and a
@@ -125,23 +166,40 @@ def _fwd(h, w, labels, n_chunks):
         lse = jax.nn.logsumexp(logits, axis=-1)
         ll = jnp.take_along_axis(logits, lck[:, None], axis=-1)[:, 0]
         correct = (jnp.argmax(logits, axis=-1) == lck).astype(jnp.float32)
-        return None, (lse - ll, correct)
+        return None, (lse - ll, correct, lse)
 
-    _, (loss_c, corr_c) = lax.scan(body, None, (hc, lc))
-    loss = loss_c.reshape(-1)[:n].reshape(lead)
-    correct = corr_c.reshape(-1)[:n].reshape(lead)
-    return loss, correct, (h, w, labels)
+    _, (loss_c, corr_c, lse_c) = lax.scan(body, None, (hc, lc))
+    loss, correct, lse = (
+        x.reshape(-1)[:n].reshape(lead) for x in (loss_c, corr_c, lse_c))
+    return loss, correct, (h, w, labels, lse)
 
 
-def _fwd_vjp(h, w, labels, n_chunks):
+def _fwd_vjp(h, w, labels, n_chunks, vocab_split):
     loss, correct, res = _fwd(h, w, labels, n_chunks)
     return (loss, correct), res
 
 
+def scans_vocab(n_rows: int, vocab: int, vocab_split: bool = False) -> bool:
+    """Which axis the backward rule scans: the one that leaves the running
+    float32 sum on the smaller side. Fewer rows than vocabulary entries:
+    scan the vocabulary (the sum is dh ``[N, D]``, each dW slice is final);
+    otherwise, and wherever the partitioner splits the vocabulary
+    (``vocab_split``), scan the rows (the sum is dW ``[D, V]``, each dh
+    chunk is final). All three are static at trace time."""
+    return not vocab_split and n_rows < vocab
+
+
 @jax.named_scope(SCOPE)
-def _bwd_vjp(n_chunks, res, cts):
-    h, w, labels = res
+def _bwd_vjp(n_chunks, vocab_split, res, cts):
+    h, w, labels, lse = res
     g_loss, _ = cts  # `correct` is piecewise constant — cotangent discarded
+    if scans_vocab(labels.size, w.shape[-1], vocab_split):
+        return _bwd_vocab_scan(n_chunks, h, w, labels, lse, g_loss)
+    return _bwd_row_scan(n_chunks, h, w, labels, g_loss)
+
+
+@jax.named_scope(ROW_SCAN)
+def _bwd_row_scan(n_chunks, h, w, labels, g_loss):
     compute_dtype = h.dtype
     hf = h.reshape(-1, h.shape[-1])
     lf = labels.reshape(-1).astype(jnp.int32)
@@ -173,6 +231,50 @@ def _bwd_vjp(n_chunks, res, cts):
     )
     dh = dh_c.reshape(-1, h.shape[-1])[:n].reshape(h.shape)
     return dh, dw.astype(w.dtype), None
+
+
+@jax.named_scope(VOCAB_SCAN)
+def _bwd_vocab_scan(n_chunks, h, w, labels, lse, g_loss):
+    """``n_chunks`` slices of the vocabulary, every row in each: the tile is
+    ``[N, ⌈V/n⌉]`` floats (the row scan's ``[⌈N/n⌉, V]``, turned), the
+    softmax is ``exp(logits − lse)`` with the forward's own ``lse``, each
+    dW slice is written once, into its final place, and dh is the running
+    sum. The slices are lane-aligned and equal, so the kernel's
+    ``compute_dtype`` copy is zero-padded up to them (50,257 divides by
+    nothing useful): a zero column adds nothing to dh, and its dW column
+    is cut after the loop."""
+    compute_dtype = h.dtype
+    d_model, v = w.shape
+    hb = h.reshape(-1, d_model).astype(compute_dtype)
+    lf = labels.reshape(-1).astype(jnp.int32)
+    gf = g_loss.reshape(-1).astype(jnp.float32)
+    lsef = lse.reshape(-1)
+    width = -(-v // n_chunks)  # ceil
+    width = -(-width // LANES) * LANES  # up to whole lanes
+    n_slices = -(-v // width)
+    wb = jnp.pad(
+        w.astype(compute_dtype), ((0, 0), (0, n_slices * width - v)))
+
+    def body(j, carry):
+        dw, dh = carry
+        start = j * width
+        w_j = lax.dynamic_slice(wb, (0, start), (d_model, width))
+        logits = lax.dot(hb, w_j, preferred_element_type=jnp.float32)
+        p = jnp.exp(logits - lsef[:, None])
+        hit = (lf - start)[:, None] == lax.iota(jnp.int32, width)
+        # d logits = (softmax - onehot(label)) · g  — the CE gradient.
+        d = ((p - hit.astype(jnp.float32)) * gf[:, None]).astype(
+            compute_dtype)
+        dw_j = lax.dot(hb.T, d, preferred_element_type=jnp.float32)
+        dh_j = lax.dot(d, w_j.T, preferred_element_type=jnp.float32)
+        return lax.dynamic_update_slice(dw, dw_j, (0, start)), dh + dh_j
+
+    dw, dh = lax.fori_loop(
+        0, n_slices, body,
+        (jnp.zeros(wb.shape, jnp.float32), jnp.zeros(hb.shape, jnp.float32)),
+    )
+    return (
+        dh.astype(h.dtype).reshape(h.shape), dw[:, :v].astype(w.dtype), None)
 
 
 fused_linear_cross_entropy.defvjp(_fwd_vjp, _bwd_vjp)

@@ -34,64 +34,113 @@ def _dense_loss(h, w, labels):
     return optax.softmax_cross_entropy_with_integer_labels(logits, labels)
 
 
+# Both sides of the backward rule (`fused_ce.scans_vocab`): rows >= V scans
+# the rows, rows < V scans the vocabulary. [B, T, V]; D is 16.
+SHAPES = [
+    # 48 rows: 3 chunks pad them to 3×16 — the non-divisible path.
+    pytest.param((2, 24, 37), id="rows48-v37"),
+    # 24 rows; one lane-padded slice whatever the chunk count.
+    pytest.param((2, 12, 97), id="rows24-v97"),
+    # Two slices of 128 from 3 chunks on.
+    pytest.param((2, 12, 256), id="rows24-v256"),
+    # A V no chunk count divides: 3 slices of 128 that pad 300 to 384.
+    pytest.param((2, 12, 300), id="rows24-v300"),
+]
+
+
+def _data(shape, dtype=jnp.float32, d=16, seed=0):
+    b, t, v = shape
+    rng = np.random.RandomState(seed)
+    h = jnp.asarray(rng.randn(b, t, d), dtype)
+    w = jnp.asarray(rng.randn(d, v) / np.sqrt(d), jnp.float32)
+    labels = jnp.asarray(rng.randint(0, v, size=(b, t)), jnp.int32)
+    return h, w, labels
+
+
+def _grads(loss_fn, h, w, weights=None):
+    """d(mean or weighted sum of the per-token loss)/d(h, w)."""
+    def scalar(h, w):
+        loss = loss_fn(h, w)
+        return loss.mean() if weights is None else (loss * weights).sum()
+
+    return jax.grad(scalar, argnums=(0, 1))(h, w)
+
+
+@pytest.mark.parametrize("shape", SHAPES)
 class TestFusedLinearCrossEntropy:
-    def _data(self, b=2, t=24, d=16, v=37, dtype=jnp.float32, seed=0):
-        rng = np.random.RandomState(seed)
-        h = jnp.asarray(rng.randn(b, t, d), dtype)
-        w = jnp.asarray(rng.randn(d, v) / np.sqrt(d), jnp.float32)
-        labels = jnp.asarray(rng.randint(0, v, size=(b, t)), jnp.int32)
-        return h, w, labels
+    def test_the_shapes_stand_on_both_sides_of_the_rule(self, shape):
+        b, t, v = shape
+        assert fused_ce.scans_vocab(b * t, v) == (v > 37)
 
     @pytest.mark.parametrize("n_chunks", [1, 3, 8])
-    def test_loss_matches_dense(self, n_chunks):
-        # 3 chunks: 48 rows pad to 3×16 — the non-divisible path.
-        h, w, labels = self._data()
+    def test_loss_matches_dense(self, shape, n_chunks):
+        h, w, labels = _data(shape)
         loss, correct = fused_linear_cross_entropy(h, w, labels, n_chunks)
         assert loss.shape == labels.shape and correct.shape == labels.shape
         ref = _dense_loss(h, w, labels)
         np.testing.assert_allclose(loss, ref, rtol=1e-5, atol=1e-5)
 
-    def test_correct_indicator_matches_argmax(self):
-        h, w, labels = self._data()
+    def test_correct_indicator_matches_argmax(self, shape):
+        h, w, labels = _data(shape)
         _, correct = fused_linear_cross_entropy(h, w, labels, 4)
         pred = jnp.argmax(h @ w, axis=-1)
         np.testing.assert_array_equal(
             np.asarray(correct, bool), np.asarray(pred == labels)
         )
 
-    @pytest.mark.parametrize("n_chunks", [1, 5])
-    def test_gradients_match_dense(self, n_chunks):
-        h, w, labels = self._data()
-
-        def fused(h, w):
-            loss, _ = fused_linear_cross_entropy(h, w, labels, n_chunks)
-            return loss.mean()
-
-        def dense(h, w):
-            return _dense_loss(h, w, labels).mean()
-
-        (dh_f, dw_f) = jax.grad(fused, argnums=(0, 1))(h, w)
-        (dh_d, dw_d) = jax.grad(dense, argnums=(0, 1))(h, w)
+    @pytest.mark.parametrize("n_chunks", [1, 3, 5, 8])
+    def test_gradients_match_dense(self, shape, n_chunks):
+        h, w, labels = _data(shape)
+        dh_f, dw_f = _grads(
+            lambda h, w: fused_linear_cross_entropy(
+                h, w, labels, n_chunks)[0], h, w)
+        dh_d, dw_d = _grads(lambda h, w: _dense_loss(h, w, labels), h, w)
         np.testing.assert_allclose(dh_f, dh_d, rtol=1e-5, atol=1e-6)
         np.testing.assert_allclose(dw_f, dw_d, rtol=1e-5, atol=1e-6)
 
-    def test_bf16_hidden_states(self):
-        h, w, labels = self._data(dtype=jnp.bfloat16)
+    @pytest.mark.parametrize("n_chunks", [1, 3, 8])
+    def test_masked_tokens_gradients_match_dense(self, shape, n_chunks):
+        # A loss mask: a third of the tokens count for nothing, the others
+        # each with a weight of its own — the cotangent the Trainer hands
+        # the op for padded and boundary-masked tokens.
+        h, w, labels = _data(shape)
+        rng = np.random.RandomState(1)
+        weights = jnp.asarray(
+            (rng.rand(*labels.shape) > 0.33) * rng.uniform(
+                0.5, 1.5, labels.shape), jnp.float32)
+        dh_f, dw_f = _grads(
+            lambda h, w: fused_linear_cross_entropy(
+                h, w, labels, n_chunks)[0], h, w, weights)
+        dh_d, dw_d = _grads(
+            lambda h, w: _dense_loss(h, w, labels), h, w, weights)
+        np.testing.assert_allclose(dh_f, dh_d, rtol=1e-5, atol=1e-6)
+        np.testing.assert_allclose(dw_f, dw_d, rtol=1e-5, atol=1e-6)
+        masked = np.asarray(weights) == 0
+        assert masked.any() and not np.asarray(dh_f)[masked].any()
+
+    def test_bf16_hidden_states(self, shape):
+        h, w, labels = _data(shape, dtype=jnp.bfloat16)
         loss, _ = fused_linear_cross_entropy(h, w, labels, 4)
         ref = _dense_loss(
             h.astype(jnp.float32), w, labels
         )
         # bf16 inputs with f32 MXU accumulation: 8-bit-mantissa input error.
         np.testing.assert_allclose(loss, ref, rtol=3e-2, atol=3e-2)
-        dh = jax.grad(
-            lambda h: fused_linear_cross_entropy(h, w, labels, 4)[0].mean()
-        )(h)
-        assert dh.dtype == jnp.bfloat16
+        dh, dw = _grads(
+            lambda h, w: fused_linear_cross_entropy(h, w, labels, 4)[0],
+            h, w)
+        assert dh.dtype == jnp.bfloat16 and dw.dtype == jnp.float32
+        dh_d, dw_d = _grads(
+            lambda h, w: _dense_loss(h, w, labels),
+            h.astype(jnp.float32), w)
+        np.testing.assert_allclose(
+            dh.astype(jnp.float32), dh_d, rtol=3e-2, atol=3e-3)
+        np.testing.assert_allclose(dw, dw_d, rtol=3e-2, atol=3e-3)
 
-    def test_correct_cotangent_is_discarded(self):
+    def test_correct_cotangent_is_discarded(self, shape):
         # Differentiating THROUGH the correctness indicator must not
         # contribute (argmax is piecewise constant, like the dense path).
-        h, w, labels = self._data()
+        h, w, labels = _data(shape)
 
         def f(h):
             loss, correct = fused_linear_cross_entropy(h, w, labels, 2)
@@ -103,27 +152,79 @@ class TestFusedLinearCrossEntropy:
         )(h)
         np.testing.assert_allclose(dh, dh_ref, rtol=1e-6)
 
-    def test_peak_memory_scales_down_with_chunks(self):
-        # The op's reason to exist: XLA's own accounting shows the compiled
-        # backward never holds the full [N, V] logits when chunked. Sized so
-        # logits (256·rows × 4096·vocab × 4 B ≈ 4 MB/copy) dominate.
-        b, t, d, v = 2, 128, 32, 4096
-        rng = np.random.RandomState(0)
-        h = jnp.asarray(rng.randn(b, t, d), jnp.float32)
-        w = jnp.asarray(rng.randn(d, v) / 6.0, jnp.float32)
-        labels = jnp.asarray(rng.randint(0, v, size=(b, t)), jnp.int32)
 
-        def temp_bytes(n_chunks):
-            def f(h, w):
-                loss, _ = fused_linear_cross_entropy(h, w, labels, n_chunks)
-                return loss.mean()
+@pytest.mark.parametrize(
+    # [B, T, D, V]; logits of 4 MB / 8 MB a copy dominate either way.
+    "b,t,d,v",
+    [pytest.param(2, 128, 32, 4096, id="rows256-v4096-vocab_scan"),
+     pytest.param(2, 1024, 32, 1024, id="rows2048-v1024-row_scan")],
+)
+def test_peak_memory_scales_down_with_chunks(b, t, d, v):
+    # The op's reason to exist: XLA's own accounting shows the compiled
+    # backward never holds the full [N, V] logits when chunked, whichever
+    # axis the chunks cut.
+    rng = np.random.RandomState(0)
+    h = jnp.asarray(rng.randn(b, t, d), jnp.float32)
+    w = jnp.asarray(rng.randn(d, v) / 6.0, jnp.float32)
+    labels = jnp.asarray(rng.randint(0, v, size=(b, t)), jnp.int32)
 
-            compiled = jax.jit(jax.grad(f, argnums=(0, 1))).lower(h, w).compile()
-            return int(compiled.memory_analysis().temp_size_in_bytes)
+    def temp_bytes(n_chunks):
+        def f(h, w):
+            loss, _ = fused_linear_cross_entropy(h, w, labels, n_chunks)
+            return loss.mean()
 
-        one = temp_bytes(1)   # dense-equivalent: full logits tile
-        many = temp_bytes(16)
-        assert many < one / 4, (one, many)
+        compiled = jax.jit(jax.grad(f, argnums=(0, 1))).lower(h, w).compile()
+        return int(compiled.memory_analysis().temp_size_in_bytes)
+
+    one = temp_bytes(1)   # dense-equivalent: full logits tile
+    many = temp_bytes(16)
+    assert many < one / 4, (one, many)
+
+
+def _backward_hlo(shape, n_chunks=3, vocab_split=False):
+    h, w, labels = _data(shape)
+
+    def f(h, w):
+        return fused_linear_cross_entropy(
+            h, w, labels, n_chunks, vocab_split)[0].mean()
+
+    return jax.jit(jax.grad(f, argnums=(0, 1))).lower(h, w).compile().as_text()
+
+
+def _scan_loops(hlo, axis):
+    """The bodies of the head's loops that carry the sub-scope ``axis``."""
+    return hlo_audit.while_bodies(hlo, f"/{axis}/while")
+
+
+def test_row_scan_is_still_the_one_loop_that_sums_dw():
+    """rows >= V, and a kernel whose vocabulary the partitioner splits
+    (`vocab_split`) whatever the sizes: the row scan as it always was —
+    one loop (the loss is not asked for, so the forward scan is dead code)
+    whose carry is the float32 dW."""
+    d = 16
+    for shape, split in ((SHAPES[0].values[0], False),
+                         (SHAPES[3].values[0], True)):
+        hlo = _backward_hlo(shape, vocab_split=split)
+        body, = hlo_audit.while_bodies(hlo, fused_ce.SCOPE)
+        assert _scan_loops(hlo, fused_ce.ROW_SCAN) == [body]
+        assert re.search(rf"f32\[{d},{shape[2]}\]\S* add\(", body)
+
+
+def test_vocab_scan_sums_dh_and_writes_each_dw_slice_once():
+    """rows < V: the forward scan (kept for its logsumexp) and a loop over
+    3 slices of 128 of a vocabulary of 300 whose running sum is dh
+    [24, 16]; dW is only written, one slice an iteration."""
+    (b, t, v), d, width = SHAPES[3].values[0], 16, 128
+    hlo = _backward_hlo((b, t, v))
+    assert len(hlo_audit.while_bodies(hlo, fused_ce.SCOPE)) == 2
+    body, = _scan_loops(hlo, fused_ce.VOCAB_SCAN)
+    assert not _scan_loops(hlo, fused_ce.ROW_SCAN)
+    assert f"f32[{b * t},{width}]" in body       # the tile
+    assert f"f32[{b * t},{v}]" not in hlo        # never the whole logits
+    assert re.search(rf"f32\[{b * t},{d}\]\S* add\(", body)
+    dw = rf"f32\[{d},{3 * width}\]\S*"
+    assert re.search(rf"{dw} dynamic-update-slice\(", body)
+    assert not re.search(rf"{dw} add\(", body)
 
 
 @pytest.mark.slow
@@ -232,8 +333,11 @@ ROWS = jax.sharding.PartitionSpec(BATCH_AXES, SEQ_AXIS)
 
 class TestHeadOnEachChipsOwnRows:
     # 4 x 10 rows in 3 chunks: 40 rows pad to 42 without a mesh, and a
-    # chip's 10 (or 20 on data=2 x model=2) pad to 12 (21) on one.
-    B, T, D, V, CHUNKS = 4, 10, 32, 64, 3
+    # chip's 10 (or 20 on data=2 x model=2) pad to 12 (21) on one. Fewer
+    # rows than the vocabulary everywhere, so the backward takes 3 slices
+    # of 128 of it (300 pad to 384) — but on data=2 x model=2, where the
+    # partitioner holds the vocabulary and the rows are scanned.
+    B, T, D, V, CHUNKS = 4, 10, 32, 300, 3
 
     def _lm(self, sharding):
         return TransformerLM(
@@ -358,9 +462,11 @@ class TestHeadOnEachChipsOwnRows:
 
 def test_compiled_data_parallel_step_keeps_the_head_local():
     """The compiled data=4 step (CPU's partitioner is the chip's GSPMD):
-    no collective inside the head's loops, tiles of the chip's own rows,
-    and dW crossing the chips once."""
-    d, v, seq, per_chip, chunks = 32, 96, 16, 2, 2
+    no collective inside the head's loops, tiles of the chip's own rows —
+    32 of them against a vocabulary of 512, so the forward scans their 2
+    chunks and the backward 2 slices of the vocabulary — and dW crossing
+    the chips once."""
+    d, v, seq, per_chip, chunks = 32, 512, 16, 2, 2
     mesh = mesh_of(data=4)
     model = TransformerLM(
         vocab_size=v, d_model=d, n_heads=4, n_layers=1, dropout=0.0,
@@ -378,17 +484,73 @@ def test_compiled_data_parallel_step_keeps_the_head_local():
     ).compile().as_text()
 
     bodies = hlo_audit.while_bodies(hlo, fused_ce.SCOPE)
-    assert len(bodies) == 2  # the forward scan and the backward scan
-    local_tile = f"f32[{per_chip * seq // chunks},{v}]"
-    global_tile = f"f32[{4 * per_chip * seq // chunks},{v}]"
+    assert len(bodies) == 2  # the forward scan and the backward loop
+    backward, = _scan_loops(hlo, fused_ce.VOCAB_SCAN)
+    forward, = (body for body in bodies if body != backward)
+    rows = per_chip * seq
+    # [a chunk of the chip's rows, V] forward; [the chip's rows, a slice of
+    # V] backward; never the global batch's.
+    assert f"f32[{rows // chunks},{v}]" in forward
+    assert f"f32[{4 * rows // chunks},{v}]" not in forward
+    assert f"f32[{rows},{v // chunks}]" in backward
+    assert f"f32[{4 * rows}," not in backward
     for body in bodies:
         assert not hlo_audit.collective_ops(body)
-        assert local_tile in body and global_tile not in body
     # dW [D, V] float32 crosses the chips once (alone, or as one operand of
     # a combined all-reduce), after the loops.
     reduced = re.findall(
         r"= (.*?) (?:all-reduce|reduce-scatter)(?:-start)?\(", hlo)
     assert sum(types.count(f"f32[{d},{v}]") for types in reduced) == 1
+
+
+@pytest.mark.parametrize("told", [True, False], ids=["told", "not-told"])
+def test_a_split_vocabulary_keeps_the_row_scan(told, monkeypatch):
+    """Why the op takes `vocab_split`, and that `LMHead` wires it: the
+    compiled data=2 x model=2 step, 32 rows a chip against a vocabulary of
+    512 (`scans_vocab` alone would scan the vocabulary). Told, the head
+    scans the rows over the chip's own half of the kernel: tiles and dW
+    `[.., V/2]`, no all-gather of the kernel. Not told (the flag dropped on
+    its way to the op), the partitioner gathers the whole `[D, V]` kernel
+    for the loop over its slices and every chip of a `model` group
+    computes every slice. The day the second half fails, the partitioner
+    has learnt to loop over a split dimension and the argument can go."""
+    d, v, seq, per_chip, chunks = 32, 512, 16, 2, 2
+    if not told:
+        op = fused_ce.fused_linear_cross_entropy
+        monkeypatch.setattr(
+            fused_ce, "fused_linear_cross_entropy",
+            lambda h, w, labels, n, vocab_split: op(h, w, labels, n))
+    mesh = mesh_of(data=2, model=2)
+    model = TransformerLM(
+        vocab_size=v, d_model=d, n_heads=4, n_layers=1, dropout=0.0,
+        fused_head_chunks=chunks, sharding=ShardingConfig(mesh=mesh),
+    )
+    trainer = hvt.Trainer(
+        model, hvt.DistributedOptimizer(optax.adamw(1e-3)), loss="module",
+        mesh=mesh, param_specs=param_specs,
+    )
+    x = (np.arange(2 * per_chip * seq, dtype=np.int32) % v).reshape(-1, seq)
+    state = trainer.build(x[:2], x[:2])
+    hlo = trainer._train_step.lower(
+        state, trainer._shard((x, x)), jnp.asarray(1.0, jnp.float32),
+        sharding_lib.replicate(trainer.zero_metrics(), mesh),
+    ).compile().as_text()
+
+    rows = per_chip * seq
+    assert rows < v
+    gathered = re.findall(r"= (\S+?)\{\S* all-gather(?:-start)?\(", hlo)
+    reduced = re.findall(
+        r"= (.*?) (?:all-reduce|reduce-scatter)(?:-start)?\(", hlo)
+    whole, half = f"f32[{d},{v}]", f"f32[{d},{v // 2}]"
+    if told:
+        backward, = _scan_loops(hlo, fused_ce.ROW_SCAN)
+        assert not _scan_loops(hlo, fused_ce.VOCAB_SCAN)
+        assert f"f32[{rows // chunks},{v // 2}]" in backward
+        assert whole not in hlo
+        assert sum(types.count(half) for types in reduced) == 1
+    else:
+        assert _scan_loops(hlo, fused_ce.VOCAB_SCAN)
+        assert whole in gathered
 
 
 class TestBuildTracesFusedPath:

@@ -164,19 +164,19 @@ def test_always_on_counters_move_with_the_exporter_off(monkeypatch, cache):
 
 # --- device scopes ---------------------------------------------------------
 
-def lowered_lm_step(accumulation: int) -> str:
+def lowered_lm_step(accumulation: int, vocab: int = 64) -> str:
     """The lowered train step of a toy LM with the fused head, with the
     debug locations that carry the name stack."""
     hvt.init()
-    model = TransformerLM(vocab_size=64, d_model=32, n_heads=4, n_layers=1,
-                          dropout=0.0, fused_head_chunks=2)
+    model = TransformerLM(vocab_size=vocab, d_model=32, n_heads=4,
+                          n_layers=1, dropout=0.0, fused_head_chunks=2)
     tr = hvt.Trainer(
         model,
         hvt.DistributedOptimizer(
             optax.adamw(1e-3), backward_passes_per_step=accumulation),
         loss="module")
     n = tr.dp_size
-    x = np.arange(n * accumulation * 16, dtype=np.int32).reshape(-1, 16) % 64
+    x = np.arange(n * accumulation * 16, dtype=np.int32).reshape(-1, 16) % vocab
     state = tr.build(x[:n], x[:n])
     if accumulation == 1:
         batch = tr._shard((x, x))
@@ -208,3 +208,31 @@ def test_lowered_step_names_the_optimizer_and_the_head(accumulation):
                    and "Block_" not in n and "LayerNorm" not in n
                    for n in set(re.findall(r'loc\("([^"]*)"', text))
                    - optimizer - head)
+
+
+@pytest.mark.parametrize(
+    # The mesh-less toy model's head sees the global batch: 16 tokens a
+    # device, 128 rows on the 8 virtual devices.
+    "vocab,axis,other",
+    [(64, fused_ce.ROW_SCAN, fused_ce.VOCAB_SCAN),
+     (4096, fused_ce.VOCAB_SCAN, fused_ce.ROW_SCAN)],
+    ids=["rows>=vocab", "rows<vocab"],
+)
+def test_head_says_which_axis_its_backward_scans(vocab, axis, other):
+    """A static choice, so a name and a gauge: the backward loop's ops
+    carry the sub-scope of the axis scanned, and `hvt_head_ce_scan` reads
+    1 on it after the trace."""
+    obs_core.reset()
+    text = lowered_lm_step(1, vocab)
+    assert fused_ce.scans_vocab(16 * jax.device_count(), vocab) == (
+        axis == fused_ce.VOCAB_SCAN)
+    named = set(re.findall(r'loc\("([^"]*hvt\.[^"]*)"', text))
+    loops = {n for n in named if n.endswith("/while/body/closed_call")}
+    assert any(f"{fused_ce.SCOPE}/{axis}/while" in n for n in loops)
+    assert not any(f"/{other}/" in n for n in named)
+    values = prom.parse_text(prom.render(obs.default_registry()))
+    assert values['hvt_head_ce_scan{axis="vocab"}'] == (
+        axis == fused_ce.VOCAB_SCAN)
+    assert values['hvt_head_ce_scan{axis="rows"}'] == (
+        axis == fused_ce.ROW_SCAN)
+    obs_core.reset()
