@@ -30,7 +30,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from horovod_tpu.models.decoding import _NEG
+from horovod_tpu.models.decoding import _NEG, require_decode_path
 
 
 def make_beam_search_fn(model, *, max_new_tokens: int, beam_size: int,
@@ -52,6 +52,7 @@ def make_beam_search_fn(model, *, max_new_tokens: int, beam_size: int,
         raise ValueError("beam_size must be >= 1")
     if max_new_tokens < 1:
         raise ValueError("max_new_tokens must be >= 1")
+    require_decode_path(model)
     w = beam_size
 
     def run(params, prompt):

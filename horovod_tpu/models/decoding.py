@@ -91,6 +91,18 @@ def _sample(logits, rng, temperature: float, top_k: int, top_p: float = 0.0):
     ).astype(jnp.int32)
 
 
+def require_decode_path(model) -> None:
+    """Refuse, by name, a model whose layers have no KV-cache path: the
+    generators clone ``model`` into decode mode, and a model without the
+    ``decode`` field would fail there on an unknown keyword."""
+    if not hasattr(model, "decode"):
+        raise NotImplementedError(
+            f"{type(model).__name__} has no decode path: its layers keep no "
+            "cache (for LatentMoELM a compressed latent cache, ROADMAP R2); "
+            "generation, beam search and speculative decoding take a "
+            "TransformerLM")
+
+
 def make_generate_fn(model, *, max_new_tokens: int, temperature: float = 0.0,
                      top_k: int = 0, top_p: float = 0.0,
                      eos_id: int | None = None,
@@ -137,6 +149,7 @@ def make_generate_fn(model, *, max_new_tokens: int, temperature: float = 0.0,
     """
     if max_new_tokens < 1:
         raise ValueError("max_new_tokens must be >= 1")
+    require_decode_path(model)
 
     def run(params, prompt, rng, lengths=None):
         prompt = prompt.astype(jnp.int32)

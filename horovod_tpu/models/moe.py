@@ -1,4 +1,20 @@
-"""Mixture-of-Experts MLP with expert parallelism over the ``expert`` axis.
+"""Two routed layers.
+
+* `MoEMlp` (below, first): the GShard/Switch dense-dispatch layer over the
+  mesh's ``expert`` axis: softmax gates, a static capacity per expert that
+  DROPS what overflows, GELU experts at 4x. What `TransformerLM(moe_every=)`
+  and `PipelinedLM` build; its all-to-all is the partitioner's.
+* `RoutedExperts` (at the end): one chip's share of a DeepSeek-V3-style
+  layer: sigmoid scores over all the published experts, top-k with a
+  selection bias that levels each sequence's loads (`level_bias`: the layer
+  carries no balancing state), gates normalised over the chosen and scaled, the
+  (token, choice) pairs that fall on the experts HELD HERE sorted by expert
+  and run through a grouped matmul with ragged group sizes (no token
+  dropped, no one-hot dispatch), SwiGLU experts and a shared expert. What
+  `models/latent_moe_lm.py` builds. On one chip there is no exchange; the
+  experts across chips with their all-to-all are ROADMAP R1.
+
+Mixture-of-Experts MLP with expert parallelism over the ``expert`` axis.
 
 The reference has no MoE (SURVEY.md §2.2: dense MLP head only,
 tensorflow2_keras_mnist.py:49-51); this fills the framework's reserved
@@ -37,12 +53,27 @@ well:
 
 from __future__ import annotations
 
+import functools
+import math
+
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
+from horovod_tpu.ops import grouped_matmul as gmm_ops
 from horovod_tpu.parallel.mesh import EXPERT_AXIS
+
+# The routed layer's names in the compiled step (`jax.named_scope`: every op
+# carries them in its metadata, forward and backward; chipbench/moe_spans.py
+# sums by them): SCOPE around the whole layer, and under it its five parts.
+SCOPE = "hvt.moe"
+ROUTE, DISPATCH, EXPERTS, COMBINE, SHARED = (
+    "route", "dispatch", "experts", "combine", "shared")
+# `RoutedExperts`' static row budget over the rows level loads put on the
+# held experts. `level_bias` keeps the loads level to within chance; what
+# still passes the budget is counted.
+BUDGET_FACTOR = 2.0
 
 
 def dispatch_group_count(g: int, group_size: int) -> int:
@@ -243,3 +274,208 @@ class MoEMlp(nn.Module):
         return jax.lax.with_sharding_constraint(
             v, jax.sharding.NamedSharding(cfg.mesh, spec)
         )
+
+
+class SwiGLU(nn.Module):
+    """``W_down(silu(W_gate h) * W_up h)``, no biases (Shazeer,
+    arXiv:2002.05202): the dense MLP and the shared expert of the
+    DeepSeek-V3 family."""
+
+    width: int
+    compute_dtype: jnp.dtype = jnp.float32
+
+    @nn.compact
+    def __call__(self, h):
+        dense = functools.partial(
+            nn.Dense, use_bias=False, dtype=self.compute_dtype)
+        gate = dense(self.width, name="gate")(h)
+        up = dense(self.width, name="up")(h)
+        return dense(h.shape[-1], name="down")(nn.silu(gate) * up)
+
+
+def level_bias(logits, k: int):
+    """The selection bias that levels the loads of one sequence: for the
+    router's logits ``[T, E]``, minus each expert's own logit at the rank
+    that leaves it ``T * k / E`` of the T tokens above. Float32 ``[E]``.
+
+    This is where the DeepSeek-V3 ``noaux_tc`` rule (bias += rate *
+    sign(mean load - load), a buffer carried from step to step) is headed,
+    found in one sort instead: whatever the tokens of a sequence share (the
+    mean over the prefix that attention hands every late token; a direction
+    the stream gains as training moves it) shifts an expert's whole column
+    and is taken out with the threshold, and what tells the tokens apart
+    decides who goes where. Each expert then has exactly ``T * k / E``
+    tokens above zero; the top ``k`` a token are not exactly those, so the
+    loads are level to within chance (1.1-1.2 x the mean on the fullest of
+    128) as long as what tells the tokens apart is not of a few dimensions.
+
+    On the logits, not on the scores as the published selection adds it:
+    after the sigmoid an expert whose column sits near 0 or 1 has too small
+    a slope to compete for any token, and one sort does not level the loads
+    (columns two logits apart: the fullest expert at 3.8 x the mean)."""
+    t, e = logits.shape
+    above = min(t, max(1, round(t * k / e)))
+    return -jnp.sort(logits, axis=0)[t - above]
+
+
+def _route(tokens, router, *, k, scale):
+    """``(chosen [B, T, k] int32, gates [B, T, k] float32)`` for tokens
+    ``[B, T, d]``: the router's logits over ALL the experts in float32, the
+    top ``k`` of logit + selection bias (`level_bias`, a sequence at a
+    time), and the chosen experts' sigmoid scores normalised over all ``k``
+    (held here or not) and scaled."""
+    logits = jnp.dot(tokens.astype(jnp.float32), router,
+                     precision=jax.lax.Precision.HIGHEST)
+    # Selection is not differentiable: the bias is a constant of the step.
+    bias = jax.vmap(lambda one: level_bias(one, k))(logits)
+    _, chosen = jax.lax.top_k(
+        jax.lax.stop_gradient(logits + bias[:, None, :]), k)
+    scores = jax.nn.sigmoid(logits)
+    # The chosen scores by a mask, not `take_along_axis`: its gather and
+    # the scatter that is its transpose took 0.8 ms a layer on the v5e, and
+    # the scatter reaches the trace with no scope.
+    hot = chosen[..., None] == jnp.arange(scores.shape[-1])  # [B, T, k, E]
+    picked = jnp.sum(jnp.where(hot, scores[..., None, :], 0.0), axis=-1)
+    gates = picked / (picked.sum(-1, keepdims=True) + 1e-20) * scale
+    return chosen.astype(jnp.int32), gates
+
+
+def _held_experts(x, router, w_gate_up, w_down, *, k, scale, held_start,
+                  budget, compute_dtype):
+    """The held experts' part of the layer's output for ``x`` [B, T, d],
+    and the three counts. The (token, choice) pairs that fall on experts
+    ``held_start .. held_start + n_held`` are sorted by expert, the first
+    ``budget`` of them gathered, pushed through two grouped matmuls and
+    scatter-added back under their gates; a pair past the budget is
+    counted as overflow."""
+    d = x.shape[-1]
+    n = x.size // d
+    n_held = w_down.shape[0]
+    with jax.named_scope(ROUTE):
+        chosen, gates = _route(x, router, k=k, scale=scale)
+    tokens = x.reshape(n, d)
+    with jax.named_scope(DISPATCH):
+        local = chosen.reshape(-1) - held_start
+        # A pair on an expert held elsewhere sorts behind every held one.
+        local = jnp.where((local >= 0) & (local < n_held), local, n_held)
+        order = jnp.argsort(local, stable=True)[:budget]
+        # (fewer pairs than one row tile: the rest are rows past the total)
+        order = jnp.pad(order, (0, budget - order.size))
+        counts = gmm_ops.group_sizes_of(local, n_held)
+        # ... and the groups are cut at the budget, last expert first.
+        ends = jnp.minimum(jnp.cumsum(counts), budget)
+        sizes = jnp.diff(ends, prepend=0)
+        token_of = order // k
+        rows = tokens.astype(compute_dtype)[token_of]  # [budget, d]
+    with jax.named_scope(EXPERTS):
+        hidden = gmm_ops.grouped_matmul(
+            rows, w_gate_up.astype(compute_dtype), sizes)
+        gate, up = jnp.split(hidden, 2, axis=-1)
+        out = gmm_ops.grouped_matmul(
+            nn.silu(gate) * up, w_down.astype(compute_dtype), sizes)
+    with jax.named_scope(COMBINE):
+        # Rows past the groups' total are zeros (ops/grouped_matmul.py), so
+        # whatever gate rides with them adds nothing.
+        # Summed in float32: a token's up to k experts meet here.
+        weight = gates.reshape(-1)[order]
+        mixed = jnp.zeros((n, d), jnp.float32).at[token_of].add(
+            out.astype(jnp.float32) * weight[:, None]).astype(compute_dtype)
+    held = jnp.sum(counts)
+    stats = {
+        "moe_overflow_rows": jnp.maximum(held - budget, 0),
+        "moe_held_rows_share": held / (n * k),
+        "moe_load_max_over_mean": jnp.max(counts) * n_held / jnp.maximum(
+            held, 1),
+    }
+    stats = {name: jnp.asarray(v, jnp.float32) for name, v in stats.items()}
+    return mixed.reshape(x.shape), stats
+
+
+class RoutedExperts(nn.Module):
+    """One chip's share of a routed expert layer with a shared expert:
+    ``[B, T, d] -> [B, T, d]``, ``sum over chosen AND held of gate_e *
+    Expert_e(h) + Shared(h)``.
+
+    The router scores all ``n_routed`` experts and picks ``k`` a token; this
+    chip holds the contiguous block ``held_start .. held_start + n_held``
+    and computes their part of the sum. What the absent experts would add
+    is left out: on one chip there is no exchange, and nothing here stands
+    in for the other chips. With ``n_held == n_routed`` it is the whole
+    layer. One chip only: a mesh of more is refused (the batch split over
+    chips comes with the cell that measures it, the experts across chips
+    with their exchange: ROADMAP R1).
+
+    The selection bias of the DeepSeek-V3 ``noaux_tc`` method is no
+    parameter here: `level_bias` solves it anew in every step, a sequence
+    at a time, from the step's own logits, so the loads are level from the
+    first step, whatever the initialisation and the learning rate (from
+    random weights at the published ``initializer_range`` the fullest of 16
+    held experts saw 3-5.5 x the mean, and AdamW at 1e-4 put every token on
+    the same few experts within 20 steps: v5e, PERF.md, PR 33). The bias
+    of a sequence looks at all its tokens, later ones too: this is a
+    training layer, as expert-choice routing is (a decode path needs the
+    published buffer carried from training: ROADMAP R1).
+
+    No token is dropped while the rows routed here stay within the static
+    row budget, `BUDGET_FACTOR` x the expected ``N * k * n_held /
+    n_routed``; past it the overflow is counted in the sown metric
+    ``moe_overflow_rows`` and those rows add nothing. Also sown:
+    ``moe_held_rows_share`` (rows that fell on held experts over N * k) and
+    ``moe_load_max_over_mean`` (the fullest held expert's rows over the
+    mean). The gauge ``hvt_moe_experts{kind}`` says at trace time how many
+    experts are held and routed.
+    """
+
+    n_routed: int
+    k: int
+    expert_width: int
+    shared_width: int
+    n_held: int
+    held_start: int
+    routed_scaling: float
+    compute_dtype: jnp.dtype = jnp.float32
+    sharding: object = None
+
+    @nn.compact
+    @jax.named_scope(SCOPE)
+    def __call__(self, x):
+        b, t, d = x.shape
+        if not 0 <= self.held_start <= self.n_routed - self.n_held:
+            raise ValueError(
+                f"experts {self.held_start}.."
+                f"{self.held_start + self.n_held} are not a block of the "
+                f"{self.n_routed} routed ones")
+        mesh = getattr(self.sharding, "mesh", None)
+        if mesh is not None and mesh.size > 1:
+            raise NotImplementedError(
+                f"RoutedExperts on a mesh of {mesh.size} chips "
+                f"({dict(mesh.shape)}): it runs on one chip; experts across "
+                "chips need the token exchange, and the batch split over "
+                "chips comes with the cell that measures it (ROADMAP R1)")
+        from horovod_tpu import obs
+
+        obs.gauge("hvt_moe_experts", float(self.n_held), kind="held")
+        obs.gauge("hvt_moe_experts", float(self.n_routed), kind="routed")
+
+        router = self.param(
+            "router", nn.initializers.lecun_normal(), (d, self.n_routed))
+        init = nn.initializers.lecun_normal(batch_axis=(0,))
+        w_gate_up = self.param(
+            "experts_gate_up", init, (self.n_held, d, 2 * self.expert_width))
+        w_down = self.param(
+            "experts_down", init, (self.n_held, self.expert_width, d))
+
+        n = b * t
+        expected = n * self.k * self.n_held / self.n_routed
+        budget = gmm_ops.row_budget(
+            min(n * self.k, math.ceil(BUDGET_FACTOR * expected)))
+        mixed, stats = _held_experts(
+            x, router, w_gate_up, w_down, k=self.k,
+            scale=self.routed_scaling, held_start=self.held_start,
+            budget=budget, compute_dtype=self.compute_dtype)
+        for name, value in stats.items():
+            self.sow("metrics", name, value)
+        with jax.named_scope(SHARED):
+            mixed = mixed + SwiGLU(
+                self.shared_width, self.compute_dtype, name="shared")(x)
+        return mixed.astype(x.dtype)

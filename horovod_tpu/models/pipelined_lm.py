@@ -131,7 +131,11 @@ class PipelinedLM(nn.Module):
         ones = nn.initializers.ones
 
         if self.mlp not in ("dense", "moe"):
-            raise ValueError(f"mlp must be 'dense' or 'moe', got {self.mlp!r}")
+            raise ValueError(
+                f"mlp must be 'dense' or 'moe', got {self.mlp!r} (the "
+                "pipeline stacks one homogeneous block: RoutedExperts, "
+                "SwiGLU and latent attention, models/latent_moe_lm.py, are "
+                "not among its layers; ROADMAP D1)")
         moe = self.mlp == "moe"
         blocks = {
             "ln1": self.param("ln1", ones, (L, d)),

@@ -78,6 +78,7 @@ from horovod_tpu.models.decoding import (
     _NEG,
     check_sampling_params,
     filter_logits,
+    require_decode_path,
 )
 
 
@@ -189,7 +190,10 @@ def make_speculative_fn(model, *, max_new_tokens: int, gamma: int = 4,
     if draft_model is not None and draft_params is None:
         raise ValueError("draft_model needs draft_params")
     for m, role in ((model, "target"), (draft_model, "draft")):
-        if m is not None and getattr(m, "moe_every", 0):
+        if m is None:
+            continue
+        require_decode_path(m)
+        if getattr(m, "moe_every", 0):
             raise ValueError(
                 f"speculative decoding requires a dense model ({role}): MoE "
                 "expert capacity binds per call group, so a chunked verify "

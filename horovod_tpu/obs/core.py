@@ -323,6 +323,13 @@ METRICS: dict[str, MetricSpec] = _decl([
                "`LMHead.fused_loss` at trace time: the choice is static "
                "per program.",
                "training", labels=("axis",)),
+    MetricSpec("hvt_moe_experts", "gauge",
+               "Experts of the last routed layer traced "
+               "(models/moe.py RoutedExperts): `routed`, the router's "
+               "width, and `held`, the block of them this chip computes "
+               "(equal where no deployment's share is cut out). Set at "
+               "trace time: both are static per program.",
+               "training", labels=("kind",)),
     MetricSpec("hvt_optimizer_steps_total", "counter",
                "Optimizer steps this process's fit loops have handed to "
                "the device (counted in the loop, exporter on or off).",

@@ -389,7 +389,8 @@ def _sweep_banded(lo_fn, n_total):
 
 
 def _block_spec(d, bt, tsel):
-    """BlockSpec for [B,H,T,D] arrays: one (1, 1, bt, D) tile per (b, h)
+    """BlockSpec for [B,H,T,D] arrays (D the array's own head size: q and
+    k's, or v, o and dO's): one (1, 1, bt, D) tile per (b, h)
     grid point — the (bt, D) tile sits in the trailing dims as the TPU
     lowering requires. ``tsel(i, j)`` maps the grid's (anchor, swept)
     coordinates to this tensor's T-block index."""
@@ -463,9 +464,11 @@ def _flash_fwd_impl(q, k, v, q_seg, kv_seg, causal, window, sinks, q_offset,
     # Kernel layout is [B, H, T, D] so the (T-block, D) tile occupies the
     # trailing dims; callers pass [B, T, H, D]. K/V carry their own Tk
     # (cross-attention); causality aligns the sequence ENDS via offset.
+    # q and k share one head size (the scores' contraction, and the scale),
+    # v and the output another: latent attention's 192 | 128.
     qt, kt, vt = (jnp.transpose(x, (0, 2, 1, 3)) for x in (q, k, v))
     b, h, tq, d = qt.shape
-    tk = kt.shape[2]
+    tk, d_v = kt.shape[2], vt.shape[3]
     segmented = q_seg is not None
     scale = d ** -0.5
     off = tk - tq if q_offset is None else q_offset
@@ -488,7 +491,7 @@ def _flash_fwd_impl(q, k, v, q_seg, kv_seg, causal, window, sinks, q_offset,
     in_specs = [
         _block_spec(d, bq, _anchor),
         _block_spec(d, bk, ksel),
-        _block_spec(d, bk, ksel),
+        _block_spec(d_v, bk, ksel),
     ]
     operands = [qt, kt, vt]
     if segmented:
@@ -499,15 +502,15 @@ def _flash_fwd_impl(q, k, v, q_seg, kv_seg, causal, window, sinks, q_offset,
         grid=grid,
         in_specs=in_specs,
         out_specs=[
-            _block_spec(d, bq, _anchor),
+            _block_spec(d_v, bq, _anchor),
             _stat_spec(bq, _anchor),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct(qt.shape, q.dtype),
+            jax.ShapeDtypeStruct((b, h, tq, d_v), q.dtype),
             jax.ShapeDtypeStruct((b, h, tq, 1), jnp.float32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((bq, d), jnp.float32),
+            pltpu.VMEM((bq, d_v), jnp.float32),
             pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, 1), jnp.float32),
         ],
@@ -544,7 +547,7 @@ def _flash_bwd_core(causal, window, sinks, q_offset, bq, bk, interpret, res,
         jnp.transpose(x, (0, 2, 1, 3)) for x in (q, k, v, g)
     )
     b, h, tq, d = qt.shape
-    tk = kt.shape[2]
+    tk, d_v = kt.shape[2], vt.shape[3]
     segmented = q_seg is not None
     scale = d ** -0.5
     off = tk - tq if q_offset is None else q_offset
@@ -571,8 +574,8 @@ def _flash_bwd_core(causal, window, sinks, q_offset, bq, bk, interpret, res,
     dq_in_specs = [
         _block_spec(d, bq, _anchor),
         _block_spec(d, bk, ksel),
-        _block_spec(d, bk, ksel),
-        _block_spec(d, bq, _anchor),
+        _block_spec(d_v, bk, ksel),
+        _block_spec(d_v, bq, _anchor),
         _stat_spec(bq, _anchor),
         _stat_spec(bq, _anchor),
     ]
@@ -598,8 +601,8 @@ def _flash_bwd_core(causal, window, sinks, q_offset, bq, bk, interpret, res,
     dkv_in_specs = [
         _block_spec(d, bq, qsel),
         _block_spec(d, bk, _anchor),
-        _block_spec(d, bk, _anchor),
-        _block_spec(d, bq, qsel),
+        _block_spec(d_v, bk, _anchor),
+        _block_spec(d_v, bq, qsel),
         _stat_spec(bq, qsel),
         _stat_spec(bq, qsel),
     ]
@@ -616,7 +619,7 @@ def _flash_bwd_core(causal, window, sinks, q_offset, bq, bk, interpret, res,
         in_specs=dkv_in_specs,
         out_specs=[
             _block_spec(d, bk, _anchor),
-            _block_spec(d, bk, _anchor),
+            _block_spec(d_v, bk, _anchor),
         ],
         out_shape=[
             jax.ShapeDtypeStruct(kt.shape, k.dtype),
@@ -624,7 +627,7 @@ def _flash_bwd_core(causal, window, sinks, q_offset, bq, bk, interpret, res,
         ],
         scratch_shapes=[
             pltpu.VMEM((bk, d), jnp.float32),
-            pltpu.VMEM((bk, d), jnp.float32),
+            pltpu.VMEM((bk, d_v), jnp.float32),
         ],
         interpret=interpret,
         name=KERNEL_DKV,
@@ -637,8 +640,8 @@ def _flash_bwd_core(causal, window, sinks, q_offset, bq, bk, interpret, res,
         sink_in_specs = [
             _block_spec(d, bq, _sweep),
             _block_spec(d, bk, _anchor),
-            _block_spec(d, bk, _anchor),
-            _block_spec(d, bq, _sweep),
+            _block_spec(d_v, bk, _anchor),
+            _block_spec(d_v, bq, _sweep),
             _stat_spec(bq, _sweep),
             _stat_spec(bq, _sweep),
         ]
@@ -654,15 +657,15 @@ def _flash_bwd_core(causal, window, sinks, q_offset, bq, bk, interpret, res,
             in_specs=sink_in_specs,
             out_specs=[
                 _block_spec(d, bk, _anchor),
-                _block_spec(d, bk, _anchor),
+                _block_spec(d_v, bk, _anchor),
             ],
             out_shape=[
                 jax.ShapeDtypeStruct((b, h, bk, d), k.dtype),
-                jax.ShapeDtypeStruct((b, h, bk, d), v.dtype),
+                jax.ShapeDtypeStruct((b, h, bk, d_v), v.dtype),
             ],
             scratch_shapes=[
                 pltpu.VMEM((bk, d), jnp.float32),
-                pltpu.VMEM((bk, d), jnp.float32),
+                pltpu.VMEM((bk, d_v), jnp.float32),
             ],
             interpret=interpret,
             name=KERNEL_DKV,
@@ -829,12 +832,12 @@ def flash_attention_with_lse(
     check_window(window, causal)
     segmented = q_segment_ids is not None
     block_q, block_k = pick_blocks(
-        q.shape[1], q.shape[-1], q.dtype, block_q, block_k, t_k=k.shape[1],
-        segmented=segmented, windowed=window is not None,
+        q.shape[1], max(q.shape[-1], v.shape[-1]), q.dtype, block_q, block_k,
+        t_k=k.shape[1], segmented=segmented, windowed=window is not None,
     )
     if not supported(
         q.shape, block_q, block_k, k_shape=k.shape, dtype=q.dtype,
-        segmented=segmented,
+        segmented=segmented, v_dim=v.shape[-1],
     ):
         _warn_dense_fallback(q, k, block_q, block_k)
         return _dense_with_lse(
@@ -858,8 +861,10 @@ def _sublane(dtype) -> int:
 
 
 def supported(q_shape, bq=DEFAULT_BLOCK_Q, bk=DEFAULT_BLOCK_K,
-              k_shape=None, dtype=jnp.float32, segmented=False) -> bool:
-    """Whether the kernel's tiling holds for [B,Tq,H,D] q and [B,Tk,H,D] k/v.
+              k_shape=None, dtype=jnp.float32, segmented=False,
+              v_dim=None) -> bool:
+    """Whether the kernel's tiling holds for [B,Tq,H,D] q, [B,Tk,H,D] k and
+    [B,Tk,H,Dv] v (``v_dim``; default D: one head size for all three).
 
     Beyond divisibility (q blocks against Tq, k blocks against K/V's own Tk —
     cross-attention runs the kernel on a rectangular nq×nk grid), the blocks
@@ -880,7 +885,7 @@ def supported(q_shape, bq=DEFAULT_BLOCK_Q, bk=DEFAULT_BLOCK_K,
     return (
         t % bq == 0 and tk % bk == 0
         and bq % granule == 0 and bk % granule == 0
-        and d <= 256
+        and max(d, v_dim or d) <= 256
     )
 
 
@@ -889,7 +894,8 @@ def pick_blocks(t: int, d: int, dtype, bq: int = DEFAULT_BLOCK_Q,
                 segmented: bool = False,
                 windowed: bool = False) -> tuple[int, int]:
     """Largest workable (block_q, block_k) ≤ the requested sizes for a
-    [*, t, *, d] attention call (``t_k`` = K/V's own length for
+    [*, t, *, d] attention call (``d``: the wider of the q/k and the v head
+    sizes, which is the one that crowds VMEM; ``t_k`` = K/V's own length for
     cross-attention; default self-attention): clamp for wide heads (a 1024²
     f32 score tile + wide q/k/v blocks would crowd VMEM), clamp to T, then
     halve until the block divides its T — so e.g. T=1536 runs 512² tiles
@@ -951,7 +957,9 @@ def flash_attention(
     interpret: bool | None = None,
 ):
     """[B,Tq,H,D] attention via the pallas kernel; when the tiling doesn't
-    hold, the dense reference with a `KernelFallbackWarning`.
+    hold, the dense reference with a `KernelFallbackWarning`. q and k share
+    one head size D (the scores are scaled by D^-1/2); v may have another,
+    Dv, which is then the output's (latent attention: 192 | 128).
     ``interpret=None`` takes `default_interpret()` (the pallas interpreter
     off-TPU, so tests/CPU paths run the same kernel code).
 
@@ -984,12 +992,12 @@ def flash_attention(
         sinks = 0  # full causal attention already sees every sink
     segmented = q_segment_ids is not None
     block_q, block_k = pick_blocks(
-        q.shape[1], q.shape[-1], q.dtype, block_q, block_k, t_k=k.shape[1],
-        segmented=segmented, windowed=window is not None,
+        q.shape[1], max(q.shape[-1], v.shape[-1]), q.dtype, block_q, block_k,
+        t_k=k.shape[1], segmented=segmented, windowed=window is not None,
     )
     kernel_ok = supported(
         q.shape, block_q, block_k, k_shape=k.shape, dtype=q.dtype,
-        segmented=segmented,
+        segmented=segmented, v_dim=v.shape[-1],
     ) and (sinks == 0 or (sinks <= block_k and q_offset is None))
     if not kernel_ok:
         _warn_dense_fallback(q, k, block_q, block_k)

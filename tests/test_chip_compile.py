@@ -177,13 +177,13 @@ def lm_trainer(mesh, sharding, n_layers=2, vocab=VOCAB):
     )
 
 
-def abstract_step_args(trainer, seq=SEQ):
+def abstract_step_args(trainer, seq=SEQ, batch=GLOBAL_BATCH):
     """`train_step`'s arguments as shapes with shardings: a described
     device cannot hold an array, so nothing is built or placed."""
     mesh = trainer.mesh
     rep = sharding_lib.replicated(mesh)
     tokens = SDS(
-        (GLOBAL_BATCH, seq), jnp.int32,
+        (batch, seq), jnp.int32,
         sharding=sharding_lib.batch_sharding(mesh, 2),
     )
     x0 = jnp.zeros((trainer.dp_size, seq), jnp.int32)
@@ -216,11 +216,11 @@ def four_chip_mesh(topo):
     )
 
 
-def compiled_step(trainer, seq=SEQ):
+def compiled_step(trainer, seq=SEQ, batch=GLOBAL_BATCH):
     """The Trainer's own jitted step, so the compile sees the options the
     program passes and not a copy of them."""
     return trainer._train_step.lower(
-        *abstract_step_args(trainer, seq)).compile()
+        *abstract_step_args(trainer, seq, batch)).compile()
 
 
 def head_loops(hlo: str) -> tuple[str, str]:
@@ -425,3 +425,85 @@ def test_meshless_model_on_four_chips_is_refused_with_the_remedy(
     fix it."""
     with pytest.raises(ValueError, match=r"ShardingConfig\(mesh="):
         lm_trainer(four_chip_mesh, ShardingConfig())
+
+
+# --- the latent-attention / routed-expert model's kernels and step ----------
+# `kanana-2-30b-a3b.seq8k.1chip` (PR 33): q and k 192 wide, v 128, one
+# sequence of 8,192 over 32 heads; 16 held experts of 2,048 x 768 over a
+# budget of 12,288 rows.
+
+def test_flash_with_two_head_sizes_compiles_for_v5e(topo):
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    qk = SDS((1, 8192, 32, 192), jnp.bfloat16, sharding=one_chip)
+    v = SDS((1, 8192, 32, 128), jnp.bfloat16, sharding=one_chip)
+
+    def loss(q, k, v):
+        with jax.named_scope("attention"):
+            out = fa.flash_attention(q, k, v, causal=True, interpret=False)
+        return out.astype(jnp.float32).sum()
+
+    compiled = jax.jit(
+        jax.value_and_grad(loss, argnums=(0, 1, 2))).lower(qk, qk, v).compile()
+    assert kernel_names(compiled) == FLASH_KERNELS
+    (_, grads) = compiled.output_shardings  # it has the three gradients
+    assert len(grads) == 3
+
+
+def test_grouped_matmul_compiles_for_v5e(topo, compiled_kernel):
+    """Both of the layer's products with their gradients, at the cell's
+    shapes: three Mosaic calls each, two under the product's name (the
+    product and dlhs) and one under drhs's."""
+    from horovod_tpu.ops import grouped_matmul as gm
+
+    one_chip = SingleDeviceSharding(topo.devices[0])
+
+    def sds(shape, dtype=jnp.bfloat16):
+        return SDS(shape, dtype, sharding=one_chip)
+
+    def loss(rows, w_gate_up, w_down, sizes):
+        with jax.named_scope("experts"):
+            gate, up = jnp.split(
+                gm.grouped_matmul(rows, w_gate_up, sizes), 2, axis=-1)
+            out = gm.grouped_matmul(jax.nn.silu(gate) * up, w_down, sizes)
+        return out.astype(jnp.float32).sum()
+
+    compiled = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2))).lower(
+        sds((12288, 2048)), sds((16, 2048, 1536)), sds((16, 768, 2048)),
+        sds((16,), jnp.int32)).compile()
+    assert kernel_names(compiled) == sorted(
+        [gm.KERNEL] * 4 + [gm.KERNEL_DW] * 2)
+
+
+def test_latent_moe_cell_step_fits_one_chip(topo, compiled_kernel):
+    """The whole training step of the cell at its own sizes (1 dense + 5
+    expert layers, 687.5 M parameters, 8,192 tokens): it compiles, every
+    layer runs the three flash kernels and every expert layer the six
+    grouped matmuls, and state + temporaries stay under the 15.0 GB that
+    chose the depth (14.62 when it was chosen; 1 + 6 layers read 16.00)."""
+    from horovod_tpu.models.latent_moe_lm import LatentMoELM
+    from horovod_tpu.ops import grouped_matmul as gm
+
+    mesh = mesh_lib.build_mesh(
+        mesh_lib.MeshSpec(data=1), devices=topo.devices[:1])
+    model = LatentMoELM(
+        vocab_size=16032, d_model=2048, n_layers=6, n_dense_layers=1,
+        dense_width=6144, n_heads=32, qk_nope_dim=128, qk_rope_dim=64,
+        v_dim=128, kv_rank=512, n_routed=128, experts_per_token=6,
+        expert_width=768, shared_width=1536, routed_scaling=2.448,
+        n_held=16, held_start=0, rope_base=1e6,
+        compute_dtype=jnp.bfloat16, fused_head_chunks=HEAD_CHUNKS,
+        sharding=ShardingConfig(mesh=mesh))
+    trainer = hvt.Trainer(
+        model, hvt.DistributedOptimizer(optax.adamw(1e-4)), loss="module",
+        mesh=mesh)
+    trainer._metric_names = (
+        "moe_held_rows_share", "moe_load_max_over_mean", "moe_overflow_rows")
+    compiled = compiled_step(trainer, seq=8192, batch=1)
+    assert kernel_names(compiled) == sorted(
+        FLASH_KERNELS * 6 + [gm.KERNEL] * 20 + [gm.KERNEL_DW] * 10)
+    memory = compiled.memory_analysis()
+    state = 687_502_336 * 12
+    assert state <= memory.argument_size_in_bytes <= state + 1_000_000
+    total = memory.argument_size_in_bytes + memory.temp_size_in_bytes
+    print(f"latent cell step: arguments + temporaries {total / 1e9:.3f} GB")
+    assert total < 15.0e9

@@ -684,3 +684,93 @@ class TestSinks:
         np.testing.assert_allclose(
             np.asarray(out), np.asarray(expected), rtol=2e-5, atol=2e-5
         )
+
+
+class TestTwoHeadSizes:
+    """q and k of one head size, v (and the output) of another: latent
+    attention's 192 | 128. Forward and the three gradients against an
+    independent dense computation, on the kernel and on the dense
+    fallback, in float32 and bfloat16."""
+
+    DK, DV = 192, 128
+
+    def _qkv(self, dtype, t=128, seed=11):
+        rng = np.random.RandomState(seed)
+        q, k = (jnp.asarray(rng.randn(2, t, 4, self.DK), dtype)
+                for _ in range(2))
+        return q, k, jnp.asarray(rng.randn(2, t, 4, self.DV), dtype)
+
+    @staticmethod
+    def _plain(q, k, v):
+        """Causal softmax(q k^T / sqrt(Dk)) v in float32, written out."""
+        q, k, v = (a.astype(jnp.float32) for a in (q, k, v))
+        t = q.shape[1]
+        s = jnp.einsum("bqhd,bkhd->bhqk", q, k) / np.sqrt(q.shape[-1])
+        s = jnp.where(jnp.tril(jnp.ones((t, t), bool)), s, -jnp.inf)
+        return jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(s, -1), v)
+
+    @pytest.mark.parametrize("dtype,tol", [
+        (jnp.float32, 5e-5), (jnp.bfloat16, 6e-2)], ids=["f32", "bf16"])
+    @pytest.mark.parametrize("path", ["kernel", "fallback"])
+    def test_forward_and_gradients(self, dtype, tol, path):
+        # T=128 at 32 x 64 tiles runs the kernel; T=100 does not tile.
+        t = 128 if path == "kernel" else 100
+        blocks = dict(block_q=32, block_k=64) if path == "kernel" else {}
+        q, k, v = self._qkv(dtype, t)
+        bq, bk = pick_blocks(t, self.DK, dtype, *(blocks.values() or (1024, 1024)))
+        assert supported(q.shape, bq, bk, dtype=dtype, v_dim=self.DV) == (
+            path == "kernel")
+
+        def flash(q, k, v):
+            return flash_attention(q, k, v, causal=True, **blocks)
+
+        out = flash(q, k, v)
+        assert out.shape == (2, t, 4, self.DV) and out.dtype == dtype
+        np.testing.assert_allclose(
+            out.astype(jnp.float32), self._plain(q, k, v), rtol=tol, atol=tol)
+        weight = jnp.asarray(
+            np.random.RandomState(12).randn(*out.shape), jnp.float32)
+        got = jax.grad(lambda *a: (flash(*a).astype(jnp.float32) * weight
+                                   ).sum(), argnums=(0, 1, 2))(q, k, v)
+        want = jax.grad(lambda *a: (self._plain(*a) * weight).sum(),
+                        argnums=(0, 1, 2))(q, k, v)
+        for a, b, like in zip(got, want, (q, k, v)):
+            assert a.shape == like.shape and a.dtype == dtype
+            scale = float(jnp.abs(b).max())
+            np.testing.assert_allclose(
+                a.astype(jnp.float32), b.astype(jnp.float32),
+                rtol=tol, atol=tol * scale)
+
+    def test_with_lse_and_window(self):
+        """The other entry points take the two sizes too: the (out, lse)
+        pair, and a sliding window (the banded grids)."""
+        from horovod_tpu.ops.flash_attention import _dense_with_lse
+
+        q, k, v = self._qkv(jnp.float32)
+        out, lse = flash_attention_with_lse(
+            q, k, v, causal=True, block_q=32, block_k=32)
+        want, want_lse = _dense_with_lse(q, k, v, causal=True)
+        np.testing.assert_allclose(out, want, rtol=5e-5, atol=5e-5)
+        np.testing.assert_allclose(lse, want_lse, rtol=5e-5, atol=5e-5)
+        out = flash_attention(q, k, v, causal=True, window=40, sinks=8,
+                              block_q=32, block_k=32)
+        want = dense_attention(q, k, v, causal=True, window=40, sinks=8)
+        np.testing.assert_allclose(out, want, rtol=5e-5, atol=5e-5)
+        grads = jax.grad(lambda v: flash_attention(
+            q, k, v, causal=True, window=40, sinks=8, block_q=32,
+            block_k=32).sum())(v)
+        want = jax.grad(lambda v: dense_attention(
+            q, k, v, causal=True, window=40, sinks=8).sum())(v)
+        np.testing.assert_allclose(grads, want, rtol=1e-4, atol=1e-4)
+
+    def test_the_wider_head_size_clamps_the_tiles(self):
+        # 1 x 32 x 8,192 rows at Dk 192: 512^2, as any head wider than 128
+        # (and so clear of the 1024^2 refusal at B*H*T >= 2^18 rows).
+        assert pick_blocks(8192, max(self.DK, self.DV), jnp.bfloat16) == (
+            512, 512)
+        assert supported((1, 8192, 32, self.DK), 512, 512,
+                         dtype=jnp.bfloat16, v_dim=self.DV)
+        assert not supported((1, 8192, 32, 128), 512, 512,
+                             dtype=jnp.bfloat16, v_dim=320)
+        # one head size for all three is what it was
+        assert supported((1, 8192, 32, 128), 1024, 1024, dtype=jnp.bfloat16)
