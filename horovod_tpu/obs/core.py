@@ -330,6 +330,17 @@ METRICS: dict[str, MetricSpec] = _decl([
                "(equal where no deployment's share is cut out). Set at "
                "trace time: both are static per program.",
                "training", labels=("kind",)),
+    MetricSpec("hvt_flash_tiles", "gauge",
+               "Grid steps a (batch, head) of the last flash-attention "
+               "forward grid traced (ops/flash_attention.py "
+               "`tile_census`), by what a step does: `skipped` builds and "
+               "fetches nothing (above the diagonal, below the band), "
+               "`full` runs the update with no mask operation (every row "
+               "sees every column), `edge` builds its mask (the diagonal "
+               "or the band's lower edge crosses it; every tile that runs "
+               "of a call with segment ids). Set at trace time: the "
+               "census is static per call.",
+               "training", labels=("kind",)),
     MetricSpec("hvt_optimizer_steps_total", "counter",
                "Optimizer steps this process's fit loops have handed to "
                "the device (counted in the loop, exporter on or off).",

@@ -28,6 +28,7 @@ import warnings
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
@@ -35,9 +36,13 @@ from jax.experimental.pallas import tpu as pltpu
 from horovod_tpu.ops.attention import check_window, dense_attention
 
 _BIG_NEG = -1e30
-# 1024-square tiles: each K/V block amortizes the per-block online-softmax
-# statistics (max/renormalize) over 4x the scores of a 512² tile (the gain
-# is not measured on this round's chip). The [bq, bk] f32 score tile is
+# 1024-square tiles where they fit. Measured on the chip at the one call of
+# the benchmark that takes them (bf16, B2·T2048·H16·D128; kernels alone,
+# forward + backward, PERF.md §6, PR 34): 1.91 ms a layer at 1024² against
+# 2.03 at 512² (2.15 against 2.41 before the tiles were classified) — the
+# 1024² grid runs 3 of its 4 steps against 10 of 16, but a step's fixed
+# cost (pipeline bookkeeping, the statistics' rescale, ≈ 130 bundles and
+# its DMA waits) is paid a quarter as often. The [bq, bk] f32 score tile is
 # 4 MB, and the backward kernels keep several of them live, which puts them
 # near v5e's 16 MiB scoped-VMEM limit: `pick_blocks` drops to 512 wherever
 # the chip's compiler was seen to refuse 1024². An oversized tile fails
@@ -67,100 +72,195 @@ _SEG_LANES = 128
 _SEG_SUBLANES = 8
 
 
-def _causal_mask(iq, ik, bq, bk, offset, window=None):
-    """[bq, bk] 0/1 mask for global rows iq*bq+r+offset ≥ cols ik*bk+c.
-
-    ``offset = Tk - Tq`` aligns the sequences at the END (the standard
-    cross-attention/decode convention, matching `_dense_with_lse`): query i
-    sees keys j ≤ i + Tk - Tq. Zero for self-attention. ``window`` further
-    restricts to the sliding band row − col < window (Mistral-style local
-    attention: each query sees its ``window`` most recent keys, itself
-    included)."""
-    rows = iq * bq + offset + lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
-    cols = ik * bk + lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
-    keep = rows >= cols
-    if window is not None:
-        keep &= cols > rows - window
-    return keep.astype(jnp.float32)
+def _nonneg(x):
+    """max(x, 0) by operators alone. The scalar arithmetic of this file's
+    grids (which tile a step holds, its class, the block a step fetches)
+    runs on traced coordinates in kernels and index maps and on numpy ones
+    in `tile_census` and the tests, so it is written without ``jnp.*``."""
+    return x * (x > 0)
 
 
-def _tile_mask(iq, ik, causal, segmented, bq, bk, offset, window,
-               qs_ref, ks_ref, sinks=0, sink_sel=None):
-    """(needed, mask): the block-skip predicate and the [bq, bk] 0/1 mask
-    (None when unmasked). ``needed`` is False when the whole tile is
-    provably masked — above the causal diagonal, below the sliding-window
-    band, or (segment early-out) the q block's id range cannot intersect
-    the k block's (a NECESSARY condition for any equality match, so the
-    skip is sound for arbitrary id layouts, and tight for the contiguous
-    runs packing produces).
-
-    ``sinks``/``sink_sel``: global+local attention. A SINK tile (sink_sel
-    True — a traced scalar when one grid handles both kinds, or the
-    literal True for a sink-only kernel) masks to cols < sinks AND below
-    the band — strictly disjoint from band tiles, so a (row, col) pair
-    visible through both the band and the sink region is never counted
-    twice."""
-    needed = True
-    mask = None
-    if causal:
-        band_needed = ik * bk <= iq * bq + bq - 1 + offset
-        if window is not None:
-            # The tile's newest key vs the tile's oldest query's horizon:
-            # every (row, col) has row − col ≥ (iq*bq + offset) − (ik*bk +
-            # bk − 1); when even that gap ≥ window the whole tile is stale.
-            band_needed &= ik * bk + bk - 1 > iq * bq + offset - window
-        if sinks and sink_sel is not None:
-            rows = iq * bq + offset + lax.broadcasted_iota(
-                jnp.int32, (bq, bk), 0
-            )
-            cols = ik * bk + lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
-            band_keep = (rows >= cols) & (cols > rows - window)
-            sink_keep = (
-                (rows >= cols) & (cols < sinks) & (cols <= rows - window)
-            )
-            # A q block whose rows are all inside the window needs no
-            # sink tile — the band tiles already cover block 0.
-            sink_needed = iq * bq + bq - 1 + offset >= window
-            if sink_sel is True:
-                needed = sink_needed
-                mask = sink_keep.astype(jnp.float32)
-            else:
-                needed = (sink_sel & sink_needed) | (~sink_sel & band_needed)
-                # f32 select: Mosaic cannot legalize a vector select on i1.
-                mask = jnp.where(
-                    sink_sel,
-                    sink_keep.astype(jnp.float32),
-                    band_keep.astype(jnp.float32),
-                )
-        else:
-            needed = band_needed
-            mask = _causal_mask(iq, ik, bq, bk, offset, window)
-    if segmented:
-        qs = qs_ref[0]  # [bq, LANES]
-        ks = ks_ref[0, 0:1, :]  # [1, bk]
-        q_ids = jnp.tile(qs, (1, bk // _SEG_LANES))  # [bq, bk]
-        smask = (q_ids == ks).astype(jnp.float32)
-        overlap = (jnp.min(ks) <= jnp.max(qs)) & (jnp.max(ks) >= jnp.min(qs))
-        needed = overlap if needed is True else (needed & overlap)
-        mask = smask if mask is None else mask * smask
-    return needed, mask
+def _affine_floordiv(i, a, c, d):
+    """(i·a + c) // d for a coordinate ``i`` and Python ints a, c, d > 0.
+    Where d divides a (equal tiles: every call the benchmark makes) the
+    division is of constants: a traced floor division costs the scalar core
+    a dozen bundles, in every index map of every grid step."""
+    if a % d == 0:
+        return i * (a // d) + c // d
+    return (i * a + c) // d
 
 
 def _band_lo_k(iq, bq, bk, offset, window):
     """First k block holding any in-band column for q block ``iq`` (the
     oldest visible key of the block's first row), clamped to 0. Floor
     division handles a negative numerator (band starting before key 0)."""
-    return jnp.maximum(0, (iq * bq + offset - (window - 1)) // bk)
+    return _nonneg(_affine_floordiv(iq, bq, offset - (window - 1), bk))
 
 
-def _band_lo_q(ik, bq, bk, offset, window):
-    """First q block holding any row that sees k block ``ik`` (rows r with
-    0 ≤ r + offset − c < window for some c in the block), clamped to 0."""
-    return jnp.maximum(0, (ik * bk - offset) // bq)
+def _band_lo_q(ik, bq, bk, offset):
+    """First q block holding any row that sees k block ``ik`` causally
+    (rows r with r + offset ≥ c for some c in the block), clamped to 0."""
+    return _nonneg(_affine_floordiv(ik, bk, -offset, bq))
+
+
+def _last_k(iq, bq, bk, offset):
+    """Last k block any row of q block ``iq`` sees causally (the newest
+    key of the block's last row), clamped to 0."""
+    return _nonneg(_affine_floordiv(iq, bq, bq - 1 + offset, bk))
+
+
+def _tile_class(iq, ik, bq, bk, offset, window=None):
+    """(needed, full) of the causal tile (q block ``iq``, k block ``ik``),
+    from scalars alone. Rows sit at key positions r + ``offset``
+    (``offset = Tk − Tq`` aligns the sequences at the END, the standard
+    cross-attention/decode convention, matching `_dense_with_lse`; zero
+    for self-attention) and see columns c ≤ r + offset, with ``window``
+    only the band r + offset − c < window (Mistral-style local attention:
+    each query's ``window`` most recent keys, itself included).
+
+    ``needed`` is False when the whole tile is provably masked: above the
+    diagonal (its first column past its last row) or below the band (even
+    its newest key is stale for its oldest query). ``full`` is True when
+    every row provably sees every column: the tile's last column ≤ its
+    first row, and its first column inside its LAST row's window. A tile
+    that is needed and not full is an EDGE tile: the diagonal or the
+    band's lower edge crosses it, and only it needs a mask."""
+    r0 = iq * bq + offset
+    r1 = r0 + bq - 1
+    c0 = ik * bk
+    c1 = c0 + bk - 1
+    needed = c0 <= r1
+    full = c1 <= r0
+    if window is not None:
+        needed &= c1 > r0 - window
+        full &= c0 > r1 - window
+    return needed, full
+
+
+def _k_sweep_steps(bq, bk, window, sinks, nk):
+    """Length of a q block's sweep over k blocks. A full grid walks all
+    ``nk``; a banded (sliding-window) one only the ≤ nb blocks that can
+    intersect the block's band (span bq + window − 1 columns, any
+    alignment), and sinks prepend one pinned tile (k block 0)."""
+    if window is None:
+        return nk
+    return min(nk, (bq + window - 2) // bk + 2) + (1 if sinks else 0)
+
+
+def _k_sweep_tile(iq, jj, causal, bq, bk, offset, window, sinks, nk):
+    """(ik, is_sink, needed, full): the k block that step ``jj`` of q block
+    ``iq``'s sweep holds, and its class — shared by the forward and dQ
+    kernels and `tile_census`, so they cannot disagree.
+
+    Banded grids enumerate ONLY the k blocks near the band: step jj holds
+    lo(iq) + jj, and the duplicates clipped at the last block are not
+    needed. With sinks, step 0 is the pinned SINK tile (k block 0,
+    ``is_sink``; never called full) and the band walks jj − 1; a q block
+    whose rows are all inside the window needs no sink tile (the band
+    tiles already cover block 0). A call that is not causal has no mask:
+    every tile is full."""
+    if not causal:
+        return jj, None, True, True
+    if window is None:
+        return (jj, None) + _tile_class(iq, jj, bq, bk, offset)
+    lo = _band_lo_k(iq, bq, bk, offset, window)
+    is_sink = (jj == 0) if sinks else None
+    ik = (lo + jj - 1) * (jj > 0) if sinks else lo + jj
+    needed, full = _tile_class(iq, ik, bq, bk, offset, window)
+    needed &= ik <= nk - 1
+    if sinks:
+        sink_needed = iq * bq + bq - 1 + offset >= window
+        needed = (is_sink & sink_needed) | (~is_sink & needed)
+        full &= ~is_sink
+    return ik, is_sink, needed, full
+
+
+def tile_census(tq, tk, bq, bk, *, causal, window=None, sinks=0,
+                q_offset=None, segmented=False):
+    """(skipped, edge, full): the grid steps of ONE (b, h) of the forward
+    grid by class — static per call, and the gauge ``hvt_flash_tiles``. A
+    skipped step builds and fetches nothing, a full one runs the update
+    with no mask operation, an edge one builds its [bq, bk] mask. The dQ
+    grid is the same; the dK/dV grid holds the same tiles transposed. With
+    segment ids nothing is provably full and every tile that runs is an
+    edge (the id ranges skip more of them at run time)."""
+    nq, nk = tq // bq, tk // bk
+    off = tk - tq if q_offset is None else q_offset
+    iq, jj = np.indices((nq, _k_sweep_steps(bq, bk, window, sinks, nk)))
+    _, _, needed, full = _k_sweep_tile(
+        iq, jj, causal, bq, bk, off, window, sinks, nk)
+    needed = np.broadcast_to(needed, iq.shape)
+    full = np.broadcast_to(full, iq.shape) & needed & (not segmented)
+    return (int((~needed).sum()), int((needed & ~full).sum()),
+            int(full.sum()))
+
+
+def _tile_mask(iq, ik, causal, segmented, bq, bk, offset, window,
+               qs_ref, ks_ref, sinks=0, is_sink=None):
+    """The [bq, bk] 0/1 float mask of an edge tile, built INSIDE its branch
+    (None for a call with no mask). Causal: rows iq*bq + r + offset ≥ cols
+    ik*bk + c, and inside the band where there is a ``window``.
+
+    ``sinks``/``is_sink``: global+local attention. A SINK tile (is_sink
+    True — a traced scalar when one grid handles both kinds, or the
+    literal True for a sink-only kernel) masks to cols < sinks AND below
+    the band — strictly disjoint from band tiles, so a (row, col) pair
+    visible through both the band and the sink region is never counted
+    twice. Segment ids multiply in their equality mask."""
+    mask = None
+    if causal:
+        rows = iq * bq + offset + lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
+        cols = ik * bk + lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
+        keep = rows >= cols
+        if window is not None:
+            keep &= cols > rows - window
+        mask = keep.astype(jnp.float32)
+        if sinks and is_sink is not None:
+            sink_mask = (
+                (rows >= cols) & (cols < sinks) & (cols <= rows - window)
+            ).astype(jnp.float32)
+            # f32 select: Mosaic cannot legalize a vector select on i1.
+            mask = sink_mask if is_sink is True else jnp.where(
+                is_sink, sink_mask, mask)
+    if segmented:
+        qs = qs_ref[0]  # [bq, LANES]
+        ks = ks_ref[0, 0:1, :]  # [1, bk]
+        q_ids = jnp.tile(qs, (1, bk // _SEG_LANES))  # [bq, bk]
+        smask = (q_ids == ks).astype(jnp.float32)
+        mask = smask if mask is None else mask * smask
+    return mask
+
+
+def _segments_overlap(qs_ref, ks_ref):
+    """Segment early-out: whether the q block's id range can intersect the
+    k block's — a NECESSARY condition for any equality match, so the skip
+    is sound for arbitrary id layouts, and tight for the contiguous runs
+    packing produces."""
+    qs = qs_ref[0]  # [bq, LANES]
+    ks = ks_ref[0, 0:1, :]  # [1, bk]
+    return (jnp.min(ks) <= jnp.max(qs)) & (jnp.max(ks) >= jnp.min(qs))
+
+
+def _update_by_class(needed, full, masked, make_mask, update):
+    """Run ``update(mask)`` as the tile's class asks, so that nothing of
+    the score tile's shape is computed outside a branch: a skipped step
+    runs neither branch; a full tile runs the update with NO mask
+    operation (on a wholly visible tile they add 0 and multiply by 1); an
+    edge tile builds its mask inside its branch and runs today's
+    arithmetic. ``masked`` False: the call has no mask at all. ``full``
+    the literal False: nothing is provable (segment ids, the sink-only
+    pass) and every tile that runs is an edge."""
+    if not masked:
+        pl.when(needed)(lambda: update(None))
+    elif full is False:
+        pl.when(needed)(lambda: update(make_mask()))
+    else:
+        pl.when(needed & full)(lambda: update(None))
+        pl.when(needed & ~full)(lambda: update(make_mask()))
 
 
 def _fwd_kernel(q_ref, k_ref, v_ref, *rest, scale, causal, segmented,
-                bq, bk, offset, window, banded, nk, sinks=0):
+                bq, bk, offset, window, nk, sinks=0):
     if segmented:
         qs_ref, ks_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref = rest
     else:
@@ -168,23 +268,17 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *rest, scale, causal, segmented,
         qs_ref = ks_ref = None
     iq, jj = pl.program_id(2), pl.program_id(3)
     nj = pl.num_programs(3)
-    # Banded (sliding-window) grids enumerate ONLY the k blocks near the
-    # band: grid coordinate jj walks lo(iq) .. lo(iq)+nj−1 — O(T·window)
-    # tiles (and, crucially, O(T·window) K/V DMA: a predicated-off tile in
-    # a full grid still streams its block; a tile the grid never names
-    # does not). The top-clipped DMA duplicates mask off via `needed`.
-    # With sinks, tile jj==0 is the pinned SINK tile (k block 0) and the
-    # band walks jj−1.
-    sink_sel = None
-    if banded and sinks:
-        sink_sel = jj == 0
-        ik = jnp.where(
-            sink_sel, 0, _band_lo_k(iq, bq, bk, offset, window) + jj - 1
-        )
-    elif banded:
-        ik = _band_lo_k(iq, bq, bk, offset, window) + jj
-    else:
-        ik = jj
+    # Block skip: a K block strictly above the causal diagonal or below the
+    # band — or with no possible segment match — contributes nothing;
+    # predicate the whole update away (half the FLOPs for causal; one
+    # matmul per co-resident segment pair for packed sequences). The kernel
+    # predicates on the TRUE coordinates: the index maps may name another
+    # block for a step that is skipped (`_k_sweep_maps`).
+    ik, is_sink, needed, full = _k_sweep_tile(
+        iq, jj, causal, bq, bk, offset, window, sinks, nk)
+    if segmented:
+        needed &= _segments_overlap(qs_ref, ks_ref)
+        full = False
 
     @pl.when(jj == 0)
     def _():
@@ -192,19 +286,12 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *rest, scale, causal, segmented,
         m_ref[:] = jnp.full_like(m_ref, _BIG_NEG)
         l_ref[:] = jnp.zeros_like(l_ref)
 
-    # Block skip: a K block strictly above the causal diagonal — or with no
-    # possible segment match — contributes nothing; predicate the whole
-    # update away (half the FLOPs for causal; one matmul per co-resident
-    # segment pair for packed sequences).
-    needed, mask = _tile_mask(
-        iq, ik, causal, segmented, bq, bk, offset, window, qs_ref, ks_ref,
-        sinks=sinks, sink_sel=sink_sel,
-    )
-    if banded:
-        needed &= ik <= nk - 1  # clipped-DMA duplicates beyond the last block
+    def make_mask():
+        return _tile_mask(
+            iq, ik, causal, segmented, bq, bk, offset, window, qs_ref,
+            ks_ref, sinks=sinks, is_sink=is_sink)
 
-    @pl.when(needed)
-    def _():
+    def update(mask):
         q = q_ref[0, 0, :, :]
         k = k_ref[0, 0, :, :]
         v = v_ref[0, 0, :, :]
@@ -228,6 +315,8 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *rest, scale, causal, segmented,
         )
         m_ref[:, 0:1] = m_new
 
+    _update_by_class(needed, full, causal or segmented, make_mask, update)
+
     @pl.when(jj == nj - 1)
     def _():
         l = l_ref[:, 0:1]
@@ -244,8 +333,8 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *rest, scale, causal, segmented,
 
 
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
-                   scale, causal, segmented, bq, bk, offset, window, banded,
-                   nk, sinks=0):
+                   scale, causal, segmented, bq, bk, offset, window, nk,
+                   sinks=0):
     if segmented:
         qs_ref, ks_ref, dq_ref, acc_ref = rest
     else:
@@ -253,30 +342,22 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
         qs_ref = ks_ref = None
     iq, jj = pl.program_id(2), pl.program_id(3)
     nj = pl.num_programs(3)
-    sink_sel = None
-    if banded and sinks:
-        sink_sel = jj == 0
-        ik = jnp.where(
-            sink_sel, 0, _band_lo_k(iq, bq, bk, offset, window) + jj - 1
-        )
-    elif banded:
-        ik = _band_lo_k(iq, bq, bk, offset, window) + jj
-    else:
-        ik = jj
+    ik, is_sink, needed, full = _k_sweep_tile(
+        iq, jj, causal, bq, bk, offset, window, sinks, nk)
+    if segmented:
+        needed &= _segments_overlap(qs_ref, ks_ref)
+        full = False
 
     @pl.when(jj == 0)
     def _():
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
-    needed, mask = _tile_mask(
-        iq, ik, causal, segmented, bq, bk, offset, window, qs_ref, ks_ref,
-        sinks=sinks, sink_sel=sink_sel,
-    )
-    if banded:
-        needed &= ik <= nk - 1
+    def make_mask():
+        return _tile_mask(
+            iq, ik, causal, segmented, bq, bk, offset, window, qs_ref,
+            ks_ref, sinks=sinks, is_sink=is_sink)
 
-    @pl.when(needed)
-    def _():
+    def update(mask):
         q = q_ref[0, 0, :, :]
         k = k_ref[0, 0, :, :]
         v = v_ref[0, 0, :, :]
@@ -305,14 +386,16 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
             preferred_element_type=jnp.float32,
         ) * scale
 
+    _update_by_class(needed, full, causal or segmented, make_mask, update)
+
     @pl.when(jj == nj - 1)
     def _():
         dq_ref[0, 0, :, :] = acc_ref[:].astype(dq_ref.dtype)
 
 
 def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
-                    scale, causal, segmented, bq, bk, offset, window, banded,
-                    nq, sinks=0, sink_only=False):
+                    scale, causal, segmented, bq, bk, offset, window, nq,
+                    sinks=0, sink_only=False):
     if segmented:
         qs_ref, ks_ref, dk_ref, dv_ref, dk_acc, dv_acc = rest
     else:
@@ -320,22 +403,33 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
         qs_ref = ks_ref = None
     ik, jj = pl.program_id(2), pl.program_id(3)
     nj = pl.num_programs(3)
-    iq = _band_lo_q(ik, bq, bk, offset, window) + jj if banded else jj
+    # The transposed sweep: k block ``ik`` anchored, q blocks swept — all of
+    # them, or (banded) lo(ik) + jj with the clipped duplicates not needed.
+    # The sink-only pass sweeps every q block over the one sink tile.
+    banded = window is not None and not sink_only
+    iq = _band_lo_q(ik, bq, bk, offset) + jj if banded else jj
+    needed, full = True, True
+    if sink_only:
+        needed, full = iq * bq + bq - 1 + offset >= window, False
+    elif causal:
+        needed, full = _tile_class(iq, ik, bq, bk, offset, window)
+    if banded:
+        needed &= iq <= nq - 1
+    if segmented:
+        needed &= _segments_overlap(qs_ref, ks_ref)
+        full = False
 
     @pl.when(jj == 0)
     def _():
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
 
-    needed, mask = _tile_mask(
-        iq, ik, causal, segmented, bq, bk, offset, window, qs_ref, ks_ref,
-        sinks=sinks, sink_sel=True if sink_only else None,
-    )
-    if banded:
-        needed &= iq <= nq - 1
+    def make_mask():
+        return _tile_mask(
+            iq, ik, causal, segmented, bq, bk, offset, window, qs_ref,
+            ks_ref, sinks=sinks, is_sink=True if sink_only else None)
 
-    @pl.when(needed)
-    def _():
+    def update(mask):
         q = q_ref[0, 0, :, :]
         k = k_ref[0, 0, :, :]
         v = v_ref[0, 0, :, :]
@@ -366,16 +460,26 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
             preferred_element_type=jnp.float32,
         ) * scale
 
+    _update_by_class(needed, full, causal or segmented, make_mask, update)
+
     @pl.when(jj == nj - 1)
     def _():
         dk_ref[0, 0, :, :] = dk_acc[:].astype(dk_ref.dtype)
         dv_ref[0, 0, :, :] = dv_acc[:].astype(dv_ref.dtype)
 
 
-# Grid-to-T-block selectors: the grid is (b, h, anchor, swept); a tensor's
-# T coordinate is either the anchored axis, the swept axis, or — for banded
-# (sliding-window) grids — a band around the anchor: lo(anchor) + swept,
-# clipped for the DMA (the kernels predicate the clipped duplicates off).
+# Grid-to-T-block selectors: the grid is (b, h, anchor, swept), and
+# ``tsel(i, j)`` maps its (anchor, swept) coordinates to a tensor's T-block
+# index. What a step FETCHES follows from its class as what it computes
+# does: a predicated-off step still streams whatever block its index map
+# names, and Pallas issues no DMA for an index that did not change. So a
+# skipped step before its sweep's first running one names that one's block;
+# a skipped step PAST the last running one names the NEXT sweep's first
+# blocks, the anchored tensors' too — the pipeline looks one step ahead,
+# so they are fetched beside the sweep's last update and not after a run of
+# empty steps, when nothing is left to hide them behind. Outputs stay on
+# the anchor. The kernels predicate on the TRUE coordinates
+# (`_k_sweep_tile`): which tiles compute does not depend on these maps.
 def _anchor(i, j):
     return i
 
@@ -384,8 +488,68 @@ def _sweep(i, j):
     return j
 
 
-def _sweep_banded(lo_fn, n_total):
-    return lambda i, j: jnp.clip(lo_fn(i) + j, 0, n_total - 1)
+def _fetch_maps(block, first, last, n_anchor, pinned=None):
+    """(anchor selector, swept selector) of a sweep's INPUT tensors.
+    ``block(i, j)`` is the true swept block of step j of anchor i,
+    ``first(i)``/``last(i)`` bound the blocks of its steps that can run
+    (clamped to blocks that exist; None: no bound on that side).
+    ``pinned``: step 0 holds this block whatever the anchor (the sink
+    tile), and ``block`` is the other steps'."""
+    def held(i, j):
+        at = block(i, j)
+        if first is not None:
+            at = jnp.maximum(at, first(i))
+        if last is not None:
+            at = jnp.minimum(at, last(i))
+        return at if pinned is None else jnp.where(j == 0, pinned, at)
+
+    if last is None:
+        return _anchor, held
+
+    def past(i, j):
+        over = (block(i, j) > last(i)) & (i < n_anchor - 1)
+        return over if pinned is None else over & (j > 0)
+
+    return (
+        lambda i, j: jnp.where(past(i, j), i + 1, i),
+        lambda i, j: jnp.where(past(i, j), held(i + 1, 0 * j), held(i, j)),
+    )
+
+
+def _k_sweep_maps(causal, bq, bk, off, window, sinks, nq, nk):
+    """(q-block selector, k-block selector) of the forward and dQ grids'
+    inputs — shared, so they cannot disagree on which k block a grid step
+    reads: the block `_k_sweep_tile` holds at step j of q block i, bounded
+    by the last block the q block sees (the steps above the diagonal, and a
+    banded sweep's duplicates past the last block)."""
+    if not causal:
+        return _anchor, _sweep
+    last = lambda i: jnp.minimum(_last_k(i, bq, bk, off), nk - 1)  # noqa: E731
+    if window is None:
+        return _fetch_maps(_sweep, None, last, nq)
+    lo = lambda i: _band_lo_k(i, bq, bk, off, window)  # noqa: E731
+    if sinks:
+        return _fetch_maps(
+            lambda i, j: lo(i) + j - 1, None, last, nq, pinned=0)
+    return _fetch_maps(lambda i, j: lo(i) + j, None, last, nq)
+
+
+def _q_sweep_maps(causal, bq, bk, off, window, nq, nk):
+    """(steps, k-block selector, q-block selector) of the dK/dV grid's
+    inputs: k block i anchored, the q blocks that can see it swept. A full
+    causal grid walks all ``nq`` and its steps above the diagonal come
+    FIRST. A banded grid walks lo(i) + j over the ≤ nbq blocks a k block's
+    band can reach, bounded by the last one inside the window."""
+    if not causal:
+        return nq, _anchor, _sweep
+    lo = lambda i: _band_lo_q(i, bq, bk, off)  # noqa: E731
+    if window is None:
+        first = lambda i: jnp.minimum(lo(i), nq - 1)  # noqa: E731
+        return (nq,) + _fetch_maps(_sweep, first, None, nk)
+    last = lambda i: jnp.minimum(  # noqa: E731
+        _nonneg(_affine_floordiv(i, bk, bk + window - 2 - off, bq)), nq - 1)
+    return (min(nq, (bk + window - 2) // bq + 2),) + _fetch_maps(
+        lambda i, j: lo(i) + j, None, last, nk)
 
 
 def _block_spec(d, bt, tsel):
@@ -432,21 +596,6 @@ def _seg_operands(q_seg, kv_seg, tq, tk):
     return qs, ks
 
 
-def _band_sweep_k(bq, bk, off, window, sinks, nk):
-    """(swept-axis size, k-block selector) for a banded [+ pinned sink
-    tile] sweep — shared by the forward and backward grids so they cannot
-    disagree on which k block a grid step reads."""
-    nb = min(nk, (bq + window - 2) // bk + 2) + (1 if sinks else 0)
-    lo = lambda i: _band_lo_k(i, bq, bk, off, window)  # noqa: E731
-    if sinks:
-        ksel = lambda i, j: jnp.where(  # noqa: E731
-            j == 0, 0, jnp.clip(lo(i) + j - 1, 0, nk - 1)
-        )
-    else:
-        ksel = _sweep_banded(lo, nk)
-    return nb, ksel
-
-
 @functools.partial(
     jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9, 10, 11)
 )
@@ -459,6 +608,24 @@ def _flash(q, k, v, q_seg, kv_seg, causal, window, sinks, q_offset, bq, bk,
     return out
 
 
+def _note_tiles(*census_args, **census_kwargs) -> None:
+    """The gauge ``hvt_flash_tiles{kind}``: the `tile_census` of the last
+    kernel call traced. Set by the public entries at trace time (the jitted
+    impls below run once a process): the census is static per call."""
+    from horovod_tpu import obs
+
+    census = tile_census(*census_args, **census_kwargs)
+    for kind, steps in zip(("skipped", "edge", "full"), census):
+        obs.gauge("hvt_flash_tiles", float(steps), kind=kind)
+
+
+# Jitted, so that the N identical attention calls of a model's layers share
+# ONE traced and ONE lowered copy of the kernels (an inner jit is traced once
+# a process and lowered once a program; XLA inlines it). Tracing and lowering
+# a Pallas kernel is Python work that no compile cache keeps: un-jitted it was
+# paid per layer and per program — 0.15 s a layer before the kernels had two
+# update bodies, 0.37 s with them (`setup_s`: PERF.md §6, PR 34).
+@functools.partial(jax.jit, static_argnums=(5, 6, 7, 8, 9, 10, 11))
 def _flash_fwd_impl(q, k, v, q_seg, kv_seg, causal, window, sinks, q_offset,
                     bq, bk, interpret):
     # Kernel layout is [B, H, T, D] so the (T-block, D) tile occupies the
@@ -473,29 +640,24 @@ def _flash_fwd_impl(q, k, v, q_seg, kv_seg, causal, window, sinks, q_offset,
     scale = d ** -0.5
     off = tk - tq if q_offset is None else q_offset
     nq, nk = tq // bq, tk // bk
-    banded = window is not None
-    if banded:
-        # Sliding window: the swept grid axis walks only the ≤ nb k blocks
-        # that can intersect q block i's band (span bq + window − 1 cols,
-        # any alignment) — O(T·window) tiles AND K/V DMA instead of O(T²).
-        # Sinks prepend one pinned tile (k block 0) to every sweep.
-        nb, ksel = _band_sweep_k(bq, bk, off, window, sinks, nk)
-    else:
-        nb, ksel = nk, _sweep
+    # Sliding window: the swept grid axis walks only the ≤ nb k blocks that
+    # can intersect q block i's band — O(T·window) tiles AND K/V DMA
+    # instead of O(T²) (a tile the grid never names streams nothing).
+    nb = _k_sweep_steps(bq, bk, window, sinks, nk)
+    qsel, ksel = _k_sweep_maps(causal, bq, bk, off, window, sinks, nq, nk)
     grid = (b, h, nq, nb)
     kernel = functools.partial(
         _fwd_kernel, scale=scale, causal=causal, segmented=segmented,
-        bq=bq, bk=bk, offset=off, window=window, banded=banded, nk=nk,
-        sinks=sinks,
+        bq=bq, bk=bk, offset=off, window=window, nk=nk, sinks=sinks,
     )
     in_specs = [
-        _block_spec(d, bq, _anchor),
+        _block_spec(d, bq, qsel),
         _block_spec(d, bk, ksel),
         _block_spec(d_v, bk, ksel),
     ]
     operands = [qt, kt, vt]
     if segmented:
-        in_specs += [_seg_q_spec(bq, _anchor), _seg_kv_spec(bk, ksel)]
+        in_specs += [_seg_q_spec(bq, qsel), _seg_kv_spec(bk, ksel)]
         operands += list(_seg_operands(q_seg, kv_seg, tq, tk))
     out, lse = pl.pallas_call(
         kernel,
@@ -535,6 +697,7 @@ def _flash_bwd(causal, window, sinks, q_offset, bq, bk, interpret, res, g):
     )
 
 
+@functools.partial(jax.jit, static_argnums=(0, 1, 2, 3, 4, 5, 6))  # as above
 def _flash_bwd_core(causal, window, sinks, q_offset, bq, bk, interpret, res,
                     g, g_lse):
     """Shared backward: the lse cotangent (from `flash_attention_with_lse`
@@ -552,16 +715,9 @@ def _flash_bwd_core(causal, window, sinks, q_offset, bq, bk, interpret, res,
     scale = d ** -0.5
     off = tk - tq if q_offset is None else q_offset
     nq, nk = tq // bq, tk // bk
-    banded = window is not None
-    if banded:
-        nb, ksel = _band_sweep_k(bq, bk, off, window, sinks, nk)
-        nbq = min(nq, (bk + window - 2) // bq + 2)
-        qsel = _sweep_banded(
-            lambda i: _band_lo_q(i, bq, bk, off, window), nq
-        )
-    else:
-        nb, ksel = nk, _sweep
-        nbq, qsel = nq, _sweep
+    nb = _k_sweep_steps(bq, bk, window, sinks, nk)
+    asel, ksel = _k_sweep_maps(causal, bq, bk, off, window, sinks, nq, nk)
+    nbq, kanchor, qsel = _q_sweep_maps(causal, bq, bk, off, window, nq, nk)
     # delta_i = Σ_d dO·O — the softmax-jacobian row term, cheap outside.
     delta = jnp.einsum(
         "bthd,bthd->bht", g.astype(jnp.float32), out.astype(jnp.float32)
@@ -572,22 +728,21 @@ def _flash_bwd_core(causal, window, sinks, q_offset, bq, bk, interpret, res,
     seg_ops = list(_seg_operands(q_seg, kv_seg, tq, tk)) if segmented else []
 
     dq_in_specs = [
-        _block_spec(d, bq, _anchor),
+        _block_spec(d, bq, asel),
         _block_spec(d, bk, ksel),
         _block_spec(d_v, bk, ksel),
-        _block_spec(d_v, bq, _anchor),
-        _stat_spec(bq, _anchor),
-        _stat_spec(bq, _anchor),
+        _block_spec(d_v, bq, asel),
+        _stat_spec(bq, asel),
+        _stat_spec(bq, asel),
     ]
     if segmented:
         dq_in_specs += [
-            _seg_q_spec(bq, _anchor), _seg_kv_spec(bk, ksel)
+            _seg_q_spec(bq, asel), _seg_kv_spec(bk, ksel)
         ]
     dq = pl.pallas_call(
         functools.partial(
             _bwd_dq_kernel, scale=scale, causal=causal, segmented=segmented,
-            bq=bq, bk=bk, offset=off, window=window, banded=banded, nk=nk,
-            sinks=sinks,
+            bq=bq, bk=bk, offset=off, window=window, nk=nk, sinks=sinks,
         ),
         grid=(b, h, nq, nb),
         in_specs=dq_in_specs,
@@ -600,20 +755,20 @@ def _flash_bwd_core(causal, window, sinks, q_offset, bq, bk, interpret, res,
 
     dkv_in_specs = [
         _block_spec(d, bq, qsel),
-        _block_spec(d, bk, _anchor),
-        _block_spec(d_v, bk, _anchor),
+        _block_spec(d, bk, kanchor),
+        _block_spec(d_v, bk, kanchor),
         _block_spec(d_v, bq, qsel),
         _stat_spec(bq, qsel),
         _stat_spec(bq, qsel),
     ]
     if segmented:
         dkv_in_specs += [
-            _seg_q_spec(bq, qsel), _seg_kv_spec(bk, _anchor)
+            _seg_q_spec(bq, qsel), _seg_kv_spec(bk, kanchor)
         ]
     dk, dv = pl.pallas_call(
         functools.partial(
             _bwd_dkv_kernel, scale=scale, causal=causal, segmented=segmented,
-            bq=bq, bk=bk, offset=off, window=window, banded=banded, nq=nq,
+            bq=bq, bk=bk, offset=off, window=window, nq=nq,
         ),
         grid=(b, h, nk, nbq),
         in_specs=dkv_in_specs,
@@ -632,26 +787,32 @@ def _flash_bwd_core(causal, window, sinks, q_offset, bq, bk, interpret, res,
         interpret=interpret,
         name=KERNEL_DKV,
     )(qt, kt, vt, gt, lse, delta, *seg_ops)
-    if banded and sinks:
+    if window is not None and sinks:
         # Sink contributions to dK/dV of k block 0: every q block sees the
         # sink columns, so this pass sweeps ALL nq q blocks for the one
         # anchored block — a separate call keeps the band pass's swept axis
         # at nbq instead of forcing the whole rectangle to nq.
+        # The q blocks whose rows are all inside the window come first and
+        # are skipped: they name the first block that runs.
+        first = min(_nonneg((window - off) // bq), nq - 1)
+        _, sink_qsel = _fetch_maps(_sweep, lambda i: first, None, 1)
         sink_in_specs = [
-            _block_spec(d, bq, _sweep),
+            _block_spec(d, bq, sink_qsel),
             _block_spec(d, bk, _anchor),
             _block_spec(d_v, bk, _anchor),
-            _block_spec(d_v, bq, _sweep),
-            _stat_spec(bq, _sweep),
-            _stat_spec(bq, _sweep),
+            _block_spec(d_v, bq, sink_qsel),
+            _stat_spec(bq, sink_qsel),
+            _stat_spec(bq, sink_qsel),
         ]
         if segmented:
-            sink_in_specs += [_seg_q_spec(bq, _sweep), _seg_kv_spec(bk, _anchor)]
+            sink_in_specs += [
+                _seg_q_spec(bq, sink_qsel), _seg_kv_spec(bk, _anchor)
+            ]
         dk0, dv0 = pl.pallas_call(
             functools.partial(
                 _bwd_dkv_kernel, scale=scale, causal=causal,
                 segmented=segmented, bq=bq, bk=bk, offset=off, window=window,
-                banded=False, nq=nq, sinks=sinks, sink_only=True,
+                nq=nq, sinks=sinks, sink_only=True,
             ),
             grid=(b, h, 1, nq),
             in_specs=sink_in_specs,
@@ -847,6 +1008,9 @@ def flash_attention_with_lse(
         )
     if interpret is None:
         interpret = default_interpret()
+    _note_tiles(
+        q.shape[1], k.shape[1], block_q, block_k, causal=causal,
+        window=window, q_offset=q_offset, segmented=segmented)
     return _flash_lse(
         q, k, v, q_segment_ids, kv_segment_ids, causal, window, 0, q_offset,
         block_q, block_k, interpret,
@@ -919,15 +1083,17 @@ def pick_blocks(t: int, d: int, dtype, bq: int = DEFAULT_BLOCK_Q,
         # allocation grows slowly with T (measured: 16.26M at T=32k,
         # 16.76M at T=131k vs the 16.00M limit — both fail, while T=8k
         # fits). 512² tiles leave ~3/4 of the score-tile footprint as
-        # headroom and measured within a few % of 1024² in the block sweep.
+        # headroom; where both compile (T 2,048, D 128) they cost 6 % of
+        # the kernels' time (see DEFAULT_BLOCK_Q).
         bq, bk = min(bq, 512), min(bk, 512)
     if segmented or windowed:
         # Extra in-kernel operands push 1024² past v5e's 16 MB VMEM stack:
         # the double-buffered segment-id tiles cost ~0.8 MB, and the band
         # mask's [bq, bk] i32 iotas a few hundred KB (measured 16.30M vs
-        # the 16M limit at seq 32768). 512² fits with headroom, measured
-        # within a few % of 1024² in the block sweep — and for windows a
-        # smaller K block also tightens the block-skip granularity.
+        # the 16M limit at seq 32768). 512² fits with headroom — and for
+        # windows a smaller K block also tightens the block-skip
+        # granularity. Whether a windowed or segmented call SHORTER than
+        # that would run faster at 1024² is not measured (ROADMAP S7).
         bq, bk = min(bq, 512), min(bk, 512)
     bq, bk = min(bq, t), min(bk, t_k)
     # Degrade no further than 128: below that the kernel's tiny score tiles
@@ -1012,6 +1178,9 @@ def flash_attention(
         return dense_attention(q, k, v, causal=causal)
     if interpret is None:
         interpret = default_interpret()
+    _note_tiles(
+        q.shape[1], k.shape[1], block_q, block_k, causal=causal,
+        window=window, sinks=sinks, q_offset=q_offset, segmented=segmented)
     return _flash(
         q, k, v, q_segment_ids, kv_segment_ids, causal, window, sinks,
         q_offset, block_q, block_k, interpret,

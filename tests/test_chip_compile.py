@@ -87,27 +87,43 @@ FLASH_KERNELS = sorted([fa.KERNEL_DKV, fa.KERNEL_DQ, fa.KERNEL_FWD])
 
 
 @pytest.mark.parametrize(
-    # [B, T, H, D], dtype, flash kwargs, segmented, Mosaic calls fwd+bwd
-    "shape,dtype,kwargs,segmented,n_calls",
+    # [B, T, H, D], dtype, flash kwargs, segmented, Mosaic calls fwd+bwd,
+    # v's head size where it is not D, `hvt_flash_tiles` after the trace
+    # (skipped / edge / full grid steps a (b, h); None: not looked at)
+    "shape,dtype,kwargs,segmented,n_calls,v_dim,tiles",
     [
         pytest.param((4, 1024, 16, 128), jnp.bfloat16, {}, False, 3,
-                     id="smoke-bf16-T1024"),
+                     None, None, id="smoke-bf16-T1024"),
         pytest.param((2, 4096, 16, 128), jnp.bfloat16, {}, True, 3,
-                     id="segments-T4096"),
+                     None, (28, 36, 0), id="segments-T4096"),
         pytest.param((2, 4096, 16, 128), jnp.bfloat16,
                      {"window": 1024, "sinks": 64}, False, 4,
-                     id="window-sinks-T4096"),
+                     None, None, id="window-sinks-T4096"),
         # Refused at 1024² tiles (16.20M of 16.00M scoped VMEM in the dK/dV
         # pass); `pick_blocks` takes 512² for 4-byte inputs.
         pytest.param((4, 2048, 8, 64), jnp.float32, {}, False, 3,
-                     id="f32-D64-T2048"),
+                     None, None, id="f32-D64-T2048"),
+        # The benchmark's cells' own calls, at the tiles `pick_blocks`
+        # gives them (two update bodies a kernel since PR 34: the 1024²
+        # dK/dV pass is the fullest).
+        pytest.param((2, 2048, 16, 128), jnp.bfloat16, {}, False, 3,
+                     None, (1, 2, 1), id="cell-cerebras-gpt-1.3b"),
+        pytest.param((1, 4096, 24, 128), jnp.bfloat16, {"window": 4096},
+                     False, 3, None, (28, 8, 28), id="cell-starcoder2-3b"),
+        pytest.param((1, 8192, 32, 192), jnp.bfloat16, {}, False, 3,
+                     128, (120, 16, 120), id="cell-kanana-2-30b-a3b"),
     ],
 )
 def test_flash_fwd_bwd_compiles_for_v5e(topo, shape, dtype, kwargs,
-                                        segmented, n_calls):
+                                        segmented, n_calls, v_dim, tiles):
+    from horovod_tpu.obs import core as obs_core
+    from horovod_tpu.obs import prom
+
     one_chip = SingleDeviceSharding(topo.devices[0])
     qkv = SDS(shape, dtype, sharding=one_chip)
-    args = [qkv, qkv, qkv]
+    v = qkv if v_dim is None else SDS(
+        shape[:3] + (v_dim,), dtype, sharding=one_chip)
+    args = [qkv, qkv, v]
     if segmented:
         args.append(SDS(shape[:2], jnp.int32, sharding=one_chip))
 
@@ -122,6 +138,7 @@ def test_flash_fwd_bwd_compiles_for_v5e(topo, shape, dtype, kwargs,
             )
         return out.astype(jnp.float32).sum()
 
+    obs_core.reset()
     compiled = jax.jit(
         jax.value_and_grad(loss, argnums=(0, 1, 2))
     ).lower(*args).compile()
@@ -130,6 +147,15 @@ def test_flash_fwd_bwd_compiles_for_v5e(topo, shape, dtype, kwargs,
     # sink-only dK/dV pass (the fourth call) is dK/dV work by name too.
     assert kernel_names(compiled) == sorted(
         FLASH_KERNELS + [fa.KERNEL_DKV] * (n_calls - 3))
+    # The census of the traced forward grid: static per call, so a gauge.
+    assert obs_core.spec("hvt_flash_tiles").kind == "gauge"
+    values = prom.parse_text(prom.render())
+    if tiles is not None:
+        assert tuple(
+            values[f'hvt_flash_tiles{{kind="{kind}"}}']
+            for kind in ("skipped", "edge", "full")
+        ) == tiles
+    obs_core.reset()
 
 
 def test_flash_kernels_keep_their_names_under_a_shard_map(topo):

@@ -50,6 +50,8 @@ SOURCE_REPO_FILES = {
 
 _NOT_THE_TREE = {".git", "build", "chiprun_out", "__pycache__",
                  ".jax_cache", ".chipbench_out", ".pytest_cache"}
+# `build/` is scratch (gitignored) but for the helpers `.gitignore` excepts.
+_COMMITTED_UNDER_BUILD = ("build/flash_bundles.py",)
 _PATH = re.compile(
     r"^(?P<path>[\w.-]+(?:/[\w.-]+)*\.(?:py|json|md|yaml|cc))"
     r"(?::[\d,:-]+)?(?:::[\w:\[\]-]+)?$"
@@ -72,6 +74,9 @@ def _tree_paths() -> frozenset:
         for name in filenames:
             parts = os.path.normpath(os.path.join(rel, name)).split(os.sep)
             paths.update("/".join(parts[i:]) for i in range(len(parts)))
+    for kept in _COMMITTED_UNDER_BUILD:
+        if os.path.isfile(os.path.join(REPO, kept)):
+            paths.update({kept, os.path.basename(kept)})
     return frozenset(paths)
 
 

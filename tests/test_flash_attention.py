@@ -6,12 +6,15 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from horovod_tpu.ops import flash_attention as fa
 from horovod_tpu.ops.attention import dense_attention
 from horovod_tpu.ops.flash_attention import (
+    _dense_with_lse,
     flash_attention,
     flash_attention_with_lse,
     pick_blocks,
     supported,
+    tile_census,
 )
 
 B, T, H, D = 2, 128, 4, 64
@@ -774,3 +777,313 @@ class TestTwoHeadSizes:
                              dtype=jnp.bfloat16, v_dim=320)
         # one head size for all three is what it was
         assert supported((1, 8192, 32, 128), 1024, 1024, dtype=jnp.bfloat16)
+
+
+def _dense_keep(tq, tk, off, window, sinks=0):
+    """(band, sink) [Tq, Tk] boolean masks as the dense reference defines
+    them: the causal band, and what the sinks re-admit beyond it."""
+    rows = np.arange(tq)[:, None] + off
+    cols = np.arange(tk)[None, :]
+    band = rows >= cols
+    sink = np.zeros_like(band)
+    if window is not None:
+        sink = band & (cols < sinks) & (cols <= rows - window)
+        band = band & (cols > rows - window)
+    return band, sink
+
+
+def _fetches(named):
+    """Grid steps (row-major, as the grid runs) at which a tensor's named
+    block changes: the DMAs Pallas issues for it a (b, h)."""
+    flat = named.ravel()
+    return np.concatenate([[True], flat[1:] != flat[:-1]])
+
+
+def _check_fetch_maps(maps, steps, n_anchor, true_block, needed,
+                      pinned_step=False):
+    """A sweep's input index maps against the tiles that run. Always: a
+    running step names its true anchor and block, and every name is a
+    block that exists (the callers bound them). Where every sweep has a
+    running step: every fetch, of the anchored tensors and of the swept
+    ones, brings what the NEXT running step needs and is issued at a step
+    that follows a running one — a skipped step never makes the pipeline
+    stream a block nothing computes on, and a sweep's first blocks arrive
+    beside the last update of the sweep before it. (``pinned_step``:
+    with sinks step 0 names the pinned block whether it runs or not, so
+    only the names' truth is asked.)"""
+    asel, bsel = maps
+    i, j = np.indices((n_anchor, steps))
+    anchors, blocks = np.asarray(asel(i, j)), np.asarray(bsel(i, j))
+    anchors = np.broadcast_to(anchors, i.shape)
+    assert (anchors[needed] == i[needed]).all()
+    assert (blocks[needed] == true_block[needed]).all()
+    if needed.any(axis=1).all() and not pinned_step:
+        run = np.flatnonzero(needed.ravel())
+        for named, true in ((anchors, i), (blocks, true_block)):
+            for t in np.flatnonzero(_fetches(named)):
+                nxt = run[np.searchsorted(run, t)] if t <= run[-1] else None
+                if nxt is not None:
+                    assert named.ravel()[t] == true.ravel()[nxt], (t, nxt)
+                # ... and is issued beside an update (the step before it
+                # runs), not after a run of empty steps.
+                assert t == 0 or needed.ravel()[t - 1], t
+    return anchors, blocks
+
+
+class TestTileClasses:
+    """The scalar classifier against the dense mask cut into tiles. A tile
+    wrongly called full lets a query see the future; one wrongly skipped
+    drops keys."""
+
+    @pytest.mark.parametrize("bq,bk", [(4, 4), (8, 4), (4, 8)])
+    @pytest.mark.parametrize(
+        "nq,nk,q_offset",
+        [(4, 4, None), (3, 5, None), (5, 3, None),
+         (4, 4, "tk"), (4, 4, "-tk"), (4, 4, 5), (4, 4, 6), (4, 4, 7),
+         (3, 4, -5)],
+        # The hops' offsets cover every residue modulo the tiles: an
+        # off-by-one in a predicate shows only where an edge falls.
+        ids=["self", "tk>tq", "tk<tq", "hop-all-visible", "hop-all-future",
+             "hop+5", "hop+6", "hop+7", "hop-5"],
+    )
+    @pytest.mark.parametrize("window", [None, 3, 6, 13, 1000])
+    def test_classes_agree_with_the_dense_mask(self, bq, bk, nq, nk,
+                                               q_offset, window):
+        tq, tk = nq * bq, nk * bk
+        q_offset = {"tk": tk, "-tk": -tk}.get(q_offset, q_offset)
+        off = tk - tq if q_offset is None else q_offset
+        for sinks in (0, 3) if window and q_offset is None else (0,):
+            band, sink = _dense_keep(tq, tk, off, window, sinks)
+            steps = fa._k_sweep_steps(bq, bk, window, sinks, nk)
+            iq, jj = np.indices((nq, steps))
+            ik, is_sink, needed, full = fa._k_sweep_tile(
+                iq, jj, True, bq, bk, off, window, sinks, nk)
+            is_sink = np.zeros_like(needed) if is_sink is None else is_sink
+            seen = np.zeros((tq, tk), int)
+            for i, j in zip(iq.ravel(), jj.ravel()):
+                if ik[i, j] > nk - 1:
+                    assert not needed[i, j]  # a clipped duplicate
+                    continue
+                dense = (sink if is_sink[i, j] else band)[
+                    i * bq:(i + 1) * bq, ik[i, j] * bk:(ik[i, j] + 1) * bk]
+                if full[i, j]:
+                    assert dense.all(), (i, j)
+                if not needed[i, j]:
+                    assert not dense.any(), (i, j)
+                else:
+                    seen[i * bq:(i + 1) * bq,
+                         ik[i, j] * bk:(ik[i, j] + 1) * bk] += dense
+            # The steps that run cover every visible pair exactly once.
+            np.testing.assert_array_equal(seen, (band | sink).astype(int))
+            census = tile_census(
+                tq, tk, bq, bk, causal=True, window=window, sinks=sinks,
+                q_offset=q_offset)
+            assert census == (
+                (~needed).sum(), (needed & ~full).sum(),
+                (needed & full).sum())
+            assert sum(census) == nq * steps
+            # The forward / dQ sweep's inputs.
+            _, named = _check_fetch_maps(
+                fa._k_sweep_maps(True, bq, bk, off, window, sinks, nq, nk),
+                steps, nq, ik, needed, pinned_step=bool(sinks))
+            assert ((named >= 0) & (named <= nk - 1)).all()
+            assert (named[is_sink.astype(bool)] == 0).all()
+            if sinks:
+                continue
+            # The dK/dV sweep: k block anchored, q blocks swept.
+            qsteps, *maps = fa._q_sweep_maps(
+                True, bq, bk, off, window, nq, nk)
+            kk, jq = np.indices((nk, qsteps))
+            tq_block = jq + (
+                fa._band_lo_q(kk, bq, bk, off) if window is not None else 0)
+            q_needed, _ = fa._tile_class(tq_block, kk, bq, bk, off, window)
+            q_needed &= tq_block <= nq - 1
+            anchors, named = _check_fetch_maps(
+                maps, qsteps, nk, tq_block, q_needed)
+            assert ((named >= 0) & (named <= nq - 1)).all()
+            assert ((anchors >= 0) & (anchors <= nk - 1)).all()
+            # Both grids run the same tiles.
+            assert q_needed.sum() == needed.sum()
+
+    @pytest.mark.parametrize(
+        "call,expected",
+        [
+            # The three cells' calls (chipbench/configs) at the tiles
+            # `pick_blocks` gives them: skipped / edge / full a (b, h).
+            (dict(t=8192, d=192, window=None), (120, 16, 120)),
+            (dict(t=4096, d=128, window=4096), (28, 8, 28)),
+            (dict(t=2048, d=128, window=None), (1, 2, 1)),
+        ],
+        ids=["kanana-2-30b-a3b", "starcoder2-3b", "cerebras-gpt-1.3b"],
+    )
+    def test_census_of_the_cells_calls(self, call, expected):
+        t, window = call["t"], call["window"]
+        bq, bk = pick_blocks(
+            t, call["d"], jnp.bfloat16, windowed=window is not None)
+        assert tile_census(
+            t, t, bq, bk, causal=True, window=window) == expected
+
+    @pytest.mark.parametrize(
+        "call,running,fetched",
+        [
+            (dict(t=8192, d=192, window=None), 136, 135),
+            (dict(t=4096, d=128, window=4096), 36, 35),
+            (dict(t=2048, d=128, window=None), 3, 2),
+        ],
+        ids=["kanana-2-30b-a3b", "starcoder2-3b", "cerebras-gpt-1.3b"],
+    )
+    def test_skipped_steps_of_the_cells_calls_fetch_nothing(
+            self, call, running, fetched):
+        """K blocks a head's forward sweep streams: one for every step of
+        the grid before the maps followed the tiles' classes (256 / 64 / 4),
+        now at most one for every step that runs."""
+        t, window = call["t"], call["window"]
+        bq, bk = pick_blocks(
+            t, call["d"], jnp.bfloat16, windowed=window is not None)
+        nq = nk = t // bq
+        steps = fa._k_sweep_steps(bq, bk, window, 0, nk)
+        iq, jj = np.indices((nq, steps))
+        ik, _, needed, _ = fa._k_sweep_tile(
+            iq, jj, True, bq, bk, 0, window, 0, nk)
+        _, blocks = _check_fetch_maps(
+            fa._k_sweep_maps(True, bq, bk, 0, window, 0, nq, nk),
+            steps, nq, ik, needed)
+        assert needed.sum() == running
+        assert _fetches(blocks).sum() == fetched
+        assert _fetches(ik).sum() == nq * steps
+
+    def test_census_without_a_provable_class(self):
+        # No mask at all: every tile is full. Segment ids: nothing is.
+        assert tile_census(64, 96, 16, 32, causal=False) == (0, 0, 12)
+        assert tile_census(
+            64, 96, 16, 32, causal=False, segmented=True) == (0, 12, 0)
+        assert tile_census(
+            64, 64, 16, 16, causal=True, segmented=True) == (6, 10, 0)
+
+    def test_gauge_reads_the_last_traced_grid(self):
+        from horovod_tpu import obs
+        from horovod_tpu.obs import core as obs_core
+        from horovod_tpu.obs import prom
+
+        assert obs_core.spec("hvt_flash_tiles").labels == ("kind",)
+        obs_core.reset()
+        q, k, v = _qkv(40)
+        jax.eval_shape(
+            lambda q, k, v: flash_attention(q, k, v, causal=True, **BLOCKS),
+            q, k, v)
+        values = prom.parse_text(prom.render(obs.default_registry()))
+        assert [
+            values[f'hvt_flash_tiles{{kind="{kind}"}}']
+            for kind in ("skipped", "edge", "full")
+        ] == [6.0, 4.0, 6.0]
+        obs_core.reset()
+
+
+class TestEveryTileClass:
+    """Forward and the three gradients through skipped, full and edge tiles
+    of grids of at least 3 × 3 (interpreter, float32), against the dense
+    reference."""
+
+    T3, TILE = 96, 32
+
+    @staticmethod
+    def _rand(shape, seed):
+        return jnp.asarray(
+            np.random.RandomState(seed).randn(*shape).astype(np.float32))
+
+    @pytest.mark.parametrize(
+        "case",
+        [
+            dict(),
+            # The diagonal and the band's lower edge in ONE tile: no tile of
+            # a window narrower than bq + bk − 1 is full.
+            dict(window=20, no_full=True),
+            dict(window=48, no_full=True),
+            # The lower edge crosses a tile the diagonal does not, (2, 0),
+            # beside full ones, (1, 0) and (2, 1).
+            dict(window=70),
+            dict(window=96),
+            dict(window=1000),
+            dict(tk=160),
+            dict(tk=160, window=70),
+            dict(dk=48, dv=32),
+            dict(window=40, sinks=8, no_full=True),
+            dict(window=70, sinks=8),
+            dict(segments=True, no_full=True),
+            dict(segments=True, window=200, no_full=True),
+        ],
+        ids=["causal", "window<tile", "window-2-tiles", "window-between",
+             "window=T",
+             "window>T", "tk>tq", "tk>tq-window", "dk!=dv", "sinks",
+             "sinks-full-tiles", "segments", "segments-window"],
+    )
+    def test_forward_and_gradients(self, case):
+        segments = case.get("segments", False)
+        # Segment ids need lane-aligned k blocks: 128² tiles there.
+        tile = 128 if segments else self.TILE
+        tq = 3 * tile
+        tk = case.get("tk", tq)
+        dk, dv = case.get("dk", 16), case.get("dv", case.get("dk", 16))
+        q = self._rand((1, tq, 2, dk), 50)
+        k = self._rand((1, tk, 2, dk), 51)
+        v = self._rand((1, tk, 2, dv), 52)
+        kwargs = dict(
+            causal=True, window=case.get("window"),
+        )
+        ids = {}
+        if segments:
+            seg = _packed_segments(np.random.RandomState(53), 1, tq, 3)
+            ids = dict(q_segment_ids=seg, kv_segment_ids=seg)
+        sinks = case.get("sinks", 0)
+        census = tile_census(
+            tq, tk, tile, tile, causal=True, window=kwargs["window"],
+            sinks=sinks, segmented=segments)
+        assert census[0] and census[1], census
+        assert bool(census[2]) != case.get("no_full", False), census
+
+        def flash(q, k, v):
+            return flash_attention(
+                q, k, v, block_q=tile, block_k=tile, sinks=sinks, **kwargs,
+                **ids)
+
+        def dense(q, k, v):
+            return _dense_with_lse(q, k, v, sinks=sinks, **kwargs, **ids)[0]
+
+        np.testing.assert_allclose(
+            np.asarray(flash(q, k, v)), np.asarray(dense(q, k, v)),
+            rtol=2e-5, atol=2e-5)
+        grads = [
+            jax.grad(lambda q, k, v: (fn(q, k, v) ** 2).sum(),
+                     argnums=(0, 1, 2))(q, k, v)
+            for fn in (flash, dense)
+        ]
+        for a, b in zip(*grads):
+            np.testing.assert_allclose(
+                np.asarray(a), np.asarray(b), rtol=1e-4, atol=1e-4)
+
+    @pytest.mark.parametrize("window", [None, 10_000])
+    def test_all_full_call_equals_the_unmasked_one_to_the_bit(self, window):
+        """A ring hop whose keys all lie behind every query (q_offset ≥ Tk)
+        has only full tiles: nothing of a mask is computed, and the output
+        and the three gradients are the unmasked call's to the bit."""
+        t = self.T3
+        q = self._rand((1, t, 2, 16), 60)
+        k = self._rand((1, t, 2, 16), 61)
+        v = self._rand((1, t, 2, 16), 62)
+        assert tile_census(
+            t, t, self.TILE, self.TILE, causal=True, window=window,
+            q_offset=t + 5) == (0, 0, 9)
+
+        def run(**kwargs):
+            def loss(q, k, v):
+                out, lse = flash_attention_with_lse(
+                    q, k, v, block_q=self.TILE, block_k=self.TILE, **kwargs)
+                return (out ** 2).sum() + lse.sum(), out
+            return jax.value_and_grad(loss, argnums=(0, 1, 2), has_aux=True)(
+                q, k, v)
+
+        (_, out), grads = run(causal=True, window=window, q_offset=t + 5)
+        (_, plain), plain_grads = run(causal=False)
+        np.testing.assert_array_equal(np.asarray(out), np.asarray(plain))
+        for a, b in zip(grads, plain_grads):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
