@@ -6,6 +6,7 @@ weight-only int8)."""
 from horovod_tpu.models.beam import make_beam_search_fn  # noqa: F401
 from horovod_tpu.models.cnn import MnistCNN  # noqa: F401
 from horovod_tpu.models.decoding import generate, make_generate_fn  # noqa: F401
+from horovod_tpu.models.hybrid_moe_lm import HybridMoELM  # noqa: F401
 from horovod_tpu.models.quant import (  # noqa: F401
     dequantize_params,
     quantize_params,

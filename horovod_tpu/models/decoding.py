@@ -98,7 +98,9 @@ def require_decode_path(model) -> None:
     if not hasattr(model, "decode"):
         raise NotImplementedError(
             f"{type(model).__name__} has no decode path: its layers keep no "
-            "cache (for LatentMoELM a compressed latent cache, ROADMAP R2); "
+            "cache (for LatentMoELM a compressed latent cache, ROADMAP R2; "
+            "for HybridMoELM a recurrent state beside the keys and values "
+            "and a decode step for its DeltaAttention layers, ROADMAP R8); "
             "generation, beam search and speculative decoding take a "
             "TransformerLM")
 

@@ -1,0 +1,343 @@
+"""A hybrid causal LM: delta-rule linear attention and gated softmax
+attention over routed experts (Solar-Open2 / Kimi-Linear style).
+
+The stack takes its layer kinds as DATA: ``layer_kinds`` is a tuple with
+one of `LINEAR` / `SOFTMAX` a layer (three linear layers to one softmax
+layer in the published model). Every layer is pre-norm RMSNorm around a
+token mixer and around a routed expert layer with a shared expert
+(`models/moe.py` `RoutedExperts`); a final RMSNorm and an untied head; no
+biases in the projections and NO positions anywhere (the recurrence and
+the causal mask order the tokens). Layer, for a token's vector h::
+
+    x += Mixer_i(RMSNorm(x));   x += MoE(RMSNorm(x))
+
+`DeltaAttention` (``linear``; Kimi Delta Attention, arXiv:2510.26692), a
+head, over t, with S [Dk, Dv] float32 from zero::
+
+    q~, k~, v~ = SiLU(conv(W_q h)), SiLU(conv(W_k h)), SiLU(conv(W_v h))
+                 causal depthwise convolution, `conv_size` taps a channel
+    q_t = q~_t / |q~_t| * Dk^-1/2;  k_t = k~_t / |k~_t|;  v_t = v~_t
+    g_t = -exp(A_log) * softplus(W_fb (W_fa h_t) + dt_bias)   [Dk], <= 0
+    beta_t = 2 * sigmoid(w_b . h_t)        (x 2: a negative eigenvalue)
+    S_t = (I - beta_t k_t k_t^T) Diag(exp(g_t)) S_{t-1} + beta_t k_t v_t^T
+    o_t = S_t^T q_t                        (ops/delta_rule.py, chunked)
+    y_t = W_o [ RMSNorm_head(o_t) * sigmoid(W_gb (W_ga h_t)) ]
+
+`GatedAttention` (``softmax``): grouped-query causal softmax attention
+through the flash kernel with no rotary and no QK-norm, its output gated
+elementwise before W_o (arXiv:2505.06708): ``y = W_o [attn *
+sigmoid(W_g h)]``.
+
+**One chip's share of a deployment is a parameter of the model.** Both
+mixers are told which heads they hold (``n_held_heads`` from
+``held_heads_start``, of ``n_heads``): they carry those heads' parameters
+only and return those heads' rows of W_o times their outputs, the partial
+sum a tensor-parallel group would add up (what all heads share, the two
+low-rank input projections and the head norm's scale, is held whole by
+every chip). A softmax layer holds the K/V heads its query heads read.
+The experts are told the same way (``n_held`` from ``held_start``), and
+the vocabulary's rows held are simply ``vocab_size``. Nothing here stands
+in for the absent chips.
+
+The model keeps the `Trainer(loss='module')` contract of `TransformerLM`:
+``apply(tokens, train=, labels=)`` returns per-token ``(loss, correct)``
+from the chunked head + CE (`LMHead.fused_loss`), without labels the
+logits. It has no decode path (a recurrent state beside the keys and
+values in the cache manager: ROADMAP R8) and `PipelinedLM` does not know
+its layers (ROADMAP D1); both refuse by name. It runs on one chip: a mesh
+of more is refused by name (ROADMAP R1).
+"""
+
+from __future__ import annotations
+
+import functools
+import math
+
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
+
+from horovod_tpu.models.moe import RoutedExperts
+from horovod_tpu.models.transformer import BATCH_AXES, LMHead, ShardingConfig
+from horovod_tpu.ops import delta_rule
+from horovod_tpu.ops.flash_attention import flash_attention
+
+LINEAR, SOFTMAX = "linear", "softmax"
+# By name in the compiled step, forward and backward (chipbench/
+# kda_spans.py): the linear layer's four parts, and everything of the
+# softmax layer but the flash kernel, whose events are found by its names.
+KDA_SCOPE = "hvt.kda"
+KDA_PROJ, KDA_CONV = f"{KDA_SCOPE}/proj", f"{KDA_SCOPE}/conv"
+KDA_SCAN, KDA_OUT = f"{KDA_SCOPE}/scan", f"{KDA_SCOPE}/out"
+GQA_SCOPE = "hvt.gqa"
+
+
+def short_conv(x, taps):
+    """Causal depthwise convolution of ``x [B, T, H, D]`` with ``taps [K,
+    H, D]``: ``y_t = sum_j taps[j] x_{t-(K-1)+j}``, the last tap on the
+    current position, zeros before the sequence's start."""
+    size = taps.shape[0]
+    padded = jnp.pad(x, ((0, 0), (size - 1, 0), (0, 0), (0, 0)))
+    t = x.shape[1]
+    return sum(padded[:, j:j + t] * taps[j].astype(x.dtype)
+               for j in range(size))
+
+
+def l2_normalised(x):
+    """``x / |x|`` over the last axis, in float32."""
+    x = x.astype(jnp.float32)
+    return x * jax.lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True) + 1e-6)
+
+
+def log_decay(a_log, dt_bias, low_rank):
+    """``-exp(A_log) softplus(low_rank + dt_bias)``: float32 ``[B, T, H,
+    Dk]``, one log-decay a channel, for ``A_log [H]`` and ``dt_bias [H,
+    Dk]``."""
+    rate = jax.nn.softplus(low_rank.astype(jnp.float32) + dt_bias)
+    return -jnp.exp(a_log)[:, None] * rate
+
+
+def write_strength(logits):
+    """``beta = 2 sigmoid``: in (0, 2), so ``I - beta k k^T`` may flip k."""
+    return 2.0 * jax.nn.sigmoid(logits.astype(jnp.float32))
+
+
+def output_gate(logits):
+    return jax.nn.sigmoid(logits.astype(jnp.float32))
+
+
+def _gated(out, gate_in):
+    """``out * gate`` in ``out``'s dtype, keeping the gate's logits and not
+    the float32 gate for the backward pass."""
+    return jax.checkpoint(
+        lambda o, logits: (o * output_gate(logits)).astype(o.dtype))(
+            out, gate_in)
+
+
+def project_out(heads_out, kernel):
+    """The held heads' part of the output projection: ``[B, T, H, D] x [H,
+    D, d]``."""
+    return jnp.einsum("bthe,hed->btd", heads_out, kernel)
+
+
+def _check_held(what, n_held, start, n_heads):
+    if not (0 < n_held and 0 <= start <= n_heads - n_held):
+        raise ValueError(
+            f"{what}: heads {start}..{start + n_held} are not a block of "
+            f"its {n_heads}")
+
+
+def _a_log_init(key, shape, dtype=jnp.float32):
+    """log of a rate drawn from [1, 16), as Kimi Linear's layer."""
+    return jnp.log(jax.random.uniform(key, shape, dtype, 1.0, 16.0))
+
+
+def _dt_bias_init(key, shape, dtype=jnp.float32):
+    """The inverse softplus of a step drawn log-uniformly from [1e-3,
+    1e-1]."""
+    dt = jnp.exp(jax.random.uniform(
+        key, shape, dtype, math.log(1e-3), math.log(1e-1)))
+    return dt + jnp.log(-jnp.expm1(-dt))
+
+
+class DeltaAttention(nn.Module):
+    """The held heads of one KDA layer, ``[B, T, d] -> [B, T, d]``."""
+
+    n_heads: int
+    n_held_heads: int
+    held_heads_start: int
+    head_dim: int
+    conv_size: int
+    rank: int        # of the decay's and of the gate's input projection
+    eps: float
+    chunk: int
+    compute_dtype: jnp.dtype
+
+    @nn.compact
+    def __call__(self, x):
+        _check_held("DeltaAttention", self.n_held_heads,
+                    self.held_heads_start, self.n_heads)
+        from horovod_tpu import obs
+
+        obs.gauge("hvt_held_heads", float(self.n_held_heads), mixer=LINEAR)
+        obs.gauge("hvt_kda_chunks",
+                  float(delta_rule.n_chunks(x.shape[1], self.chunk)))
+        cd, held, dim = self.compute_dtype, self.n_held_heads, self.head_dim
+        dense = functools.partial(nn.DenseGeneral, use_bias=False, dtype=cd)
+        taps_init = nn.initializers.normal(self.conv_size ** -0.5)
+        # What stands between the projections and the scan, and between the
+        # scan and W_o, is elementwise: each stretch keeps its inputs only
+        # and is formed again in the backward pass (a closure a trace, so
+        # that no cached trace outlives the functions it called).
+        with jax.named_scope(KDA_PROJ):
+            q, k, v = (dense((held, dim), name=f"{n}_proj")(x) for n in "qkv")
+            decay_in = dense((held, dim), name="f_b")(
+                dense(self.rank, name="f_a")(x))
+            beta_in = dense(held, name="b_proj")(x)
+            gate_in = dense((held, dim), name="g_b")(
+                dense(self.rank, name="g_a")(x))
+            a_log = self.param("A_log", _a_log_init, (held,))
+            dt_bias = self.param("dt_bias", _dt_bias_init, (held, dim))
+            g, beta = jax.checkpoint(lambda low, strength: (
+                log_decay(a_log, dt_bias, low), write_strength(strength)))(
+                    decay_in, beta_in)
+        with jax.named_scope(KDA_CONV):
+            taps = [self.param(f"{n}_conv", taps_init,
+                               (self.conv_size, held, dim)) for n in "qkv"]
+
+            def conv_unit(q, k, v):
+                q, k, v = (nn.silu(short_conv(a, w))
+                           for a, w in zip((q, k, v), taps))
+                return ((l2_normalised(q) * dim ** -0.5).astype(cd),
+                        l2_normalised(k).astype(cd), v)
+
+            q, k, v = jax.checkpoint(conv_unit)(q, k, v)
+        with jax.named_scope(KDA_SCAN):
+            out = delta_rule.gated_delta_rule(
+                q, k, v, g, beta, chunk=self.chunk)
+        with jax.named_scope(KDA_OUT):
+            out = nn.RMSNorm(epsilon=self.eps, dtype=cd, name="o_norm")(out)
+            out = _gated(out, gate_in)
+            kernel = self.param(
+                "o_proj", nn.initializers.lecun_normal(in_axis=(0, 1)),
+                (held, dim, x.shape[-1]))
+            return project_out(out, kernel.astype(cd))
+
+
+class GatedAttention(nn.Module):
+    """The held query heads of one softmax layer with the K/V heads they
+    read, ``[B, T, d] -> [B, T, d]``: no positions, the output gated."""
+
+    n_heads: int
+    n_kv_heads: int
+    n_held_heads: int
+    held_heads_start: int
+    head_dim: int
+    compute_dtype: jnp.dtype
+
+    @nn.compact
+    def __call__(self, x):
+        _check_held("GatedAttention", self.n_held_heads,
+                    self.held_heads_start, self.n_heads)
+        group = self.n_heads // self.n_kv_heads
+        if (self.n_heads % self.n_kv_heads or self.n_held_heads % group
+                or self.held_heads_start % group):
+            raise ValueError(
+                f"GatedAttention: query heads {self.held_heads_start}.."
+                f"{self.held_heads_start + self.n_held_heads} do not cover "
+                f"whole groups of {self.n_heads} / {self.n_kv_heads} heads "
+                "to a K/V head")
+        from horovod_tpu import obs
+
+        obs.gauge("hvt_held_heads", float(self.n_held_heads), mixer=SOFTMAX)
+        cd, held, dim = self.compute_dtype, self.n_held_heads, self.head_dim
+        dense = functools.partial(nn.DenseGeneral, use_bias=False, dtype=cd)
+        with jax.named_scope(GQA_SCOPE):
+            q = dense((held, dim), name="q_proj")(x)
+            k, v = (dense((held // group, dim), name=f"{n}_proj")(x)
+                    for n in "kv")
+            gate_in = dense((held, dim), name="g_proj")(x)
+            k, v = (jnp.repeat(a, group, axis=2) for a in (k, v))
+        out = flash_attention(q, k, v, causal=True)
+        with jax.named_scope(GQA_SCOPE):
+            kernel = self.param(
+                "o_proj", nn.initializers.lecun_normal(in_axis=(0, 1)),
+                (held, dim, x.shape[-1]))
+            return project_out(_gated(out, gate_in), kernel.astype(cd))
+
+
+class HybridBlock(nn.Module):
+    """``x += mixer(norm(x)); x += mlp(norm(x))`` with both given (unbound:
+    they are adopted here under the names ``mixer`` and ``mlp``)."""
+
+    mixer: nn.Module
+    mlp: nn.Module
+    eps: float
+    compute_dtype: jnp.dtype
+
+    @nn.compact
+    def __call__(self, x):
+        norm = functools.partial(
+            nn.RMSNorm, epsilon=self.eps, dtype=self.compute_dtype)
+        x = x + self.mixer(norm(name="mixer_norm")(x))
+        return x + self.mlp(norm(name="mlp_norm")(x))
+
+
+class HybridMoELM(nn.Module):
+    """Causal LM over integer tokens, ``[B, T] -> [B, T, vocab]`` logits or,
+    with ``labels``, per-token ``(loss, correct)``."""
+
+    vocab_size: int
+    d_model: int
+    layer_kinds: tuple    # LINEAR / SOFTMAX, one a layer
+    head_dim: int
+    linear_heads: int     # of the whole layer ...
+    softmax_heads: int
+    softmax_kv_heads: int
+    n_held_heads: int     # ... and the block of them held here, both mixers
+    held_heads_start: int
+    conv_size: int
+    low_rank: int
+    kda_chunk: int
+    n_routed: int         # the router's width
+    experts_per_token: int
+    expert_width: int
+    shared_width: int
+    routed_scaling: float
+    n_held: int           # the routed experts held here, a block ...
+    held_start: int       # ... from this index
+    eps: float
+    compute_dtype: jnp.dtype
+    fused_head_chunks: int
+    sharding: ShardingConfig = ShardingConfig()
+
+    @nn.compact
+    def __call__(self, tokens, *, train: bool = False, labels=None):
+        del train  # no dropout, and the routed layer sows in every mode
+        cfg, cd = self.sharding, self.compute_dtype
+        if cfg.mesh is not None and cfg.mesh.size > 1:
+            raise NotImplementedError(
+                f"HybridMoELM on a mesh of {cfg.mesh.size} chips "
+                f"({dict(cfg.mesh.shape)}): its layers run on one chip "
+                "(ROADMAP R1, R8)")
+        unknown = sorted(set(self.layer_kinds) - {LINEAR, SOFTMAX})
+        if unknown or not self.layer_kinds:
+            raise ValueError(
+                f"layer_kinds {self.layer_kinds!r}: a layer is {LINEAR!r} "
+                f"or {SOFTMAX!r}")
+        from horovod_tpu import obs
+
+        for kind in (LINEAR, SOFTMAX):
+            obs.gauge("hvt_layer_kinds",
+                      float(self.layer_kinds.count(kind)), kind=kind)
+        x = nn.Embed(self.vocab_size, self.d_model, dtype=cd, name="embed")(
+            tokens)
+        x = cfg.constrain(x, P(BATCH_AXES, None, None))
+        for i, kind in enumerate(self.layer_kinds):
+            if kind == LINEAR:
+                mixer = DeltaAttention(
+                    self.linear_heads, self.n_held_heads,
+                    self.held_heads_start, self.head_dim, self.conv_size,
+                    self.low_rank, self.eps, self.kda_chunk, cd, parent=None)
+            else:
+                mixer = GatedAttention(
+                    self.softmax_heads, self.softmax_kv_heads,
+                    self.n_held_heads, self.held_heads_start, self.head_dim,
+                    cd, parent=None)
+            mlp = RoutedExperts(
+                n_routed=self.n_routed, k=self.experts_per_token,
+                expert_width=self.expert_width,
+                shared_width=self.shared_width, n_held=self.n_held,
+                held_start=self.held_start,
+                routed_scaling=self.routed_scaling, compute_dtype=cd,
+                sharding=cfg, parent=None)
+            x = HybridBlock(mixer, mlp, self.eps, cd, name=f"Block_{i}")(x)
+            x = cfg.constrain(x, P(BATCH_AXES, None, None))
+        x = nn.RMSNorm(epsilon=self.eps, dtype=cd, name="final_norm")(x)
+        head = LMHead(
+            self.d_model, self.vocab_size, compute_dtype=cd, sharding=cfg,
+            name="lm_head")
+        if labels is not None:
+            return head.fused_loss(x, labels, self.fused_head_chunks)
+        return head(x)

@@ -134,8 +134,10 @@ class PipelinedLM(nn.Module):
             raise ValueError(
                 f"mlp must be 'dense' or 'moe', got {self.mlp!r} (the "
                 "pipeline stacks one homogeneous block: RoutedExperts, "
-                "SwiGLU and latent attention, models/latent_moe_lm.py, are "
-                "not among its layers; ROADMAP D1)")
+                "SwiGLU and latent attention, models/latent_moe_lm.py, and "
+                "DeltaAttention, GatedAttention, models/hybrid_moe_lm.py, "
+                "whose stages would be of unequal cost, are not among its "
+                "layers; ROADMAP D1, R8)")
         moe = self.mlp == "moe"
         blocks = {
             "ln1": self.param("ln1", ones, (L, d)),

@@ -341,6 +341,23 @@ METRICS: dict[str, MetricSpec] = _decl([
                "of a call with segment ids). Set at trace time: the "
                "census is static per call.",
                "training", labels=("kind",)),
+    MetricSpec("hvt_layer_kinds", "gauge",
+               "Layers of the last HybridMoELM traced "
+               "(models/hybrid_moe_lm.py), by their token mixer: `linear` "
+               "(delta-rule linear attention) and `softmax` (gated "
+               "grouped-query attention). Set at trace time: the stack's "
+               "kinds are data of the model, static per program.",
+               "training", labels=("kind",)),
+    MetricSpec("hvt_held_heads", "gauge",
+               "Heads this chip holds of the last mixer traced of each "
+               "kind (models/hybrid_moe_lm.py DeltaAttention `linear`, "
+               "GatedAttention `softmax`): the layer returns their rows "
+               "of the output projection only. Set at trace time.",
+               "training", labels=("mixer",)),
+    MetricSpec("hvt_kda_chunks", "gauge",
+               "Chunks a sequence is walked in by the last delta-rule "
+               "layer traced (ops/delta_rule.py: the steps of its one "
+               "sequential scan). Set at trace time.", "training"),
     MetricSpec("hvt_optimizer_steps_total", "counter",
                "Optimizer steps this process's fit loops have handed to "
                "the device (counted in the loop, exporter on or off).",

@@ -533,3 +533,89 @@ def test_latent_moe_cell_step_fits_one_chip(topo, compiled_kernel):
     total = memory.argument_size_in_bytes + memory.temp_size_in_bytes
     print(f"latent cell step: arguments + temporaries {total / 1e9:.3f} GB")
     assert total < 15.0e9
+
+
+# --- the hybrid linear-attention / routed-expert model's scan and step -------
+# `solar-open2-250b.seq8k.1chip` (PR 35): 8 held heads of 128 over one
+# sequence of 8,192 in chunks of 64; one softmax and three KDA layers over
+# 320-way routing onto 8 held experts of 4,096 x 1,280.
+
+def test_delta_rule_compiles_for_v5e(topo):
+    """The chunked scan with its backward pass (autodiff under
+    `jax.checkpoint`) at the cell's shapes: plain XLA, no Mosaic call, one
+    while loop over the 128 chunks each way and one more for the backward
+    pass's recomputation, and what it needs beside its arguments stays
+    under 1.3 GB (1.16 when it was written)."""
+    from horovod_tpu.ops import delta_rule
+
+    one_chip = SingleDeviceSharding(topo.devices[0])
+
+    def sds(shape, dtype=jnp.bfloat16):
+        return SDS(shape, dtype, sharding=one_chip)
+
+    wide = (1, 8192, 8, 128)
+
+    def loss(q, k, v, g, beta):
+        with jax.named_scope("hvt.kda/scan"):
+            out = delta_rule.gated_delta_rule(q, k, v, g, beta, chunk=64)
+        return out.astype(jnp.float32).sum()
+
+    compiled = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
+        sds(wide), sds(wide), sds(wide), sds(wide, jnp.float32),
+        sds(wide[:3], jnp.float32)).compile()
+    assert kernel_calls(compiled) == []
+    assert compiled.as_text().count("hvt.kda/scan") > 100
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    print(f"delta rule forward + backward: temporaries {temp / 1e9:.3f} GB")
+    assert temp < 1.3e9
+
+
+def test_hybrid_moe_cell_step_fits_one_chip(topo, compiled_kernel):
+    """The whole training step of the cell at its own sizes (one period:
+    softmax, KDA, KDA, KDA; 840.9 M parameters, 8,192 tokens): it compiles,
+    the softmax layer runs the three flash kernels and every layer the six
+    grouped matmuls at the new shapes, the mixers' scopes are in the
+    program, the gauges say what was built, and state + temporaries stay
+    under 15.0 GB (14.89 when it was written; 15.02 before the mixers'
+    elementwise stretches were recomputed in the backward pass)."""
+    from horovod_tpu.models import hybrid_moe_lm as hybrid
+    from horovod_tpu.obs import prom
+    from horovod_tpu.ops import grouped_matmul as gm
+
+    mesh = mesh_lib.build_mesh(
+        mesh_lib.MeshSpec(data=1), devices=topo.devices[:1])
+    model = hybrid.HybridMoELM(
+        vocab_size=24576, d_model=4096,
+        layer_kinds=(hybrid.SOFTMAX,) + (hybrid.LINEAR,) * 3, head_dim=128,
+        linear_heads=64, softmax_heads=64, softmax_kv_heads=8,
+        n_held_heads=8, held_heads_start=0, conv_size=4, low_rank=128,
+        kda_chunk=64, n_routed=320, experts_per_token=8, expert_width=1280,
+        shared_width=1280, routed_scaling=1.0, n_held=8, held_start=0,
+        eps=1e-5, compute_dtype=jnp.bfloat16, fused_head_chunks=HEAD_CHUNKS,
+        sharding=ShardingConfig(mesh=mesh))
+    trainer = hvt.Trainer(
+        model, hvt.DistributedOptimizer(optax.adamw(1e-4)), loss="module",
+        mesh=mesh)
+    trainer._metric_names = (
+        "moe_held_rows_share", "moe_load_max_over_mean", "moe_overflow_rows")
+    compiled = compiled_step(trainer, seq=8192, batch=1)
+    assert kernel_names(compiled) == sorted(
+        FLASH_KERNELS + [gm.KERNEL] * 16 + [gm.KERNEL_DW] * 8)
+    hlo = compiled.as_text()
+    for scope in (hybrid.KDA_PROJ, hybrid.KDA_CONV, hybrid.KDA_SCAN,
+                  hybrid.KDA_OUT, hybrid.GQA_SCOPE):
+        assert "jvp(HybridMoELM)/Block_" in hlo and scope in hlo, scope
+        assert re.search(
+            rf"transpose\(jvp\(HybridMoELM\)\)/Block_\d/mixer/{scope}", hlo
+        ), scope
+    gauges = prom.render()
+    assert 'hvt_layer_kinds{kind="linear"} 3' in gauges
+    assert 'hvt_layer_kinds{kind="softmax"} 1' in gauges
+    assert 'hvt_held_heads{mixer="linear"} 8' in gauges
+    assert "hvt_kda_chunks 128" in gauges
+    memory = compiled.memory_analysis()
+    state = 840_871_320 * 12
+    assert state <= memory.argument_size_in_bytes <= state + 1_000_000
+    total = memory.argument_size_in_bytes + memory.temp_size_in_bytes
+    print(f"hybrid cell step: arguments + temporaries {total / 1e9:.3f} GB")
+    assert total < 15.0e9
