@@ -357,7 +357,17 @@ METRICS: dict[str, MetricSpec] = _decl([
     MetricSpec("hvt_kda_chunks", "gauge",
                "Chunks a sequence is walked in by the last delta-rule "
                "layer traced (ops/delta_rule.py: the steps of its one "
-               "sequential scan). Set at trace time.", "training"),
+               "sequential walk). Set at trace time.", "training"),
+    MetricSpec("hvt_kda_scan", "gauge",
+               "Which form of the delta-rule scan the last call traced took "
+               "(ops/delta_rule.py): 1 on it, 0 on the other. `pallas` "
+               "where the chip's tiling takes the shapes (a head's channels "
+               "multiples of 128, the chunk a multiple of the sub-chunk): "
+               "the state walks the chunks in VMEM inside the Mosaic calls "
+               "hvt_kda_fwd / hvt_kda_bwd; `xla` otherwise (one lax.scan, "
+               "backward by autodiff). Set by `gated_delta_rule` at trace "
+               "time: the choice is static per shape.",
+               "training", labels=("impl",)),
     MetricSpec("hvt_optimizer_steps_total", "counter",
                "Optimizer steps this process's fit loops have handed to "
                "the device (counted in the loop, exporter on or off).",

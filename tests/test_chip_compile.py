@@ -83,6 +83,22 @@ def kernel_names(compiled) -> list[str]:
     )
 
 
+def while_trip_counts(hlo: str) -> list:
+    """The constant each `while` of a compiled program counts up to (its
+    condition's ``compare(counter, constant), direction=LT``); None for a
+    condition of another form."""
+    trips = []
+    for cond in re.findall(r" while\(.*?condition=%([\w.\-]+)", hlo):
+        start = hlo.index(f"%{cond} (")
+        body = hlo[start:hlo.index("\n}", start)]
+        bound = re.search(r"compare\(%[\w.\-]+, %([\w.\-]+)\), direction=LT",
+                          body)
+        value = bound and re.search(
+            rf"%{re.escape(bound[1])} = s32\[\]\S* constant\((\d+)\)", body)
+        trips.append(int(value[1]) if value else None)
+    return trips
+
+
 FLASH_KERNELS = sorted([fa.KERNEL_DKV, fa.KERNEL_DQ, fa.KERNEL_FWD])
 
 
@@ -540,12 +556,16 @@ def test_latent_moe_cell_step_fits_one_chip(topo, compiled_kernel):
 # sequence of 8,192 in chunks of 64; one softmax and three KDA layers over
 # 320-way routing onto 8 held experts of 4,096 x 1,280.
 
-def test_delta_rule_compiles_for_v5e(topo):
-    """The chunked scan with its backward pass (autodiff under
-    `jax.checkpoint`) at the cell's shapes: plain XLA, no Mosaic call, one
-    while loop over the 128 chunks each way and one more for the backward
-    pass's recomputation, and what it needs beside its arguments stays
-    under 1.3 GB (1.16 when it was written)."""
+def test_delta_rule_compiles_for_v5e(topo, compiled_kernel):
+    """The scan with its hand-written backward at the cell's shapes: the
+    Mosaic calls are named `hvt_kda_fwd` (the forward pass: the pair
+    matrices, then the walk with the state in VMEM) and `hvt_kda_bwd` (the
+    backward pass: the pair matrices again, the walk that keeps the states,
+    the walk's transpose) and nothing else, they carry the caller's scope,
+    no `while` over the 128 chunks is left either way (what is left takes a
+    head at a time: the ratio sums inside the sub-chunks, which stay XLA's),
+    and what it needs beside its arguments stays under 1.3 GB (0.59 when the
+    kernels came; 1.16 as one `lax.scan` with autodiff)."""
     from horovod_tpu.ops import delta_rule
 
     one_chip = SingleDeviceSharding(topo.devices[0])
@@ -563,11 +583,47 @@ def test_delta_rule_compiles_for_v5e(topo):
     compiled = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
         sds(wide), sds(wide), sds(wide), sds(wide, jnp.float32),
         sds(wide[:3], jnp.float32)).compile()
-    assert kernel_calls(compiled) == []
-    assert compiled.as_text().count("hvt.kda/scan") > 100
+    assert kernel_names(compiled) == (
+        [delta_rule.KERNEL_BWD] * 3 + [delta_rule.KERNEL_FWD] * 2)
+    assert all("hvt.kda/scan" in line for line in kernel_calls(compiled))
+    trips = while_trip_counts(compiled.as_text())
+    assert None not in trips and 128 not in trips, trips
     temp = compiled.memory_analysis().temp_size_in_bytes
     print(f"delta rule forward + backward: temporaries {temp / 1e9:.3f} GB")
     assert temp < 1.3e9
+
+
+@pytest.mark.parametrize("t,chunk,dk,dtype", [
+    (200, 16, 128, jnp.bfloat16), (100, 32, 128, jnp.float32),
+    (200, 48, 128, jnp.bfloat16), (300, 128, 128, jnp.bfloat16),
+    (40, 64, 128, jnp.float32), (256, 64, 256, jnp.bfloat16)],
+    ids=["one_sub_chunk", "two_sub_chunks_100_over_32", "three_sub_chunks",
+         "chunk_128", "shorter_than_a_chunk", "keys_of_256"])
+def test_delta_rule_kernels_compile_at_other_shapes(topo, compiled_kernel, t,
+                                                    chunk, dk, dtype):
+    """Every shape `takes_kernels` admits has to pass Mosaic, not only the
+    cell's: other numbers of sub-chunks a chunk (with two, a sum of
+    comparisons folded into a comparison of booleans, which Mosaic refused
+    on the chip while the interpreter passed), a T the chunk does not
+    divide or that is shorter, wider keys, float32 inputs."""
+    from horovod_tpu.ops import delta_rule
+
+    assert delta_rule.takes_kernels(dk, 128, chunk)
+    one_chip = SingleDeviceSharding(topo.devices[0])
+
+    def sds(shape, dtype):
+        return SDS(shape, dtype, sharding=one_chip)
+
+    def loss(q, k, v, g, beta):
+        out = delta_rule.gated_delta_rule(q, k, v, g, beta, chunk=chunk)
+        return out.astype(jnp.float32).sum()
+
+    keys = (1, t, 2, dk)
+    compiled = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
+        sds(keys, dtype), sds(keys, dtype), sds((1, t, 2, 128), dtype),
+        sds(keys, jnp.float32), sds(keys[:3], jnp.float32)).compile()
+    assert kernel_names(compiled) == (
+        [delta_rule.KERNEL_BWD] * 3 + [delta_rule.KERNEL_FWD] * 2)
 
 
 def test_hybrid_moe_cell_step_fits_one_chip(topo, compiled_kernel):
@@ -580,6 +636,7 @@ def test_hybrid_moe_cell_step_fits_one_chip(topo, compiled_kernel):
     elementwise stretches were recomputed in the backward pass)."""
     from horovod_tpu.models import hybrid_moe_lm as hybrid
     from horovod_tpu.obs import prom
+    from horovod_tpu.ops import delta_rule
     from horovod_tpu.ops import grouped_matmul as gm
 
     mesh = mesh_lib.build_mesh(
@@ -600,7 +657,8 @@ def test_hybrid_moe_cell_step_fits_one_chip(topo, compiled_kernel):
         "moe_held_rows_share", "moe_load_max_over_mean", "moe_overflow_rows")
     compiled = compiled_step(trainer, seq=8192, batch=1)
     assert kernel_names(compiled) == sorted(
-        FLASH_KERNELS + [gm.KERNEL] * 16 + [gm.KERNEL_DW] * 8)
+        FLASH_KERNELS + [gm.KERNEL] * 16 + [gm.KERNEL_DW] * 8
+        + [delta_rule.KERNEL_FWD] * 6 + [delta_rule.KERNEL_BWD] * 9)
     hlo = compiled.as_text()
     for scope in (hybrid.KDA_PROJ, hybrid.KDA_CONV, hybrid.KDA_SCAN,
                   hybrid.KDA_OUT, hybrid.GQA_SCOPE):
@@ -613,6 +671,7 @@ def test_hybrid_moe_cell_step_fits_one_chip(topo, compiled_kernel):
     assert 'hvt_layer_kinds{kind="softmax"} 1' in gauges
     assert 'hvt_held_heads{mixer="linear"} 8' in gauges
     assert "hvt_kda_chunks 128" in gauges
+    assert 'hvt_kda_scan{impl="pallas"} 1' in gauges
     memory = compiled.memory_analysis()
     state = 840_871_320 * 12
     assert state <= memory.argument_size_in_bytes <= state + 1_000_000

@@ -2,7 +2,10 @@
 per-channel decay, against the recurrence it computes, run token by token:
 forward and all five gradients, chunks that do and do not divide the
 sequence, a sequence shorter than a chunk, decays that would overflow
-float32 if a ratio were ever formed as exp(-G), and what it refuses."""
+float32 if a ratio were ever formed as exp(-G), and what it refuses. Both
+forms: the XLA one at toy widths, and at heads of 128 the Mosaic kernels
+with their hand-written backward, in the Pallas interpreter, against the
+recurrence and against the XLA form's autodiff."""
 
 import jax
 import jax.numpy as jnp
@@ -51,7 +54,16 @@ def inputs(seed, t, *, b=2, h=3, dk=16, dv=8, plunge=False):
     return q, k, v, g, beta
 
 
-def both_with_gradients(args, chunk):
+WIDE = dict(b=1, h=2, dk=128, dv=128)   # what the kernels take
+WIDTHS = pytest.mark.parametrize("width", [{}, WIDE], ids=["toy", "wide"])
+
+
+def xla_form(chunk):
+    return lambda *args: delta_rule._xla_form(*args, chunk)
+
+
+def both_with_gradients(args, chunk, oracle=None):
+    oracle = oracle or token_by_token
     weight = jax.random.normal(jax.random.PRNGKey(9), args[2].shape)
 
     def run(fn):
@@ -59,22 +71,33 @@ def both_with_gradients(args, chunk):
             lambda *a: (fn(*a) * weight).sum(), argnums=(0, 1, 2, 3, 4),
             has_aux=False)(*args)
 
-    want = token_by_token(*args)
+    want = oracle(*args)
     got = delta_rule.gated_delta_rule(*args, chunk=chunk)
     (_, want_grads), (_, got_grads) = (
-        run(token_by_token),
+        run(oracle),
         run(lambda *a: delta_rule.gated_delta_rule(*a, chunk=chunk)))
     return want, got, want_grads, got_grads
 
 
-@pytest.mark.parametrize("t,chunk", [
-    (64, 64), (64, 16), (64, 32), (64, 48), (64, 8), (128, 64), (100, 32),
-    (40, 64)],
+@pytest.mark.parametrize("t,chunk,width,oracle", [
+    (64, 64, {}, None), (64, 16, {}, None), (64, 32, {}, None),
+    (64, 48, {}, None), (64, 8, {}, None), (128, 64, {}, None),
+    (100, 32, {}, None), (40, 64, {}, None),
+    (128, 64, WIDE, None), (64, 16, WIDE, None), (112, 48, WIDE, None),
+    (100, 32, WIDE, None), (40, 64, WIDE, None),
+    (192, 64, WIDE, xla_form), (100, 32, WIDE, xla_form),
+    (40, 64, WIDE, xla_form)],
     ids=["one_chunk", "sub_chunk", "halves", "48_does_not_divide",
-         "under_a_sub_chunk", "two_chunks", "100_over_32", "shorter_than_one"])
-def test_chunked_form_is_the_recurrence(t, chunk):
+         "under_a_sub_chunk", "two_chunks", "100_over_32", "shorter_than_one",
+         "kernels_two_chunks", "kernels_sub_chunk", "kernels_48_does_not_divide",
+         "kernels_100_over_32", "kernels_shorter_than_one",
+         "kernels_as_xla_three_chunks", "kernels_as_xla_100_over_32",
+         "kernels_as_xla_shorter_than_one"])
+def test_chunked_form_is_the_recurrence(t, chunk, width, oracle):
+    assert delta_rule.takes_kernels(
+        width.get("dk", 16), width.get("dv", 8), chunk) == bool(width)
     want, got, want_grads, got_grads = both_with_gradients(
-        inputs(t + chunk, t), chunk)
+        inputs(t + chunk, t, **width), chunk, oracle and oracle(chunk))
     np.testing.assert_allclose(got, want, atol=2e-6, rtol=2e-5)
     for name, a, b in zip("q k v g beta".split(), got_grads, want_grads):
         np.testing.assert_allclose(
@@ -82,11 +105,12 @@ def test_chunked_form_is_the_recurrence(t, chunk):
             err_msg=name)
 
 
+@WIDTHS
 @pytest.mark.parametrize("t", [64, 128])
-def test_fast_decays_are_finite_and_right(t):
+def test_fast_decays_are_finite_and_right(t, width):
     """The running log-decay passes -1,900 inside a chunk: a ratio formed
     as exp(G_t) * exp(-G_s) would be 0 * inf."""
-    args = inputs(t, t, plunge=True)
+    args = inputs(t, t, plunge=True, **width)
     running = jnp.cumsum(args[3][:, :64], axis=1)
     assert float(running.min()) < -1900
     with np.errstate(over="ignore"):
@@ -101,12 +125,19 @@ def test_fast_decays_are_finite_and_right(t):
             err_msg=name)
 
 
-def test_compute_dtype_in_float32_state_inside():
+@WIDTHS
+def test_compute_dtype_in_float32_state_inside(width):
     """bfloat16 q, k, v come back as bfloat16, near the float32 result of
     the same rounded inputs: the sums, the decays, the solve and the state
-    are float32 whatever comes in."""
-    q, k, v, g, beta = inputs(3, 128)
+    are float32 whatever comes in; the gradients come back in the inputs'
+    dtypes."""
+    q, k, v, g, beta = inputs(3, 128, **width)
     low = tuple(a.astype(jnp.bfloat16) for a in (q, k, v))
+    grads = jax.grad(
+        lambda *a: delta_rule.gated_delta_rule(*a, chunk=64).astype(
+            jnp.float32).sum(), argnums=(0, 1, 2, 3, 4))(*low, g, beta)
+    assert [a.dtype for a in grads] == [jnp.bfloat16] * 3 + [jnp.float32] * 2
+    assert all(np.isfinite(a.astype(jnp.float32)).all() for a in grads)
     got = delta_rule.gated_delta_rule(*low, g, beta, chunk=64)
     assert got.dtype == jnp.bfloat16
     want = token_by_token(*(a.astype(jnp.float32) for a in low), g, beta)
@@ -115,10 +146,11 @@ def test_compute_dtype_in_float32_state_inside():
         rtol=0.02)
 
 
-def test_one_traced_copy_for_a_models_identical_calls():
+@WIDTHS
+def test_one_traced_copy_for_a_models_identical_calls(width):
     """The implementation is jitted, so three layers' calls at one shape
     are one `pjit` of one jaxpr in the program that holds them."""
-    args = inputs(0, 64)
+    args = inputs(0, 64, **width)
 
     def three(*a):
         return sum(delta_rule.gated_delta_rule(*a, chunk=32) for _ in range(3))
@@ -127,6 +159,47 @@ def test_one_traced_copy_for_a_models_identical_calls():
              if eqn.primitive.name in ("pjit", "jit")]
     assert len(calls) == 3
     assert len({id(eqn.params["jaxpr"]) for eqn in calls}) == 1
+
+
+def all_eqns(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs it calls."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from all_eqns(sub)
+
+
+@pytest.mark.parametrize("dk,dv,chunk,impl", [
+    (128, 128, 64, "pallas"), (256, 128, 16, "pallas"), (16, 8, 64, "xla"),
+    (128, 64, 64, "xla"), (64, 128, 64, "xla"), (128, 128, 8, "xla")],
+    ids=["the_cells", "wider_keys", "toy", "narrow_values", "narrow_keys",
+         "chunk_under_a_sub_chunk"])
+def test_which_form_a_shape_takes(dk, dv, chunk, impl):
+    """The kernels where a head's channels are whole lanes and the chunk
+    whole sub-chunks, the XLA form elsewhere: told from the shapes alone,
+    left in the gauge `hvt_kda_scan{impl}`, and in the program: Mosaic
+    calls under the two names and no loop over the chunks, or one scan."""
+    from horovod_tpu.obs import prom
+
+    args = inputs(0, 3 * chunk, b=1, h=1, dk=dk, dv=dv)
+    jaxpr = jax.make_jaxpr(jax.grad(
+        lambda *a: delta_rule.gated_delta_rule(*a, chunk=chunk).sum(),
+        argnums=(0, 1, 2, 3, 4)))(*args)
+    other = {"pallas": "xla", "xla": "pallas"}[impl]
+    gauges = prom.render()
+    assert f'hvt_kda_scan{{impl="{impl}"}} 1' in gauges
+    assert f'hvt_kda_scan{{impl="{other}"}} 0' in gauges
+    eqns = list(all_eqns(jaxpr.jaxpr))
+    names = sorted(str(eqn.params["name"]) for eqn in eqns
+                   if eqn.primitive.name == "pallas_call")
+    over_chunks = [eqn for eqn in eqns if eqn.primitive.name == "scan"
+                   and eqn.params["length"] == 3]   # one head: its own are 1
+    if impl == "pallas":
+        assert names == [delta_rule.KERNEL_BWD] * 3 + [
+            delta_rule.KERNEL_FWD] * 2   # a pass: the pairs, then the walk(s)
+        assert over_chunks == []
+    else:
+        assert names == [] and len(over_chunks) >= 2   # each way
 
 
 def test_chunks_counted():
