@@ -50,6 +50,7 @@ __all__ = [
     "CollectiveOp",
     "ProgramAuditError",
     "ProgramExpectation",
+    "ReductionHost",
     "ScheduledReduction",
     "assert_program",
     "asynchronous_share",
@@ -62,6 +63,7 @@ __all__ = [
     "payload_alltoalls",
     "reduction_schedule",
     "scatter_reductions",
+    "scope_path",
     "while_bodies",
     "while_count",
     "wire_dtype",
@@ -367,45 +369,123 @@ def while_bodies(text: str, scope: str = "") -> list[str]:
 
 
 @dataclasses.dataclass(frozen=True)
-class ScheduledReduction:
-    """One cross-chip reduction of a compiled program and how it runs.
-    ``dtype`` and ``shape`` are its first result's (a combined all-reduce
-    is tuple-shaped), ``nbytes`` every result's."""
+class ReductionHost:
+    """A compute fusion that carries steps of an asynchronous collective:
+    ``name`` is the instruction (``fusion.668``), ``host_scope`` what the
+    compute it calls is (see `reduction_schedule`)."""
 
-    kind: str             # "all-reduce" | "reduce-scatter"
+    name: str
+    host_scope: str
+
+
+@dataclasses.dataclass(frozen=True)
+class ScheduledReduction:
+    """One cross-chip collective of a compiled program, how it runs, whose
+    it is, and which instructions carry it. ``dtype`` and ``shape`` are its
+    first result's (a combined all-reduce is tuple-shaped), ``nbytes``
+    every result's. ``scope`` is the scope path of the collective's own
+    ``op_name``. ``start`` is the instruction that issues it and ``done``
+    the one during which the chip waits for it: the two fusions of an
+    asynchronous collective (``async-collective-start.N`` / ``-done.N``),
+    a plain ``-start`` / ``-done`` pair, or, twice, the one instruction of
+    a synchronous collective. ``hosts`` are the compute fusions between
+    the two that carry its steps. The names are what a profiler's device
+    events are called, so a trace is joined to this table by name."""
+
+    kind: str             # "all-reduce" | "reduce-scatter" | "all-gather"
     dtype: str
     shape: tuple
     nbytes: int
     asynchronous: bool
+    channel: int | None = None
+    scope: str = ""
+    start: str = ""
+    done: str = ""
+    hosts: tuple = ()     # of ReductionHost
+
+    @property
+    def reduces(self) -> bool:
+        """Whether it sums (an all-gather moves bytes and adds nothing)."""
+        return self.kind != "all-gather"
 
 
 _HLO_REDUCTION_RE = re.compile(
-    r"=\s*(\([^=]*?\)|\S+)\s+(all-reduce|reduce-scatter)(-start)?\("
+    r"=\s*(\([^=]*?\)|\S+)\s+(all-reduce|reduce-scatter|all-gather)"
+    r"(-start)?\("
 )
+_HLO_DONE_RE = re.compile(
+    r"(?:all-reduce|reduce-scatter|all-gather)-done\((?:.*?[ (])?%?([\w.\-]+)\)"
+)
+_HLO_INSTRUCTION_RE = re.compile(r"^\s*(?:ROOT )?%?([\w.\-]+) = ")
+_HLO_CALLS_RE = re.compile(r"\bcalls=%?([\w.\-]+)")
 _HLO_CHANNEL_RE = re.compile(r"channel_id=(\d+)")
+_HLO_OP_NAME_RE = re.compile(r'op_name="([^"]*)"')
 # XLA:TPU runs an asynchronous collective as fusions of the entry
 # computation: `async-collective-start`, steps that ride inside compute
 # fusions (computations named `async_collective_fusion.N`), and
 # `async-collective-done`. Each of their computations restates the
 # collective under its one channel id.
 _ASYNC_FUSION = "async_collective_fusion"
-_HLO_ASYNC_EDGE_RE = re.compile(
-    r"%async-collective-(?:start|done)[\w.\-]* = [^\n]*?calls=%?([\w.\-]+)"
-)
+_ASYNC_START, _ASYNC_DONE = "async-collective-start", "async-collective-done"
+# What the program itself names (`jax.named_scope("hvt.optimizer")`, ...).
+PROGRAM_SCOPE = "hvt."
+
+
+def scope_path(op_name: str) -> str:
+    """The named scopes of an instruction's ``op_name``: what lies between
+    the leading ``jit(<fn>)`` and the trailing primitive.
+    ``jit(train_step)/hvt.optimizer/add`` -> ``hvt.optimizer``;
+    ``jit(train_step)/add`` -> nothing."""
+    parts = op_name.split("/")
+    if re.fullmatch(r"p?jit\(.*\)", parts[0]):
+        parts = parts[1:]
+    return "/".join(parts[:-1])
+
+
+def _host_scope(body: str) -> str:
+    """What the compute of a host fusion is, from the ``op_name``s of its
+    computation's instructions other than the collective: the scope path
+    most of them share, among those that hold a program scope where any
+    does (an AdamW pass also holds converts that name nothing), and never
+    simply the first instruction's (often a convert or a broadcast under
+    no scope)."""
+    paths = [
+        scope_path(op_name)
+        for line in body.splitlines() if not _HLO_REDUCTION_RE.search(line)
+        for op_name in _HLO_OP_NAME_RE.findall(line)
+    ]
+    paths = [p for p in paths if p]
+    named = [p for p in paths if PROGRAM_SCOPE in p]
+    counts: dict = {}
+    for path in named or paths:
+        counts[path] = counts.get(path, 0) + 1
+    return max(counts, key=counts.get) if counts else ""
 
 
 def reduction_schedule(text: str) -> list[ScheduledReduction]:
-    """Compiled HLO: every non-scalar all-reduce and reduce-scatter, once,
-    told apart by how the compiler scheduled it. Asynchronous: a
+    """Compiled HLO: every non-scalar all-reduce, reduce-scatter and
+    all-gather, once, told apart by how the compiler scheduled it, with
+    the instructions that carry it (`ScheduledReduction`). Asynchronous: a
     ``-start`` / ``-done`` pair, or a collective inside the fusions of an
     asynchronous collective (the scheduler may then put compute between
     its start and its done). Synchronous: the plain instruction, during
     which the chip's compute waits. (Scalars are the metric means, as in
-    `gradient_reductions`.)"""
-    edges = set(_HLO_ASYNC_EDGE_RE.findall(text))
-    found, in_flight = [], set()
-    for name, body in _HLO_COMPUTATION_RE.findall(text):
-        fused = name.startswith(_ASYNC_FUSION) or name in edges
+    `gradient_reductions`.) A text without metadata gives empty scopes."""
+    computations = _HLO_COMPUTATION_RE.findall(text)
+    callers = {}  # computation -> the fusion instruction that calls it
+    dones = {}    # `-start` instruction -> its `-done`
+    for _, body in computations:
+        for line in body.splitlines():
+            name = _HLO_INSTRUCTION_RE.match(line)
+            if name and (calls := _HLO_CALLS_RE.search(line)):
+                callers[calls.group(1)] = name.group(1)
+            if name and (done := _HLO_DONE_RE.search(line)):
+                dones[done.group(1)] = name.group(1)
+    rows, by_channel = [], {}
+    for computation, body in computations:
+        caller = callers.get(computation, "")
+        fused = computation.startswith(_ASYNC_FUSION) or caller.startswith(
+            (_ASYNC_START, _ASYNC_DONE))
         for line in body.splitlines():
             m = _HLO_REDUCTION_RE.search(line)
             if not m:
@@ -415,25 +495,49 @@ def reduction_schedule(text: str) -> list[ScheduledReduction]:
                  tuple(int(d) for d in dims.split(",") if d))
                 for dtype, dims in _HLO_TYPE_RE.findall(m.group(1))
             ]
+            if m.group(2) == "all-gather" and m.group(3):
+                # `all-gather-start` gives (operands, results).
+                results = results[len(results) // 2:]
             if not any(shape for _, shape in results):
                 continue
-            if fused:
-                channel = _HLO_CHANNEL_RE.search(line)
-                if channel and channel.group(1) in in_flight:
-                    continue  # the same sum, restated by a later fusion
-                if channel:
-                    in_flight.add(channel.group(1))
-            found.append(ScheduledReduction(
-                m.group(2), *results[0],
-                nbytes=sum(_nbytes(*result) for result in results),
-                asynchronous=fused or bool(m.group(3)),
-            ))
-    return found
+            channel = _HLO_CHANNEL_RE.search(line)
+            channel = int(channel.group(1)) if channel else None
+            op_name = _HLO_OP_NAME_RE.search(line)
+            row = by_channel.get(channel) if fused else None
+            if row is None:
+                row = {
+                    "kind": m.group(2), "dtype": results[0][0],
+                    "shape": results[0][1],
+                    "nbytes": sum(_nbytes(*result) for result in results),
+                    "asynchronous": fused or bool(m.group(3)),
+                    "channel": channel, "scope": "", "start": "", "done": "",
+                    "hosts": [],
+                }
+                rows.append(row)
+                if fused and channel is not None:
+                    by_channel[channel] = row
+            if op_name and not row["scope"]:
+                row["scope"] = scope_path(op_name.group(1))
+            if not fused:
+                own = _HLO_INSTRUCTION_RE.match(line).group(1)
+                row["start"] = own
+                row["done"] = dones.get(own, own) if m.group(3) else own
+            elif caller.startswith(_ASYNC_START):
+                row["start"] = caller
+            elif caller.startswith(_ASYNC_DONE):
+                row["done"] = caller
+            elif caller:
+                row["hosts"].append(ReductionHost(caller, _host_scope(body)))
+    return [
+        ScheduledReduction(**{**row, "hosts": tuple(row["hosts"])})
+        for row in rows
+    ]
 
 
 def asynchronous_share(reductions) -> float | None:
     """Bytes of the asynchronous reductions over the bytes of all of
     them; None for a program that reduces nothing across chips."""
+    reductions = [r for r in reductions if r.reduces]
     total = sum(r.nbytes for r in reductions)
     if not total:
         return None

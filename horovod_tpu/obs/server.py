@@ -79,6 +79,10 @@ class _ProfileTrigger:
             time.sleep(seconds)
             try:
                 jax.profiler.stop_trace()
+                # The key to the dump's `fusion.N` events, beside it.
+                from horovod_tpu import trace
+
+                trace.write_step_reductions(out_dir)
             finally:
                 with self._lock:
                     self._active = None

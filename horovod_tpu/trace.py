@@ -21,10 +21,13 @@ count against the chip's peak; "match or beat" needs this denominator."""
 from __future__ import annotations
 
 import contextlib
+import dataclasses
 import json
 import os
 import threading
 import time
+import warnings
+import weakref
 
 import jax
 
@@ -131,10 +134,14 @@ def profile_dir() -> str | None:
 @contextlib.contextmanager
 def maybe_trace(log_dir: str | None):
     """`trace(...)` when a directory is given, no-op otherwise — callers can
-    wrap hot loops unconditionally with `maybe_trace(profile_dir())`."""
+    wrap hot loops unconditionally with `maybe_trace(profile_dir())`. A
+    session the program closes itself leaves the key to its device events
+    beside the dump (`write_step_reductions`)."""
     if log_dir:
         with trace(log_dir):
             yield
+        if runtime.is_primary():
+            write_step_reductions(log_dir)
     else:
         yield
 
@@ -150,6 +157,66 @@ def trace(log_dir: str, primary_only: bool = True):
     finally:
         if active:
             jax.profiler.stop_trace()
+
+
+# --- the step program's cross-chip sums --------------------------------------
+#
+# On several chips the compiled step's gradient sums are `fusion` events in
+# a trace (`async-collective-start.N`, `fusion.N`, `async-collective-done.N`)
+# whose own HLO lines hold no collective and no scope: which sum, of which
+# gradient, and what a host fusion computes beside it are in the compiled
+# module's called computations, which only the program has. So the program
+# says it: `Trainer.step_reductions` builds the table
+# (`analysis.hlo_audit.reduction_schedule`) from the text of the step it
+# ran, and a reader joins it to the device's events by instruction name.
+
+STEP_REDUCTIONS_FILE = "hvt_step_reductions.json"
+# The trainer whose fit loop last remembered a step program; weak, so that
+# asking for a table keeps no trainer (and no device state) alive.
+_newest_fit = lambda: None  # noqa: E731 — a dead reference until a fit
+
+
+def note_step_program(trainer) -> None:
+    """`Trainer.remember_step_program`'s note: this process's newest fit."""
+    global _newest_fit
+    _newest_fit = weakref.ref(trainer)
+
+
+def step_reductions() -> list[dict] | None:
+    """The table of the cross-chip sums of the step program of this
+    process's newest streamed fit, a row a collective: ``kind``, ``dtype``,
+    ``shape``, ``nbytes``, ``asynchronous``, ``channel``, ``scope`` (whose
+    gradient: the scope path of the collective's own ``op_name``), and the
+    compiled step's instructions that carry it, which are what a
+    profiler's device events are named by: ``start``, ``done`` and
+    ``hosts`` (``name``, ``host_scope``: the compute fusions that carry
+    its steps, and what they compute). ``[]`` on one chip, None where no
+    fit has run (or its trainer is gone). Lowers and compiles the
+    remembered program on first demand (`Trainer.step_reductions`)."""
+    trainer = _newest_fit()
+    rows = trainer.step_reductions() if trainer is not None else None
+    if rows is None:
+        return None
+    return [dataclasses.asdict(row) for row in rows]
+
+
+def write_step_reductions(log_dir: str) -> str | None:
+    """Write `step_reductions()` as ``hvt_step_reductions.json`` into
+    ``log_dir``, beside a profiler dump; None where there is no table. A
+    failure costs the file, never the training run, and is said."""
+    try:
+        rows = step_reductions()
+        if rows is None:
+            return None
+        from horovod_tpu import checkpoint
+
+        os.makedirs(log_dir, exist_ok=True)
+        path = os.path.join(log_dir, STEP_REDUCTIONS_FILE)
+        checkpoint._atomic_write(path, json.dumps(rows).encode())
+        return path
+    except Exception as e:
+        warnings.warn(f"{STEP_REDUCTIONS_FILE} not written: {e!r}")
+        return None
 
 
 # --- spans: one API, two sinks ----------------------------------------------

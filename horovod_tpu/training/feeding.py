@@ -472,7 +472,7 @@ def _maybe_step_sampler(trainer):
     exporter is on (`HVT_METRICS_PORT` — obs/server.py): None otherwise,
     so the default fit path carries ZERO instrumentation cost. The
     examples-per-step figure is inferred from the first chunk's shapes
-    (`capture_step_args` time)."""
+    (when the trainer remembers its step program)."""
     from horovod_tpu.obs import server as obs_server
 
     if obs_server.ensure_trainer_exporter() is None:
@@ -565,6 +565,7 @@ def fit_epochs(trainer, it, pending, zero_acc, epochs, initial_epoch, steps_per_
         place = lambda b: trainer._shard_chunk(b, 2 if accum > 1 else 1)  # noqa: E731
     prefetcher = DevicePrefetcher(host_chunks(), place, depth=depth)
     sampler = _maybe_step_sampler(trainer)
+    remembered = False
     try:
         for epoch in range(initial_epoch, epochs):
             if trainer.stop_training:
@@ -594,26 +595,25 @@ def fit_epochs(trainer, it, pending, zero_acc, epochs, initial_epoch, steps_per_
                 obs.counter("hvt_input_wait_seconds_total", waited)
                 if sampler is not None:
                     sampler.add_input_wait(waited)
-                    if sampler._step_shapes is None:
-                        # First chunk: derive examples per OPTIMIZER step
-                        # from the placed shapes ([spe?, K?, G, ...]) and
-                        # snapshot the step args for the cost-model MFU.
+                if not remembered:
+                    # First chunk of the fit, before it is donated: the
+                    # trainer remembers the program it is about to run
+                    # (one tree.map; nothing is lowered until somebody
+                    # asks). k, not spe: a resumed epoch's FIRST chunk
+                    # can be a remainder chunk with fewer steps, and the
+                    # program's FLOPs must divide by the steps of the
+                    # program actually remembered or hvt_mfu mis-scales.
+                    remembered = True
+                    trainer.remember_step_program(
+                        run, (trainer.state, chunk, scale, metric_acc), k)
+                    if sampler is not None:
+                        # Examples per OPTIMIZER step, from the placed
+                        # shapes ([spe?, K?, G, ...]).
                         leaf = jax.tree_util.tree_leaves(chunk[0])[0]
-                        lead = 1 + (1 if spe > 1 else 0) + (
-                            1 if accum > 1 else 0
-                        )
+                        lead = 1 + (spe > 1) + (accum > 1)
                         rows = int(np.prod(leaf.shape[:lead]))
                         sampler.examples_per_step = rows // (
                             leaf.shape[0] if spe > 1 else 1
-                        )
-                        # k, not spe: the FIRST chunk of a resumed epoch
-                        # can be a remainder chunk with fewer steps, and
-                        # the captured executable's FLOPs must divide by
-                        # the step count of the program actually
-                        # captured or hvt_mfu mis-scales for the run.
-                        sampler.capture_step_args(
-                            run, (trainer.state, chunk, scale, metric_acc),
-                            k,
                         )
                 t_run = time.perf_counter() if sampler is not None else 0.0
                 # The HOST's call into the step program, not the step:
@@ -696,6 +696,9 @@ def fit_device_cached(trainer, x, y, batch_size, epochs, initial_epoch, steps_pe
     from horovod_tpu.analysis import registry
 
     chunk = registry.get_int("HVT_EPOCH_CHUNK_STEPS") or 0
+    # An epoch program is not a step program: what an earlier streamed fit
+    # remembered (`Trainer.remember_step_program`) is not this fit's.
+    trainer._step_program = None
     sampler = _maybe_step_sampler(trainer)
     if sampler is not None:
         # Device-cached feeding has no host input leg by construction;

@@ -21,7 +21,7 @@ import functools
 import os
 import time
 import warnings
-from typing import Any, Callable, Sequence
+from typing import Any, Callable, NamedTuple, Sequence
 
 import flax.struct
 import jax
@@ -1186,6 +1186,76 @@ class Trainer:
         `training.feeding.run_predict`."""
         return feeding.run_predict(self, x, batch_size)
 
+    # --- the step program, and what it sums across chips -------------------
+    # (Kept below every function a step is traced from: a line that moves
+    # there moves inside the Mosaic kernels' serialized source locations,
+    # and the step then misses its parent's compile-cache entry.)
+
+    # The step program of the newest streamed fit and, once somebody has
+    # asked, the table of its cross-chip sums (class defaults: no fit yet).
+    _step_program: StepProgram | None = None
+    _step_reductions: list | None = None
+
+    def remember_step_program(self, run, args, steps: int) -> None:
+        """What the streamed fit loop runs: the jitted step callable, its
+        arguments as shapes with shardings (taken before the batch is
+        donated) and the optimizer steps of one call. One ``tree.map``,
+        once a fit; nothing is lowered, compiled or parsed until somebody
+        asks (`step_reductions`, the exporter's `StepPhaseSampler`)."""
+        from horovod_tpu import trace as trace_lib
+
+        def struct(a):
+            if isinstance(a, jax.Array):
+                # An uncommitted array (the update-scale scalar) sits on
+                # one device until jit places it: its shape goes without
+                # a sharding, as the call itself lowers it, so that the
+                # program lowered from these shapes IS the one that ran
+                # (same module, same compile-cache entry).
+                return jax.ShapeDtypeStruct(
+                    a.shape, a.dtype,
+                    sharding=a.sharding if a.committed else None)
+            return a
+
+        self._step_program = StepProgram(
+            run, jax.tree.map(struct, args), max(1, int(steps)))
+        self._step_reductions = None
+        trace_lib.note_step_program(self)
+
+    def step_reductions(self, compiled=None) -> list | None:
+        """The cross-chip sums of the remembered step program
+        (`hlo_audit.reduction_schedule` rows: sum, leaf scope, bytes, and
+        the start / host / done instructions a profiler's device events
+        are named by); ``[]`` on one chip, None before a streamed fit.
+        Built on first demand and kept: the remembered jit is lowered for
+        the remembered shapes and compiled with the options the fit used
+        (it carries them). That is the program that ran, instruction
+        names and all, and no second compile: JAX hands back the lowering
+        and the executable it holds for the call (else the persistent
+        compile cache's entry). ``compiled`` hands in that executable
+        where the caller has it already."""
+        if self._step_program is None:
+            return None
+        if self._step_reductions is None:
+            if self.mesh.devices.size == 1:
+                self._step_reductions = []
+            else:
+                from horovod_tpu.analysis import hlo_audit
+
+                program = self._step_program
+                if compiled is None:
+                    compiled = program.run.lower(*program.shapes).compile()
+                self._step_reductions = hlo_audit.reduction_schedule(
+                    compiled.as_text())
+        return self._step_reductions
+
+class StepProgram(NamedTuple):
+    """The program a streamed fit runs, as `Trainer.remember_step_program`
+    keeps it: enough to lower and compile it again, nothing of its data."""
+
+    run: Callable         # the jitted step (it carries its compile options)
+    shapes: tuple         # its arguments as ShapeDtypeStructs with shardings
+    steps: int            # optimizer steps of one call
+
 
 class StepPhaseSampler:
     """Live per-step phase timing for the trainer-side metrics exporter
@@ -1244,8 +1314,6 @@ class StepPhaseSampler:
         self._input_s = 0.0        # host input-wait inside the window
         self._step_call_s = 0.0    # host time inside step calls (window)
         self._window_t0 = None     # None until the first drained edge
-        self._step_shapes = None   # ShapeDtypeStructs of the step args
-        self._steps_per_exec = 1
         self._comm = None          # (jitted fn, zero grads) once warmed
         self._comm_s = 0.0         # cached isolated-comm seconds
         self._flops = None         # FLOPs per optimizer step (cost model)
@@ -1254,32 +1322,6 @@ class StepPhaseSampler:
         self.skew_probe = SkewProbe.maybe()
 
     # -- hooks the feeding loops call ---------------------------------------
-
-    def capture_step_args(self, run, args, steps_per_exec: int) -> None:
-        """Record the jitted step callable + its arg SHAPES (taken before
-        the batch is donated) so the first sample can cost-analyze the
-        executable. Cheap (one tree.map); called once per fit."""
-        if self._step_shapes is not None:
-            return
-        mesh_devices = set(self.trainer.mesh.devices.flat)
-
-        def struct(a):
-            if isinstance(a, jax.Array):
-                sh = a.sharding
-                if set(sh.device_set) != mesh_devices:
-                    # Uncommitted scalars (the update-scale arg) sit on
-                    # one device until jit broadcasts them; lowering
-                    # needs the POST-commit placement — replicated over
-                    # the step's mesh — or the shapes are incompatible.
-                    sh = jax.sharding.NamedSharding(
-                        self.trainer.mesh, jax.sharding.PartitionSpec()
-                    )
-                return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sh)
-            return a
-
-        self._run = run
-        self._step_shapes = jax.tree.map(struct, args)
-        self._steps_per_exec = max(1, int(steps_per_exec))
 
     def add_input_wait(self, seconds: float) -> None:
         self._input_s += seconds
@@ -1378,9 +1420,10 @@ class StepPhaseSampler:
                 f"StepPhaseSampler: isolated reduction probe failed, "
                 f"hvt_step_phase_ms{{comm}} will read 0: {e!r}"
             )
-        if self._step_shapes is not None:
+        program = self.trainer._step_program
+        if program is not None:
             try:
-                compiled = self._run.lower(*self._step_shapes).compile()
+                compiled = program.run.lower(*program.shapes).compile()
             except Exception as e:
                 warnings.warn(
                     f"StepPhaseSampler: cost-model compile of the step "
@@ -1389,8 +1432,9 @@ class StepPhaseSampler:
             else:
                 flops = trace_lib.compiled_cost_flops(compiled)
                 if flops:
-                    self._flops = flops / self._steps_per_exec
-                _publish_reduction_schedule(compiled.as_text())
+                    self._flops = flops / program.steps
+                _publish_reduction_schedule(
+                    self.trainer.step_reductions(compiled))
 
     def _timed_comm(self) -> float:
         if self._comm is None:
@@ -1407,14 +1451,14 @@ class StepPhaseSampler:
         return self._comm_s
 
 
-def _publish_reduction_schedule(compiled_text: str) -> None:
-    """How the compiler scheduled a step program's cross-chip sums, on
-    `/metrics`: read once from the text a compile already has, so a step
-    pays nothing for it."""
+def _publish_reduction_schedule(reductions) -> None:
+    """How the compiler scheduled a step program's cross-chip sums
+    (`Trainer.step_reductions`), on `/metrics`: read once from the text a
+    compile already has, so a step pays nothing for it."""
     from horovod_tpu import obs
     from horovod_tpu.analysis import hlo_audit
 
-    reductions = hlo_audit.reduction_schedule(compiled_text)
+    reductions = [r for r in reductions if r.reduces]
     share = hlo_audit.asynchronous_share(reductions)
     if share is None:  # one chip, or nothing summed across chips
         return

@@ -387,6 +387,41 @@ def test_cell_step_sums_its_gradients_asynchronously(cell_compiled):
     assert 411e6 < wire["f32"] < 413e6
 
 
+def test_cell_step_table_names_every_carrier_of_its_sums(cell_compiled):
+    """The table a reader joins to the chip's trace by instruction name
+    (PR 37): every start, done and host fusion of the entry computation is
+    in it exactly once, every sum says whose it is and every host what it
+    computes, and most hosts are AdamW passes (74 of 116 when this was
+    written; the counts are XLA's and are not pinned)."""
+    hlo = cell_compiled.as_text()
+    rows = hlo_audit.reduction_schedule(hlo)
+    entry = hlo[hlo.index("\nENTRY "):]
+
+    def instructions(pattern):
+        return sorted(re.findall(
+            rf"^\s*(?:ROOT )?%({pattern}) = ", entry, re.M))
+
+    hosts = [host for r in rows for host in r.hosts]
+    carried = [r for r in rows if r.hosts or r.start != r.done]
+    assert instructions(r"async-collective-start[\w.\-]*") == sorted(
+        r.start for r in carried)
+    assert instructions(r"async-collective-done[\w.\-]*") == sorted(
+        r.done for r in carried)
+    assert instructions(
+        r"[\w.\-]+(?= = [^\n]*calls=%async_collective_fusion)"
+    ) == sorted(host.name for host in hosts)
+    assert len(carried) > 40 and len(hosts) > len(carried)
+    assert all(r.asynchronous for r in carried)
+    # 1.41 GB of bfloat16 and 412 MB of float32, each sum once.
+    assert 1.8255e9 < sum(r.nbytes for r in rows) < 1.8260e9
+    assert all(r.scope and r.channel is not None for r in rows)
+    assert all(host.host_scope for host in hosts)
+    adamw = [h for h in hosts if trainer_lib.OPTIMIZER_SCOPE in h.host_scope]
+    assert len(adamw) > len(hosts) / 2
+    head_dw, = (r for r in rows if r.shape == (D_MODEL, CELL_VOCAB))
+    assert fused_ce.SCOPE in head_dw.scope
+
+
 # `temp_size_in_bytes` of this step at the parent of PR 32 (the row scan's
 # float32 [D, V] accumulator): `step_temp_gb` on the cell's ledger lines.
 CELL_TEMP_BYTES_PR31 = 3_857_022_976

@@ -41,7 +41,7 @@ DOCUMENTS = [
 # Written by a run, never committed: the documents name them as outputs.
 RUNTIME_FILES = {
     "signature.json", "index.json", "tune.json", "trace.json",
-    "tokenizer.json", ".meta.json",
+    "tokenizer.json", ".meta.json", "hvt_step_reductions.json",
 }
 # The source repository's own files, which SURVEY.md cites by line.
 SOURCE_REPO_FILES = {
@@ -51,7 +51,8 @@ SOURCE_REPO_FILES = {
 _NOT_THE_TREE = {".git", "build", "chiprun_out", "__pycache__",
                  ".jax_cache", ".chipbench_out", ".pytest_cache"}
 # `build/` is scratch (gitignored) but for the helpers `.gitignore` excepts.
-_COMMITTED_UNDER_BUILD = ("build/flash_bundles.py", "build/kda_probe.py")
+_COMMITTED_UNDER_BUILD = ("build/flash_bundles.py", "build/kda_probe.py",
+                          "build/reduction_table.py")
 _PATH = re.compile(
     r"^(?P<path>[\w.-]+(?:/[\w.-]+)*\.(?:py|json|md|yaml|cc))"
     r"(?::[\d,:-]+)?(?:::[\w:\[\]-]+)?$"

@@ -252,9 +252,108 @@ class TestReductionSchedule:
         assert hlo_audit.asynchronous_share(found) == pytest.approx(share)
 
     def test_a_program_that_sums_nothing_has_no_share(self):
-        assert hlo_audit.reduction_schedule(HLO_SAMPLE.replace(
-            "all-reduce", "add")) == []
+        gathers = hlo_audit.reduction_schedule(HLO_SAMPLE.replace(
+            "all-reduce", "add"))
+        assert [r for r in gathers if r.reduces] == []
+        assert hlo_audit.asynchronous_share(gathers) is None
         assert hlo_audit.asynchronous_share([]) is None
+
+
+# The same three texts with the metadata XLA writes: the collective's own
+# `op_name` on every restatement, the compute's on what a host computes,
+# and a first instruction (a convert) under no scope.
+_JIT = "jit(train_step)/"
+_MLP = "transpose(jvp(TransformerLM))/Block_3/Block_3._mlp/mlp_down"
+
+
+def _meta(op_name):
+    return f', metadata={{op_name="{_JIT}{op_name}" source_line=7}}'
+
+
+NAMED_ASYNC_COLLECTIVE_FUSION = (
+    ASYNC_COLLECTIVE_FUSION
+    .replace(f"{_SUM}\n", _SUM + _meta(f"{_MLP}/dot_general") + "\n")
+    .replace("  %convolution.495 = ",
+             "  %convert.9 = bf16[2048]{0} convert(%c)"
+             + _meta("convert_element_type") + "\n"
+             "  %convolution.495 = ")
+    .replace("window={size=2x1}",
+             "window={size=2x1}" + _meta(f"{_MLP}/dot_general"))
+    .replace("  %all-reduce.150 = ",
+             "  %multiply.3 = f32[2048,8192]{1,0} multiply(%m, %v)"
+             + _meta("hvt.optimizer/mul") + "\n"
+             "  %add.5 = f32[2048,8192]{1,0} add(%multiply.3, %g)"
+             + _meta(f"{_MLP}/add_any") + "\n"
+             "  %all-reduce.150 = ")
+    .replace("channel_id=1, to_apply=%add",
+             "channel_id=1, to_apply=%add" + _meta(
+                 "transpose(jvp(TransformerLM))/lm_head.fused_loss/"
+                 "hvt.head_ce/psum"))
+)
+
+ALL_GATHER = """\
+HloModule jit_train_step, is_scheduled=true
+
+ENTRY %main.1_spmd (p: bf16[512,8192]) -> bf16[2048,8192] {
+  %p = bf16[512,8192]{1,0} parameter(0)
+  %ag = (bf16[512,8192]{1,0}, bf16[2048,8192]{1,0}) all-gather-start(%p), channel_id=3, dimensions={0}, metadata={op_name="jit(train_step)/hvt.optimizer/all_gather"}
+  ROOT %ag-d = bf16[2048,8192]{1,0} all-gather-done((bf16[512,8192]{1,0}, bf16[2048,8192]{1,0}) %ag)
+}
+"""
+
+
+class TestReductionTable:
+    """What `reduction_schedule`'s rows say beyond bytes and a boolean:
+    whose sum it is and which instructions carry it (PR 37)."""
+
+    def test_an_asynchronous_sum_names_its_start_hosts_and_done(self):
+        row, psum = hlo_audit.reduction_schedule(
+            NAMED_ASYNC_COLLECTIVE_FUSION)
+        assert (row.channel, row.asynchronous) == (7, True)
+        assert (row.start, row.done) == (
+            "async-collective-start.2", "async-collective-done.2")
+        assert row.scope == _MLP
+        # `fusion.668`: what its convolution names, not its first
+        # instruction (a convert under no scope); `fusion.669`: a program
+        # scope wins over what more instructions share.
+        assert row.hosts == (
+            hlo_audit.ReductionHost("fusion.668", _MLP),
+            hlo_audit.ReductionHost("fusion.669", "hvt.optimizer"))
+        # The synchronous sum names itself, twice, and has no hosts.
+        assert (psum.start, psum.done, psum.hosts) == ("psum.7", "psum.7", ())
+        assert (psum.channel, psum.asynchronous) == (1, False)
+        assert psum.scope.endswith("lm_head.fused_loss/hvt.head_ce")
+
+    def test_a_start_done_pair_names_both(self):
+        row, = hlo_audit.reduction_schedule(START_DONE_PAIR)
+        assert (row.start, row.done, row.hosts) == (
+            "all-reduce-start.1", "all-reduce-done.1", ())
+
+    def test_an_all_gather_is_a_row_and_no_reduction(self):
+        row, = hlo_audit.reduction_schedule(ALL_GATHER)
+        assert (row.kind, row.dtype, row.shape) == (
+            "all-gather", "bf16", (2048, 8192))
+        assert row.nbytes == 2048 * 8192 * 2  # what arrives, not the pair
+        assert (row.start, row.done, row.asynchronous) == ("ag", "ag-d", True)
+        assert row.scope == "hvt.optimizer" and not row.reduces
+        assert hlo_audit.asynchronous_share([row]) is None
+
+    @pytest.mark.parametrize("text", [
+        PLAIN_ALL_REDUCE, START_DONE_PAIR, ASYNC_COLLECTIVE_FUSION])
+    def test_a_text_without_metadata_gives_empty_scopes(self, text):
+        rows = hlo_audit.reduction_schedule(text)
+        assert rows and all(r.scope == "" for r in rows)
+        assert all(h.host_scope == "" for r in rows for h in r.hosts)
+        assert all(r.start and r.done for r in rows)
+
+    @pytest.mark.parametrize("op_name,path", [
+        ("jit(train_step)/hvt.optimizer/add", "hvt.optimizer"),
+        ("pjit(step)/jvp(M)/Block_1/qkv/dot_general", "jvp(M)/Block_1/qkv"),
+        ("jit(train_step)/add", ""),
+        ("reduce_sum", ""),
+    ])
+    def test_scope_path(self, op_name, path):
+        assert hlo_audit.scope_path(op_name) == path
 
 
 class TestExpectations:

@@ -162,6 +162,101 @@ def test_always_on_counters_move_with_the_exporter_off(monkeypatch, cache):
     obs_core.reset()
 
 
+# --- the step program's cross-chip sums (PR 37) ------------------------------
+
+class StepCompiles:
+    """Counts what JAX lowers and compiles of ``jit(train_step)`` (JAX
+    keeps its listeners for the life of the process: one serves it)."""
+
+    def __init__(self):
+        self.lowered = self.compiled = 0
+        jax.monitoring.register_event_duration_secs_listener(self._on)
+
+    def _on(self, event, duration_secs, fun_name=None, **_):
+        if fun_name == "jit(train_step)":
+            self.lowered += event.endswith("jaxpr_to_mlir_module_duration")
+            self.compiled += event.endswith("backend_compile_duration")
+
+
+@pytest.fixture(scope="module")
+def step_compiles():
+    return StepCompiles()
+
+
+def test_a_fit_that_asks_for_nothing_compiles_nothing_more(step_compiles):
+    """The fit remembers its step program (shapes, one tree.map) and
+    nothing is lowered, compiled or parsed for it until somebody asks: the
+    step is lowered and compiled once, by its first call, as before PR 37.
+    And asking costs no second compile: the program lowered from the
+    remembered shapes IS the one the call made (an uncommitted argument
+    goes without a sharding, as the call lowers it), so JAX hands back
+    the lowering and the executable it holds; only the parse is new."""
+    before = step_compiles.lowered, step_compiles.compiled
+    t = dense_fit(cache=None)
+    once = before[0] + 1, before[1] + 1
+    assert (step_compiles.lowered, step_compiles.compiled) == once
+    assert t._step_program.steps == 1 and t._step_reductions is None
+    scale = t._step_program.shapes[2]
+    assert scale.shape == () and scale.sharding is None
+    rows = trace.step_reductions()
+    assert rows and t._step_reductions is not None
+    assert trace.step_reductions() == rows
+    assert (step_compiles.lowered, step_compiles.compiled) == once
+
+
+def test_step_reductions_is_the_newest_fits_table():
+    """On the CPU mesh every gradient sum is synchronous: each names
+    itself, twice, whose gradient it is, and no host."""
+    t = dense_fit(cache=None)
+    rows = trace.step_reductions()
+    assert rows and rows == [
+        trace.dataclasses.asdict(r) for r in t.step_reductions()]
+    n_params = sum(p.size for p in jax.tree.leaves(t.state.params))
+    # (XLA:CPU combines the sums into one, the step's scalar means with it.)
+    assert 0 <= sum(r["nbytes"] for r in rows) - 4 * n_params <= 64
+    for r in rows:
+        assert r["kind"] == "all-reduce" and not r["asynchronous"]
+        assert r["start"] == r["done"] and r["start"].startswith("all-reduce")
+        # (The module's name is whatever the program that first compiled
+        # this HLO called it: the compile cache's key leaves metadata out.)
+        assert re.fullmatch(r"transpose\(jvp\(\w+\)\)/Dense_0", r["scope"])
+        assert r["hosts"] == ()
+    # An epoch program is not a step program.
+    t.fit(x=np.zeros((8 * jax.device_count(), 8), np.float32),
+          y=np.zeros(8 * jax.device_count(), np.int32), batch_size=8,
+          cache="device", verbose=0)
+    assert trace.step_reductions() is None
+
+
+def test_one_chip_has_an_empty_table_and_compiles_nothing_for_it(
+        step_compiles):
+    mesh = hvt.build_mesh(hvt.MeshSpec(data=1), devices=jax.devices()[:1])
+    t = hvt.Trainer(Dense(), hvt.DistributedOptimizer(optax.adam(1e-3)),
+                    mesh=mesh)
+    t.fit(x=np.zeros((16, 8), np.float32), y=np.zeros(16, np.int32),
+          batch_size=8, verbose=0)
+    before = step_compiles.lowered, step_compiles.compiled
+    assert trace.step_reductions() == []
+    assert (step_compiles.lowered, step_compiles.compiled) == before
+
+
+def test_hvt_profile_leaves_the_table_beside_the_dump(tmp_path, monkeypatch):
+    monkeypatch.setenv("HVT_PROFILE", str(tmp_path))
+    t = dense_fit(cache=None)
+    assert glob.glob(str(tmp_path / "plugins/profile/*/*.xplane.pb"))
+    written = json.loads((tmp_path / trace.STEP_REDUCTIONS_FILE).read_text())
+    assert written == json.loads(json.dumps(trace.step_reductions()))
+    assert [r["start"] for r in written] == [
+        r.start for r in t.step_reductions()]
+
+
+def test_no_fit_no_table_and_no_file(tmp_path, monkeypatch):
+    monkeypatch.setattr(trace, "_newest_fit", lambda: None)
+    assert trace.step_reductions() is None
+    assert trace.write_step_reductions(str(tmp_path)) is None
+    assert not list(tmp_path.iterdir())
+
+
 # --- device scopes ---------------------------------------------------------
 
 def lowered_lm_step(accumulation: int, vocab: int = 64) -> str:
