@@ -44,7 +44,9 @@ CELLS = {
     "starcoder2": dict(b=1, t=4096, heads=24, dk=128, dv=128, window=4096),
     "cerebras": dict(b=2, t=2048, heads=16, dk=128, dv=128, window=None),
 }
-KERNELS = ("hvt_flash_fwd", "hvt_flash_dq", "hvt_flash_dkv")
+# Every name a flash call can take; a backward is `hvt_flash_bwd` alone or
+# `hvt_flash_dq` + `hvt_flash_dkv` (`flash_attention.fused_backward`).
+KERNELS = ("hvt_flash_fwd", "hvt_flash_bwd", "hvt_flash_dq", "hvt_flash_dkv")
 # A predicated region shorter than this is the pipeline's (a DMA issue, a
 # semaphore wait), not a `pl.when` of the kernel.
 MIN_REGION = 24
@@ -180,6 +182,27 @@ def report(name, bundle_path, slot_path):
             f"{k} {n}" for k, n in mask.items() if n))
 
 
+def report_dump(dump: str) -> None:
+    """Every kernel of KERNELS the dump holds a schedule of; one that is not
+    there is said to be absent (the backward took the other form), and a
+    dump with none at all is a compile that failed before the kernels."""
+    schedules = {
+        kernel: sorted(glob.glob(
+            os.path.join(dump, f"*{kernel}*-71-final_bundles.txt")))
+        for kernel in KERNELS}
+    if not any(schedules.values()):
+        print("\nno schedule dumped (the compile failed before the "
+              "kernels: run the --child command by hand)")
+        return
+    for kernel, found in schedules.items():
+        if not found:
+            print(f"\n{kernel}: absent from this call")
+        for path in found:
+            stem = path[:-len("-71-final_bundles.txt")]
+            slots = glob.glob(stem + "-69-*utilization.txt")[0]
+            report(os.path.basename(stem).split("-", 1)[1], path, slots)
+
+
 def main() -> None:
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--cell", choices=sorted(CELLS))
@@ -207,17 +230,7 @@ def main() -> None:
         compile_in_child(args, dump)
         print(f"B {args.b} T {args.t} heads {args.heads} Dk {args.dk} "
               f"Dv {args.dv} window {args.window} block {args.block}")
-        for kernel in KERNELS:
-            found = sorted(glob.glob(
-                os.path.join(dump, f"*{kernel}*-71-final_bundles.txt")))
-            if not found:
-                print(f"\n{kernel}: no schedule dumped (the compile failed "
-                      "before the kernel: run the --child command by hand)")
-                continue
-            for path in found:
-                stem = path[:-len("-71-final_bundles.txt")]
-                slots = glob.glob(stem + "-69-*utilization.txt")[0]
-                report(os.path.basename(stem).split("-", 1)[1], path, slots)
+        report_dump(dump)
     finally:
         if not args.keep:
             shutil.rmtree(dump, ignore_errors=True)

@@ -341,6 +341,16 @@ METRICS: dict[str, MetricSpec] = _decl([
                "of a call with segment ids). Set at trace time: the "
                "census is static per call.",
                "training", labels=("kind",)),
+    MetricSpec("hvt_flash_backward", "gauge",
+               "Form of the last flash-attention backward traced "
+               "(ops/flash_attention.py `fused_backward`): 1 on the one "
+               "taken, 0 on the other. `fused` is one kernel, "
+               "hvt_flash_bwd (dQ, dK and dV from one pass over the "
+               "tiles), where a head's dQ fits half the chip's VMEM; "
+               "`split` is hvt_flash_dq + hvt_flash_dkv (longer contexts, "
+               "calls with sinks). Set at trace time: the form follows "
+               "from the call's shapes.",
+               "training", labels=("impl",)),
     MetricSpec("hvt_layer_kinds", "gauge",
                "Layers of the last HybridMoELM traced "
                "(models/hybrid_moe_lm.py), by their token mixer: `linear` "

@@ -9,9 +9,13 @@ within-chip counterpart), and writes the normalized output once per Q block.
 O(T) memory instead of O(T²), matmuls on the MXU in the input dtype,
 statistics in float32.
 
-Backward is a custom VJP with the standard two-kernel recomputation scheme
-(dq swept over K blocks, dK/dV swept over Q blocks) using the saved
-logsumexp, so residual memory is O(T) as well.
+Backward is a custom VJP that recomputes the scores from the saved
+logsumexp, so residual memory is O(T) as well. Where a head's whole dQ fits
+VMEM (`fused_backward`: from the shapes and the chip's VMEM alone) it is ONE
+kernel: the K block anchored and the Q blocks swept, dK/dV accumulated a
+block and dQ a head, so a tile's scores and dP are formed once. Otherwise
+the standard two kernels (dQ swept over K blocks, dK/dV swept over Q
+blocks), which form them twice.
 
 `flash_attention` is shape-checked: when the kernel's tiling constraints
 don't hold it runs the dense reference (`ops.attention.dense_attention`)
@@ -57,8 +61,10 @@ DEFAULT_BLOCK_K = 1024
 # `transpose` or `shard_map` the call sits in. The benchmark's per-kernel
 # metrics key on them (chipbench/spans.py): keep them stable, and never end
 # one in a digit (the reduction strips an instruction's trailing number).
-# The sink-only dK/dV pass is dK/dV work and shares its name.
+# The sink-only dK/dV pass is dK/dV work and shares its name. A backward is
+# KERNEL_BWD alone or KERNEL_DQ + KERNEL_DKV (`fused_backward`).
 KERNEL_FWD = "hvt_flash_fwd"
+KERNEL_BWD = "hvt_flash_bwd"
 KERNEL_DQ = "hvt_flash_dq"
 KERNEL_DKV = "hvt_flash_dkv"
 
@@ -395,12 +401,19 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
 
 def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
                     scale, causal, segmented, bq, bk, offset, window, nq,
-                    sinks=0, sink_only=False):
+                    sinks=0, sink_only=False, with_dq=False):
+    """dK/dV of one k block a sweep and, ``with_dq`` (the fused backward),
+    dQ of the whole (b, h) beside them: every tile's ``ds·k`` lands on its q
+    block's rows of a float32 [Tq, Dk] scratch that outlives the sweeps. A
+    q block's sum over the k blocks then runs in ascending ``ik``, the
+    order of `_bwd_dq_kernel`'s sweep, so the two forms agree to the bit."""
+    qs_ref = ks_ref = dq_ref = dq_acc = None
     if segmented:
-        qs_ref, ks_ref, dk_ref, dv_ref, dk_acc, dv_acc = rest
+        qs_ref, ks_ref, *rest = rest
+    if with_dq:
+        dk_ref, dv_ref, dq_ref, dk_acc, dv_acc, dq_acc = rest
     else:
         dk_ref, dv_ref, dk_acc, dv_acc = rest
-        qs_ref = ks_ref = None
     ik, jj = pl.program_id(2), pl.program_id(3)
     nj = pl.num_programs(3)
     # The transposed sweep: k block ``ik`` anchored, q blocks swept — all of
@@ -423,6 +436,11 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
     def _():
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
+
+    if with_dq:
+        @pl.when((ik == 0) & (jj == 0))
+        def _():
+            dq_acc[:] = jnp.zeros_like(dq_acc)
 
     def make_mask():
         return _tile_mask(
@@ -459,6 +477,13 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
             ds, q.astype(jnp.float32), (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         ) * scale
+        if with_dq:
+            # dQ[q block iq] += dS · K, as `_bwd_dq_kernel` forms it
+            rows = pl.ds(pl.multiple_of(iq * bq, bq), bq)
+            dq_acc[rows, :] = dq_acc[rows, :] + jax.lax.dot_general(
+                ds, k.astype(jnp.float32), (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            ) * scale
 
     _update_by_class(needed, full, causal or segmented, make_mask, update)
 
@@ -466,6 +491,11 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
     def _():
         dk_ref[0, 0, :, :] = dk_acc[:].astype(dk_ref.dtype)
         dv_ref[0, 0, :, :] = dv_acc[:].astype(dv_ref.dtype)
+
+    if with_dq:
+        @pl.when((ik == pl.num_programs(2) - 1) & (jj == nj - 1))
+        def _():
+            dq_ref[0, 0, :, :] = dq_acc[:].astype(dq_ref.dtype)
 
 
 # Grid-to-T-block selectors: the grid is (b, h, anchor, swept), and
@@ -697,14 +727,33 @@ def _flash_bwd(causal, window, sinks, q_offset, bq, bk, interpret, res, g):
     )
 
 
-@functools.partial(jax.jit, static_argnums=(0, 1, 2, 3, 4, 5, 6))  # as above
 def _flash_bwd_core(causal, window, sinks, q_offset, bq, bk, interpret, res,
                     g, g_lse):
+    """Both entries' backward rule: the form is picked here, from the
+    shapes alone, and the gauge ``hvt_flash_backward{impl}`` reads which
+    the last backward traced took."""
+    from horovod_tpu import obs
+
+    q = res[0]
+    fused = fused_backward(q.shape[1], q.shape[3], q.dtype, sinks=sinks)
+    for impl, taken in (("fused", fused), ("split", not fused)):
+        obs.gauge("hvt_flash_backward", float(taken), impl=impl)
+    return _flash_bwd_impl(
+        fused, causal, window, sinks, q_offset, bq, bk, interpret, res, g,
+        g_lse,
+    )
+
+
+@functools.partial(jax.jit, static_argnums=(0, 1, 2, 3, 4, 5, 6, 7))  # as above
+def _flash_bwd_impl(fused, causal, window, sinks, q_offset, bq, bk, interpret,
+                    res, g, g_lse):
     """Shared backward: the lse cotangent (from `flash_attention_with_lse`
     consumers like the ring merge) folds into the per-row jacobian term —
     with s → p = exp(s−lse), o = p·v:  ds = p ⊙ (dp − (δ − dlse)) where
     δ_i = Σ_d dO·O, because ∂lse/∂s = p. So the kernels run unchanged with
-    an adjusted δ."""
+    an adjusted δ. ``fused``: one call (KERNEL_BWD), the dK/dV sweep with
+    dQ beside it, in place of KERNEL_DQ + KERNEL_DKV; the same gradients
+    to the bit."""
     q, k, v, q_seg, kv_seg, out, lse = res
     qt, kt, vt, gt = (
         jnp.transpose(x, (0, 2, 1, 3)) for x in (q, k, v, g)
@@ -715,8 +764,6 @@ def _flash_bwd_core(causal, window, sinks, q_offset, bq, bk, interpret, res,
     scale = d ** -0.5
     off = tk - tq if q_offset is None else q_offset
     nq, nk = tq // bq, tk // bk
-    nb = _k_sweep_steps(bq, bk, window, sinks, nk)
-    asel, ksel = _k_sweep_maps(causal, bq, bk, off, window, sinks, nq, nk)
     nbq, kanchor, qsel = _q_sweep_maps(causal, bq, bk, off, window, nq, nk)
     # delta_i = Σ_d dO·O — the softmax-jacobian row term, cheap outside.
     delta = jnp.einsum(
@@ -726,67 +773,91 @@ def _flash_bwd_core(causal, window, sinks, q_offset, bq, bk, interpret, res,
         # g_lse arrives in the caller-facing [B, T, H] layout.
         delta = delta - jnp.transpose(g_lse, (0, 2, 1))[..., None]
     seg_ops = list(_seg_operands(q_seg, kv_seg, tq, tk)) if segmented else []
+    operands = (qt, kt, vt, gt, lse, delta, *seg_ops)
 
-    dq_in_specs = [
-        _block_spec(d, bq, asel),
-        _block_spec(d, bk, ksel),
-        _block_spec(d_v, bk, ksel),
-        _block_spec(d_v, bq, asel),
-        _stat_spec(bq, asel),
-        _stat_spec(bq, asel),
-    ]
-    if segmented:
-        dq_in_specs += [
-            _seg_q_spec(bq, asel), _seg_kv_spec(bk, ksel)
+    def k_sweep_dq():
+        nb = _k_sweep_steps(bq, bk, window, sinks, nk)
+        asel, ksel = _k_sweep_maps(
+            causal, bq, bk, off, window, sinks, nq, nk)
+        in_specs = [
+            _block_spec(d, bq, asel),
+            _block_spec(d, bk, ksel),
+            _block_spec(d_v, bk, ksel),
+            _block_spec(d_v, bq, asel),
+            _stat_spec(bq, asel),
+            _stat_spec(bq, asel),
         ]
-    dq = pl.pallas_call(
-        functools.partial(
-            _bwd_dq_kernel, scale=scale, causal=causal, segmented=segmented,
-            bq=bq, bk=bk, offset=off, window=window, nk=nk, sinks=sinks,
-        ),
-        grid=(b, h, nq, nb),
-        in_specs=dq_in_specs,
-        out_specs=_block_spec(d, bq, _anchor),
-        out_shape=jax.ShapeDtypeStruct(qt.shape, q.dtype),
-        scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
-        interpret=interpret,
-        name=KERNEL_DQ,
-    )(qt, kt, vt, gt, lse, delta, *seg_ops)
+        if segmented:
+            in_specs += [_seg_q_spec(bq, asel), _seg_kv_spec(bk, ksel)]
+        return pl.pallas_call(
+            functools.partial(
+                _bwd_dq_kernel, scale=scale, causal=causal,
+                segmented=segmented, bq=bq, bk=bk, offset=off, window=window,
+                nk=nk, sinks=sinks,
+            ),
+            grid=(b, h, nq, nb),
+            in_specs=in_specs,
+            out_specs=_block_spec(d, bq, _anchor),
+            out_shape=jax.ShapeDtypeStruct(qt.shape, q.dtype),
+            scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
+            interpret=interpret,
+            name=KERNEL_DQ,
+        )(*operands)
 
-    dkv_in_specs = [
-        _block_spec(d, bq, qsel),
-        _block_spec(d, bk, kanchor),
-        _block_spec(d_v, bk, kanchor),
-        _block_spec(d_v, bq, qsel),
-        _stat_spec(bq, qsel),
-        _stat_spec(bq, qsel),
-    ]
-    if segmented:
-        dkv_in_specs += [
-            _seg_q_spec(bq, qsel), _seg_kv_spec(bk, kanchor)
+    def q_sweep(with_dq):
+        """(dk, dv), and ``with_dq`` dq after them: a whole (b, h)'s block
+        and float32 scratch, resident while the head's k blocks pass."""
+        in_specs = [
+            _block_spec(d, bq, qsel),
+            _block_spec(d, bk, kanchor),
+            _block_spec(d_v, bk, kanchor),
+            _block_spec(d_v, bq, qsel),
+            _stat_spec(bq, qsel),
+            _stat_spec(bq, qsel),
         ]
-    dk, dv = pl.pallas_call(
-        functools.partial(
-            _bwd_dkv_kernel, scale=scale, causal=causal, segmented=segmented,
-            bq=bq, bk=bk, offset=off, window=window, nq=nq,
-        ),
-        grid=(b, h, nk, nbq),
-        in_specs=dkv_in_specs,
-        out_specs=[
+        if segmented:
+            in_specs += [_seg_q_spec(bq, qsel), _seg_kv_spec(bk, kanchor)]
+        out_specs = [
             _block_spec(d, bk, _anchor),
             _block_spec(d_v, bk, _anchor),
-        ],
-        out_shape=[
+        ]
+        out_shape = [
             jax.ShapeDtypeStruct(kt.shape, k.dtype),
             jax.ShapeDtypeStruct(vt.shape, v.dtype),
-        ],
-        scratch_shapes=[
+        ]
+        scratch_shapes = [
             pltpu.VMEM((bk, d), jnp.float32),
             pltpu.VMEM((bk, d_v), jnp.float32),
-        ],
-        interpret=interpret,
-        name=KERNEL_DKV,
-    )(qt, kt, vt, gt, lse, delta, *seg_ops)
+        ]
+        compiler_params = None
+        if with_dq:
+            out_specs.append(_block_spec(d, tq, lambda i, j: 0))
+            out_shape.append(jax.ShapeDtypeStruct(qt.shape, q.dtype))
+            scratch_shapes.append(pltpu.VMEM((tq, d), jnp.float32))
+            compiler_params = pltpu.CompilerParams(
+                vmem_limit_bytes=_resident_dq_bytes(tq, d, q.dtype)
+                + _SWEEP_VMEM_BYTES)
+        return pl.pallas_call(
+            functools.partial(
+                _bwd_dkv_kernel, scale=scale, causal=causal,
+                segmented=segmented, bq=bq, bk=bk, offset=off, window=window,
+                nq=nq, with_dq=with_dq,
+            ),
+            grid=(b, h, nk, nbq),
+            in_specs=in_specs,
+            out_specs=out_specs,
+            out_shape=out_shape,
+            scratch_shapes=scratch_shapes,
+            interpret=interpret,
+            name=KERNEL_BWD if with_dq else KERNEL_DKV,
+            compiler_params=compiler_params,
+        )(*operands)
+
+    if fused:
+        dk, dv, dq = q_sweep(with_dq=True)
+    else:
+        dq = k_sweep_dq()
+        dk, dv = q_sweep(with_dq=False)
     if window is not None and sinks:
         # Sink contributions to dK/dV of k block 0: every q block sees the
         # sink columns, so this pass sweeps ALL nq q blocks for the one
@@ -1108,6 +1179,43 @@ def pick_blocks(t: int, d: int, dtype, bq: int = DEFAULT_BLOCK_Q,
     while t_k % bk and bk // 2 >= floor:
         bk //= 2
     return bq, bk
+
+
+# What the fused backward's tiles may take of VMEM beside the resident dQ:
+# twice the 16 MiB scoped default the two kernels compile within, since its
+# body holds the dK/dV pass's tiles and one product more. A ceiling for
+# Mosaic's allocation, not a reservation.
+_SWEEP_VMEM_BYTES = 32 * 2**20
+_V5E_VMEM_BYTES = 128 * 2**20
+
+
+def _chip_vmem_bytes() -> int:
+    """VMEM of the chip the kernels are built for: the attached TPU's; off
+    TPU (the interpreter, a compile for the described chip) a v5e's."""
+    if jax.devices()[0].platform == "tpu":
+        return pltpu.get_tpu_info().vmem_capacity_bytes
+    return _V5E_VMEM_BYTES
+
+
+def _resident_dq_bytes(t_q: int, d: int, dtype) -> int:
+    """VMEM the fused backward keeps for a whole (b, h): the float32
+    [Tq, D] accumulator and the two buffers of the dQ output block, D
+    padded to the 128 lanes."""
+    lanes = -(-d // 128) * 128
+    return t_q * lanes * (4 + 2 * jnp.dtype(dtype).itemsize)
+
+
+def fused_backward(t_q: int, d: int, dtype, sinks: int = 0) -> bool:
+    """Whether the backward of a [*, t_q, *, d] call (``d``: q and k's head
+    size) is the one kernel KERNEL_BWD: where a head's dQ, resident while
+    its k blocks pass, takes no more than half the chip's VMEM (Tq 65,536
+    at D 128 in bf16 on a v5e; the tiles get the rest). Longer contexts
+    take KERNEL_DQ + KERNEL_DKV, and so do calls with sinks: their
+    sink-only pass adds to dK/dV of block 0 from a second sweep, and the
+    dQ sweep holds the sink tile, which the k-anchored sweep does not."""
+    if sinks:
+        return False
+    return _resident_dq_bytes(t_q, d, dtype) <= _chip_vmem_bytes() // 2
 
 
 def flash_attention(

@@ -99,39 +99,53 @@ def while_trip_counts(hlo: str) -> list:
     return trips
 
 
-FLASH_KERNELS = sorted([fa.KERNEL_DKV, fa.KERNEL_DQ, fa.KERNEL_FWD])
+# The Mosaic calls of a forward + backward, by the backward's form
+# (`fa.fused_backward`); the sink-only dK/dV pass is dK/dV work by name too.
+FUSED = sorted([fa.KERNEL_FWD, fa.KERNEL_BWD])
+SPLIT = sorted([fa.KERNEL_FWD, fa.KERNEL_DQ, fa.KERNEL_DKV])
+SPLIT_SINKS = sorted(SPLIT + [fa.KERNEL_DKV])
 
 
 @pytest.mark.parametrize(
-    # [B, T, H, D], dtype, flash kwargs, segmented, Mosaic calls fwd+bwd,
-    # v's head size where it is not D, `hvt_flash_tiles` after the trace
-    # (skipped / edge / full grid steps a (b, h); None: not looked at)
-    "shape,dtype,kwargs,segmented,n_calls,v_dim,tiles",
+    # [B, T, H, D], dtype, flash kwargs, segmented, the Mosaic calls of
+    # forward + backward by name, v's head size where it is not D,
+    # `hvt_flash_tiles` after the trace (skipped / edge / full grid steps a
+    # (b, h); None: not looked at)
+    "shape,dtype,kwargs,segmented,kernels,v_dim,tiles",
     [
-        pytest.param((4, 1024, 16, 128), jnp.bfloat16, {}, False, 3,
+        pytest.param((4, 1024, 16, 128), jnp.bfloat16, {}, False, FUSED,
                      None, None, id="smoke-bf16-T1024"),
-        pytest.param((2, 4096, 16, 128), jnp.bfloat16, {}, True, 3,
+        pytest.param((2, 4096, 16, 128), jnp.bfloat16, {}, True, FUSED,
                      None, (28, 36, 0), id="segments-T4096"),
         pytest.param((2, 4096, 16, 128), jnp.bfloat16,
-                     {"window": 1024, "sinks": 64}, False, 4,
+                     {"window": 1024, "sinks": 64}, False, SPLIT_SINKS,
                      None, None, id="window-sinks-T4096"),
         # Refused at 1024² tiles (16.20M of 16.00M scoped VMEM in the dK/dV
         # pass); `pick_blocks` takes 512² for 4-byte inputs.
-        pytest.param((4, 2048, 8, 64), jnp.float32, {}, False, 3,
+        pytest.param((4, 2048, 8, 64), jnp.float32, {}, False, FUSED,
                      None, None, id="f32-D64-T2048"),
         # The benchmark's cells' own calls, at the tiles `pick_blocks`
-        # gives them (two update bodies a kernel since PR 34: the 1024²
-        # dK/dV pass is the fullest).
-        pytest.param((2, 2048, 16, 128), jnp.bfloat16, {}, False, 3,
+        # gives them (two update bodies a kernel since PR 34; the 1024²
+        # backward is the fullest). Kanana's keeps 16 MiB of dQ resident.
+        pytest.param((2, 2048, 16, 128), jnp.bfloat16, {}, False, FUSED,
                      None, (1, 2, 1), id="cell-cerebras-gpt-1.3b"),
         pytest.param((1, 4096, 24, 128), jnp.bfloat16, {"window": 4096},
-                     False, 3, None, (28, 8, 28), id="cell-starcoder2-3b"),
-        pytest.param((1, 8192, 32, 192), jnp.bfloat16, {}, False, 3,
+                     False, FUSED, None, (28, 8, 28),
+                     id="cell-starcoder2-3b"),
+        pytest.param((1, 8192, 32, 192), jnp.bfloat16, {}, False, FUSED,
                      128, (120, 16, 120), id="cell-kanana-2-30b-a3b"),
+        # Long contexts, in whichever form the predicate picks: 32 MiB of
+        # dQ resident, and the budget's edge (64 MiB: half the VMEM).
+        pytest.param((1, 32768, 2, 128), jnp.bfloat16, {}, False, None,
+                     None, None, id="T32768-D128"),
+        pytest.param((1, 32768, 2, 256), jnp.bfloat16, {}, False, None,
+                     None, None, id="T32768-D256-at-the-budget"),
+        pytest.param((1, 65536, 1, 256), jnp.bfloat16, {}, False, None,
+                     None, None, id="T65536-D256-past-the-budget"),
     ],
 )
 def test_flash_fwd_bwd_compiles_for_v5e(topo, shape, dtype, kwargs,
-                                        segmented, n_calls, v_dim, tiles):
+                                        segmented, kernels, v_dim, tiles):
     from horovod_tpu.obs import core as obs_core
     from horovod_tpu.obs import prom
 
@@ -158,14 +172,16 @@ def test_flash_fwd_bwd_compiles_for_v5e(topo, shape, dtype, kwargs,
     compiled = jax.jit(
         jax.value_and_grad(loss, argnums=(0, 1, 2))
     ).lower(*args).compile()
-    assert len(kernel_calls(compiled)) == n_calls
-    # The instruction names the profiler's device events carry; the
-    # sink-only dK/dV pass (the fourth call) is dK/dV work by name too.
-    assert kernel_names(compiled) == sorted(
-        FLASH_KERNELS + [fa.KERNEL_DKV] * (n_calls - 3))
+    if kernels is None:
+        kernels = FUSED if fa.fused_backward(
+            shape[1], shape[3], dtype) else SPLIT
+    # The instruction names the profiler's device events carry.
+    assert kernel_names(compiled) == kernels
+    values = prom.parse_text(prom.render())
+    assert values['hvt_flash_backward{impl="fused"}'] == (kernels == FUSED)
+    assert values['hvt_flash_backward{impl="split"}'] == (kernels != FUSED)
     # The census of the traced forward grid: static per call, so a gauge.
     assert obs_core.spec("hvt_flash_tiles").kind == "gauge"
-    values = prom.parse_text(prom.render())
     if tiles is not None:
         assert tuple(
             values[f'hvt_flash_tiles{{kind="{kind}"}}']
@@ -197,7 +213,7 @@ def test_flash_kernels_keep_their_names_under_a_shard_map(topo):
     compiled = jax.jit(
         jax.value_and_grad(loss, argnums=(0, 1, 2))
     ).lower(qkv, qkv, qkv).compile()
-    assert kernel_names(compiled) == FLASH_KERNELS
+    assert kernel_names(compiled) == FUSED
 
 
 # --- one whole train step on the four described chips ----------------------
@@ -316,8 +332,8 @@ def test_train_step_compiles_data_parallel_on_four_chips(
     assert f"f32[{4 * local_rows // HEAD_CHUNKS},{VOCAB}]" not in hlo
     assert f"f32[{4 * local_rows},{VOCAB // HEAD_CHUNKS}]" not in hlo
     calls = kernel_calls(compiled)
-    assert len(calls) == 2 * 3  # layers x fwd/dq/dkv
-    assert kernel_names(compiled) == sorted(FLASH_KERNELS * 2)
+    assert len(calls) == 2 * 2  # layers x forward, backward
+    assert kernel_names(compiled) == sorted(FUSED * 2)
     # The shard_map hands each chip's kernel its quarter of the batch.
     kernel_batches = {
         int(b) for line in calls
@@ -431,7 +447,7 @@ def test_cell_step_keeps_its_loops_and_kernels(cell_compiled):
     hlo = cell_compiled.as_text()
     forward, backward = head_loops(hlo)
     assert not hlo_audit.collective_ops(forward + backward)
-    assert kernel_names(cell_compiled) == sorted(FLASH_KERNELS * CELL_LAYERS)
+    assert kernel_names(cell_compiled) == sorted(FUSED * CELL_LAYERS)
     mem = cell_compiled.memory_analysis()
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16e9
 
@@ -521,7 +537,7 @@ def test_flash_with_two_head_sizes_compiles_for_v5e(topo):
 
     compiled = jax.jit(
         jax.value_and_grad(loss, argnums=(0, 1, 2))).lower(qk, qk, v).compile()
-    assert kernel_names(compiled) == FLASH_KERNELS
+    assert kernel_names(compiled) == FUSED
     (_, grads) = compiled.output_shardings  # it has the three gradients
     assert len(grads) == 3
 
@@ -554,7 +570,7 @@ def test_grouped_matmul_compiles_for_v5e(topo, compiled_kernel):
 def test_latent_moe_cell_step_fits_one_chip(topo, compiled_kernel):
     """The whole training step of the cell at its own sizes (1 dense + 5
     expert layers, 687.5 M parameters, 8,192 tokens): it compiles, every
-    layer runs the three flash kernels and every expert layer the six
+    layer runs the two flash kernels and every expert layer the six
     grouped matmuls, and state + temporaries stay under the 15.0 GB that
     chose the depth (14.62 when it was chosen; 1 + 6 layers read 16.00)."""
     from horovod_tpu.models.latent_moe_lm import LatentMoELM
@@ -577,7 +593,7 @@ def test_latent_moe_cell_step_fits_one_chip(topo, compiled_kernel):
         "moe_held_rows_share", "moe_load_max_over_mean", "moe_overflow_rows")
     compiled = compiled_step(trainer, seq=8192, batch=1)
     assert kernel_names(compiled) == sorted(
-        FLASH_KERNELS * 6 + [gm.KERNEL] * 20 + [gm.KERNEL_DW] * 10)
+        FUSED * 6 + [gm.KERNEL] * 20 + [gm.KERNEL_DW] * 10)
     memory = compiled.memory_analysis()
     state = 687_502_336 * 12
     assert state <= memory.argument_size_in_bytes <= state + 1_000_000
@@ -664,7 +680,7 @@ def test_delta_rule_kernels_compile_at_other_shapes(topo, compiled_kernel, t,
 def test_hybrid_moe_cell_step_fits_one_chip(topo, compiled_kernel):
     """The whole training step of the cell at its own sizes (one period:
     softmax, KDA, KDA, KDA; 840.9 M parameters, 8,192 tokens): it compiles,
-    the softmax layer runs the three flash kernels and every layer the six
+    the softmax layer runs the two flash kernels and every layer the six
     grouped matmuls at the new shapes, the mixers' scopes are in the
     program, the gauges say what was built, and state + temporaries stay
     under 15.0 GB (14.89 when it was written; 15.02 before the mixers'
@@ -692,7 +708,7 @@ def test_hybrid_moe_cell_step_fits_one_chip(topo, compiled_kernel):
         "moe_held_rows_share", "moe_load_max_over_mean", "moe_overflow_rows")
     compiled = compiled_step(trainer, seq=8192, batch=1)
     assert kernel_names(compiled) == sorted(
-        FLASH_KERNELS + [gm.KERNEL] * 16 + [gm.KERNEL_DW] * 8
+        FUSED + [gm.KERNEL] * 16 + [gm.KERNEL_DW] * 8
         + [delta_rule.KERNEL_FWD] * 6 + [delta_rule.KERNEL_BWD] * 9)
     hlo = compiled.as_text()
     for scope in (hybrid.KDA_PROJ, hybrid.KDA_CONV, hybrid.KDA_SCAN,

@@ -991,33 +991,38 @@ class TestEveryTileClass:
         return jnp.asarray(
             np.random.RandomState(seed).randn(*shape).astype(np.float32))
 
-    @pytest.mark.parametrize(
-        "case",
-        [
-            dict(),
-            # The diagonal and the band's lower edge in ONE tile: no tile of
-            # a window narrower than bq + bk − 1 is full.
-            dict(window=20, no_full=True),
-            dict(window=48, no_full=True),
-            # The lower edge crosses a tile the diagonal does not, (2, 0),
-            # beside full ones, (1, 0) and (2, 1).
-            dict(window=70),
-            dict(window=96),
-            dict(window=1000),
-            dict(tk=160),
-            dict(tk=160, window=70),
-            dict(dk=48, dv=32),
-            dict(window=40, sinks=8, no_full=True),
-            dict(window=70, sinks=8),
-            dict(segments=True, no_full=True),
-            dict(segments=True, window=200, no_full=True),
-        ],
-        ids=["causal", "window<tile", "window-2-tiles", "window-between",
-             "window=T",
-             "window>T", "tk>tq", "tk>tq-window", "dk!=dv", "sinks",
-             "sinks-full-tiles", "segments", "segments-window"],
-    )
-    def test_forward_and_gradients(self, case):
+    CASES = [
+        dict(),
+        # The diagonal and the band's lower edge in ONE tile: no tile of
+        # a window narrower than bq + bk − 1 is full.
+        dict(window=20, no_full=True),
+        dict(window=48, no_full=True),
+        # The lower edge crosses a tile the diagonal does not, (2, 0),
+        # beside full ones, (1, 0) and (2, 1).
+        dict(window=70),
+        dict(window=96),
+        dict(window=1000),
+        dict(tk=160),
+        dict(tk=160, window=70),
+        # A ring hop's alignment: the rows sit at key positions r + 10,
+        # not at the sequences' ends (r + 64).
+        dict(tk=160, q_offset=10),
+        dict(tk=160, q_offset=10, window=80),
+        dict(dk=48, dv=32),
+        dict(window=40, sinks=8, no_full=True),
+        dict(window=70, sinks=8),
+        dict(segments=True, no_full=True),
+        dict(segments=True, window=200, no_full=True),
+    ]
+    IDS = ["causal", "window<tile", "window-2-tiles", "window-between",
+           "window=T", "window>T", "tk>tq", "tk>tq-window", "q_offset",
+           "q_offset-window", "dk!=dv", "sinks", "sinks-full-tiles",
+           "segments", "segments-window"]
+
+    def _call(self, case):
+        """(q, k, v, tile, flash kwargs) of a case, its census checked: a
+        grid of 3 × 3 tiles at least, with skipped and edge tiles, and full
+        ones where the case can have them."""
         segments = case.get("segments", False)
         # Segment ids need lane-aligned k blocks: 128² tiles there.
         tile = 128 if segments else self.TILE
@@ -1029,25 +1034,29 @@ class TestEveryTileClass:
         v = self._rand((1, tk, 2, dv), 52)
         kwargs = dict(
             causal=True, window=case.get("window"),
+            q_offset=case.get("q_offset"), sinks=case.get("sinks", 0),
         )
-        ids = {}
         if segments:
             seg = _packed_segments(np.random.RandomState(53), 1, tq, 3)
-            ids = dict(q_segment_ids=seg, kv_segment_ids=seg)
-        sinks = case.get("sinks", 0)
+            kwargs.update(q_segment_ids=seg, kv_segment_ids=seg)
         census = tile_census(
             tq, tk, tile, tile, causal=True, window=kwargs["window"],
-            sinks=sinks, segmented=segments)
+            sinks=kwargs["sinks"], q_offset=kwargs["q_offset"],
+            segmented=segments)
         assert census[0] and census[1], census
         assert bool(census[2]) != case.get("no_full", False), census
+        return q, k, v, tile, kwargs
+
+    @pytest.mark.parametrize("case", CASES, ids=IDS)
+    def test_forward_and_gradients(self, case):
+        q, k, v, tile, kwargs = self._call(case)
 
         def flash(q, k, v):
             return flash_attention(
-                q, k, v, block_q=tile, block_k=tile, sinks=sinks, **kwargs,
-                **ids)
+                q, k, v, block_q=tile, block_k=tile, **kwargs)
 
         def dense(q, k, v):
-            return _dense_with_lse(q, k, v, sinks=sinks, **kwargs, **ids)[0]
+            return _dense_with_lse(q, k, v, **kwargs)[0]
 
         np.testing.assert_allclose(
             np.asarray(flash(q, k, v)), np.asarray(dense(q, k, v)),
@@ -1060,6 +1069,32 @@ class TestEveryTileClass:
         for a, b in zip(*grads):
             np.testing.assert_allclose(
                 np.asarray(a), np.asarray(b), rtol=1e-4, atol=1e-4)
+
+    @pytest.mark.parametrize("lse_cotangent", [False, True],
+                             ids=["out", "out+lse"])
+    @pytest.mark.parametrize(
+        "case", [c for c in CASES if "sinks" not in c],
+        ids=[i for i, c in zip(IDS, CASES) if "sinks" not in c])
+    def test_fused_backward_equals_the_two_kernels_to_the_bit(
+            self, case, lse_cotangent):
+        """dq, dk and dv of the one-kernel backward against the two-kernel
+        form (the oracle) on the same residuals and cotangents: the same
+        products, and a q block's sum over the k blocks in the same order.
+        Segment ids go the fused way like any call without sinks."""
+        q, k, v, tile, kwargs = self._call(case)
+        static = (True, kwargs["window"], 0, kwargs["q_offset"], tile, tile,
+                  True)
+        out, res = fa._flash_fwd(
+            q, k, v, kwargs.get("q_segment_ids"),
+            kwargs.get("kv_segment_ids"), *static)
+        g = self._rand(out.shape, 54)
+        g_lse = self._rand(out.shape[:3], 55) if lse_cotangent else None
+        assert fa.fused_backward(q.shape[1], q.shape[3], q.dtype)
+        fused = fa._flash_bwd_impl(True, *static, res, g, g_lse)
+        split = fa._flash_bwd_impl(False, *static, res, g, g_lse)
+        for a, b in zip(fused[:3], split[:3]):
+            assert np.abs(np.asarray(b)).max() > 0
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
     @pytest.mark.parametrize("window", [None, 10_000])
     def test_all_full_call_equals_the_unmasked_one_to_the_bit(self, window):
@@ -1087,3 +1122,80 @@ class TestEveryTileClass:
         np.testing.assert_array_equal(np.asarray(out), np.asarray(plain))
         for a, b in zip(grads, plain_grads):
             np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+class TestFusedBackward:
+    """Which form the backward takes: `fused_backward`, from the shapes and
+    the chip's VMEM alone (off TPU a v5e's 128 MiB)."""
+
+    @pytest.mark.parametrize(
+        "t_q,d,dtype,sinks,fused",
+        [
+            # The cells' calls; Kanana's is the largest: 16 MiB resident.
+            (8192, 192, jnp.bfloat16, 0, True),
+            (4096, 128, jnp.bfloat16, 0, True),
+            (2048, 128, jnp.bfloat16, 0, True),
+            # Both sides of half the VMEM: 8 bytes a padded lane and row
+            # in bf16 (float32 accumulator, two output buffers), 12 in f32.
+            (65536, 128, jnp.bfloat16, 0, True),
+            (65536, 192, jnp.bfloat16, 0, False),
+            (131072, 128, jnp.bfloat16, 0, False),
+            (32768, 128, jnp.float32, 0, True),
+            (65536, 128, jnp.float32, 0, False),
+            # The dQ sweep holds the sink tile; the k-anchored one does not.
+            (2048, 128, jnp.bfloat16, 64, False),
+        ],
+    )
+    def test_predicate_on_both_sides_of_its_budget(self, t_q, d, dtype,
+                                                   sinks, fused):
+        assert fa.fused_backward(t_q, d, dtype, sinks=sinks) is fused
+        assert fa._resident_dq_bytes(8192, 192, jnp.bfloat16) == 2 ** 24
+
+    @staticmethod
+    def _trace(**kwargs):
+        """(flash kernel names in a lowered forward + backward, the gauge
+        `hvt_flash_backward` by impl) of one toy call."""
+        import re
+
+        from horovod_tpu.obs import core as obs_core
+        from horovod_tpu.obs import prom
+
+        obs_core.reset()
+        q = jnp.ones((1, 96, 2, 16), jnp.float32)
+
+        def loss(q, k, v):
+            return flash_attention(
+                q, k, v, causal=True, block_q=32, block_k=32, **kwargs).sum()
+
+        text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+            q, q, q).as_text(debug_info=True)
+        names = {
+            kernel for loc in re.findall(r'loc\("([^"]+)"', text)
+            for kernel in re.findall(r"hvt_flash_[a-z]+", loc)}
+        values = prom.parse_text(prom.render())
+        gauge = {impl: values[f'hvt_flash_backward{{impl="{impl}"}}']
+                 for impl in ("fused", "split")}
+        obs_core.reset()
+        return names, gauge
+
+    def test_a_call_inside_the_budget_is_one_kernel(self):
+        from horovod_tpu.obs import core as obs_core
+
+        assert obs_core.spec("hvt_flash_backward").labels == ("impl",)
+        names, gauge = self._trace()
+        assert names == {fa.KERNEL_FWD, fa.KERNEL_BWD}
+        assert gauge == {"fused": 1.0, "split": 0.0}
+
+    def test_a_shape_past_the_budget_takes_the_two_kernels(self, monkeypatch):
+        # A chip whose VMEM the toy call's resident dQ (96 rows × 128 lanes
+        # × 12 bytes) does not fit twice.
+        monkeypatch.setattr(
+            fa, "_chip_vmem_bytes", lambda: 2 * 96 * 128 * 12 - 2)
+        names, gauge = self._trace()
+        assert names == {fa.KERNEL_FWD, fa.KERNEL_DQ, fa.KERNEL_DKV}
+        assert gauge == {"fused": 0.0, "split": 1.0}
+
+    def test_sinks_take_the_two_kernels(self):
+        names, gauge = self._trace(window=40, sinks=8)
+        assert names == {fa.KERNEL_FWD, fa.KERNEL_DQ, fa.KERNEL_DKV}
+        assert gauge == {"fused": 0.0, "split": 1.0}

@@ -69,3 +69,28 @@ def test_cut_finds_the_branches_of_a_grid_step(helper, tmp_path):
     # five bundles, the branch itself.
     first_when = next(i for i, p in enumerate(pieces) if p[1] == "when")
     assert sum(b - a + 1 for _, _, a, b in pieces[:first_when]) == 2 + 5 + 1
+
+
+def test_a_kernel_the_call_does_not_run_is_said_to_be_absent(
+        helper, tmp_path, capsys):
+    """A dump of a call whose backward is the one kernel: the forward and
+    `hvt_flash_bwd` are reported, the two-kernel form's names are absent,
+    and that is no error. A dump with no kernel at all is a failed compile."""
+    assert helper.KERNELS == (
+        "hvt_flash_fwd", "hvt_flash_bwd", "hvt_flash_dq", "hvt_flash_dkv")
+    helper.report_dump(str(tmp_path))
+    assert "no schedule dumped" in capsys.readouterr().out
+    for kernel, body in (("hvt_flash_fwd", 40), ("hvt_flash_bwd", 70)):
+        stem = tmp_path / f"123-{kernel}.1"
+        text = schedule([("step", 5), ("when", body, 44), ("step", 2)])
+        (tmp_path / f"{stem.name}-71-final_bundles.txt").write_text(text)
+        rows = "\n".join("1 0 2 0 0 0 0" for _ in text.splitlines())
+        (tmp_path / f"{stem.name}-69-final-utilization.txt").write_text(
+            "slots\nMXU, XLU, VALU, EUP, VLOAD, VLOAD:FILL, VSTORE\n"
+            "== UTILIZATION:\n" + rows + "\n")
+    helper.report_dump(str(tmp_path))
+    out = capsys.readouterr().out
+    assert "hvt_flash_fwd.1:" in out and "hvt_flash_bwd.1:" in out
+    assert f"{'. . when':<12}{70:>8}" in out
+    assert "hvt_flash_dq: absent" in out and "hvt_flash_dkv: absent" in out
+    assert "no schedule dumped" not in out

@@ -153,7 +153,7 @@ def test_the_compiled_step_names_the_layers_forward_and_backward():
     assert any("Block_0/mixer" in n and hybrid.GQA_SCOPE in n for n in names)
     assert not any("Block_0/mixer" in n and hybrid.KDA_SCOPE in n
                    for n in names)
-    for kernel in ("hvt_flash_fwd", "hvt_flash_dq", "hvt_flash_dkv"):
+    for kernel in ("hvt_flash_fwd", "hvt_flash_bwd"):
         assert kernel in text
 
 

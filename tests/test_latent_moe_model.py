@@ -167,8 +167,7 @@ def test_the_compiled_step_names_the_layers_forward_and_backward():
         assert forward and backward, scope
     assert any("hvt.moe/dispatch" in n and "sort" in n for n in names)
     assert any("hvt.moe/combine" in n and "scatter" in n for n in names)
-    for kernel in (gm.KERNEL, gm.KERNEL_DW, "hvt_flash_fwd", "hvt_flash_dq",
-                   "hvt_flash_dkv"):
+    for kernel in (gm.KERNEL, gm.KERNEL_DW, "hvt_flash_fwd", "hvt_flash_bwd"):
         assert any(re.search(rf"{kernel}(\)|/|$)", n) for n in names), kernel
     # the flash kernels sit outside hvt.mla: its metric is the projections
     assert not any("hvt.mla" in n and "hvt_flash" in n for n in names)
