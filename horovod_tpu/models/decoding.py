@@ -100,7 +100,9 @@ def require_decode_path(model) -> None:
             f"{type(model).__name__} has no decode path: its layers keep no "
             "cache (for LatentMoELM a compressed latent cache, ROADMAP R2; "
             "for HybridMoELM a recurrent state beside the keys and values "
-            "and a decode step for its DeltaAttention layers, ROADMAP R8); "
+            "and a decode step for its DeltaAttention layers, and for its "
+            "StateSpaceMixer layers a state and a convolution's tail, "
+            "ROADMAP R8); "
             "generation, beam search and speculative decoding take a "
             "TransformerLM")
 

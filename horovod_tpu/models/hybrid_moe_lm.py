@@ -1,15 +1,26 @@
-"""A hybrid causal LM: delta-rule linear attention and gated softmax
-attention over routed experts (Solar-Open2 / Kimi-Linear style).
+"""A hybrid causal LM: delta-rule linear attention, state-space layers
+and softmax attention over routed experts (Solar-Open2 / Kimi-Linear and
+Granite-4.0-H style).
 
 The stack takes its layer kinds as DATA: ``layer_kinds`` is a tuple with
-one of `LINEAR` / `SOFTMAX` a layer (three linear layers to one softmax
-layer in the published model). Every layer is pre-norm RMSNorm around a
-token mixer and around a routed expert layer with a shared expert
-(`models/moe.py` `RoutedExperts`); a final RMSNorm and an untied head; no
-biases in the projections and NO positions anywhere (the recurrence and
-the causal mask order the tokens). Layer, for a token's vector h::
+one of `LINEAR` / `SOFTMAX` / `SSM` a layer (three linear layers to one
+softmax layer in Solar-Open2; nine state-space layers to one softmax layer
+in Granite-4.0-H). Every layer is pre-norm RMSNorm around a token mixer and
+around a routed expert layer with a shared expert (`models/moe.py`
+`RoutedExperts`, its gate's scoring as data); a final RMSNorm and a head,
+its own or TIED to the embedding's table (``tied_head``); no biases in the
+projections and NO positions anywhere (the recurrences and the causal mask
+order the tokens). With the three multipliers (all 1 unless given), for a
+token's vector::
 
-    x += Mixer_i(RMSNorm(x));   x += MoE(RMSNorm(x))
+    x_0 = embedding_multiplier * Embed[token]
+    x += residual_multiplier * Mixer_i(RMSNorm(x))
+    x += residual_multiplier * MoE(RMSNorm(x))
+    logits = (RMSNorm(x_L) . W_head) / logits_divisor
+
+With ``remat`` every block is rematerialised in the backward pass
+(`nn.remat(HybridBlock)`): a block keeps its input only and its forward
+runs twice a step.
 
 `DeltaAttention` (``linear``; Kimi Delta Attention, arXiv:2510.26692), a
 head, over t, with S [Dk, Dv] float32 from zero::
@@ -23,33 +34,52 @@ head, over t, with S [Dk, Dv] float32 from zero::
     o_t = S_t^T q_t                        (ops/delta_rule.py, chunked)
     y_t = W_o [ RMSNorm_head(o_t) * sigmoid(W_gb (W_ga h_t)) ]
 
-`GatedAttention` (``softmax``): grouped-query causal softmax attention
-through the flash kernel with no rotary and no QK-norm, its output gated
-elementwise before W_o (arXiv:2505.06708): ``y = W_o [attn *
-sigmoid(W_g h)]``.
+`StateSpaceMixer` (``ssm``; Mamba-2, arXiv:2405.21060, its sizes one
+`StateSpaceSizes`), H heads of P channels over ONE group's B and C of N,
+with S [P, N] float32 a head from zero::
 
-**One chip's share of a deployment is a parameter of the model.** Both
+    z, x, (B | C), dt_raw = W_z h, W_x h, W_bc h, W_dt h
+    x, (B | C) = SiLU(conv(x) + b), SiLU(conv(B | C) + b)     taps and a bias
+    dt_t = softplus(dt_raw_t + dt_bias);   a_t = exp(-dt_t exp(A_log))
+    S_t = a_t S_{t-1} + dt_t x_t B_t^T;   y_t = S_t C_t + D x_t
+                                           (ops/ssd.py, chunked)
+    out = W_o [ RMSNorm_{all H P channels}(y * SiLU(z)) * w ]  gate, THEN norm
+
+`GatedAttention` (``softmax``): grouped-query causal softmax attention
+through the flash kernel with no rotary and no QK-norm; its output gated
+elementwise before W_o (arXiv:2505.06708: ``y = W_o [attn * sigmoid(W_g
+h)]``) unless ``softmax_gate`` is off, its scores scaled by D^-1/2 or by
+``softmax_scale`` where that is given.
+
+**One chip's share of a deployment is a parameter of the model.** The
 mixers are told which heads they hold (``n_held_heads`` from
-``held_heads_start``, of ``n_heads``): they carry those heads' parameters
-only and return those heads' rows of W_o times their outputs, the partial
-sum a tensor-parallel group would add up (what all heads share, the two
-low-rank input projections and the head norm's scale, is held whole by
-every chip). A softmax layer holds the K/V heads its query heads read.
-The experts are told the same way (``n_held`` from ``held_start``), and
-the vocabulary's rows held are simply ``vocab_size``. Nothing here stands
-in for the absent chips.
+``held_heads_start``, of ``n_heads``; the state-space kind in its own
+sizes): they carry those heads' parameters only and return those heads'
+rows of W_o times their outputs, the partial sum a tensor-parallel group
+would add up (what all heads share, KDA's two low-rank input projections
+and head norm's scale, the state-space layer's B and C projections with
+their taps, is held whole by every chip). A softmax layer holds the K/V
+heads its query heads read. The state-space layer's gated norm is over ALL
+the layer's channels, the one exchange inside a layer: with
+``heads_axis`` named the sum of squares is `lax.psum`'d over it (under a
+`shard_map` or `vmap` that binds the name) and divided by all ``n_heads *
+head_dim`` channels; not named, it is over the channels held. The experts
+are told the same way (``n_held`` from ``held_start``), and the
+vocabulary's rows held are simply ``vocab_size``. Nothing here stands in
+for the absent chips.
 
 The model keeps the `Trainer(loss='module')` contract of `TransformerLM`:
 ``apply(tokens, train=, labels=)`` returns per-token ``(loss, correct)``
 from the chunked head + CE (`LMHead.fused_loss`), without labels the
-logits. It has no decode path (a recurrent state beside the keys and
-values in the cache manager: ROADMAP R8) and `PipelinedLM` does not know
-its layers (ROADMAP D1); both refuse by name. It runs on one chip: a mesh
-of more is refused by name (ROADMAP R1).
+logits. It has no decode path (a recurrent state, and a convolution's tail,
+beside the keys and values in the cache manager: ROADMAP R8) and
+`PipelinedLM` does not know its layers (ROADMAP D1); both refuse by name.
+It runs on one chip: a mesh of more is refused by name (ROADMAP R1).
 """
 
 from __future__ import annotations
 
+import dataclasses
 import functools
 import math
 
@@ -60,10 +90,10 @@ from jax.sharding import PartitionSpec as P
 
 from horovod_tpu.models.moe import RoutedExperts
 from horovod_tpu.models.transformer import BATCH_AXES, LMHead, ShardingConfig
-from horovod_tpu.ops import delta_rule
+from horovod_tpu.ops import delta_rule, ssd
 from horovod_tpu.ops.flash_attention import flash_attention
 
-LINEAR, SOFTMAX = "linear", "softmax"
+LINEAR, SOFTMAX, SSM = "linear", "softmax", "ssm"
 # By name in the compiled step, forward and backward (chipbench/
 # kda_spans.py): the linear layer's four parts, and everything of the
 # softmax layer but the flash kernel, whose events are found by its names.
@@ -71,14 +101,19 @@ KDA_SCOPE = "hvt.kda"
 KDA_PROJ, KDA_CONV = f"{KDA_SCOPE}/proj", f"{KDA_SCOPE}/conv"
 KDA_SCAN, KDA_OUT = f"{KDA_SCOPE}/scan", f"{KDA_SCOPE}/out"
 GQA_SCOPE = "hvt.gqa"
+# ... and the state-space layer's four (chipbench/ssm_spans.py).
+SSM_SCOPE = "hvt.ssm"
+SSM_PROJ, SSM_CONV = f"{SSM_SCOPE}/proj", f"{SSM_SCOPE}/conv"
+SSM_SCAN, SSM_OUT = f"{SSM_SCOPE}/scan", f"{SSM_SCOPE}/out"
 
 
 def short_conv(x, taps):
-    """Causal depthwise convolution of ``x [B, T, H, D]`` with ``taps [K,
-    H, D]``: ``y_t = sum_j taps[j] x_{t-(K-1)+j}``, the last tap on the
+    """Causal depthwise convolution of ``x [B, T, ...]`` with ``taps [K,
+    ...]``: ``y_t = sum_j taps[j] x_{t-(K-1)+j}``, the last tap on the
     current position, zeros before the sequence's start."""
     size = taps.shape[0]
-    padded = jnp.pad(x, ((0, 0), (size - 1, 0), (0, 0), (0, 0)))
+    padded = jnp.pad(
+        x, ((0, 0), (size - 1, 0)) + ((0, 0),) * (x.ndim - 2))
     t = x.shape[1]
     return sum(padded[:, j:j + t] * taps[j].astype(x.dtype)
                for j in range(size))
@@ -205,9 +240,110 @@ class DeltaAttention(nn.Module):
             return project_out(out, kernel.astype(cd))
 
 
+def time_step(raw, dt_bias):
+    """``dt = softplus(dt_raw + dt_bias)``: float32 ``[B, T, H]``, above
+    zero, one step a head."""
+    return jax.nn.softplus(raw.astype(jnp.float32) + dt_bias)
+
+
+def split_b_c(b_c):
+    """``(B, C)``, each ``[B, T, N]``, of the one group's ``[B, T, 2 N]``
+    projection: B, what a position writes along, first."""
+    return tuple(jnp.split(b_c, 2, axis=-1))
+
+
+def gated_norm(y, z, scale, eps, *, heads_axis, n_channels):
+    """``RMSNorm(y * SiLU(z)) * scale`` over ALL the layer's channels, the
+    gate BEFORE the norm: float32 ``[B, T, H, P]`` for the held heads' ``y``
+    and ``z``. The mean square is the one quantity a head-split layer
+    exchanges: with ``heads_axis`` the held channels' sum of squares is
+    summed over that axis; ``n_channels`` divides it."""
+    gated = y.astype(jnp.float32) * nn.silu(z.astype(jnp.float32))
+    squares = jnp.sum(gated * gated, axis=(-2, -1), keepdims=True)
+    if heads_axis is not None:
+        squares = jax.lax.psum(squares, heads_axis)
+    return gated * jax.lax.rsqrt(squares / n_channels + eps) * scale
+
+
+@dataclasses.dataclass(frozen=True)
+class StateSpaceSizes:
+    """The sizes of the state-space kind (Mamba-2's names in brackets)."""
+
+    n_heads: int           # of the whole layer (mamba_n_heads) ...
+    n_held_heads: int      # ... and the block of them held here ...
+    held_heads_start: int  # ... from this head
+    head_dim: int          # P (mamba_d_head)
+    state_dim: int         # N (mamba_d_state); one group of B and C
+    conv_size: int         # taps a channel (mamba_d_conv), with a bias
+    chunk: int             # positions a chunk of ops/ssd.py's scan
+    # The mesh or `vmap` axis the layer's heads are split over, where the
+    # gated norm's sum of squares is added up; None: over the heads held.
+    heads_axis: str | None = None
+
+
+class StateSpaceMixer(nn.Module):
+    """The held heads of one Mamba-2 layer, ``[B, T, d] -> [B, T, d]``."""
+
+    sizes: StateSpaceSizes
+    eps: float
+    compute_dtype: jnp.dtype
+
+    @nn.compact
+    def __call__(self, x):
+        s, cd = self.sizes, self.compute_dtype
+        _check_held("StateSpaceMixer", s.n_held_heads, s.held_heads_start,
+                    s.n_heads)
+        from horovod_tpu import obs
+
+        obs.gauge("hvt_held_heads", float(s.n_held_heads), mixer=SSM)
+        obs.gauge("hvt_ssd_chunks",
+                  float(ssd.n_chunks(x.shape[1], s.chunk)))
+        obs.gauge("hvt_ssd_scan", 1.0, impl="xla")
+        held, dim, n = s.n_held_heads, s.head_dim, s.state_dim
+        dense = functools.partial(nn.DenseGeneral, use_bias=False, dtype=cd)
+        spread = s.conv_size ** -0.5  # the taps' as KDA's; the bias within it
+        taps_init = nn.initializers.normal(spread)
+        bias_init = functools.partial(
+            jax.random.uniform, minval=-spread, maxval=spread)
+        with jax.named_scope(SSM_PROJ):
+            z = dense((held, dim), name="z_proj")(x)
+            inner = dense((held, dim), name="x_proj")(x)
+            # One group: every head, on every chip of a group, reads these.
+            b_c = dense(2 * n, name="bc_proj")(x)
+            a_log = self.param("A_log", _a_log_init, (held,))
+            dt = time_step(
+                dense(held, name="dt_proj")(x),
+                self.param("dt_bias", _dt_bias_init, (held,)))
+        with jax.named_scope(SSM_CONV):
+            def conv(a, name):
+                width = a.shape[2:]
+                taps = self.param(
+                    f"{name}_conv", taps_init, (s.conv_size,) + width)
+                bias = self.param(f"{name}_conv_bias", bias_init, width)
+                return nn.silu(short_conv(a, taps) + bias.astype(cd))
+
+            inner, (b, c) = conv(inner, "x"), split_b_c(conv(b_c, "bc"))
+        with jax.named_scope(SSM_SCAN):
+            y = ssd.ssd_scan(inner, dt, a_log, b, c, chunk=s.chunk)
+        with jax.named_scope(SSM_OUT):
+            skip = self.param("D", nn.initializers.ones, (held,))
+            y = y + skip[:, None] * inner.astype(jnp.float32)
+            y = gated_norm(
+                y, z, self.param("norm", nn.initializers.ones, (held, dim)),
+                self.eps, heads_axis=s.heads_axis,
+                n_channels=dim * (
+                    held if s.heads_axis is None else s.n_heads))
+            kernel = self.param(
+                "o_proj", nn.initializers.lecun_normal(in_axis=(0, 1)),
+                (held, dim, x.shape[-1]))
+            return project_out(y.astype(cd), kernel.astype(cd))
+
+
 class GatedAttention(nn.Module):
     """The held query heads of one softmax layer with the K/V heads they
-    read, ``[B, T, d] -> [B, T, d]``: no positions, the output gated."""
+    read, ``[B, T, d] -> [B, T, d]``: no positions; the output gated unless
+    ``gate`` is off; the scores scaled by ``scale`` where given, else by
+    ``head_dim ** -0.5``."""
 
     n_heads: int
     n_kv_heads: int
@@ -215,6 +351,8 @@ class GatedAttention(nn.Module):
     held_heads_start: int
     head_dim: int
     compute_dtype: jnp.dtype
+    gate: bool = True
+    scale: float | None = None
 
     @nn.compact
     def __call__(self, x):
@@ -237,31 +375,47 @@ class GatedAttention(nn.Module):
             q = dense((held, dim), name="q_proj")(x)
             k, v = (dense((held // group, dim), name=f"{n}_proj")(x)
                     for n in "kv")
-            gate_in = dense((held, dim), name="g_proj")(x)
+            if self.gate:
+                gate_in = dense((held, dim), name="g_proj")(x)
+            if self.scale is not None:
+                # The kernel scales by D^-1/2: the rest rides on q.
+                q = q * jnp.asarray(self.scale * dim ** 0.5, cd)
             k, v = (jnp.repeat(a, group, axis=2) for a in (k, v))
         out = flash_attention(q, k, v, causal=True)
         with jax.named_scope(GQA_SCOPE):
             kernel = self.param(
                 "o_proj", nn.initializers.lecun_normal(in_axis=(0, 1)),
                 (held, dim, x.shape[-1]))
-            return project_out(_gated(out, gate_in), kernel.astype(cd))
+            if self.gate:
+                out = _gated(out, gate_in)
+            return project_out(out, kernel.astype(cd))
+
+
+def residual(x, out, multiplier):
+    """``x + multiplier * out`` (the multiplier 1: ``x + out``)."""
+    return x + (out if multiplier == 1 else out * jnp.asarray(
+        multiplier, out.dtype))
 
 
 class HybridBlock(nn.Module):
-    """``x += mixer(norm(x)); x += mlp(norm(x))`` with both given (unbound:
-    they are adopted here under the names ``mixer`` and ``mlp``)."""
+    """``x += m mixer(norm(x)); x += m mlp(norm(x))`` with both given
+    (unbound: they are adopted here under the names ``mixer`` and
+    ``mlp``) and ``m`` the residual multiplier."""
 
     mixer: nn.Module
     mlp: nn.Module
     eps: float
     compute_dtype: jnp.dtype
+    residual_multiplier: float = 1.0
 
     @nn.compact
     def __call__(self, x):
         norm = functools.partial(
             nn.RMSNorm, epsilon=self.eps, dtype=self.compute_dtype)
-        x = x + self.mixer(norm(name="mixer_norm")(x))
-        return x + self.mlp(norm(name="mlp_norm")(x))
+        x = residual(x, self.mixer(norm(name="mixer_norm")(x)),
+                     self.residual_multiplier)
+        return residual(x, self.mlp(norm(name="mlp_norm")(x)),
+                        self.residual_multiplier)
 
 
 class HybridMoELM(nn.Module):
@@ -270,14 +424,14 @@ class HybridMoELM(nn.Module):
 
     vocab_size: int
     d_model: int
-    layer_kinds: tuple    # LINEAR / SOFTMAX, one a layer
-    head_dim: int
+    layer_kinds: tuple    # LINEAR / SOFTMAX / SSM, one a layer
+    head_dim: int         # of the linear and the softmax kinds
     linear_heads: int     # of the whole layer ...
     softmax_heads: int
     softmax_kv_heads: int
     n_held_heads: int     # ... and the block of them held here, both mixers
     held_heads_start: int
-    conv_size: int
+    conv_size: int        # the linear kind's
     low_rank: int
     kda_chunk: int
     n_routed: int         # the router's width
@@ -291,6 +445,15 @@ class HybridMoELM(nn.Module):
     compute_dtype: jnp.dtype
     fused_head_chunks: int
     sharding: ShardingConfig = ShardingConfig()
+    ssm: StateSpaceSizes | None = None  # the state-space kind's sizes
+    softmax_gate: bool = True
+    softmax_scale: float | None = None  # None: head_dim ** -0.5
+    moe_scoring: str = "sigmoid"        # `RoutedExperts.scoring`
+    residual_multiplier: float = 1.0
+    embedding_multiplier: float = 1.0
+    logits_divisor: float = 1.0
+    tied_head: bool = False  # the head reads the embedding's table
+    remat: bool = False      # every block rematerialised in the backward pass
 
     @nn.compact
     def __call__(self, tokens, *, train: bool = False, labels=None):
@@ -301,43 +464,63 @@ class HybridMoELM(nn.Module):
                 f"HybridMoELM on a mesh of {cfg.mesh.size} chips "
                 f"({dict(cfg.mesh.shape)}): its layers run on one chip "
                 "(ROADMAP R1, R8)")
-        unknown = sorted(set(self.layer_kinds) - {LINEAR, SOFTMAX})
+        unknown = sorted(set(self.layer_kinds) - {LINEAR, SOFTMAX, SSM})
         if unknown or not self.layer_kinds:
             raise ValueError(
                 f"layer_kinds {self.layer_kinds!r}: a layer is {LINEAR!r} "
-                f"or {SOFTMAX!r}")
+                f"or {SOFTMAX!r} or {SSM!r}")
+        if SSM in self.layer_kinds and self.ssm is None:
+            raise ValueError(
+                f"layer_kinds holds {SSM!r} and `ssm`, the kind's "
+                "`StateSpaceSizes`, is not given")
         from horovod_tpu import obs
 
-        for kind in (LINEAR, SOFTMAX):
+        for kind in (LINEAR, SOFTMAX, SSM):
             obs.gauge("hvt_layer_kinds",
                       float(self.layer_kinds.count(kind)), kind=kind)
-        x = nn.Embed(self.vocab_size, self.d_model, dtype=cd, name="embed")(
-            tokens)
+        obs.gauge("hvt_remat_blocks",
+                  float(len(self.layer_kinds) if self.remat else 0))
+        obs.gauge("hvt_tied_head", float(self.tied_head))
+        embed = nn.Embed(self.vocab_size, self.d_model, dtype=cd, name="embed")
+        x = embed(tokens)
+        if self.embedding_multiplier != 1:
+            x = x * jnp.asarray(self.embedding_multiplier, cd)
         x = cfg.constrain(x, P(BATCH_AXES, None, None))
+        block = nn.remat(HybridBlock) if self.remat else HybridBlock
         for i, kind in enumerate(self.layer_kinds):
             if kind == LINEAR:
                 mixer = DeltaAttention(
                     self.linear_heads, self.n_held_heads,
                     self.held_heads_start, self.head_dim, self.conv_size,
                     self.low_rank, self.eps, self.kda_chunk, cd, parent=None)
+            elif kind == SSM:
+                mixer = StateSpaceMixer(self.ssm, self.eps, cd, parent=None)
             else:
                 mixer = GatedAttention(
                     self.softmax_heads, self.softmax_kv_heads,
                     self.n_held_heads, self.held_heads_start, self.head_dim,
-                    cd, parent=None)
+                    cd, gate=self.softmax_gate, scale=self.softmax_scale,
+                    parent=None)
             mlp = RoutedExperts(
                 n_routed=self.n_routed, k=self.experts_per_token,
                 expert_width=self.expert_width,
                 shared_width=self.shared_width, n_held=self.n_held,
                 held_start=self.held_start,
                 routed_scaling=self.routed_scaling, compute_dtype=cd,
-                sharding=cfg, parent=None)
-            x = HybridBlock(mixer, mlp, self.eps, cd, name=f"Block_{i}")(x)
+                sharding=cfg, scoring=self.moe_scoring, parent=None)
+            x = block(mixer, mlp, self.eps, cd, self.residual_multiplier,
+                      name=f"Block_{i}")(x)
             x = cfg.constrain(x, P(BATCH_AXES, None, None))
         x = nn.RMSNorm(epsilon=self.eps, dtype=cd, name="final_norm")(x)
+        if self.logits_divisor != 1:
+            # On the head's input: the head is linear, and the logits are
+            # never formed whole.
+            x = x * jnp.asarray(1.0 / self.logits_divisor, cd)
         head = LMHead(
             self.d_model, self.vocab_size, compute_dtype=cd, sharding=cfg,
-            name="lm_head")
+            tied=self.tied_head, name="lm_head")
+        table = embed.embedding if self.tied_head else None
         if labels is not None:
-            return head.fused_loss(x, labels, self.fused_head_chunks)
-        return head(x)
+            return head.fused_loss(
+                x, labels, self.fused_head_chunks, table=table)
+        return head(x, table=table)

@@ -5,13 +5,14 @@
   DROPS what overflows, GELU experts at 4x. What `TransformerLM(moe_every=)`
   and `PipelinedLM` build; its all-to-all is the partitioner's.
 * `RoutedExperts` (at the end): one chip's share of a DeepSeek-V3-style
-  layer: sigmoid scores over all the published experts, top-k with a
+  layer: scores over all the published experts (sigmoid, or the softmax
+  over the chosen logits: `RoutedExperts.scoring`), top-k with a
   selection bias that levels each sequence's loads (`level_bias`: the layer
   carries no balancing state), gates normalised over the chosen and scaled, the
   (token, choice) pairs that fall on the experts HELD HERE sorted by expert
   and run through a grouped matmul with ragged group sizes (no token
   dropped, no one-hot dispatch), SwiGLU experts and a shared expert. What
-  `models/latent_moe_lm.py` builds. On one chip there is no exchange; the
+  `models/latent_moe_lm.py` and `models/hybrid_moe_lm.py` build. On one chip there is no exchange; the
   experts across chips with their all-to-all are ROADMAP R1.
 
 Mixture-of-Experts MLP with expert parallelism over the ``expert`` axis.
@@ -318,30 +319,44 @@ def level_bias(logits, k: int):
     return -jnp.sort(logits, axis=0)[t - above]
 
 
-def _route(tokens, router, *, k, scale):
+SIGMOID, SOFTMAX = "sigmoid", "softmax"
+
+
+def _gates(logits, chosen, *, scoring, scale):
+    """``[B, T, k]`` float32: the chosen experts' scores normalised over
+    all ``k`` (held here or not) and scaled. ``"sigmoid"``: the logits'
+    sigmoids (DeepSeek-V3). ``"softmax"``: the softmax over the CHOSEN
+    logits, ``exp(logit - the token's largest chosen logit)`` normalised."""
+    values = jax.nn.sigmoid(logits) if scoring == SIGMOID else logits
+    # The chosen values by a mask, not `take_along_axis`: its gather and
+    # the scatter that is its transpose took 0.8 ms a layer on the v5e, and
+    # the scatter reaches the trace with no scope.
+    hot = chosen[..., None] == jnp.arange(logits.shape[-1])  # [B, T, k, E]
+    picked = jnp.sum(jnp.where(hot, values[..., None, :], 0.0), axis=-1)
+    if scoring == SOFTMAX:
+        picked = jnp.exp(picked - jax.lax.stop_gradient(
+            picked.max(-1, keepdims=True)))
+    return picked / (picked.sum(-1, keepdims=True) + 1e-20) * scale
+
+
+def _route(tokens, router, *, k, scale, scoring=SIGMOID):
     """``(chosen [B, T, k] int32, gates [B, T, k] float32)`` for tokens
     ``[B, T, d]``: the router's logits over ALL the experts in float32, the
     top ``k`` of logit + selection bias (`level_bias`, a sequence at a
-    time), and the chosen experts' sigmoid scores normalised over all ``k``
-    (held here or not) and scaled."""
+    time), and the chosen experts' gates (`_gates`: scores without the
+    bias)."""
     logits = jnp.dot(tokens.astype(jnp.float32), router,
                      precision=jax.lax.Precision.HIGHEST)
     # Selection is not differentiable: the bias is a constant of the step.
     bias = jax.vmap(lambda one: level_bias(one, k))(logits)
     _, chosen = jax.lax.top_k(
         jax.lax.stop_gradient(logits + bias[:, None, :]), k)
-    scores = jax.nn.sigmoid(logits)
-    # The chosen scores by a mask, not `take_along_axis`: its gather and
-    # the scatter that is its transpose took 0.8 ms a layer on the v5e, and
-    # the scatter reaches the trace with no scope.
-    hot = chosen[..., None] == jnp.arange(scores.shape[-1])  # [B, T, k, E]
-    picked = jnp.sum(jnp.where(hot, scores[..., None, :], 0.0), axis=-1)
-    gates = picked / (picked.sum(-1, keepdims=True) + 1e-20) * scale
+    gates = _gates(logits, chosen, scoring=scoring, scale=scale)
     return chosen.astype(jnp.int32), gates
 
 
 def _held_experts(x, router, w_gate_up, w_down, *, k, scale, held_start,
-                  budget, compute_dtype):
+                  budget, compute_dtype, scoring=SIGMOID):
     """The held experts' part of the layer's output for ``x`` [B, T, d],
     and the three counts. The (token, choice) pairs that fall on experts
     ``held_start .. held_start + n_held`` are sorted by expert, the first
@@ -352,7 +367,7 @@ def _held_experts(x, router, w_gate_up, w_down, *, k, scale, held_start,
     n = x.size // d
     n_held = w_down.shape[0]
     with jax.named_scope(ROUTE):
-        chosen, gates = _route(x, router, k=k, scale=scale)
+        chosen, gates = _route(x, router, k=k, scale=scale, scoring=scoring)
     tokens = x.reshape(n, d)
     with jax.named_scope(DISPATCH):
         local = chosen.reshape(-1) - held_start
@@ -424,6 +439,12 @@ class RoutedExperts(nn.Module):
     ``moe_load_max_over_mean`` (the fullest held expert's rows over the
     mean). The gauge ``hvt_moe_experts{kind}`` says at trace time how many
     experts are held and routed.
+
+    ``scoring`` is how the chosen experts' gates are scored (`_gates`; the
+    gauge ``hvt_moe_gate{scoring}``): ``"sigmoid"``, the logits' sigmoids
+    normalised over the chosen (DeepSeek-V3), or ``"softmax"``, the softmax
+    over the chosen logits (Granite-4.0: the gates add up to the scale).
+    The selection, and its bias, are the same either way.
     """
 
     n_routed: int
@@ -435,11 +456,16 @@ class RoutedExperts(nn.Module):
     routed_scaling: float
     compute_dtype: jnp.dtype = jnp.float32
     sharding: object = None
+    scoring: str = SIGMOID  # or SOFTMAX: over the chosen logits (`_gates`)
 
     @nn.compact
     @jax.named_scope(SCOPE)
     def __call__(self, x):
         b, t, d = x.shape
+        if self.scoring not in (SIGMOID, SOFTMAX):
+            raise ValueError(
+                f"RoutedExperts: scoring {self.scoring!r} is neither "
+                f"{SIGMOID!r} nor {SOFTMAX!r}")
         if not 0 <= self.held_start <= self.n_routed - self.n_held:
             raise ValueError(
                 f"experts {self.held_start}.."
@@ -456,6 +482,7 @@ class RoutedExperts(nn.Module):
 
         obs.gauge("hvt_moe_experts", float(self.n_held), kind="held")
         obs.gauge("hvt_moe_experts", float(self.n_routed), kind="routed")
+        obs.gauge("hvt_moe_gate", 1.0, scoring=self.scoring)
 
         router = self.param(
             "router", nn.initializers.lecun_normal(), (d, self.n_routed))
@@ -472,7 +499,8 @@ class RoutedExperts(nn.Module):
         mixed, stats = _held_experts(
             x, router, w_gate_up, w_down, k=self.k,
             scale=self.routed_scaling, held_start=self.held_start,
-            budget=budget, compute_dtype=self.compute_dtype)
+            budget=budget, compute_dtype=self.compute_dtype,
+            scoring=self.scoring)
         for name, value in stats.items():
             self.sow("metrics", name, value)
         with jax.named_scope(SHARED):

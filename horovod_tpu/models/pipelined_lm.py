@@ -135,7 +135,8 @@ class PipelinedLM(nn.Module):
                 f"mlp must be 'dense' or 'moe', got {self.mlp!r} (the "
                 "pipeline stacks one homogeneous block: RoutedExperts, "
                 "SwiGLU and latent attention, models/latent_moe_lm.py, and "
-                "DeltaAttention, GatedAttention, models/hybrid_moe_lm.py, "
+                "DeltaAttention, GatedAttention, StateSpaceMixer, "
+                "models/hybrid_moe_lm.py, "
                 "whose stages would be of unequal cost, are not among its "
                 "layers; ROADMAP D1, R8)")
         moe = self.mlp == "moe"

@@ -666,7 +666,10 @@ class LMHead(nn.Module):
     """The LM head as an explicit ``[d_model, vocab]`` kernel (param path
     ``lm_head/kernel``, identical to the former DenseGeneral's) so the fused
     chunked-CE path (ops/fused_ce.py) can reach the kernel without
-    materializing full logits."""
+    materializing full logits. With ``tied`` it owns no parameter: the
+    model hands each call the embedding's ``[vocab, d_model]`` table and the
+    kernel is its transpose, so the table's gradient is the lookup's
+    scatter-add plus this head's dW."""
 
     d_model: int
     vocab_size: int
@@ -674,32 +677,44 @@ class LMHead(nn.Module):
     logits_dtype: jnp.dtype = jnp.float32
     int8_compute: bool = False
     sharding: ShardingConfig = ShardingConfig()
+    tied: bool = False
 
     def setup(self):
-        self.kernel = self.param(
-            "kernel",
-            nn.initializers.lecun_normal(),
-            (self.d_model, self.vocab_size),
-        )
+        if not self.tied:
+            self.kernel = self.param(
+                "kernel",
+                nn.initializers.lecun_normal(),
+                (self.d_model, self.vocab_size),
+            )
+
+    def _kernel_of(self, table):
+        """``[d_model, vocab]``: the head's own, or the table's transpose."""
+        if self.tied != (table is not None):
+            raise ValueError(
+                "LMHead: a tied head is handed the embedding's table at "
+                "each call, an untied one none "
+                f"(tied={self.tied}, table given: {table is not None})")
+        return table.T if self.tied else self.kernel
 
     @jax.named_scope(fused_ce.SCOPE)
-    def __call__(self, x):
+    def __call__(self, x, table=None):
+        kernel = self._kernel_of(table)
         if self.int8_compute:
             from horovod_tpu.models.quant import int8_dot_general
 
             logits = int8_dot_general(
                 x.astype(self.compute_dtype),
-                self.kernel.astype(self.compute_dtype),
+                kernel.astype(self.compute_dtype),
                 (((x.ndim - 1,), (0,)), ((), ())),
                 preferred_element_type=self.logits_dtype,
             )
             return logits
         logits = jnp.dot(
-            x.astype(self.compute_dtype), self.kernel.astype(self.compute_dtype)
+            x.astype(self.compute_dtype), kernel.astype(self.compute_dtype)
         )
         return logits.astype(self.logits_dtype)
 
-    def fused_loss(self, x, labels, n_chunks: int):
+    def fused_loss(self, x, labels, n_chunks: int, table=None):
         """(per-token loss, per-token correct) without full logits.
 
         Where the mesh splits the rows of ``x`` ``[B, T, D]`` (live
@@ -742,7 +757,8 @@ class LMHead(nn.Module):
                 out_specs=(rows, rows),
                 axis_names=frozenset(ROW_AXES), check_vma=False,
             )))
-        return head(x.astype(self.compute_dtype), self.kernel, labels)
+        return head(
+            x.astype(self.compute_dtype), self._kernel_of(table), labels)
 
 
 def _row_shards(mesh: Mesh, b: int, t: int) -> int:

@@ -354,15 +354,17 @@ METRICS: dict[str, MetricSpec] = _decl([
     MetricSpec("hvt_layer_kinds", "gauge",
                "Layers of the last HybridMoELM traced "
                "(models/hybrid_moe_lm.py), by their token mixer: `linear` "
-               "(delta-rule linear attention) and `softmax` (gated "
-               "grouped-query attention). Set at trace time: the stack's "
-               "kinds are data of the model, static per program.",
+               "(delta-rule linear attention), `softmax` (grouped-query "
+               "attention) and `ssm` (Mamba-2 state-space layers). Set at "
+               "trace time: the stack's kinds are data of the model, "
+               "static per program.",
                "training", labels=("kind",)),
     MetricSpec("hvt_held_heads", "gauge",
                "Heads this chip holds of the last mixer traced of each "
                "kind (models/hybrid_moe_lm.py DeltaAttention `linear`, "
-               "GatedAttention `softmax`): the layer returns their rows "
-               "of the output projection only. Set at trace time.",
+               "GatedAttention `softmax`, StateSpaceMixer `ssm`): the "
+               "layer returns their rows of the output projection only. "
+               "Set at trace time.",
                "training", labels=("mixer",)),
     MetricSpec("hvt_kda_chunks", "gauge",
                "Chunks a sequence is walked in by the last delta-rule "
@@ -378,6 +380,31 @@ METRICS: dict[str, MetricSpec] = _decl([
                "backward by autodiff). Set by `gated_delta_rule` at trace "
                "time: the choice is static per shape.",
                "training", labels=("impl",)),
+    MetricSpec("hvt_ssd_chunks", "gauge",
+               "Chunks a sequence is cut into by the last state-space "
+               "layer traced (ops/ssd.py: the steps of its one sequential "
+               "scan over the carried state). Set at trace time.",
+               "training"),
+    MetricSpec("hvt_ssd_scan", "gauge",
+               "Which form of the state-space scan the last layer traced "
+               "took (ops/ssd.py): 1 on it. `xla`: matmuls over all "
+               "chunks and one lax.scan, backward by autodiff, the only "
+               "form there is. Set at trace time.",
+               "training", labels=("impl",)),
+    MetricSpec("hvt_moe_gate", "gauge",
+               "How the last routed layer traced scores its chosen "
+               "experts (models/moe.py RoutedExperts.scoring): 1 on "
+               "`sigmoid` (the logits' sigmoids, normalised) or `softmax` "
+               "(the softmax over the chosen logits). Set at trace time.",
+               "training", labels=("scoring",)),
+    MetricSpec("hvt_remat_blocks", "gauge",
+               "Blocks of the last HybridMoELM traced that are "
+               "rematerialised in the backward pass (`remat`: all of them "
+               "or 0). Set at trace time.", "training"),
+    MetricSpec("hvt_tied_head", "gauge",
+               "1 where the last HybridMoELM traced reads its head's "
+               "kernel from the embedding's table (`tied_head`), else 0. "
+               "Set at trace time.", "training"),
     MetricSpec("hvt_optimizer_steps_total", "counter",
                "Optimizer steps this process's fit loops have handed to "
                "the device (counted in the loop, exporter on or off).",

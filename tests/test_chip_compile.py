@@ -729,3 +729,96 @@ def test_hybrid_moe_cell_step_fits_one_chip(topo, compiled_kernel):
     total = memory.argument_size_in_bytes + memory.temp_size_in_bytes
     print(f"hybrid cell step: arguments + temporaries {total / 1e9:.3f} GB")
     assert total < 15.0e9
+
+
+# --- the state-space / routed-expert model's scan and step ---------------------
+# `granite-4.0-h-small.seq4k.1chip` (PR 39): 16 held Mamba-2 heads of 64 with a
+# state of 128 over one sequence of 4,096 in chunks of 256; nine such layers
+# and one NoPE GQA layer over 72-way softmax routing onto 8 held experts of
+# 4,096 x 768, a tied head, every block rematerialised.
+
+@pytest.mark.parametrize("t,chunk,dtype", [
+    (4096, 256, jnp.bfloat16), (1000, 256, jnp.bfloat16),
+    (100, 256, jnp.float32), (8192, 128, jnp.bfloat16)],
+    ids=["the_cell", "t_1000", "under_one_chunk", "8k_in_chunks_of_128"])
+def test_ssd_scan_compiles_for_v5e(topo, t, chunk, dtype):
+    """The scan with autodiff's backward at the cell's head sizes, at the
+    cell's length and three others: it compiles, no Mosaic call is in it
+    (the form is XLA's), the one `while` left is the walk over the chunks'
+    states, forward and backward, and at the cell's length what it needs
+    beside its arguments stays under 0.7 GB (0.52 when it was written)."""
+    from horovod_tpu.ops import ssd
+
+    one_chip = SingleDeviceSharding(topo.devices[0])
+
+    def sds(shape, kind=dtype):
+        return SDS(shape, kind, sharding=one_chip)
+
+    def loss(x, dt, a_log, b, c):
+        with jax.named_scope("hvt.ssm/scan"):
+            y = ssd.ssd_scan(x, dt, a_log, b, c, chunk=chunk)
+            return jnp.square(y).sum()
+
+    compiled = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
+        sds((1, t, 16, 64)), sds((1, t, 16), jnp.float32),
+        sds((16,), jnp.float32), sds((1, t, 128)), sds((1, t, 128))).compile()
+    assert kernel_names(compiled) == []
+    trips = [n for n in while_trip_counts(compiled.as_text()) if n != 1]
+    assert trips in ([], [ssd.n_chunks(t, chunk)] * 2), trips
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    print(f"ssd scan T {t}: temporaries {temp / 1e9:.3f} GB")
+    if t == 4096:
+        assert temp < 0.7e9
+
+
+def test_ssm_moe_cell_step_fits_one_chip(topo, compiled_kernel):
+    """The whole training step of the cell as the benchmark builds it (its
+    family's `build` under the cell's trainer keys; one period: five
+    Mamba-2, attention, four Mamba-2; 1,126.7 M parameters, 4,096 tokens,
+    every block rematerialised): it compiles, the attention layer runs the
+    forward flash kernel twice and the backward one once, every layer the
+    grouped matmuls eight times (the forward's two once more), the mixer's
+    scopes are in the program in all three passes, the gauges say what was
+    built, and state + temporaries stay under 16.4 GB of the chip's 16.9
+    (16.243 when it was written: 13.521 of state, 2.722 of temporaries, of
+    which ten layers' bf16 expert-weight gradients held for the AdamW
+    passes the compiler schedules last are 1.5)."""
+    import pathlib
+
+    from chipbench import run
+    from horovod_tpu.models import hybrid_moe_lm as hybrid
+    from horovod_tpu.obs import prom
+    from horovod_tpu.ops import grouped_matmul as gm
+
+    root = pathlib.Path(__file__).resolve().parents[1]
+    cell = run.load_cell(root, "granite-4.0-h-small.seq4k.1chip")
+    trainer = run.build_trainer(cell, topo.devices[:1], 7)
+    trainer._metric_names = (
+        "moe_held_rows_share", "moe_load_max_over_mean", "moe_overflow_rows")
+    assert trainer.module.remat and trainer.module.tied_head
+    compiled = compiled_step(trainer, seq=4096, batch=1)
+    assert kernel_names(compiled) == sorted(
+        [fa.KERNEL_FWD] * 2 + [fa.KERNEL_BWD]
+        + [gm.KERNEL] * 60 + [gm.KERNEL_DW] * 20)
+    hlo = compiled.as_text()
+    for scope in (hybrid.SSM_PROJ, hybrid.SSM_CONV, hybrid.SSM_SCAN,
+                  hybrid.SSM_OUT):
+        for path in (r"jit\(train_step\)/jvp\(HybridMoELM\)/",
+                     "checkpoint/rematted_computation/", "checkpoint/"):
+            assert re.search(rf"{path}Block_\d/mixer/{scope}", hlo), (
+                path, scope)
+    gauges = prom.render()
+    assert 'hvt_layer_kinds{kind="ssm"} 9' in gauges
+    assert 'hvt_layer_kinds{kind="softmax"} 1' in gauges
+    assert 'hvt_held_heads{mixer="ssm"} 16' in gauges
+    assert 'hvt_held_heads{mixer="softmax"} 4' in gauges
+    assert "hvt_ssd_chunks 16" in gauges
+    assert 'hvt_moe_gate{scoring="softmax"} 1' in gauges
+    assert "hvt_remat_blocks 10" in gauges and "hvt_tied_head 1" in gauges
+    memory = compiled.memory_analysis()
+    state = cell["config"]["n_parameters"] * 12
+    assert state == 1_126_717_104 * 12
+    assert state <= memory.argument_size_in_bytes <= state + 1_000_000
+    total = memory.argument_size_in_bytes + memory.temp_size_in_bytes
+    print(f"ssm cell step: arguments + temporaries {total / 1e9:.3f} GB")
+    assert total < 16.4e9

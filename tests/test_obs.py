@@ -307,7 +307,9 @@ class TestMetricsServer:
                     method="POST",
                 ))
             assert e.value.code == 409
-            deadline = time.monotonic() + 10
+            # (the profiler's start took over 10 s once, under six busy
+            # workers: PR 39's whole run)
+            deadline = time.monotonic() + 60
             while time.monotonic() < deadline:
                 if os.path.isdir(body["profiling"]) and any(
                     os.scandir(body["profiling"])
