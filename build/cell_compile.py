@@ -48,7 +48,7 @@ if "--lower-only" in sys.argv:
     sys.exit(0)
 compiled = lowered.compile()
 m = compiled.memory_analysis()
-print(f"compile {time.time()-t:.0f}s arguments {m.argument_size_in_bytes/1e9:.3f} temp {m.temp_size_in_bytes/1e9:.3f} total {(m.argument_size_in_bytes+m.temp_size_in_bytes)/1e9:.3f} GB; output {m.output_size_in_bytes/1e9:.3f} alias {m.alias_size_in_bytes/1e9:.3f}")
+print(f"compile {time.time()-t:.0f}s arguments {m.argument_size_in_bytes/1e9:.3f} temp {m.temp_size_in_bytes/1e9:.3f} total {(m.argument_size_in_bytes+m.temp_size_in_bytes)/1e9:.3f} GB (temp {m.temp_size_in_bytes:,} bytes); output {m.output_size_in_bytes/1e9:.3f} alias {m.alias_size_in_bytes/1e9:.3f}")
 print(sorted(set(tcc.kernel_names(compiled))), len(tcc.kernel_names(compiled)))
 import collections
 print(collections.Counter(tcc.kernel_names(compiled)))

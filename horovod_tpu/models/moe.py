@@ -8,7 +8,8 @@
   layer: scores over all the published experts (sigmoid, or the softmax
   over the chosen logits: `RoutedExperts.scoring`), top-k with a
   selection bias that levels each sequence's loads (`level_bias`: the layer
-  carries no balancing state), gates normalised over the chosen and scaled, the
+  carries no balancing state; bias and top-k are found by counting, not by
+  sorting the logits), gates normalised over the chosen and scaled, the
   (token, choice) pairs that fall on the experts HELD HERE sorted by expert
   and run through a grouped matmul with ragged group sizes (no token
   dropped, no one-hot dispatch), SwiGLU experts and a shared expert. What
@@ -60,6 +61,7 @@ import math
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from horovod_tpu.ops import grouped_matmul as gmm_ops
@@ -294,6 +296,23 @@ class SwiGLU(nn.Module):
         return dense(h.shape[-1], name="down")(nn.silu(gate) * up)
 
 
+_SIGN = np.uint32(0x80000000)
+
+
+def _order_key(x):
+    """float32 -> uint32 whose integer order is the floats' order (the sign
+    and magnitude folded: a negative's bits inverted, the sign bit set on
+    the others); ``-0.0`` lies just under ``0.0``."""
+    bits = jax.lax.bitcast_convert_type(x, jnp.uint32)
+    return jnp.where(bits >= _SIGN, ~bits, bits | _SIGN)
+
+
+def _key_value(key):
+    """`_order_key`'s inverse."""
+    bits = jnp.where(key >= _SIGN, key ^ _SIGN, ~key)
+    return jax.lax.bitcast_convert_type(bits, jnp.float32)
+
+
 def level_bias(logits, k: int):
     """The selection bias that levels the loads of one sequence: for the
     router's logits ``[T, E]``, minus each expert's own logit at the rank
@@ -301,7 +320,7 @@ def level_bias(logits, k: int):
 
     This is where the DeepSeek-V3 ``noaux_tc`` rule (bias += rate *
     sign(mean load - load), a buffer carried from step to step) is headed,
-    found in one sort instead: whatever the tokens of a sequence share (the
+    found in one step instead: whatever the tokens of a sequence share (the
     mean over the prefix that attention hands every late token; a direction
     the stream gains as training moves it) shifts an expert's whole column
     and is taken out with the threshold, and what tells the tokens apart
@@ -310,13 +329,45 @@ def level_bias(logits, k: int):
     loads are level to within chance (1.1-1.2 x the mean on the fullest of
     128) as long as what tells the tokens apart is not of a few dimensions.
 
+    Found by counting, not by sorting: the ``above``-th largest value of a
+    column is the largest ``v`` with ``count(column >= v) >= above``, and
+    that ``v`` is built exactly, from its top bit down, by bisection on the
+    floats' order keys (`_order_key`): a pass sets the next bit, counts the
+    tokens at or above the candidate, and keeps the bit where the count
+    still reaches. 32 passes whatever the data, no sort of ``[T, E]``; the
+    value is an element of the column (a column that holds both zeros may
+    give either).
+
     On the logits, not on the scores as the published selection adds it:
     after the sigmoid an expert whose column sits near 0 or 1 has too small
-    a slope to compete for any token, and one sort does not level the loads
-    (columns two logits apart: the fullest expert at 3.8 x the mean)."""
+    a slope to compete for any token, and one threshold does not level the
+    loads (columns two logits apart: the fullest expert at 3.8 x the
+    mean)."""
     t, e = logits.shape
     above = min(t, max(1, round(t * k / e)))
-    return -jnp.sort(logits, axis=0)[t - above]
+    key = _order_key(logits)
+
+    def narrow(i, found):
+        candidate = found | (_SIGN >> i.astype(jnp.uint32))
+        reached = jnp.sum(key >= candidate, axis=0, dtype=jnp.int32) >= above
+        return jnp.where(reached, candidate, found)
+
+    found = jax.lax.fori_loop(0, 32, narrow, jnp.zeros((e,), jnp.uint32))
+    return -_key_value(found)
+
+
+def _largest(values, k: int):
+    """``[..., k]`` int32: the indices of the ``k`` largest along the last
+    axis, largest first and a tie to the lower index (`jax.lax.top_k`'s
+    indices on finite values), as ``k`` maxima: the first index of the
+    largest, then that one lowered to ``-inf``. No sort."""
+    index = jax.lax.broadcasted_iota(jnp.int32, values.shape, values.ndim - 1)
+    chosen = []
+    for _ in range(k):
+        first = jnp.argmax(values, axis=-1, keepdims=True).astype(jnp.int32)
+        chosen.append(first)
+        values = jnp.where(index == first, -jnp.inf, values)
+    return jnp.concatenate(chosen, axis=-1)
 
 
 SIGMOID, SOFTMAX = "sigmoid", "softmax"
@@ -343,16 +394,15 @@ def _route(tokens, router, *, k, scale, scoring=SIGMOID):
     """``(chosen [B, T, k] int32, gates [B, T, k] float32)`` for tokens
     ``[B, T, d]``: the router's logits over ALL the experts in float32, the
     top ``k`` of logit + selection bias (`level_bias`, a sequence at a
-    time), and the chosen experts' gates (`_gates`: scores without the
-    bias)."""
+    time; `_largest`: both by counting and comparing, no sort), and the
+    chosen experts' gates (`_gates`: scores without the bias)."""
     logits = jnp.dot(tokens.astype(jnp.float32), router,
                      precision=jax.lax.Precision.HIGHEST)
     # Selection is not differentiable: the bias is a constant of the step.
     bias = jax.vmap(lambda one: level_bias(one, k))(logits)
-    _, chosen = jax.lax.top_k(
-        jax.lax.stop_gradient(logits + bias[:, None, :]), k)
+    chosen = _largest(jax.lax.stop_gradient(logits + bias[:, None, :]), k)
     gates = _gates(logits, chosen, scoring=scoring, scale=scale)
-    return chosen.astype(jnp.int32), gates
+    return chosen, gates
 
 
 def _held_experts(x, router, w_gate_up, w_down, *, k, scale, held_start,
@@ -444,7 +494,10 @@ class RoutedExperts(nn.Module):
     gauge ``hvt_moe_gate{scoring}``): ``"sigmoid"``, the logits' sigmoids
     normalised over the chosen (DeepSeek-V3), or ``"softmax"``, the softmax
     over the chosen logits (Granite-4.0: the gates add up to the scale).
-    The selection, and its bias, are the same either way.
+    The selection, and its bias, are the same either way, and made by
+    counting and comparing (`level_bias`, `_largest`; the gauge
+    ``hvt_moe_selection{impl="count"}``): nothing on this path sorts the
+    ``[T, E]`` logits.
     """
 
     n_routed: int
@@ -483,6 +536,7 @@ class RoutedExperts(nn.Module):
         obs.gauge("hvt_moe_experts", float(self.n_held), kind="held")
         obs.gauge("hvt_moe_experts", float(self.n_routed), kind="routed")
         obs.gauge("hvt_moe_gate", 1.0, scoring=self.scoring)
+        obs.gauge("hvt_moe_selection", 1.0, impl="count")
 
         router = self.param(
             "router", nn.initializers.lecun_normal(), (d, self.n_routed))

@@ -397,6 +397,13 @@ METRICS: dict[str, MetricSpec] = _decl([
                "`sigmoid` (the logits' sigmoids, normalised) or `softmax` "
                "(the softmax over the chosen logits). Set at trace time.",
                "training", labels=("scoring",)),
+    MetricSpec("hvt_moe_selection", "gauge",
+               "How the last routed layer traced selects its experts "
+               "(models/moe.py level_bias, _largest): 1 on `count`, the "
+               "level bias as an order statistic by bisection on the "
+               "floats' order keys and the top k as k maxima; no sort of "
+               "the [T, E] logits. The only form there is. Set at trace "
+               "time.", "training", labels=("impl",)),
     MetricSpec("hvt_remat_blocks", "gauge",
                "Blocks of the last HybridMoELM traced that are "
                "rematerialised in the backward pass (`remat`: all of them "

@@ -567,6 +567,50 @@ def test_grouped_matmul_compiles_for_v5e(topo, compiled_kernel):
         [gm.KERNEL] * 4 + [gm.KERNEL_DW] * 2)
 
 
+def sorts_of(hlo: str) -> list[str]:
+    """The compiled program's `sort` instructions, a line each."""
+    return [line.strip() for line in hlo.splitlines()
+            if re.search(r" sort\(", line)]
+
+
+@pytest.mark.parametrize("tokens,d,routed,held,k", [
+    (512, 256, 16, 4, 2),     # a toy layer, forward and backward
+    (8192, 4096, 320, 8, 8),  # Solar's: the widest logits a cell routes
+])
+def test_routed_layer_selects_without_sorting_its_logits(
+        topo, compiled_kernel, tokens, d, routed, held, k):
+    """`RoutedExperts` compiled for the described v5e, forward and
+    backward: `level_bias` and `_largest` select by counting, so no `sort`
+    instruction has an operand the logits' shape (either way round) and no
+    `top_k` call is left. The one sort that stays is the dispatch's
+    `argsort` of the T * k pair keys. The gauge says so at trace time."""
+    from horovod_tpu.models.moe import RoutedExperts
+    from horovod_tpu.obs import prom
+
+    layer = RoutedExperts(
+        n_routed=routed, k=k, expert_width=d // 2, shared_width=d // 2,
+        n_held=held, held_start=0, routed_scaling=2.5,
+        compute_dtype=jnp.bfloat16)
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    x = SDS((1, tokens, d), jnp.bfloat16, sharding=one_chip)
+    params = jax.tree.map(
+        lambda leaf: SDS(leaf.shape, leaf.dtype, sharding=one_chip),
+        jax.eval_shape(layer.init, jax.random.PRNGKey(0), x))
+
+    def loss(params, x):
+        return layer.apply(params, x).astype(jnp.float32).sum()
+
+    hlo = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        params, x).compile().as_text()
+    assert "top_k" not in hlo.lower() and "topk" not in hlo.lower()
+    logits = (rf"\[(1,)?{tokens},{routed}\]", rf"\[(1,)?{routed},{tokens}\]")
+    sorts = sorts_of(hlo)
+    assert not [line for line in sorts
+                if any(re.search(shape, line) for shape in logits)], sorts
+    assert any(f"[{tokens * k}]" in line for line in sorts), sorts
+    assert 'hvt_moe_selection{impl="count"} 1' in prom.render()
+
+
 def test_latent_moe_cell_step_fits_one_chip(topo, compiled_kernel):
     """The whole training step of the cell at its own sizes (1 dense + 5
     expert layers, 687.5 M parameters, 8,192 tokens): it compiles, every

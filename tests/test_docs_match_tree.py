@@ -53,7 +53,8 @@ _NOT_THE_TREE = {".git", "build", "chiprun_out", "__pycache__",
 # `build/` is scratch (gitignored) but for the helpers `.gitignore` excepts.
 _COMMITTED_UNDER_BUILD = ("build/flash_bundles.py", "build/kda_probe.py",
                           "build/reduction_table.py", "build/ssd_probe.py",
-                          "build/cell_compile.py", "build/moe_logs.py")
+                          "build/cell_compile.py", "build/moe_logs.py",
+                          "build/moe_probe.py")
 _PATH = re.compile(
     r"^(?P<path>[\w.-]+(?:/[\w.-]+)*\.(?:py|json|md|yaml|cc))"
     r"(?::[\d,:-]+)?(?:::[\w:\[\]-]+)?$"

@@ -572,3 +572,201 @@ class TestExpertChoice:
         np.testing.assert_allclose(
             np.asarray(a), np.asarray(b), rtol=2e-5, atol=2e-5
         )
+
+
+# --- `RoutedExperts`' selection: by counting, against the sort form ---------
+#
+# `moe.level_bias` and `moe._largest` select by counting and comparing; the
+# sort form they replaced (one `jnp.sort` down the tokens, `jax.lax.top_k`
+# along the experts) lives on here as their oracle. The threshold has to be
+# equal as a number and the chosen experts equal to the index, in order.
+
+from horovod_tpu.models import moe as moe_lib  # noqa: E402
+
+
+def _above(t, e, k):
+    return min(t, max(1, round(t * k / e)))
+
+
+def sorted_bias(logits, k):
+    t, e = logits.shape
+    return -jnp.sort(logits, axis=0)[t - _above(t, e, k)]
+
+
+def sorted_choice(values, k):
+    return jax.lax.top_k(values, k)[1]
+
+
+def sorted_route(tokens, router, *, k, scale, scoring):
+    """`moe._route` as the parent made it."""
+    logits = jnp.dot(tokens.astype(jnp.float32), router,
+                     precision=jax.lax.Precision.HIGHEST)
+    bias = jax.vmap(lambda one: sorted_bias(one, k))(logits)
+    chosen = sorted_choice(
+        jax.lax.stop_gradient(logits + bias[:, None, :]), k)
+    return chosen, moe_lib._gates(logits, chosen, scoring=scoring, scale=scale)
+
+
+def _normal(t, e, seed=0):
+    return np.random.default_rng(seed).standard_normal((t, e)).astype(
+        np.float32)
+
+
+def _planted(name):
+    """``(logits [T, E], k)`` of one planted case."""
+    if name == "constant_column":
+        x = _normal(64, 8)
+        x[:, 3] = 0.25
+        return x, 2
+    if name == "ties_across_the_threshold":
+        # Integers between -2 and 2: every column's threshold value stands
+        # many times on both sides of its rank.
+        return np.clip(np.round(_normal(128, 12)), -2, 2), 3
+    if name == "signed_zeros":
+        x = _normal(64, 8)
+        x[::2] = 0.0
+        x[1::4] = -0.0
+        return x, 4
+    if name == "infinities":
+        x = _normal(64, 8)
+        x[:20, 0], x[:50, 1] = np.inf, np.inf
+        x[:20, 2], x[:60, 3] = -np.inf, -np.inf
+        x[:, 4] = np.inf
+        x[:, 5] = -np.inf
+        return x, 2
+    if name == "denormals":
+        x = _normal(64, 8) * np.float32(1e-41)
+        assert np.all(np.abs(x) < np.finfo(np.float32).tiny) and np.any(x)
+        return x, 2
+    if name == "thirty_decades_apart":
+        x = _normal(64, 8)
+        x[::3] *= np.float32(1e30)
+        x[1::3] *= np.float32(1e-30)
+        return x, 2
+    if name == "one_above":  # round(T k / E) is 0: the column's largest
+        return _normal(4, 16), 2
+    if name == "all_above":  # k == E: the column's smallest
+        return _normal(37, 4), 4
+    raise KeyError(name)
+
+
+PLANTED = ("constant_column", "ties_across_the_threshold", "signed_zeros",
+           "infinities", "denormals", "thirty_decades_apart", "one_above",
+           "all_above")
+NORMAL_SHAPES = [(64, 8, 2), (100, 12, 3), (4096, 72, 10), (8192, 320, 8)]
+
+
+@pytest.mark.parametrize("t,e,k", NORMAL_SHAPES)
+def test_level_bias_is_the_sorted_threshold_on_normal_logits(t, e, k):
+    x = jnp.asarray(_normal(t, e, seed=t + e))
+    got = jax.jit(lambda x: moe_lib.level_bias(x, k))(x)
+    assert got.dtype == jnp.float32 and got.shape == (e,)
+    np.testing.assert_array_equal(got, sorted_bias(x, k))
+
+
+@pytest.mark.parametrize("name", PLANTED)
+def test_level_bias_is_the_sorted_threshold_on_planted_logits(name):
+    x, k = _planted(name)
+    t, e = x.shape
+    if name == "one_above":
+        assert _above(t, e, k) == 1
+    if name == "all_above":
+        assert _above(t, e, k) == t
+    got = np.asarray(moe_lib.level_bias(jnp.asarray(x), k))
+    # numpy's sort is the oracle's oracle: it keeps denormals whatever the
+    # backend's float comparisons flush.
+    want = -np.sort(x, axis=0)[t - _above(t, e, k)]
+    np.testing.assert_array_equal(got, want)
+    if name != "denormals":
+        np.testing.assert_array_equal(got, sorted_bias(jnp.asarray(x), k))
+    # ... and the threshold is an element of its column.
+    assert all(np.any(x[:, j] == -got[j]) for j in range(e))
+
+
+@pytest.mark.parametrize("t,e,k", NORMAL_SHAPES[:3])
+def test_level_bias_under_jit_and_the_layers_vmap(t, e, k):
+    x = jnp.asarray(_normal(3 * t, e, seed=5).reshape(3, t, e))
+    got = jax.jit(jax.vmap(lambda one: moe_lib.level_bias(one, k)))(x)
+    want = jnp.stack([sorted_bias(one, k) for one in x])
+    np.testing.assert_array_equal(got, want)
+
+
+def test_order_key_keeps_the_floats_order_and_comes_back():
+    x = np.array([-np.inf, -3e38, -1.0, -1e-30, -1e-45, -0.0, 0.0, 1e-45,
+                  1e-30, 1.0, 3e38, np.inf], np.float32)
+    key = np.asarray(moe_lib._order_key(jnp.asarray(x)))
+    assert key.dtype == np.uint32 and np.all(np.diff(key.astype(np.int64)) > 0)
+    back = np.asarray(moe_lib._key_value(jnp.asarray(key)))
+    assert back.tobytes() == x.tobytes()
+
+
+@pytest.mark.parametrize("t,e,k", NORMAL_SHAPES)
+def test_largest_is_top_k_on_biased_normal_logits(t, e, k):
+    x = jnp.asarray(_normal(t, e, seed=t + e + 1))
+    values = x + sorted_bias(x, k)
+    got = jax.jit(lambda v: moe_lib._largest(v, k))(values)
+    assert got.dtype == jnp.int32 and got.shape == (t, k)
+    np.testing.assert_array_equal(got, sorted_choice(values, k))
+
+
+@pytest.mark.parametrize("name", [
+    "tied_maxima", "one_value", "as_many_finite_as_k", "k_is_all",
+    "batched"])
+def test_largest_is_top_k_on_planted_rows(name):
+    k = 3
+    if name == "tied_maxima":  # a tie goes to the lower index, in order
+        values = np.clip(np.round(_normal(256, 12, seed=3)), -1, 1) + 0.0
+        assert any(np.sum(row == row.max()) > 1 for row in values)
+    elif name == "one_value":
+        values = np.full((5, 12), 0.5, np.float32)
+    elif name == "as_many_finite_as_k":  # -inf is what a taken one becomes
+        values = _normal(16, 8, seed=4)
+        values[:, [0, 2, 5, 6, 7]] = -np.inf
+    elif name == "k_is_all":
+        values, k = _normal(16, 8, seed=6), 8
+    else:
+        values = _normal(2 * 3 * 64, 12, seed=7).reshape(2, 3, 64, 12)
+    values = jnp.asarray(values, jnp.float32)
+    got = moe_lib._largest(values, k)
+    np.testing.assert_array_equal(got, sorted_choice(values, k))
+    if name == "one_value":
+        np.testing.assert_array_equal(got, np.tile(np.arange(k), (5, 1)))
+
+
+@pytest.mark.parametrize("scoring", [moe_lib.SIGMOID, moe_lib.SOFTMAX])
+@pytest.mark.parametrize("shape,e,k", [((2, 64, 16), 8, 2),
+                                       ((1, 256, 32), 72, 10)])
+def test_route_chooses_and_differentiates_as_the_sort_form(shape, e, k,
+                                                           scoring):
+    rng = np.random.default_rng(11)
+    tokens = jnp.asarray(rng.standard_normal(shape), jnp.bfloat16)
+    router = jnp.asarray(rng.standard_normal((shape[-1], e)) * 0.3,
+                         jnp.float32)
+    weight = jnp.asarray(rng.standard_normal((*shape[:2], k)), jnp.float32)
+
+    def run(route):
+        def loss(router, tokens):
+            chosen, gates = route(tokens, router, k=k, scale=2.5,
+                                  scoring=scoring)
+            return jnp.sum(gates * weight), (chosen, gates)
+        return jax.jit(jax.value_and_grad(
+            loss, argnums=(0, 1), has_aux=True))(router, tokens)
+
+    (_, (chosen, gates)), grads = run(moe_lib._route)
+    (_, (want_chosen, want_gates)), want_grads = run(sorted_route)
+    assert chosen.dtype == jnp.int32
+    np.testing.assert_array_equal(chosen, want_chosen)
+    np.testing.assert_array_equal(gates, want_gates)
+    for got, want in zip(grads, want_grads):
+        assert np.any(np.asarray(want, np.float32))
+        np.testing.assert_array_equal(got, want)
+
+
+def test_routed_path_holds_no_sort_of_the_logits():
+    """What leaves `_route`'s jaxpr: no `sort`, no `top_k` primitive (the
+    dispatch's `argsort` of the pair keys is outside `_route`)."""
+    tokens, router = jnp.zeros((1, 64, 16)), jnp.zeros((16, 8))
+    text = str(jax.make_jaxpr(
+        lambda t, r: moe_lib._route(t, r, k=2, scale=1.0))(tokens, router))
+    assert " sort[" not in text and "top_k" not in text
+    assert " scan[" in text or " while[" in text  # the bisection's passes
