@@ -1,21 +1,24 @@
-"""A hybrid causal LM: delta-rule linear attention, state-space layers
-and softmax attention over routed experts (Solar-Open2 / Kimi-Linear and
-Granite-4.0-H style).
+"""A hybrid causal LM: delta-rule linear attention, state-space layers,
+full and sliding-window softmax attention, over routed experts
+(Solar-Open2 / Kimi-Linear, Granite-4.0-H and Laguna style).
 
 The stack takes its layer kinds as DATA: ``layer_kinds`` is a tuple with
-one of `LINEAR` / `SOFTMAX` / `SSM` a layer (three linear layers to one
-softmax layer in Solar-Open2; nine state-space layers to one softmax layer
-in Granite-4.0-H). Every layer is pre-norm RMSNorm around a token mixer and
-around a routed expert layer with a shared expert (`models/moe.py`
+one of `LINEAR` / `SOFTMAX` / `WINDOW` / `SSM` a layer (three linear layers
+to one softmax layer in Solar-Open2; nine state-space layers to one softmax
+layer in Granite-4.0-H; one full softmax layer to three sliding-window
+ones in Laguna). Every layer is pre-norm RMSNorm around a token mixer and
+around an MLP: a dense SwiGLU in the first ``n_dense_layers`` layers, else
+a routed expert layer with a shared expert (`models/moe.py`
 `RoutedExperts`, its gate's scoring as data); a final RMSNorm and a head,
 its own or TIED to the embedding's table (``tied_head``); no biases in the
-projections and NO positions anywhere (the recurrences and the causal mask
-order the tokens). With the three multipliers (all 1 unless given), for a
+projections. Positions enter only where a softmax kind's sizes give a
+rotary (Laguna's two kinds); elsewhere the recurrences and the causal mask
+order the tokens. With the three multipliers (all 1 unless given), for a
 token's vector::
 
     x_0 = embedding_multiplier * Embed[token]
     x += residual_multiplier * Mixer_i(RMSNorm(x))
-    x += residual_multiplier * MoE(RMSNorm(x))
+    x += residual_multiplier * MLP_i(RMSNorm(x))
     logits = (RMSNorm(x_L) . W_head) / logits_divisor
 
 With ``remat`` every block is rematerialised in the backward pass
@@ -45,11 +48,26 @@ with S [P, N] float32 a head from zero::
                                            (ops/ssd.py, chunked)
     out = W_o [ RMSNorm_{all H P channels}(y * SiLU(z)) * w ]  gate, THEN norm
 
-`GatedAttention` (``softmax``): grouped-query causal softmax attention
-through the flash kernel with no rotary and no QK-norm; its output gated
-elementwise before W_o (arXiv:2505.06708: ``y = W_o [attn * sigmoid(W_g
-h)]``) unless ``softmax_gate`` is off, its scores scaled by D^-1/2 or by
-``softmax_scale`` where that is given.
+`GatedAttention` (``softmax`` and ``window``; each kind's sizes one
+`AttentionSizes`): grouped-query causal softmax attention through the flash
+kernel, no QK-norm. A kind of H query heads over G K/V heads of D, group g =
+H / G, at positions t::
+
+    q = W_q h [H, D];  k = W_k h, v = W_v h [G, D];  gate = W_g h [H, D]
+    q, k = R(q, t), R(k, t)                 (a rotary where the kind has one)
+    a_i = sum_{j in M(i)} softmax_j(q_i . k_{j/g} / sqrt(D)) v_{j/g}
+    y = W_o [a * sigmoid(gate)]             (the gate unless ``softmax_gate``
+                                             is off: arXiv:2505.06708)
+
+``M(i) = {j <= i}``, or with a window W ``{i - W < j <= i}``: W keys,
+itself included (the kernel's band). ``R`` (`transformer.partial_rope`)
+turns channels j and j + r/2 of the first r together by ``t w_j``, the
+others pass; with YaRN the frequencies are blended between ``w_j`` and
+``w_j / factor`` and cos and sin scaled by its attention factor (Laguna's
+full layers: r 64 of 128 at base 500,000, YaRN x 64 over 4,096 original
+positions; its window layers: all 128 at base 10,000). The scores are
+scaled by D^-1/2, or by ``softmax_scale`` where that is given (the rest
+rides on q).
 
 **One chip's share of a deployment is a parameter of the model.** The
 mixers are told which heads they hold (``n_held_heads`` from
@@ -88,12 +106,13 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from horovod_tpu.models.moe import RoutedExperts
-from horovod_tpu.models.transformer import BATCH_AXES, LMHead, ShardingConfig
+from horovod_tpu.models.moe import RoutedExperts, SwiGLU
+from horovod_tpu.models.transformer import (
+    BATCH_AXES, LMHead, Rotary, ShardingConfig, partial_rope)
 from horovod_tpu.ops import delta_rule, ssd
 from horovod_tpu.ops.flash_attention import flash_attention
 
-LINEAR, SOFTMAX, SSM = "linear", "softmax", "ssm"
+LINEAR, SOFTMAX, SSM, WINDOW = "linear", "softmax", "ssm", "window"
 # By name in the compiled step, forward and backward (chipbench/
 # kda_spans.py): the linear layer's four parts, and everything of the
 # softmax layer but the flash kernel, whose events are found by its names.
@@ -101,6 +120,10 @@ KDA_SCOPE = "hvt.kda"
 KDA_PROJ, KDA_CONV = f"{KDA_SCOPE}/proj", f"{KDA_SCOPE}/conv"
 KDA_SCAN, KDA_OUT = f"{KDA_SCOPE}/scan", f"{KDA_SCOPE}/out"
 GQA_SCOPE = "hvt.gqa"
+GQA_ROPE = f"{GQA_SCOPE}/rope"
+# The window kind's flash calls (chipbench/window_spans.py), outside
+# `GQA_SCOPE`: readers match scopes by substring.
+SWA_SCOPE = "hvt.swa"
 # ... and the state-space layer's four (chipbench/ssm_spans.py).
 SSM_SCOPE = "hvt.ssm"
 SSM_PROJ, SSM_CONV = f"{SSM_SCOPE}/proj", f"{SSM_SCOPE}/conv"
@@ -339,9 +362,22 @@ class StateSpaceMixer(nn.Module):
             return project_out(y.astype(cd), kernel.astype(cd))
 
 
+@dataclasses.dataclass(frozen=True)
+class AttentionSizes:
+    """The sizes of a softmax kind (full or sliding-window)."""
+
+    n_heads: int                 # query heads of the whole layer ...
+    n_kv_heads: int
+    n_held_heads: int            # ... and the block of them held here ...
+    held_heads_start: int        # ... from this head, with their K/V heads
+    window: int | None = None    # keys a query reads, itself included
+    rotary: Rotary | None = None  # None: no positions
+
+
 class GatedAttention(nn.Module):
     """The held query heads of one softmax layer with the K/V heads they
-    read, ``[B, T, d] -> [B, T, d]``: no positions; the output gated unless
+    read, ``[B, T, d] -> [B, T, d]``: positions where ``rotary`` is given;
+    every key before a query or the ``window`` last; the output gated unless
     ``gate`` is off; the scores scaled by ``scale`` where given, else by
     ``head_dim ** -0.5``."""
 
@@ -353,6 +389,8 @@ class GatedAttention(nn.Module):
     compute_dtype: jnp.dtype
     gate: bool = True
     scale: float | None = None
+    window: int | None = None
+    rotary: Rotary | None = None
 
     @nn.compact
     def __call__(self, x):
@@ -368,7 +406,12 @@ class GatedAttention(nn.Module):
                 "to a K/V head")
         from horovod_tpu import obs
 
-        obs.gauge("hvt_held_heads", float(self.n_held_heads), mixer=SOFTMAX)
+        kind = SOFTMAX if self.window is None else WINDOW
+        obs.gauge("hvt_held_heads", float(self.n_held_heads), mixer=kind)
+        obs.gauge("hvt_rotary_dims",
+                  float(self.rotary.dims if self.rotary else 0), kind=kind)
+        if self.window is not None:
+            obs.gauge("hvt_attn_window", float(self.window))
         cd, held, dim = self.compute_dtype, self.n_held_heads, self.head_dim
         dense = functools.partial(nn.DenseGeneral, use_bias=False, dtype=cd)
         with jax.named_scope(GQA_SCOPE):
@@ -377,11 +420,21 @@ class GatedAttention(nn.Module):
                     for n in "kv")
             if self.gate:
                 gate_in = dense((held, dim), name="g_proj")(x)
+            if self.rotary is not None:
+                with jax.named_scope("rope"):
+                    positions = jnp.arange(x.shape[1])
+                    q, k = (partial_rope(a, positions, self.rotary)
+                            for a in (q, k))
             if self.scale is not None:
                 # The kernel scales by D^-1/2: the rest rides on q.
                 q = q * jnp.asarray(self.scale * dim ** 0.5, cd)
             k, v = (jnp.repeat(a, group, axis=2) for a in (k, v))
-        out = flash_attention(q, k, v, causal=True)
+        if self.window is None:
+            out = flash_attention(q, k, v, causal=True)
+        else:
+            with jax.named_scope(SWA_SCOPE):
+                out = flash_attention(
+                    q, k, v, causal=True, window=self.window)
         with jax.named_scope(GQA_SCOPE):
             kernel = self.param(
                 "o_proj", nn.initializers.lecun_normal(in_axis=(0, 1)),
@@ -424,10 +477,10 @@ class HybridMoELM(nn.Module):
 
     vocab_size: int
     d_model: int
-    layer_kinds: tuple    # LINEAR / SOFTMAX / SSM, one a layer
+    layer_kinds: tuple    # LINEAR / SOFTMAX / WINDOW / SSM, one a layer
     head_dim: int         # of the linear and the softmax kinds
     linear_heads: int     # of the whole layer ...
-    softmax_heads: int
+    softmax_heads: int    # (the softmax kind's unless `softmax` is given)
     softmax_kv_heads: int
     n_held_heads: int     # ... and the block of them held here, both mixers
     held_heads_start: int
@@ -446,6 +499,12 @@ class HybridMoELM(nn.Module):
     fused_head_chunks: int
     sharding: ShardingConfig = ShardingConfig()
     ssm: StateSpaceSizes | None = None  # the state-space kind's sizes
+    # The softmax kind's sizes (None: the fields above, no window, no
+    # positions) and the sliding-window kind's.
+    softmax: AttentionSizes | None = None
+    window: AttentionSizes | None = None
+    n_dense_layers: int = 0  # leading layers whose MLP is a dense SwiGLU
+    dense_width: int = 0
     softmax_gate: bool = True
     softmax_scale: float | None = None  # None: head_dim ** -0.5
     moe_scoring: str = "sigmoid"        # `RoutedExperts.scoring`
@@ -464,18 +523,32 @@ class HybridMoELM(nn.Module):
                 f"HybridMoELM on a mesh of {cfg.mesh.size} chips "
                 f"({dict(cfg.mesh.shape)}): its layers run on one chip "
                 "(ROADMAP R1, R8)")
-        unknown = sorted(set(self.layer_kinds) - {LINEAR, SOFTMAX, SSM})
+        kinds = (LINEAR, SOFTMAX, SSM, WINDOW)
+        unknown = sorted(set(self.layer_kinds) - set(kinds))
         if unknown or not self.layer_kinds:
             raise ValueError(
-                f"layer_kinds {self.layer_kinds!r}: a layer is {LINEAR!r} "
-                f"or {SOFTMAX!r} or {SSM!r}")
+                f"layer_kinds {self.layer_kinds!r}: a layer is "
+                + " or ".join(map(repr, kinds)))
         if SSM in self.layer_kinds and self.ssm is None:
             raise ValueError(
                 f"layer_kinds holds {SSM!r} and `ssm`, the kind's "
                 "`StateSpaceSizes`, is not given")
+        if WINDOW in self.layer_kinds and (
+                self.window is None or self.window.window is None):
+            raise ValueError(
+                f"layer_kinds holds {WINDOW!r} and `window`, the kind's "
+                "`AttentionSizes` with its window, is not given")
+        if self.softmax is not None and self.softmax.window is not None:
+            raise ValueError(
+                f"the {SOFTMAX!r} kind reads every key before a query: its "
+                f"sizes give a window ({self.softmax.window})")
+        if not 0 <= self.n_dense_layers <= len(self.layer_kinds):
+            raise ValueError(
+                f"{self.n_dense_layers} leading dense layers of "
+                f"{len(self.layer_kinds)}")
         from horovod_tpu import obs
 
-        for kind in (LINEAR, SOFTMAX, SSM):
+        for kind in kinds:
             obs.gauge("hvt_layer_kinds",
                       float(self.layer_kinds.count(kind)), kind=kind)
         obs.gauge("hvt_remat_blocks",
@@ -496,18 +569,25 @@ class HybridMoELM(nn.Module):
             elif kind == SSM:
                 mixer = StateSpaceMixer(self.ssm, self.eps, cd, parent=None)
             else:
+                sizes = self.window if kind == WINDOW else (
+                    self.softmax or AttentionSizes(
+                        self.softmax_heads, self.softmax_kv_heads,
+                        self.n_held_heads, self.held_heads_start))
                 mixer = GatedAttention(
-                    self.softmax_heads, self.softmax_kv_heads,
-                    self.n_held_heads, self.held_heads_start, self.head_dim,
-                    cd, gate=self.softmax_gate, scale=self.softmax_scale,
-                    parent=None)
-            mlp = RoutedExperts(
-                n_routed=self.n_routed, k=self.experts_per_token,
-                expert_width=self.expert_width,
-                shared_width=self.shared_width, n_held=self.n_held,
-                held_start=self.held_start,
-                routed_scaling=self.routed_scaling, compute_dtype=cd,
-                sharding=cfg, scoring=self.moe_scoring, parent=None)
+                    sizes.n_heads, sizes.n_kv_heads, sizes.n_held_heads,
+                    sizes.held_heads_start, self.head_dim, cd,
+                    gate=self.softmax_gate, scale=self.softmax_scale,
+                    window=sizes.window, rotary=sizes.rotary, parent=None)
+            if i < self.n_dense_layers:
+                mlp = SwiGLU(self.dense_width, cd, parent=None)
+            else:
+                mlp = RoutedExperts(
+                    n_routed=self.n_routed, k=self.experts_per_token,
+                    expert_width=self.expert_width,
+                    shared_width=self.shared_width, n_held=self.n_held,
+                    held_start=self.held_start,
+                    routed_scaling=self.routed_scaling, compute_dtype=cd,
+                    sharding=cfg, scoring=self.moe_scoring, parent=None)
             x = block(mixer, mlp, self.eps, cd, self.residual_multiplier,
                       name=f"Block_{i}")(x)
             x = cfg.constrain(x, P(BATCH_AXES, None, None))

@@ -28,9 +28,11 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import math
 
 import flax.linen as nn
 import jax
+import numpy as np
 
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
@@ -61,6 +63,78 @@ def _rope(x, positions, *, base: float = 10000.0):
         [x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1
     )
     return rotated.astype(x.dtype)
+
+
+@dataclasses.dataclass(frozen=True)
+class YaRN:
+    """YaRN's blend of a rotary's frequencies (arXiv:2309.00071, as
+    transformers' ``_compute_yarn_parameters`` with truncation): pairs that
+    turn fewer than ``beta_slow`` times over ``original_max_positions`` are
+    slowed by ``factor``, those that turn more than ``beta_fast`` times are
+    kept, a linear ramp between; cos and sin are scaled by
+    ``attention_factor``."""
+
+    factor: float
+    original_max_positions: int
+    beta_fast: float
+    beta_slow: float
+    attention_factor: float
+
+
+@dataclasses.dataclass(frozen=True)
+class Rotary:
+    """Rotate-half rotary over the first ``dims`` channels of a head at
+    ``base``, YaRN-scaled where ``yarn`` is given; the other channels pass
+    through untouched."""
+
+    dims: int
+    base: float
+    yarn: YaRN | None = None
+
+
+def rotary_inv_freq(rotary: Rotary) -> np.ndarray:
+    """float32 ``[dims // 2]``: the pairs' angular frequencies, on the host
+    (a constant of the traced program). Without YaRN ``base^(-2j / dims)``;
+    with it ``w_j / factor (1 - e_j) + w_j e_j``, ``e_j = 1 - clamp((j -
+    lo) / (hi - lo), 0, 1)`` between the correction dimensions of
+    ``beta_fast`` (floored) and ``beta_slow`` (ceiled)."""
+    half = rotary.dims // 2
+    kept = rotary.base ** (-2.0 * np.arange(half) / rotary.dims)
+    yarn = rotary.yarn
+    if yarn is None:
+        return kept.astype(np.float32)
+
+    def correction_dim(rotations):
+        return (rotary.dims * math.log(
+            yarn.original_max_positions / (rotations * 2 * math.pi))
+            / (2 * math.log(rotary.base)))
+
+    lo = max(math.floor(correction_dim(yarn.beta_fast)), 0)
+    hi = min(math.ceil(correction_dim(yarn.beta_slow)), rotary.dims - 1)
+    ramp = np.clip((np.arange(half) - lo) / max(hi - lo, 1e-3), 0.0, 1.0)
+    extrapolated = 1.0 - ramp
+    return (kept / yarn.factor * (1.0 - extrapolated)
+            + kept * extrapolated).astype(np.float32)
+
+
+def partial_rope(x, positions, rotary: Rotary):
+    """``[B, T, H, D]`` with the rotary of ``rotary`` at ``positions``
+    ``[T]``: channels ``j`` and ``j + dims / 2`` of the first ``dims`` turn
+    together, angles and products in float32, the result in ``x``'s
+    dtype."""
+    half = rotary.dims // 2
+    angles = (positions.astype(jnp.float32)[:, None]
+              * rotary_inv_freq(rotary))[:, None, :]  # [T, 1, half]
+    cos, sin = jnp.cos(angles), jnp.sin(angles)
+    if rotary.yarn is not None:
+        cos = cos * rotary.yarn.attention_factor
+        sin = sin * rotary.yarn.attention_factor
+    x1, x2 = x[..., :half], x[..., half:rotary.dims]
+    turned = [(x1 * cos - x2 * sin).astype(x.dtype),
+              (x1 * sin + x2 * cos).astype(x.dtype)]
+    if rotary.dims < x.shape[-1]:
+        turned.append(x[..., rotary.dims:])
+    return jnp.concatenate(turned, axis=-1)
 
 
 def packed_positions(segment_ids):

@@ -355,17 +355,31 @@ METRICS: dict[str, MetricSpec] = _decl([
                "Layers of the last HybridMoELM traced "
                "(models/hybrid_moe_lm.py), by their token mixer: `linear` "
                "(delta-rule linear attention), `softmax` (grouped-query "
-               "attention) and `ssm` (Mamba-2 state-space layers). Set at "
+               "attention), `window` (the same over a sliding window) and "
+               "`ssm` (Mamba-2 state-space layers). Set at "
                "trace time: the stack's kinds are data of the model, "
                "static per program.",
                "training", labels=("kind",)),
     MetricSpec("hvt_held_heads", "gauge",
                "Heads this chip holds of the last mixer traced of each "
                "kind (models/hybrid_moe_lm.py DeltaAttention `linear`, "
-               "GatedAttention `softmax`, StateSpaceMixer `ssm`): the "
+               "GatedAttention `softmax` and `window`, StateSpaceMixer "
+               "`ssm`): the "
                "layer returns their rows of the output projection only. "
                "Set at trace time.",
                "training", labels=("mixer",)),
+    MetricSpec("hvt_attn_window", "gauge",
+               "Keys a query reads, itself included, in the last "
+               "sliding-window softmax layer traced (models/hybrid_moe_lm.py "
+               "GatedAttention `window`; the flash kernel's band). Set at "
+               "trace time.", "training"),
+    MetricSpec("hvt_rotary_dims", "gauge",
+               "Channels of a head the rotary turns in the last softmax "
+               "layer traced of each kind (models/hybrid_moe_lm.py "
+               "GatedAttention, `softmax` / `window`; "
+               "transformer.partial_rope): 0 where the kind has no "
+               "positions. Set at trace time.",
+               "training", labels=("kind",)),
     MetricSpec("hvt_kda_chunks", "gauge",
                "Chunks a sequence is walked in by the last delta-rule "
                "layer traced (ops/delta_rule.py: the steps of its one "

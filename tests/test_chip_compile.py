@@ -134,6 +134,13 @@ SPLIT_SINKS = sorted(SPLIT + [fa.KERNEL_DKV])
                      id="cell-starcoder2-3b"),
         pytest.param((1, 8192, 32, 192), jnp.bfloat16, {}, False, FUSED,
                      128, (120, 16, 120), id="cell-kanana-2-30b-a3b"),
+        # Laguna's two kinds (PR 41): the window layers' band at 512² tiles,
+        # every running tile an edge tile, and the full layers at group 6.
+        pytest.param((1, 8192, 64, 128), jnp.bfloat16, {"window": 512},
+                     False, FUSED, None, (17, 31, 0),
+                     id="cell-laguna-xs.2-window"),
+        pytest.param((1, 8192, 48, 128), jnp.bfloat16, {}, False, FUSED,
+                     None, (28, 8, 28), id="cell-laguna-xs.2-full"),
         # Long contexts, in whichever form the predicate picks: 32 MiB of
         # dQ resident, and the budget's edge (64 MiB: half the VMEM).
         pytest.param((1, 32768, 2, 128), jnp.bfloat16, {}, False, None,
@@ -866,3 +873,59 @@ def test_ssm_moe_cell_step_fits_one_chip(topo, compiled_kernel):
     total = memory.argument_size_in_bytes + memory.temp_size_in_bytes
     print(f"ssm cell step: arguments + temporaries {total / 1e9:.3f} GB")
     assert total < 16.4e9
+
+
+# --- the window / full softmax stack's step ----------------------------------
+# `laguna-xs.2.seq8k.1chip` (PR 41): three sliding-window layers (64 heads,
+# window 512) and two full ones (48 heads, partial YaRN rotary) over 8 K/V
+# heads of 128, a dense layer 0, 256-way routing onto 32 held experts of
+# 2,048 x 512, every block rematerialised, 8,192 tokens.
+
+def test_window_moe_cell_step_fits_one_chip(topo, compiled_kernel):
+    """The whole training step of the cell as the benchmark builds it: it
+    compiles, every layer runs the forward flash kernel twice and the
+    backward once, the window layers' three times each under the scope
+    `hvt.swa` and the full layers' under none, every routed layer the
+    grouped matmuls eight times, the gauges say what was built, and state
+    + temporaries stay under 12.0 GB of the chip's 16.9 (11.656 when it
+    was written: 9.198 of state, 2.457 of temporaries; 15.943 without the
+    rematerialisation)."""
+    import pathlib
+
+    from chipbench import run
+    from horovod_tpu.models import hybrid_moe_lm as hybrid
+    from horovod_tpu.obs import prom
+    from horovod_tpu.ops import grouped_matmul as gm
+
+    root = pathlib.Path(__file__).resolve().parents[1]
+    cell = run.load_cell(root, "laguna-xs.2.seq8k.1chip")
+    trainer = run.build_trainer(cell, topo.devices[:1], 7)
+    trainer._metric_names = (
+        "moe_held_rows_share", "moe_load_max_over_mean", "moe_overflow_rows")
+    assert trainer.module.remat and trainer.module.n_dense_layers == 1
+    compiled = compiled_step(trainer, seq=8192, batch=1)
+    assert kernel_names(compiled) == sorted(
+        [fa.KERNEL_FWD] * 10 + [fa.KERNEL_BWD] * 5
+        + [gm.KERNEL] * 24 + [gm.KERNEL_DW] * 8)
+    scopes = re.findall(
+        r"%hvt_flash_(?:fwd|bwd)[.\d]* = .*?op_name=\"([^\"]*)\"",
+        compiled.as_text())
+    assert len(scopes) == 15
+    windowed = [s for s in scopes if f"/{hybrid.SWA_SCOPE}/" in s]
+    assert len(windowed) == 9 and all(
+        re.search(r"/Block_[123]/mixer/", s) for s in windowed)
+    assert not any(hybrid.GQA_SCOPE in s for s in scopes)
+    gauges = prom.render()
+    assert 'hvt_layer_kinds{kind="window"} 3' in gauges
+    assert 'hvt_layer_kinds{kind="softmax"} 2' in gauges
+    assert 'hvt_held_heads{mixer="window"} 64' in gauges
+    assert 'hvt_held_heads{mixer="softmax"} 48' in gauges
+    assert "hvt_attn_window 512" in gauges
+    assert 'hvt_rotary_dims{kind="softmax"} 64' in gauges
+    assert 'hvt_rotary_dims{kind="window"} 128' in gauges
+    memory = compiled.memory_analysis()
+    state = cell["config"]["n_parameters"] * 12
+    assert state <= memory.argument_size_in_bytes <= state + 1_000_000
+    total = memory.argument_size_in_bytes + memory.temp_size_in_bytes
+    print(f"window cell step: arguments + temporaries {total / 1e9:.3f} GB")
+    assert total < 12.0e9

@@ -18,7 +18,8 @@ from horovod_tpu.models import decoding
 from horovod_tpu.models import hybrid_moe_lm as hybrid
 from horovod_tpu.models.beam import make_beam_search_fn
 from horovod_tpu.models.hybrid_moe_lm import (
-    LINEAR, SOFTMAX, DeltaAttention, GatedAttention, HybridMoELM)
+    LINEAR, SOFTMAX, WINDOW, AttentionSizes, DeltaAttention, GatedAttention,
+    HybridMoELM)
 from horovod_tpu.models.pipelined_lm import PipelinedLM
 from horovod_tpu.models.speculative import make_speculative_fn
 from horovod_tpu.models.transformer import ShardingConfig
@@ -112,6 +113,43 @@ def test_layer_kinds_are_data():
     assert model.apply({"params": params}, x).shape == (2, 64, 96)
 
 
+def test_each_softmax_kind_builds_its_own_sizes_and_dense_layers_lead():
+    """A window kind of 6 heads over a full kind of 4, both over 2 K/V
+    heads, and a dense SwiGLU in the first layer: the shapes say which is
+    which, and the stack is the parent's where the new fields are left
+    at their defaults (tests/chipbench/test_window_moe_lm.py holds the
+    arithmetic to the reference)."""
+    from horovod_tpu.models.transformer import Rotary
+
+    x = tokens()
+    model = HybridMoELM(**SIZES | dict(
+        layer_kinds=(SOFTMAX, WINDOW, LINEAR),
+        softmax=AttentionSizes(4, 2, 4, 0, rotary=Rotary(8, 500000.0)),
+        window=AttentionSizes(6, 2, 6, 0, window=16,
+                              rotary=Rotary(16, 10000.0)),
+        n_dense_layers=1, dense_width=40))
+    shapes = jax.tree.map(
+        lambda a: a.shape, model.init(jax.random.PRNGKey(0), x)["params"])
+    assert shapes["Block_0"]["mixer"]["q_proj"]["kernel"] == (64, 4, 16)
+    assert shapes["Block_1"]["mixer"]["q_proj"]["kernel"] == (64, 6, 16)
+    assert shapes["Block_1"]["mixer"]["k_proj"]["kernel"] == (64, 2, 16)
+    assert shapes["Block_0"]["mlp"] == {
+        "gate": {"kernel": (64, 40)}, "up": {"kernel": (64, 40)},
+        "down": {"kernel": (40, 64)}}
+    assert "router" in shapes["Block_1"]["mlp"]
+    text = prom.render()
+    assert 'hvt_layer_kinds{kind="window"} 1' in text
+    assert 'hvt_held_heads{mixer="window"} 6' in text
+    assert 'hvt_rotary_dims{kind="softmax"} 8' in text
+    assert "hvt_attn_window 16" in text
+    plain = HybridMoELM(**SIZES)
+    params = plain.init(jax.random.PRNGKey(0), x)["params"]
+    assert 'hvt_layer_kinds{kind="window"} 0' in prom.render()
+    sized = HybridMoELM(**SIZES | dict(softmax=AttentionSizes(4, 2, 2, 2)))
+    np.testing.assert_array_equal(
+        sized.apply({"params": params}, x), plain.apply({"params": params}, x))
+
+
 def test_trainer_fit_with_the_module_loss_logs_the_sown_metrics():
     model = HybridMoELM(**SIZES)
     trainer = hvt.Trainer(
@@ -188,13 +226,23 @@ def test_more_than_one_chip_is_refused_by_name():
 
 
 @pytest.mark.parametrize("change,says", [
-    (dict(layer_kinds=(LINEAR, "window")), "a layer is 'linear' or 'softmax'"),
+    (dict(layer_kinds=(LINEAR, "local")), "a layer is 'linear' or 'softmax'"),
     (dict(layer_kinds=()), "a layer is 'linear' or 'softmax'"),
     (dict(held_heads_start=3), "are not a block of its 4"),
     (dict(n_held_heads=1, held_heads_start=0), "do not cover whole groups"),
     (dict(held_start=14), "are not a block of the 16"),
+    (dict(layer_kinds=(SOFTMAX, WINDOW)),
+     "`window`, the kind's `AttentionSizes` with its window, is not given"),
+    (dict(layer_kinds=(SOFTMAX, WINDOW),
+          window=AttentionSizes(4, 2, 4, 0)),
+     "`window`, the kind's `AttentionSizes` with its window, is not given"),
+    (dict(softmax=AttentionSizes(4, 2, 4, 0, window=16)),
+     "reads every key before a query: its sizes give a window"),
+    (dict(n_dense_layers=5), "5 leading dense layers of 4"),
 ], ids=["unknown_kind", "no_layers", "heads_past_the_end",
-        "half_a_kv_group", "experts_past_the_router"])
+        "half_a_kv_group", "experts_past_the_router", "window_unsized",
+        "window_without_its_window", "softmax_with_a_window",
+        "dense_past_the_stack"])
 def test_what_cannot_be_built_is_refused_by_name(change, says):
     with pytest.raises(ValueError, match=says):
         HybridMoELM(**SIZES | change).init(jax.random.PRNGKey(0), tokens())

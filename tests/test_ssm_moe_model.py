@@ -335,7 +335,7 @@ def test_more_than_one_chip_is_refused_by_name():
 
 @pytest.mark.parametrize("change,says", [
     (dict(ssm=None), "`ssm`, the kind's `StateSpaceSizes`, is not given"),
-    (dict(layer_kinds=(SSM, "window")), "or 'softmax' or 'ssm'"),
+    (dict(layer_kinds=(SSM, "local")), "or 'softmax' or 'ssm'"),
     (dict(ssm=StateSpaceSizes(8, 2, 7, 8, 16, 4, 16)),
      "StateSpaceMixer: heads 7..9 are not a block of its 8"),
     (dict(moe_scoring="tanh"), "scoring 'tanh' is neither"),
