@@ -1,4 +1,4 @@
-"""The routed layer's parts alone on the chip, at the three routed cells'
+"""The routed layer's parts alone on the chip, at the four routed cells'
 shapes (tokens, width, router's experts, experts a token, experts held):
 
     chiprun -- env PYTHONPATH=. python build/moe_probe.py            # times
@@ -13,7 +13,13 @@ kept here and in `tests/test_moe.py`), forward and forward + backward, and
 whether the two choose the same experts; the two selections without the
 router's matmul; (b) the dispatch's row gather, (c) the combine's gated
 float32 scatter-add, (d) the transpose of each, every one a program of its
-own on the indices a real routing of random tokens gives.
+own on the indices a real routing of random tokens gives; (e) what took the
+place of the scatter-adds (c) and (d): the rows' token order
+(`segment_sum.token_order`, made once a layer) and the segment sum
+`hvt_moe_combine` in its combine form (gated, float32 sum rounded once to
+bfloat16) and its transpose form (weight 1), beside the cheapest other form:
+the rows gathered into token order, then XLA's scatter-add told that its
+indices are sorted.
 
 **chosen**: for each cell named, its model as the benchmark builds it, the
 parameters of its first step (the trainer's seed) and its first sequence:
@@ -44,12 +50,14 @@ import numpy as np
 from chipbench import reduce
 from horovod_tpu.models import moe
 from horovod_tpu.ops import grouped_matmul as gmm_ops
+from horovod_tpu.ops import segment_sum as seg_ops
 
 ROOT = pathlib.Path(".").resolve()
 SHAPES = {  # tokens, d_model, routed, k, held
     "solar-open2-250b.seq8k.1chip": (8192, 4096, 320, 8, 8),
     "kanana-2-30b-a3b.seq8k.1chip": (8192, 2048, 128, 6, 16),
     "granite-4.0-h-small.seq4k.1chip": (4096, 4096, 72, 10, 8),
+    "laguna-xs.2.seq8k.1chip": (8192, 2048, 256, 8, 32),
 }
 
 
@@ -163,6 +171,42 @@ def times(name, shape):
     row["gather_transpose_ms"] = timed_ms(*transpose_of(gather, rows))
     row["scatter_add_transpose_ms"] = timed_ms(
         *transpose_of(scatter_add, out, gate))
+
+    # (e) The segment sum. Rows past the groups' total belong to no token
+    # (in the layer the grouped matmul makes them zeros).
+    total = jnp.minimum(jnp.sum(local < held), budget)
+    held_rows = jnp.where((jnp.arange(budget) < total)[:, None], out, 0)
+    by_token = jax.jit(seg_ops.token_order, static_argnums=(2, 3))(
+        order, total, n, k)
+    row["held_rows"] = int(total)
+
+    def in_token_order(out, weight, dtype):
+        """The cheapest other form: a gather into token order, then the
+        scatter-add with sorted indices."""
+        rows = out[by_token.by_token].astype(jnp.float32)
+        if weight is not None:
+            rows = rows * weight[by_token.by_token][:, None]
+        return jnp.zeros((n + 1, d), jnp.float32).at[by_token.token].add(
+            rows, indices_are_sorted=True)[:n].astype(dtype)
+
+    row["token_order_ms"] = timed_ms(
+        jax.jit(seg_ops.token_order, static_argnums=(2, 3)),
+        (order, total, n, k))
+    row["segment_sum_ms"] = timed_ms(jax.jit(
+        lambda out, gate: seg_ops.segment_sum(
+            out, gate, by_token, n, jnp.bfloat16)), (held_rows, gate))
+    row["segment_sum_transpose_ms"] = timed_ms(jax.jit(
+        lambda out: seg_ops.segment_sum(out, None, by_token, n, jnp.bfloat16)),
+        (held_rows,))
+    row["sorted_scatter_add_ms"] = timed_ms(jax.jit(
+        lambda out, gate: in_token_order(out, gate, jnp.bfloat16)),
+        (held_rows, gate))
+    row["sorted_scatter_add_transpose_ms"] = timed_ms(jax.jit(
+        lambda out: in_token_order(out, None, jnp.bfloat16)), (held_rows,))
+    want = scatter_add(held_rows, gate).astype(jnp.float32)
+    got = seg_ops.segment_sum(held_rows, gate, by_token, n, jnp.bfloat16)
+    row["segment_sum_max_abs_diff"] = float(
+        jnp.max(jnp.abs(got.astype(jnp.float32) - want)))
     print(json.dumps(row), flush=True)
 
 

@@ -12,7 +12,8 @@
   sorting the logits), gates normalised over the chosen and scaled, the
   (token, choice) pairs that fall on the experts HELD HERE sorted by expert
   and run through a grouped matmul with ragged group sizes (no token
-  dropped, no one-hot dispatch), SwiGLU experts and a shared expert. What
+  dropped, no one-hot dispatch), summed back per token by a segment sum
+  in token order, SwiGLU experts and a shared expert. What
   `models/latent_moe_lm.py` and `models/hybrid_moe_lm.py` build. On one chip there is no exchange; the
   experts across chips with their all-to-all are ROADMAP R1.
 
@@ -65,6 +66,7 @@ import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from horovod_tpu.ops import grouped_matmul as gmm_ops
+from horovod_tpu.ops import segment_sum as seg_ops
 from horovod_tpu.parallel.mesh import EXPERT_AXIS
 
 # The routed layer's names in the compiled step (`jax.named_scope`: every op
@@ -405,20 +407,96 @@ def _route(tokens, router, *, k, scale, scoring=SIGMOID):
     return chosen, gates
 
 
+# The routed rows' two sums back to the tokens, each a `jax.custom_vjp` whose
+# rules both enter the part's scope: called where the layer's `hvt.moe` is
+# open, every op and the kernel carry ``hvt.moe/dispatch`` or
+# ``hvt.moe/combine``, forward and backward (the backward rule is traced
+# with the call's scopes, not those opened inside the forward rule).
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+@jax.named_scope(DISPATCH)
+def _dispatched(tokens, token_of, order, n):
+    """The rows ``tokens[token_of]``; backward, each token's rows summed
+    (`seg_ops.segment_sum` in float32, rounded once)."""
+    return tokens[token_of]
+
+
+@jax.named_scope(DISPATCH)
+def _dispatched_fwd(tokens, token_of, order, n):
+    return tokens[token_of], order
+
+
+@jax.named_scope(DISPATCH)
+def _dispatched_bwd(n, order, drows):
+    return seg_ops.segment_sum(drows, None, order, n, drows.dtype), None, None
+
+
+_dispatched.defvjp(_dispatched_fwd, _dispatched_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
+@jax.named_scope(COMBINE)
+def _combined(rows, weight, token_of, order, n, dtype):
+    """Each token's rows times their float32 weights, summed in float32
+    and rounded once to ``dtype`` (`seg_ops.segment_sum`); backward, the
+    cotangent gathered to the rows and its products with them."""
+    return seg_ops.segment_sum(rows, weight, order, n, dtype)
+
+
+@jax.named_scope(COMBINE)
+def _combined_fwd(rows, weight, token_of, order, n, dtype):
+    return (seg_ops.segment_sum(rows, weight, order, n, dtype),
+            (rows, weight, token_of))
+
+
+@jax.named_scope(COMBINE)
+def _combined_bwd(n, dtype, residuals, dmixed):
+    rows, weight, token_of = residuals
+    spread = dmixed.astype(jnp.float32)[token_of]
+    return ((spread * weight[:, None]).astype(rows.dtype),
+            jnp.sum(spread * rows.astype(jnp.float32), axis=-1), None, None)
+
+
+_combined.defvjp(_combined_fwd, _combined_bwd)
+
+
 def _held_experts(x, router, w_gate_up, w_down, *, k, scale, held_start,
                   budget, compute_dtype, scoring=SIGMOID):
     """The held experts' part of the layer's output for ``x`` [B, T, d],
-    and the three counts. The (token, choice) pairs that fall on experts
-    ``held_start .. held_start + n_held`` are sorted by expert, the first
-    ``budget`` of them gathered, pushed through two grouped matmuls and
-    scatter-added back under their gates; a pair past the budget is
-    counted as overflow."""
+    and the three counts: the router's choices and gates (`_route`), then
+    `_through_held`."""
     d = x.shape[-1]
     n = x.size // d
     n_held = w_down.shape[0]
     with jax.named_scope(ROUTE):
         chosen, gates = _route(x, router, k=k, scale=scale, scoring=scoring)
-    tokens = x.reshape(n, d)
+    mixed, counts = _through_held(
+        x.reshape(n, d), chosen.reshape(n, k), gates.reshape(n, k), w_gate_up,
+        w_down, held_start=held_start, budget=budget,
+        compute_dtype=compute_dtype)
+    held = jnp.sum(counts)
+    stats = {
+        "moe_overflow_rows": jnp.maximum(held - budget, 0),
+        "moe_held_rows_share": held / (n * k),
+        "moe_load_max_over_mean": jnp.max(counts) * n_held / jnp.maximum(
+            held, 1),
+    }
+    stats = {name: jnp.asarray(v, jnp.float32) for name, v in stats.items()}
+    return mixed.reshape(x.shape), stats
+
+
+def _through_held(tokens, chosen, gates, w_gate_up, w_down, *, held_start,
+                  budget, compute_dtype):
+    """``([n, d] in ``compute_dtype``, the held experts' row counts)`` for
+    tokens ``[n, d]`` that chose ``chosen`` [n, k] under ``gates`` [n, k]:
+    the (token, choice) pairs that fall on experts ``held_start ..
+    held_start + n_held`` are sorted by expert, the first ``budget`` of
+    them gathered, pushed through two grouped matmuls and summed back per
+    token under their gates; a pair past the budget adds nothing. Both
+    sums back to the tokens (the combine, and the gather's transpose) are
+    one segment sum over the rows in token order (`seg_ops.segment_sum`),
+    whose order is made once."""
+    n, k = chosen.shape
+    n_held = w_down.shape[0]
     with jax.named_scope(DISPATCH):
         local = chosen.reshape(-1) - held_start
         # A pair on an expert held elsewhere sorts behind every held one.
@@ -431,7 +509,11 @@ def _held_experts(x, router, w_gate_up, w_down, *, k, scale, held_start,
         ends = jnp.minimum(jnp.cumsum(counts), budget)
         sizes = jnp.diff(ends, prepend=0)
         token_of = order // k
-        rows = tokens.astype(compute_dtype)[token_of]  # [budget, d]
+        # Rows past the groups' total belong to no token: the grouped
+        # matmul gives them zeros, forward and backward.
+        by_token = seg_ops.token_order(order, ends[-1], n, k)
+        tokens = tokens.astype(compute_dtype)
+    rows = _dispatched(tokens, token_of, by_token, n)
     with jax.named_scope(EXPERTS):
         hidden = gmm_ops.grouped_matmul(
             rows, w_gate_up.astype(compute_dtype), sizes)
@@ -439,21 +521,9 @@ def _held_experts(x, router, w_gate_up, w_down, *, k, scale, held_start,
         out = gmm_ops.grouped_matmul(
             nn.silu(gate) * up, w_down.astype(compute_dtype), sizes)
     with jax.named_scope(COMBINE):
-        # Rows past the groups' total are zeros (ops/grouped_matmul.py), so
-        # whatever gate rides with them adds nothing.
-        # Summed in float32: a token's up to k experts meet here.
         weight = gates.reshape(-1)[order]
-        mixed = jnp.zeros((n, d), jnp.float32).at[token_of].add(
-            out.astype(jnp.float32) * weight[:, None]).astype(compute_dtype)
-    held = jnp.sum(counts)
-    stats = {
-        "moe_overflow_rows": jnp.maximum(held - budget, 0),
-        "moe_held_rows_share": held / (n * k),
-        "moe_load_max_over_mean": jnp.max(counts) * n_held / jnp.maximum(
-            held, 1),
-    }
-    stats = {name: jnp.asarray(v, jnp.float32) for name, v in stats.items()}
-    return mixed.reshape(x.shape), stats
+    # Summed in float32: a token's up to k experts meet here.
+    return _combined(out, weight, token_of, by_token, n, compute_dtype), counts
 
 
 class RoutedExperts(nn.Module):
@@ -497,7 +567,10 @@ class RoutedExperts(nn.Module):
     The selection, and its bias, are the same either way, and made by
     counting and comparing (`level_bias`, `_largest`; the gauge
     ``hvt_moe_selection{impl="count"}``): nothing on this path sorts the
-    ``[T, E]`` logits.
+    ``[T, E]`` logits. The expert rows come back to their tokens, forward
+    and in the gather's transpose, by one segment sum in token order
+    (`_through_held`; the gauge ``hvt_moe_combine{impl="segment"}``):
+    nothing on this path scatter-adds into ``[T, d]``.
     """
 
     n_routed: int
@@ -537,6 +610,7 @@ class RoutedExperts(nn.Module):
         obs.gauge("hvt_moe_experts", float(self.n_routed), kind="routed")
         obs.gauge("hvt_moe_gate", 1.0, scoring=self.scoring)
         obs.gauge("hvt_moe_selection", 1.0, impl="count")
+        obs.gauge("hvt_moe_combine", 1.0, impl="segment")
 
         router = self.param(
             "router", nn.initializers.lecun_normal(), (d, self.n_routed))

@@ -418,6 +418,14 @@ METRICS: dict[str, MetricSpec] = _decl([
                "floats' order keys and the top k as k maxima; no sort of "
                "the [T, E] logits. The only form there is. Set at trace "
                "time.", "training", labels=("impl",)),
+    MetricSpec("hvt_moe_combine", "gauge",
+               "How the last routed layer traced sums its rows back to the "
+               "tokens, the combine and the transpose of the row gather "
+               "(models/moe.py, ops/segment_sum.py): 1 on `segment`, one "
+               "segment sum over the rows in token order (the Mosaic call "
+               "hvt_moe_combine); no scatter-add into [T, d]. The only "
+               "form there is. Set at trace time.", "training",
+               labels=("impl",)),
     MetricSpec("hvt_remat_blocks", "gauge",
                "Blocks of the last HybridMoELM traced that are "
                "rematerialised in the backward pass (`remat`: all of them "

@@ -618,6 +618,86 @@ def test_routed_layer_selects_without_sorting_its_logits(
     assert 'hvt_moe_selection{impl="count"} 1' in prom.render()
 
 
+@pytest.mark.parametrize("rows,d,tokens,k", [
+    (3328, 4096, 8192, 8),    # Solar's
+    (12288, 2048, 8192, 6),   # Kanana's
+    (9216, 4096, 4096, 10),   # Granite's
+    (16384, 2048, 8192, 8),   # Laguna's
+], ids=["solar", "kanana", "granite", "laguna"])
+def test_segment_sum_compiles_for_v5e(topo, compiled_kernel, rows, d, tokens,
+                                      k):
+    """The routed layer's two sums back to the tokens at a routed cell's
+    rows x width -> tokens: the combine (gated) and, in the backward pass,
+    the transpose of the dispatch's row gather (weight 1), one Mosaic call
+    `hvt_moe_combine` each."""
+    from horovod_tpu.models import moe
+    from horovod_tpu.ops import segment_sum as seg
+
+    one_chip = SingleDeviceSharding(topo.devices[0])
+
+    def sds(shape, dtype=jnp.bfloat16):
+        return SDS(shape, dtype, sharding=one_chip)
+
+    def loss(x, out, weight, pairs, held):
+        order = seg.token_order(pairs, held, tokens, k)
+        token_of = pairs // k
+        gathered = moe._dispatched(x, token_of, order, tokens)
+        mixed = moe._combined(out, weight, token_of, order, tokens,
+                              jnp.bfloat16)
+        return (mixed.astype(jnp.float32).sum()
+                + gathered.astype(jnp.float32).sum())
+
+    compiled = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2))).lower(
+        sds((tokens, d)), sds((rows, d)), sds((rows,), jnp.float32),
+        sds((rows,), jnp.int32), sds((), jnp.int32)).compile()
+    assert kernel_names(compiled) == [seg.KERNEL] * 2
+
+
+@pytest.mark.parametrize("tokens,d,routed,held,k", [
+    (512, 256, 16, 4, 2),      # a toy layer
+    (4096, 4096, 72, 8, 10),   # Granite's: the widest rows a cell sums
+])
+def test_routed_layer_sums_its_rows_without_a_scatter(
+        topo, compiled_kernel, tokens, d, routed, held, k):
+    """`RoutedExperts` compiled for the described v5e, forward and
+    backward: no `scatter` instruction has a result of the tokens' shape
+    ``[tokens, d]``; the combine and the transpose of the row gather are
+    the two calls of `hvt_moe_combine`, each under its part's scope (the
+    one the benchmark's `moe_dispatch_ms_per_step` reads the kernel by),
+    the combine's in the forward pass and the gather's in the backward.
+    The gauge says so at trace time."""
+    from horovod_tpu.models.moe import RoutedExperts
+    from horovod_tpu.obs import prom
+    from horovod_tpu.ops import segment_sum as seg
+
+    layer = RoutedExperts(
+        n_routed=routed, k=k, expert_width=d // 4, shared_width=d // 4,
+        n_held=held, held_start=0, routed_scaling=2.5,
+        compute_dtype=jnp.bfloat16)
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    x = SDS((1, tokens, d), jnp.bfloat16, sharding=one_chip)
+    params = jax.tree.map(
+        lambda leaf: SDS(leaf.shape, leaf.dtype, sharding=one_chip),
+        jax.eval_shape(layer.init, jax.random.PRNGKey(0), x))
+
+    def loss(params, x):
+        return layer.apply(params, x).astype(jnp.float32).sum()
+
+    hlo = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(
+        params, x).compile().as_text()
+    scatters = [line for line in hlo.splitlines()
+                if re.search(rf"\[(1,)?{tokens},{d}\]\S* scatter\(", line)]
+    assert not scatters, scatters
+    scopes = re.findall(
+        rf"%{seg.KERNEL}[.\d]* = .*?op_name=\"([^\"]*)\"", hlo)
+    assert len(scopes) == 2, scopes
+    assert any("hvt.moe/combine/" in s and "transpose(" not in s
+               for s in scopes), scopes
+    assert any("hvt.moe/dispatch/" in s and "transpose(" in s
+               for s in scopes), scopes
+    assert 'hvt_moe_combine{impl="segment"} 1' in prom.render()
+
+
 def test_latent_moe_cell_step_fits_one_chip(topo, compiled_kernel):
     """The whole training step of the cell at its own sizes (1 dense + 5
     expert layers, 687.5 M parameters, 8,192 tokens): it compiles, every
@@ -626,6 +706,7 @@ def test_latent_moe_cell_step_fits_one_chip(topo, compiled_kernel):
     chose the depth (14.62 when it was chosen; 1 + 6 layers read 16.00)."""
     from horovod_tpu.models.latent_moe_lm import LatentMoELM
     from horovod_tpu.ops import grouped_matmul as gm
+    from horovod_tpu.ops import segment_sum as seg
 
     mesh = mesh_lib.build_mesh(
         mesh_lib.MeshSpec(data=1), devices=topo.devices[:1])
@@ -644,7 +725,8 @@ def test_latent_moe_cell_step_fits_one_chip(topo, compiled_kernel):
         "moe_held_rows_share", "moe_load_max_over_mean", "moe_overflow_rows")
     compiled = compiled_step(trainer, seq=8192, batch=1)
     assert kernel_names(compiled) == sorted(
-        FUSED * 6 + [gm.KERNEL] * 20 + [gm.KERNEL_DW] * 10)
+        FUSED * 6 + [gm.KERNEL] * 20 + [gm.KERNEL_DW] * 10
+        + [seg.KERNEL] * 10)
     memory = compiled.memory_analysis()
     state = 687_502_336 * 12
     assert state <= memory.argument_size_in_bytes <= state + 1_000_000
@@ -740,6 +822,7 @@ def test_hybrid_moe_cell_step_fits_one_chip(topo, compiled_kernel):
     from horovod_tpu.obs import prom
     from horovod_tpu.ops import delta_rule
     from horovod_tpu.ops import grouped_matmul as gm
+    from horovod_tpu.ops import segment_sum as seg
 
     mesh = mesh_lib.build_mesh(
         mesh_lib.MeshSpec(data=1), devices=topo.devices[:1])
@@ -759,7 +842,7 @@ def test_hybrid_moe_cell_step_fits_one_chip(topo, compiled_kernel):
         "moe_held_rows_share", "moe_load_max_over_mean", "moe_overflow_rows")
     compiled = compiled_step(trainer, seq=8192, batch=1)
     assert kernel_names(compiled) == sorted(
-        FUSED + [gm.KERNEL] * 16 + [gm.KERNEL_DW] * 8
+        FUSED + [gm.KERNEL] * 16 + [gm.KERNEL_DW] * 8 + [seg.KERNEL] * 8
         + [delta_rule.KERNEL_FWD] * 6 + [delta_rule.KERNEL_BWD] * 9)
     hlo = compiled.as_text()
     for scope in (hybrid.KDA_PROJ, hybrid.KDA_CONV, hybrid.KDA_SCAN,
@@ -840,6 +923,7 @@ def test_ssm_moe_cell_step_fits_one_chip(topo, compiled_kernel):
     from horovod_tpu.models import hybrid_moe_lm as hybrid
     from horovod_tpu.obs import prom
     from horovod_tpu.ops import grouped_matmul as gm
+    from horovod_tpu.ops import segment_sum as seg
 
     root = pathlib.Path(__file__).resolve().parents[1]
     cell = run.load_cell(root, "granite-4.0-h-small.seq4k.1chip")
@@ -850,7 +934,7 @@ def test_ssm_moe_cell_step_fits_one_chip(topo, compiled_kernel):
     compiled = compiled_step(trainer, seq=4096, batch=1)
     assert kernel_names(compiled) == sorted(
         [fa.KERNEL_FWD] * 2 + [fa.KERNEL_BWD]
-        + [gm.KERNEL] * 60 + [gm.KERNEL_DW] * 20)
+        + [gm.KERNEL] * 60 + [gm.KERNEL_DW] * 20 + [seg.KERNEL] * 20)
     hlo = compiled.as_text()
     for scope in (hybrid.SSM_PROJ, hybrid.SSM_CONV, hybrid.SSM_SCAN,
                   hybrid.SSM_OUT):
@@ -896,6 +980,7 @@ def test_window_moe_cell_step_fits_one_chip(topo, compiled_kernel):
     from horovod_tpu.models import hybrid_moe_lm as hybrid
     from horovod_tpu.obs import prom
     from horovod_tpu.ops import grouped_matmul as gm
+    from horovod_tpu.ops import segment_sum as seg
 
     root = pathlib.Path(__file__).resolve().parents[1]
     cell = run.load_cell(root, "laguna-xs.2.seq8k.1chip")
@@ -906,7 +991,7 @@ def test_window_moe_cell_step_fits_one_chip(topo, compiled_kernel):
     compiled = compiled_step(trainer, seq=8192, batch=1)
     assert kernel_names(compiled) == sorted(
         [fa.KERNEL_FWD] * 10 + [fa.KERNEL_BWD] * 5
-        + [gm.KERNEL] * 24 + [gm.KERNEL_DW] * 8)
+        + [gm.KERNEL] * 24 + [gm.KERNEL_DW] * 8 + [seg.KERNEL] * 8)
     scopes = re.findall(
         r"%hvt_flash_(?:fwd|bwd)[.\d]* = .*?op_name=\"([^\"]*)\"",
         compiled.as_text())

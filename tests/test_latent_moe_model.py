@@ -166,8 +166,13 @@ def test_the_compiled_step_names_the_layers_forward_and_backward():
         backward = [n for n in names if scope in n and "transpose" in n]
         assert forward and backward, scope
     assert any("hvt.moe/dispatch" in n and "sort" in n for n in names)
-    assert any("hvt.moe/combine" in n and "scatter" in n for n in names)
-    for kernel in (gm.KERNEL, gm.KERNEL_DW, "hvt_flash_fwd", "hvt_flash_bwd"):
+    # The segment sum (`hvt_moe_combine`, a jitted call) is the combine's
+    # forward and the dispatch's backward, under each part's scope.
+    sums = [n for n in names if n.endswith("/jit(_segment_sum_impl)")]
+    assert any("hvt.moe/combine/" in n and "transpose" not in n for n in sums)
+    assert any("hvt.moe/dispatch/" in n and "transpose" in n for n in sums)
+    for kernel in (gm.KERNEL, gm.KERNEL_DW, "hvt_flash_fwd", "hvt_flash_bwd",
+                   "hvt_moe_combine"):
         assert any(re.search(rf"{kernel}(\)|/|$)", n) for n in names), kernel
     # the flash kernels sit outside hvt.mla: its metric is the projections
     assert not any("hvt.mla" in n and "hvt_flash" in n for n in names)

@@ -770,3 +770,127 @@ def test_routed_path_holds_no_sort_of_the_logits():
         lambda t, r: moe_lib._route(t, r, k=2, scale=1.0))(tokens, router))
     assert " sort[" not in text and "top_k" not in text
     assert " scan[" in text or " while[" in text  # the bisection's passes
+
+
+# --- `RoutedExperts`' sums back to the tokens: a segment sum in token order --
+#
+# The combine and the transpose of the dispatch's row gather are one segment
+# sum over the rows in token order (`ops/segment_sum.py`, the Mosaic call
+# `hvt_moe_combine`, in the Pallas interpreter here); the two scatter-adds
+# into `[T, d]` they replaced live on here as their oracle.
+
+from horovod_tpu.ops import segment_sum as seg_lib  # noqa: E402
+
+
+def scatter_dispatched(tokens, token_of, order, n):
+    """The parent's dispatch: the gather, whose transpose is a
+    scatter-add."""
+    return tokens[token_of]
+
+
+def scatter_combined(rows, weight, token_of, order, n, dtype):
+    """The parent's combine: a gated float32 scatter-add into `[n, d]`."""
+    return jnp.zeros((n, rows.shape[1]), jnp.float32).at[token_of].add(
+        rows.astype(jnp.float32) * weight[:, None]).astype(dtype)
+
+
+def _routing(case):
+    """``(chosen [n, k], budget, what the case plants)`` over 16 routed
+    experts of which 0..3 are held."""
+    rng = np.random.default_rng(len(case))
+    n, k, budget = {"no_held_pair": (64, 2, 64),
+                    "all_k_held": (64, 4, 128),
+                    "past_the_budget": (96, 2, 16),
+                    "rows_past_the_total": (64, 2, 256),
+                    "budget_under_a_row_chunk": (24, 2, 24),
+                    "run_over_several_chunks": (160, 4, 768)}[case]
+    chosen = np.stack([rng.permutation(16)[:k] for _ in range(n)])
+    if case == "no_held_pair":
+        chosen[:20] = rng.permutation(np.arange(4, 16))[:k]
+    if case == "all_k_held":
+        chosen[3] = [2, 0, 3, 1]
+    if case == "run_over_several_chunks":
+        # The first token tile's 128 tokens put every choice on the held
+        # experts: a run of 512 rows, four chunks of 128.
+        chosen[:128] = [rng.permutation(4)[:k] for _ in range(128)]
+    return jnp.asarray(chosen, jnp.int32), budget
+
+
+ROUTINGS = ("no_held_pair", "all_k_held", "past_the_budget",
+            "rows_past_the_total", "budget_under_a_row_chunk",
+            "run_over_several_chunks")
+
+
+@pytest.mark.parametrize("case", ROUTINGS)
+def test_routed_sums_are_the_scatter_adds(case, monkeypatch):
+    """`moe._through_held` (dispatch, two grouped matmuls, combine) with the
+    segment sums against the same with the two scatter-adds: the output
+    and the gradients with respect to the tokens, the experts' weights and
+    the gates, to float32 rounding; and `segment_sum` alone against its
+    oracle on bfloat16 rows, within the split of its weights (2^-18 of
+    each term) and exactly where the weight is 1."""
+    chosen, budget = _routing(case)
+    n, k = chosen.shape
+    d, width, held = 128, 64, 4
+    rng = np.random.default_rng(7)
+    tokens = jnp.asarray(rng.standard_normal((n, d)), jnp.float32)
+    gates = jnp.asarray(rng.random((n, k)) + 0.5, jnp.float32)
+    w_gate_up = jnp.asarray(rng.standard_normal((held, d, 2 * width)) * 0.1,
+                            jnp.float32)
+    w_down = jnp.asarray(rng.standard_normal((held, width, d)) * 0.1,
+                         jnp.float32)
+    probe = jnp.asarray(rng.standard_normal((n, d)), jnp.float32)
+
+    def run():
+        def loss(tokens, w_gate_up, w_down, gates):
+            mixed, counts = moe_lib._through_held(
+                tokens, chosen, gates, w_gate_up, w_down, held_start=0,
+                budget=budget, compute_dtype=jnp.float32)
+            return jnp.sum(mixed * probe), (mixed, counts)
+        return jax.jit(jax.value_and_grad(
+            loss, argnums=(0, 1, 2, 3), has_aux=True))(
+                tokens, w_gate_up, w_down, gates)
+
+    (_, (mixed, counts)), grads = run()
+    with monkeypatch.context() as patched:
+        patched.setattr(moe_lib, "_dispatched", scatter_dispatched)
+        patched.setattr(moe_lib, "_combined", scatter_combined)
+        (_, (want_mixed, _)), want_grads = run()
+    held_pairs = int(jnp.sum(counts))
+    if case == "past_the_budget":
+        assert held_pairs > budget
+    else:
+        assert held_pairs <= budget
+    if case in ("rows_past_the_total", "budget_under_a_row_chunk"):
+        assert held_pairs < budget
+    if case == "no_held_pair":
+        np.testing.assert_array_equal(mixed[:20], 0.0)
+    assert np.any(np.asarray(mixed))
+    for got, want in zip((mixed, *grads), (want_mixed, *want_grads)):
+        scale = float(jnp.max(jnp.abs(want)))
+        assert scale > 0
+        np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-6 * scale)
+
+    # `segment_sum` alone on the case's token order, bfloat16 rows.
+    local = jnp.where(chosen.reshape(-1) < held, chosen.reshape(-1), held)
+    pairs = jnp.pad(jnp.argsort(local, stable=True)[:budget],
+                    (0, max(0, budget - n * k)))
+    total = min(held_pairs, budget)
+    order = seg_lib.token_order(pairs, total, n, k)
+    valid = (jnp.arange(budget) < total)[:, None]
+    rows = jnp.where(valid, jnp.asarray(
+        rng.standard_normal((budget, d)), jnp.bfloat16), 0)
+    weight = jnp.asarray(rng.random(budget) + 0.5, jnp.float32)
+    for w in (weight, None):
+        got = seg_lib.segment_sum(rows, w, order, n, jnp.float32)
+        ones = jnp.ones(budget, jnp.float32) if w is None else w
+        want = scatter_combined(rows, ones, pairs // k, None, n, jnp.float32)
+        terms = scatter_combined(jnp.abs(rows.astype(jnp.float32)), ones,
+                                 pairs // k, None, n, jnp.float32)
+        bound = 0.0 if w is None else 2.0 ** -17
+        assert np.all(np.abs(got - want) <= bound * terms + 1e-6 * terms)
+        in_dtype = seg_lib.segment_sum(rows, w, order, n, jnp.bfloat16)
+        assert in_dtype.dtype == jnp.bfloat16 and in_dtype.shape == (n, d)
+        np.testing.assert_allclose(
+            np.asarray(in_dtype, np.float32), want, rtol=2.0 ** -8,
+            atol=2.0 ** -8 * float(jnp.max(terms)))
