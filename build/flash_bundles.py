@@ -22,7 +22,11 @@ bundles every grid step pays (before / between / after), and each
 predicated region (`pl.when`: the accumulator init, the update bodies, the
 finalisation; the pipeline's own DMA branches are short and are folded into
 the straight-line parts) — with the slot totals and the vector opcodes
-that tell a mask being built: iota (`vlaneseq`), compares, selects.
+that tell a mask being built: iota (`vlaneseq`), compares, selects. The
+regions that use the MXU are the update bodies, named in source order:
+`full` (no mask), then either `edge` (the whole tile masked) or the
+triangle tiles the call runs by row strips, `diag` (the causal diagonal)
+and `band` (the band's lower edge).
 A kernel PR starts from this table, not from a guess (PERF.md §3, §7).
 """
 
@@ -38,11 +42,13 @@ import subprocess
 import sys
 import tempfile
 
-# The three cells' calls (chipbench/configs): B, T, heads, Dk, Dv, window.
+# The cells' calls (chipbench/configs): B, T, heads, Dk, Dv, window;
+# `laguna` is that cell's window layer.
 CELLS = {
     "kanana": dict(b=1, t=8192, heads=32, dk=192, dv=128, window=None),
     "starcoder2": dict(b=1, t=4096, heads=24, dk=128, dv=128, window=4096),
     "cerebras": dict(b=2, t=2048, heads=16, dk=128, dv=128, window=None),
+    "laguna": dict(b=1, t=8192, heads=64, dk=128, dv=128, window=512),
 }
 # Every name a flash call can take; a backward is `hvt_flash_bwd` alone or
 # `hvt_flash_dq` + `hvt_flash_dkv` (`flash_attention.fused_backward`).
@@ -164,12 +170,15 @@ def cut_loop(bundles):
     return cut(bundles, lb, end - 1)
 
 
-def report(name, bundle_path, slot_path):
+def report(name, bundle_path, slot_path, bodies=()):
+    """The kernel's grid loop by piece; the MXU regions take the names of
+    ``bodies`` in order."""
     bundles = read_bundles(bundle_path)
     slot_names, slots = read_slots(slot_path, len(bundles))
+    names = iter(bodies)
     print(f"\n{name}: {len(bundles)} bundles")
     print(f"  {'piece':<12}{'bundles':>8}  " + " ".join(
-        f"{s:>6}" for s in slot_names[:7]) + "   mask ops")
+        f"{s:>6}" for s in slot_names[:7]) + "   body   mask ops")
     for depth, kind, a, b in cut_loop(bundles):
         ops = collections.Counter()
         for _, text in bundles[a:b + 1]:
@@ -177,15 +186,33 @@ def report(name, bundle_path, slot_path):
         mask = {k: sum(n for op, n in ops.items() if op.startswith(k))
                 for k in MASK_OPS}
         total = [sum(r[c] for r in slots[a:b + 1]) for c in range(7)]
+        body = next(names, "") if kind == "when" and total[0] else ""
         print(f"  {'. ' * depth + kind:<12}{b - a + 1:>8}  " + " ".join(
-            f"{t:>6}" for t in total) + "   " + " ".join(
+            f"{t:>6}" for t in total) + f"   {body:<6} " + " ".join(
             f"{k} {n}" for k, n in mask.items() if n))
 
 
-def report_dump(dump: str) -> None:
+def update_bodies(args) -> list[str]:
+    """The update bodies of the call's kernels, in source order."""
+    import jax.numpy as jnp
+
+    from horovod_tpu.ops import flash_attention as fa
+
+    block = args.block or fa.DEFAULT_BLOCK_Q
+    bq, bk = fa.pick_blocks(
+        args.t, max(args.dk, args.dv), jnp.bfloat16, block, block,
+        windowed=args.window is not None)
+    kinds = fa._triangle_kinds(
+        args.t, args.t, bq, bk, causal=True, window=args.window, sinks=0,
+        offset=0, segmented=False)
+    return ["full", *(kinds or ["edge"])]
+
+
+def report_dump(dump: str, bodies=()) -> None:
     """Every kernel of KERNELS the dump holds a schedule of; one that is not
     there is said to be absent (the backward took the other form), and a
-    dump with none at all is a compile that failed before the kernels."""
+    dump with none at all is a compile that failed before the kernels.
+    ``bodies``: the update bodies' names (`update_bodies`)."""
     schedules = {
         kernel: sorted(glob.glob(
             os.path.join(dump, f"*{kernel}*-71-final_bundles.txt")))
@@ -200,7 +227,8 @@ def report_dump(dump: str) -> None:
         for path in found:
             stem = path[:-len("-71-final_bundles.txt")]
             slots = glob.glob(stem + "-69-*utilization.txt")[0]
-            report(os.path.basename(stem).split("-", 1)[1], path, slots)
+            report(os.path.basename(stem).split("-", 1)[1], path, slots,
+                   bodies)
 
 
 def main() -> None:
@@ -230,7 +258,7 @@ def main() -> None:
         compile_in_child(args, dump)
         print(f"B {args.b} T {args.t} heads {args.heads} Dk {args.dk} "
               f"Dv {args.dv} window {args.window} block {args.block}")
-        report_dump(dump)
+        report_dump(dump, update_bodies(args))
     finally:
         if not args.keep:
             shutil.rmtree(dump, ignore_errors=True)

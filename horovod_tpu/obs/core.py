@@ -341,6 +341,18 @@ METRICS: dict[str, MetricSpec] = _decl([
                "of a call with segment ids). Set at trace time: the "
                "census is static per call.",
                "training", labels=("kind",)),
+    MetricSpec("hvt_flash_subtiles", "gauge",
+               "Sub-blocks of the triangle tiles of a (batch, head) of the "
+               "last flash-attention forward grid traced "
+               "(ops/flash_attention.py `subtile_census`): where every "
+               "edge tile is the causal diagonal's or the band's lower "
+               "edge's triangle, such a tile runs in row strips, and its "
+               "strip × strip blocks are `run_full` (no mask operation), "
+               "`run_masked` (the block the edge crosses builds its mask) "
+               "or `skipped` (the empty half: not computed). All 0 on a "
+               "call that runs its edge tiles whole. Set at trace time: "
+               "the census is static per call.",
+               "training", labels=("kind",)),
     MetricSpec("hvt_flash_backward", "gauge",
                "Form of the last flash-attention backward traced "
                "(ops/flash_attention.py `fused_backward`): 1 on the one "

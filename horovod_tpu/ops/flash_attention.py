@@ -186,7 +186,8 @@ def tile_census(tq, tk, bq, bk, *, causal, window=None, sinks=0,
     """(skipped, edge, full): the grid steps of ONE (b, h) of the forward
     grid by class — static per call, and the gauge ``hvt_flash_tiles``. A
     skipped step builds and fetches nothing, a full one runs the update
-    with no mask operation, an edge one builds its [bq, bk] mask. The dQ
+    with no mask operation, an edge one builds its [bq, bk] mask, or runs
+    by strips where it is a triangle (`subtile_census`). The dQ
     grid is the same; the dK/dV grid holds the same tiles transposed. With
     segment ids nothing is provably full and every tile that runs is an
     edge (the id ranges skip more of them at run time)."""
@@ -199,6 +200,99 @@ def tile_census(tq, tk, bq, bk, *, causal, window=None, sinks=0,
     full = np.broadcast_to(full, iq.shape) & needed & (not segmented)
     return (int((~needed).sum()), int((needed & ~full).sum()),
             int(full.sum()))
+
+
+# Triangle tiles. Where the tiles are square (bq == bk = b) and the q↔k
+# offset and the window are multiples of b, an edge tile is one of two
+# triangles, told apart by its first row r0 and first column c0 alone:
+# the causal DIAGONAL (c0 == r0: a pair is visible iff its tile-local
+# column κ ≤ its row ρ) and the band's LOWER EDGE (c0 == r0 − window:
+# visible iff κ > ρ). Such a tile runs in row strips of _STRIP_ROWS: strip
+# i of the diagonal reads columns [0, (i+1)·s), of the lower edge [i·s, b),
+# and only the s × s block on the triangle's own diagonal builds a mask —
+# the tile's empty half is neither loaded nor computed. The strip height
+# is the MXU's side and the lanes' count (a strip's scores are [s, ·] over
+# a lane-aligned column slice); of the heights that tile 512 and 1024, 128
+# gives the shortest static schedules (`build/flash_bundles.py`: at 256
+# the bodies are 8–36 % longer; PERF.md §6).
+_STRIP_ROWS = 128
+
+
+def _triangle_tiles(tq, tk, bq, bk, offset, window):
+    """{kind: triangle tiles a (b, h)} of a call whose edge tiles are all
+    triangles, else {}: q block iq has its diagonal tile where its first
+    row r0 = iq·b + offset is a key (0 ≤ r0 < Tk), its band's lower-edge
+    tile where r0 − window is one. Every other needed tile is full."""
+    if bq != bk or offset % bk or (window is not None and window % bk):
+        return {}
+    r0 = np.arange(tq // bq) * bq + offset
+    tiles = {"diag": int(((r0 >= 0) & (r0 < tk)).sum())}
+    if window is not None:
+        tiles["band"] = int(((r0 >= window) & (r0 - window < tk)).sum())
+    return tiles
+
+
+def _triangle_kinds(tq, tk, bq, bk, *, causal, window, sinks, offset,
+                    segmented):
+    """The triangle kinds of a call's grid that run by strips, static per
+    call; () where the call takes the general edge body (no mask, segment
+    ids, sinks, unequal tiles, an offset or window off the tile grid, a
+    tile no taller than a strip)."""
+    if not causal or segmented or sinks or bk <= _STRIP_ROWS \
+            or bk % _STRIP_ROWS:
+        return ()
+    tiles = _triangle_tiles(tq, tk, bq, bk, offset, window)
+    return tuple(kind for kind, n in tiles.items() if n)
+
+
+def _triangle_strips(kind, b):
+    """[(rows, cols, at)]: the row strips of a triangle tile of side ``b``,
+    the columns each reads, and where among them the s × s block the
+    triangle's edge crosses begins (the diagonal's last, the lower edge's
+    first). Narrowest strip first: the order the chip's compiler schedules
+    best (PERF.md §6)."""
+    s, strips = _STRIP_ROWS, []
+    for i in range(b // s):
+        lo, hi = i * s, (i + 1) * s
+        if kind == "diag":
+            strips.append((slice(lo, hi), slice(0, hi), lo))
+        else:
+            lo, hi = b - hi, b - lo
+            strips.append((slice(lo, hi), slice(lo, b), 0))
+    return strips
+
+
+def subtile_census(tq, tk, bq, bk, *, causal, window=None, sinks=0,
+                   q_offset=None, segmented=False):
+    """(run_full, run_masked, skipped): the s × s sub-blocks of ONE (b, h)
+    of the forward grid's triangle tiles by what they do — the gauge
+    ``hvt_flash_subtiles``. (0, 0, 0) where the call runs no triangle
+    tile."""
+    off = tk - tq if q_offset is None else q_offset
+    if not _triangle_kinds(
+            tq, tk, bq, bk, causal=causal, window=window, sinks=sinks,
+            offset=off, segmented=segmented):
+        return (0, 0, 0)
+    n = bk // _STRIP_ROWS
+    tiles = sum(_triangle_tiles(tq, tk, bq, bk, off, window).values())
+    return (tiles * n * (n - 1) // 2, tiles * n, tiles * n * (n - 1) // 2)
+
+
+def _is_triangle(kind, iq, ik, b, offset, window):
+    """Whether tile (iq, ik) is the ``kind`` triangle of its q block: a
+    scalar compare of the grid's coordinates (`_triangle_tiles`)."""
+    shift = offset if kind == "diag" else offset - window
+    return ik == iq + shift // b
+
+
+def _triangle_mask(kind):
+    """The [s, s] 0/1 float mask of the block a triangle's edge crosses:
+    κ ≤ ρ on the diagonal, κ > ρ on the band's lower edge."""
+    s = _STRIP_ROWS
+    rows = lax.broadcasted_iota(jnp.int32, (s, s), 0)
+    cols = lax.broadcasted_iota(jnp.int32, (s, s), 1)
+    keep = rows >= cols if kind == "diag" else cols > rows
+    return keep.astype(jnp.float32)
 
 
 def _tile_mask(iq, ik, causal, segmented, bq, bk, offset, window,
@@ -247,26 +341,80 @@ def _segments_overlap(qs_ref, ks_ref):
     return (jnp.min(ks) <= jnp.max(qs)) & (jnp.max(ks) >= jnp.min(qs))
 
 
-def _update_by_class(needed, full, masked, make_mask, update):
-    """Run ``update(mask)`` as the tile's class asks, so that nothing of
+# A whole tile's rows or columns, as `update` indexes them.
+_ALL = slice(None)
+
+
+def _masked(x, mask, at, fn):
+    """``fn(x, mask)`` where the mask covers all of ``x`` (``at`` None),
+    else on the mask's lanes of ``x`` from lane ``at`` on alone: the other
+    lanes of a triangle's strip are visible and take no mask operation."""
+    if at is None:
+        return fn(x, mask)
+    w = mask.shape[1]
+    pieces = [x[:, :at], fn(x[:, at:at + w], mask), x[:, at + w:]]
+    return jnp.concatenate([p for p in pieces if p.shape[1]], axis=1)
+
+
+def _to_big_neg(s, mask):
+    """Masked scores pushed to _BIG_NEG, BEFORE exp: a large masked score
+    would overflow exp to inf, and the TPU's inf*0 is NaN — the post-hoc
+    ``p * mask`` alone is only safe in interpret mode."""
+    return s + (1.0 - mask) * _BIG_NEG
+
+
+# The strips of a whole tile: all its rows and columns, under the mask
+# given, if any (`_masked`).
+_WHOLE = ((_ALL, _ALL, None, None),)
+
+
+def _by_strips(update, kind, b):
+    """A triangle tile as its row strips: one ``update`` of them all, the
+    one s × s mask built once."""
+    mask = _triangle_mask(kind)
+    update([(rows, cols, mask, at)
+            for rows, cols, at in _triangle_strips(kind, b)])
+
+
+def _update_by_class(needed, full, masked, make_mask, update,
+                     triangles=()):
+    """Run ``update(strips)`` as the tile's class asks, so that nothing of
     the score tile's shape is computed outside a branch: a skipped step
     runs neither branch; a full tile runs the update with NO mask
     operation (on a wholly visible tile they add 0 and multiply by 1); an
     edge tile builds its mask inside its branch and runs today's
     arithmetic. ``masked`` False: the call has no mask at all. ``full``
     the literal False: nothing is provable (segment ids, the sink-only
-    pass) and every tile that runs is an edge."""
+    pass) and every tile that runs is an edge. ``triangles``: [(is_kind,
+    kind, b)] where every edge tile is one of them (`_triangle_kinds`):
+    each kind runs by strips, and no general edge body is built.
+
+    ``strips`` is [(rows, cols, mask, at)] (`_WHOLE` by default): each
+    strip's rows against its columns, under its mask (`_masked`); the
+    update runs each of its phases over every strip before the next,
+    which the chip's compiler schedules best (PERF.md §6)."""
     if not masked:
-        pl.when(needed)(lambda: update(None))
+        pl.when(needed)(lambda: update())
     elif full is False:
-        pl.when(needed)(lambda: update(make_mask()))
+        pl.when(needed)(lambda: update([(_ALL, _ALL, make_mask(), None)]))
     else:
-        pl.when(needed & full)(lambda: update(None))
-        pl.when(needed & ~full)(lambda: update(make_mask()))
+        pl.when(needed & full)(lambda: update())
+        if not triangles:
+            pl.when(needed & ~full)(
+                lambda: update([(_ALL, _ALL, make_mask(), None)]))
+        for is_kind, kind, b in triangles:
+            pl.when(needed & is_kind)(
+                functools.partial(_by_strips, update, kind, b))
+
+
+def _kernel_triangles(kinds, iq, ik, b, offset, window):
+    """`_update_by_class`'s ``triangles`` of a kernel's tile (iq, ik)."""
+    return [(_is_triangle(kind, iq, ik, b, offset, window), kind, b)
+            for kind in kinds]
 
 
 def _fwd_kernel(q_ref, k_ref, v_ref, *rest, scale, causal, segmented,
-                bq, bk, offset, window, nk, sinks=0):
+                bq, bk, offset, window, nk, sinks=0, triangles=()):
     if segmented:
         qs_ref, ks_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref = rest
     else:
@@ -297,31 +445,44 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *rest, scale, causal, segmented,
             iq, ik, causal, segmented, bq, bk, offset, window, qs_ref,
             ks_ref, sinks=sinks, is_sink=is_sink)
 
-    def update(mask):
-        q = q_ref[0, 0, :, :]
-        k = k_ref[0, 0, :, :]
-        v = v_ref[0, 0, :, :]
+    def update(strips=_WHOLE):
+        # Rows are independent: a strip's update is its rows' share of a
+        # whole tile's.
+        qkv = [(q_ref[0, 0, rows, :], k_ref[0, 0, cols, :],
+                v_ref[0, 0, cols, :]) for rows, cols, _, _ in strips]
 
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        ) * scale  # [bq, bk]
-        if mask is not None:
-            s = s + (1.0 - mask) * _BIG_NEG
+        scores = []
+        for (q, k, _), (_, _, mask, at) in zip(qkv, strips):
+            s = jax.lax.dot_general(
+                q, k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            ) * scale  # [rows, cols]
+            if mask is not None:
+                s = _masked(s, mask, at, _to_big_neg)
+            scores.append(s)
 
-        m_prev = m_ref[:, 0:1]
-        m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new)
-        if mask is not None:
-            p = p * mask  # exact zeros on masked lanes
-        l_ref[:, 0:1] = l_ref[:, 0:1] * alpha + p.sum(axis=-1, keepdims=True)
-        acc_ref[:] = acc_ref[:] * alpha + jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        m_ref[:, 0:1] = m_new
+        stats = []
+        for s, (rows, _, mask, at) in zip(scores, strips):
+            m_prev = m_ref[rows, 0:1]
+            m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+            alpha = jnp.exp(m_prev - m_new)
+            p = jnp.exp(s - m_new)
+            if mask is not None:
+                p = _masked(p, mask, at, jnp.multiply)  # exact zeros
+            stats.append((m_new, alpha, p))
+        for (_, _, v), (m_new, alpha, p), (rows, _, _, _) in zip(
+                qkv, stats, strips):
+            l_ref[rows, 0:1] = (
+                l_ref[rows, 0:1] * alpha + p.sum(axis=-1, keepdims=True))
+            acc_ref[rows] = acc_ref[rows] * alpha + jax.lax.dot_general(
+                p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )
+            m_ref[rows, 0:1] = m_new
 
-    _update_by_class(needed, full, causal or segmented, make_mask, update)
+    _update_by_class(
+        needed, full, causal or segmented, make_mask, update,
+        _kernel_triangles(triangles, iq, ik, bk, offset, window))
 
     @pl.when(jj == nj - 1)
     def _():
@@ -338,9 +499,48 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *rest, scale, causal, segmented,
         )
 
 
+def _bwd_operands(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, rows,
+                  cols):
+    """(q, k, v, dO, lse, δ) of a strip: its rows of the q-side operands
+    (dO in float32), its columns of k and v."""
+    return (q_ref[0, 0, rows, :], k_ref[0, 0, cols, :], v_ref[0, 0, cols, :],
+            do_ref[0, 0, rows, :].astype(jnp.float32),
+            lse_ref[0, 0, rows, :], delta_ref[0, 0, rows, :])
+
+
+def _bwd_probs(tiles, strips, scale):
+    """Each strip's probabilities exp(S − lse), recomputed from the saved
+    logsumexp, with exact zeros where its mask, if any, hides a pair."""
+    scores = []
+    for (q, k, *_), (_, _, mask, at) in zip(tiles, strips):
+        s = jax.lax.dot_general(
+            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        ) * scale
+        if mask is not None:
+            s = _masked(s, mask, at, _to_big_neg)  # as the forward does
+        scores.append(s)
+    probs = []
+    for (*_, lse, _), s, (_, _, mask, at) in zip(tiles, scores, strips):
+        p = jnp.exp(s - lse)
+        if mask is not None:
+            p = _masked(p, mask, at, jnp.multiply)
+        probs.append(p)
+    return probs
+
+
+def _bwd_ds(tile, p):
+    """dS = P ⊙ (dP − δ), dP = dO · Vᵀ: the softmax's jacobian."""
+    _, _, v, do, _, delta = tile
+    dp = jax.lax.dot_general(
+        do, v.astype(jnp.float32), (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    )
+    return p * (dp - delta)
+
+
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
                    scale, causal, segmented, bq, bk, offset, window, nk,
-                   sinks=0):
+                   sinks=0, triangles=()):
     if segmented:
         qs_ref, ks_ref, dq_ref, acc_ref = rest
     else:
@@ -363,36 +563,21 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
             iq, ik, causal, segmented, bq, bk, offset, window, qs_ref,
             ks_ref, sinks=sinks, is_sink=is_sink)
 
-    def update(mask):
-        q = q_ref[0, 0, :, :]
-        k = k_ref[0, 0, :, :]
-        v = v_ref[0, 0, :, :]
-        do = do_ref[0, 0, :, :].astype(jnp.float32)
-        lse = lse_ref[0, 0, :, :]
-        delta = delta_ref[0, 0, :, :]
+    def update(strips=_WHOLE):
+        tiles = [_bwd_operands(q_ref, k_ref, v_ref, do_ref, lse_ref,
+                               delta_ref, rows, cols)
+                 for rows, cols, _, _ in strips]
+        probs = _bwd_probs(tiles, strips, scale)
+        dss = [_bwd_ds(tile, p) for tile, p in zip(tiles, probs)]
+        for (_, k, *_), ds, (rows, _, _, _) in zip(tiles, dss, strips):
+            acc_ref[rows] = acc_ref[rows] + jax.lax.dot_general(
+                ds, k.astype(jnp.float32), (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            ) * scale
 
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        ) * scale
-        if mask is not None:
-            # Mask BEFORE exp (as the forward does): a large masked score
-            # would overflow exp to inf, and the TPU's inf*0 is NaN — the
-            # post-hoc `p * mask` alone is only safe in interpret mode.
-            s = s + (1.0 - mask) * _BIG_NEG
-        p = jnp.exp(s - lse)
-        if mask is not None:
-            p = p * mask
-        dp = jax.lax.dot_general(
-            do, v.astype(jnp.float32), (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        ds = p * (dp - delta)
-        acc_ref[:] = acc_ref[:] + jax.lax.dot_general(
-            ds, k.astype(jnp.float32), (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * scale
-
-    _update_by_class(needed, full, causal or segmented, make_mask, update)
+    _update_by_class(
+        needed, full, causal or segmented, make_mask, update,
+        _kernel_triangles(triangles, iq, ik, bk, offset, window))
 
     @pl.when(jj == nj - 1)
     def _():
@@ -401,7 +586,8 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
 
 def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
                     scale, causal, segmented, bq, bk, offset, window, nq,
-                    sinks=0, sink_only=False, with_dq=False):
+                    sinks=0, sink_only=False, with_dq=False,
+                    triangles=()):
     """dK/dV of one k block a sweep and, ``with_dq`` (the fused backward),
     dQ of the whole (b, h) beside them: every tile's ``ds·k`` lands on its q
     block's rows of a float32 [Tq, Dk] scratch that outlives the sweeps. A
@@ -447,45 +633,44 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
             iq, ik, causal, segmented, bq, bk, offset, window, qs_ref,
             ks_ref, sinks=sinks, is_sink=True if sink_only else None)
 
-    def update(mask):
-        q = q_ref[0, 0, :, :]
-        k = k_ref[0, 0, :, :]
-        v = v_ref[0, 0, :, :]
-        do = do_ref[0, 0, :, :].astype(jnp.float32)
-        lse = lse_ref[0, 0, :, :]
-        delta = delta_ref[0, 0, :, :]
-
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        ) * scale
-        if mask is not None:
-            s = s + (1.0 - mask) * _BIG_NEG  # pre-exp: see _bwd_dq_kernel
-        p = jnp.exp(s - lse)
-        if mask is not None:
-            p = p * mask
-        # dV += Pᵀ · dO
-        dv_acc[:] = dv_acc[:] + jax.lax.dot_general(
-            p, do, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
-        )
-        dp = jax.lax.dot_general(
-            do, v.astype(jnp.float32), (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        ds = p * (dp - delta)
-        # dK += dSᵀ · Q
-        dk_acc[:] = dk_acc[:] + jax.lax.dot_general(
-            ds, q.astype(jnp.float32), (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * scale
-        if with_dq:
-            # dQ[q block iq] += dS · K, as `_bwd_dq_kernel` forms it
-            rows = pl.ds(pl.multiple_of(iq * bq, bq), bq)
-            dq_acc[rows, :] = dq_acc[rows, :] + jax.lax.dot_general(
+    def update(strips=_WHOLE):
+        tiles = [_bwd_operands(q_ref, k_ref, v_ref, do_ref, lse_ref,
+                               delta_ref, rows, cols)
+                 for rows, cols, _, _ in strips]
+        probs = _bwd_probs(tiles, strips, scale)
+        for (_, _, _, do, _, _), p, (_, cols, _, _) in zip(
+                tiles, probs, strips):
+            # dV += Pᵀ · dO
+            dv_acc[cols] = dv_acc[cols] + jax.lax.dot_general(
+                p, do, (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )
+        dss = [_bwd_ds(tile, p) for tile, p in zip(tiles, probs)]
+        for (q, *_), ds, (_, cols, _, _) in zip(tiles, dss, strips):
+            # dK += dSᵀ · Q
+            dk_acc[cols] = dk_acc[cols] + jax.lax.dot_general(
+                ds, q.astype(jnp.float32), (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            ) * scale
+        if not with_dq:
+            return
+        for (_, k, *_), ds, (rows, _, _, _) in zip(tiles, dss, strips):
+            # dQ[the rows of q block iq] += dS · K, as `_bwd_dq_kernel`
+            # forms it
+            if rows == _ALL:
+                at = pl.ds(pl.multiple_of(iq * bq, bq), bq)
+            else:
+                height = rows.stop - rows.start
+                at = pl.ds(
+                    pl.multiple_of(iq * bq + rows.start, height), height)
+            dq_acc[at, :] = dq_acc[at, :] + jax.lax.dot_general(
                 ds, k.astype(jnp.float32), (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32,
             ) * scale
 
-    _update_by_class(needed, full, causal or segmented, make_mask, update)
+    _update_by_class(
+        needed, full, causal or segmented, make_mask, update,
+        _kernel_triangles(triangles, iq, ik, bk, offset, window))
 
     @pl.when(jj == nj - 1)
     def _():
@@ -639,14 +824,18 @@ def _flash(q, k, v, q_seg, kv_seg, causal, window, sinks, q_offset, bq, bk,
 
 
 def _note_tiles(*census_args, **census_kwargs) -> None:
-    """The gauge ``hvt_flash_tiles{kind}``: the `tile_census` of the last
-    kernel call traced. Set by the public entries at trace time (the jitted
-    impls below run once a process): the census is static per call."""
+    """The gauges ``hvt_flash_tiles{kind}`` and ``hvt_flash_subtiles{kind}``:
+    the `tile_census` and `subtile_census` of the last kernel call traced.
+    Set by the public entries at trace time (the jitted impls below run
+    once a process): both censuses are static per call."""
     from horovod_tpu import obs
 
     census = tile_census(*census_args, **census_kwargs)
     for kind, steps in zip(("skipped", "edge", "full"), census):
         obs.gauge("hvt_flash_tiles", float(steps), kind=kind)
+    census = subtile_census(*census_args, **census_kwargs)
+    for kind, blocks in zip(("run_full", "run_masked", "skipped"), census):
+        obs.gauge("hvt_flash_subtiles", float(blocks), kind=kind)
 
 
 # Jitted, so that the N identical attention calls of a model's layers share
@@ -679,6 +868,9 @@ def _flash_fwd_impl(q, k, v, q_seg, kv_seg, causal, window, sinks, q_offset,
     kernel = functools.partial(
         _fwd_kernel, scale=scale, causal=causal, segmented=segmented,
         bq=bq, bk=bk, offset=off, window=window, nk=nk, sinks=sinks,
+        triangles=_triangle_kinds(
+            tq, tk, bq, bk, causal=causal, window=window, sinks=sinks,
+            offset=off, segmented=segmented),
     )
     in_specs = [
         _block_spec(d, bq, qsel),
@@ -765,6 +957,9 @@ def _flash_bwd_impl(fused, causal, window, sinks, q_offset, bq, bk, interpret,
     off = tk - tq if q_offset is None else q_offset
     nq, nk = tq // bq, tk // bk
     nbq, kanchor, qsel = _q_sweep_maps(causal, bq, bk, off, window, nq, nk)
+    triangles = _triangle_kinds(
+        tq, tk, bq, bk, causal=causal, window=window, sinks=sinks,
+        offset=off, segmented=segmented)
     # delta_i = Σ_d dO·O — the softmax-jacobian row term, cheap outside.
     delta = jnp.einsum(
         "bthd,bthd->bht", g.astype(jnp.float32), out.astype(jnp.float32)
@@ -793,7 +988,7 @@ def _flash_bwd_impl(fused, causal, window, sinks, q_offset, bq, bk, interpret,
             functools.partial(
                 _bwd_dq_kernel, scale=scale, causal=causal,
                 segmented=segmented, bq=bq, bk=bk, offset=off, window=window,
-                nk=nk, sinks=sinks,
+                nk=nk, sinks=sinks, triangles=triangles,
             ),
             grid=(b, h, nq, nb),
             in_specs=in_specs,
@@ -841,7 +1036,7 @@ def _flash_bwd_impl(fused, causal, window, sinks, q_offset, bq, bk, interpret,
             functools.partial(
                 _bwd_dkv_kernel, scale=scale, causal=causal,
                 segmented=segmented, bq=bq, bk=bk, offset=off, window=window,
-                nq=nq, with_dq=with_dq,
+                nq=nq, with_dq=with_dq, triangles=triangles,
             ),
             grid=(b, h, nk, nbq),
             in_specs=in_specs,

@@ -110,49 +110,58 @@ SPLIT_SINKS = sorted(SPLIT + [fa.KERNEL_DKV])
     # [B, T, H, D], dtype, flash kwargs, segmented, the Mosaic calls of
     # forward + backward by name, v's head size where it is not D,
     # `hvt_flash_tiles` after the trace (skipped / edge / full grid steps a
-    # (b, h); None: not looked at)
-    "shape,dtype,kwargs,segmented,kernels,v_dim,tiles",
+    # (b, h); None: not looked at), `hvt_flash_subtiles` (run_full /
+    # run_masked / skipped 128 × 128 blocks of the triangle tiles, which
+    # run by row strips: every causal call without segment ids or sinks)
+    "shape,dtype,kwargs,segmented,kernels,v_dim,tiles,subtiles",
     [
         pytest.param((4, 1024, 16, 128), jnp.bfloat16, {}, False, FUSED,
-                     None, None, id="smoke-bf16-T1024"),
+                     None, None, (28, 8, 28), id="smoke-bf16-T1024"),
         pytest.param((2, 4096, 16, 128), jnp.bfloat16, {}, True, FUSED,
-                     None, (28, 36, 0), id="segments-T4096"),
+                     None, (28, 36, 0), (0, 0, 0), id="segments-T4096"),
         pytest.param((2, 4096, 16, 128), jnp.bfloat16,
                      {"window": 1024, "sinks": 64}, False, SPLIT_SINKS,
-                     None, None, id="window-sinks-T4096"),
+                     None, None, (0, 0, 0), id="window-sinks-T4096"),
         # Refused at 1024² tiles (16.20M of 16.00M scoped VMEM in the dK/dV
         # pass); `pick_blocks` takes 512² for 4-byte inputs.
         pytest.param((4, 2048, 8, 64), jnp.float32, {}, False, FUSED,
-                     None, None, id="f32-D64-T2048"),
+                     None, None, (24, 16, 24), id="f32-D64-T2048"),
         # The benchmark's cells' own calls, at the tiles `pick_blocks`
-        # gives them (two update bodies a kernel since PR 34; the 1024²
+        # gives them (a full body and a triangle body a kernel; the 1024²
         # backward is the fullest). Kanana's keeps 16 MiB of dQ resident.
         pytest.param((2, 2048, 16, 128), jnp.bfloat16, {}, False, FUSED,
-                     None, (1, 2, 1), id="cell-cerebras-gpt-1.3b"),
+                     None, (1, 2, 1), (56, 16, 56),
+                     id="cell-cerebras-gpt-1.3b"),
         pytest.param((1, 4096, 24, 128), jnp.bfloat16, {"window": 4096},
-                     False, FUSED, None, (28, 8, 28),
+                     False, FUSED, None, (28, 8, 28), (48, 32, 48),
                      id="cell-starcoder2-3b"),
         pytest.param((1, 8192, 32, 192), jnp.bfloat16, {}, False, FUSED,
-                     128, (120, 16, 120), id="cell-kanana-2-30b-a3b"),
-        # Laguna's two kinds (PR 41): the window layers' band at 512² tiles,
-        # every running tile an edge tile, and the full layers at group 6.
+                     128, (120, 16, 120), (96, 64, 96),
+                     id="cell-kanana-2-30b-a3b"),
+        # Laguna's two kinds: the window layers' band at 512² tiles, every
+        # running tile a triangle (the band's lower edge or the diagonal),
+        # and the full layers at group 6.
         pytest.param((1, 8192, 64, 128), jnp.bfloat16, {"window": 512},
-                     False, FUSED, None, (17, 31, 0),
+                     False, FUSED, None, (17, 31, 0), (186, 124, 186),
                      id="cell-laguna-xs.2-window"),
         pytest.param((1, 8192, 48, 128), jnp.bfloat16, {}, False, FUSED,
-                     None, (28, 8, 28), id="cell-laguna-xs.2-full"),
+                     None, (28, 8, 28), (224, 64, 224),
+                     id="cell-laguna-xs.2-full"),
         # Long contexts, in whichever form the predicate picks: 32 MiB of
         # dQ resident, and the budget's edge (64 MiB: half the VMEM).
         pytest.param((1, 32768, 2, 128), jnp.bfloat16, {}, False, None,
-                     None, None, id="T32768-D128"),
+                     None, None, (384, 256, 384), id="T32768-D128"),
         pytest.param((1, 32768, 2, 256), jnp.bfloat16, {}, False, None,
-                     None, None, id="T32768-D256-at-the-budget"),
+                     None, None, (384, 256, 384),
+                     id="T32768-D256-at-the-budget"),
         pytest.param((1, 65536, 1, 256), jnp.bfloat16, {}, False, None,
-                     None, None, id="T65536-D256-past-the-budget"),
+                     None, None, (768, 512, 768),
+                     id="T65536-D256-past-the-budget"),
     ],
 )
 def test_flash_fwd_bwd_compiles_for_v5e(topo, shape, dtype, kwargs,
-                                        segmented, kernels, v_dim, tiles):
+                                        segmented, kernels, v_dim, tiles,
+                                        subtiles):
     from horovod_tpu.obs import core as obs_core
     from horovod_tpu.obs import prom
 
@@ -194,6 +203,10 @@ def test_flash_fwd_bwd_compiles_for_v5e(topo, shape, dtype, kwargs,
             values[f'hvt_flash_tiles{{kind="{kind}"}}']
             for kind in ("skipped", "edge", "full")
         ) == tiles
+    assert tuple(
+        values[f'hvt_flash_subtiles{{kind="{kind}"}}']
+        for kind in ("run_full", "run_masked", "skipped")
+    ) == subtiles
     obs_core.reset()
 
 

@@ -978,6 +978,95 @@ class TestTileClasses:
         ] == [6.0, 4.0, 6.0]
         obs_core.reset()
 
+    @pytest.mark.parametrize(
+        "nq,nk,q_offset",
+        [(4, 4, None), (3, 5, None), (5, 3, None), (4, 4, "tk"),
+         (4, 4, "-tk"), (4, 4, 4), (4, 4, 8), (3, 4, -4)],
+        ids=["self", "tk>tq", "tk<tq", "hop-all-visible", "hop-all-future",
+             "hop+1-tile", "hop+2-tiles", "hop-1-tile"],
+    )
+    @pytest.mark.parametrize("window", [None, 4, 8, 12, 40])
+    def test_aligned_edge_tiles_are_the_two_triangles(self, nq, nk, q_offset,
+                                                      window):
+        """Square tiles, an offset and a window on the tile grid: every
+        edge tile of both sweeps is the diagonal's triangle (κ ≤ ρ) or the
+        band's lower edge's (κ > ρ) by `_is_triangle`, never both, and no
+        full or skipped tile is taken for one."""
+        b = 4
+        tq, tk = nq * b, nk * b
+        q_offset = {"tk": tk, "-tk": -tk}.get(q_offset, q_offset)
+        off = tk - tq if q_offset is None else q_offset
+        band, _ = _dense_keep(tq, tk, off, window)
+        rho, kappa = np.indices((b, b))
+        pattern = {"diag": kappa <= rho, "band": kappa > rho}
+        tiles = fa._triangle_tiles(tq, tk, b, b, off, window)
+        steps = fa._k_sweep_steps(b, b, window, 0, nk)
+        iq, jj = np.indices((nq, steps))
+        ik, _, needed, full = fa._k_sweep_tile(
+            iq, jj, True, b, b, off, window, 0, nk)
+        qsteps, *_ = fa._q_sweep_maps(True, b, b, off, window, nq, nk)
+        kk, jq = np.indices((nk, qsteps))
+        qb = jq + (fa._band_lo_q(kk, b, b, off) if window is not None else 0)
+        q_needed, q_full = fa._tile_class(qb, kk, b, b, off, window)
+        q_needed &= qb <= nq - 1
+        for rows, cols, need, whole in ((iq, ik, needed, full),
+                                        (qb, kk, q_needed, q_full)):
+            need = np.broadcast_to(need, rows.shape)
+            edge = need & ~np.broadcast_to(whole, rows.shape)
+            kinds = {kind: need & fa._is_triangle(kind, rows, cols, b, off,
+                                                  window)
+                     for kind in tiles}
+            assert (sum(kinds.values()) == edge).all()
+            for kind, at in kinds.items():
+                assert at.sum() == tiles[kind]
+                for i, k in zip(rows[at], cols[at]):
+                    np.testing.assert_array_equal(
+                        band[i * b:(i + 1) * b, k * b:(k + 1) * b],
+                        pattern[kind])
+
+    @pytest.mark.parametrize(
+        "call,expected",
+        [
+            # The cells' calls at the tiles `pick_blocks` gives them:
+            # run_full / run_masked / skipped 128 × 128 blocks a (b, h).
+            (dict(t=8192, d=128, window=512), (186, 124, 186)),
+            (dict(t=8192, d=128, window=None), (224, 64, 224)),
+            (dict(t=8192, d=192, window=None), (96, 64, 96)),
+            (dict(t=4096, d=128, window=4096), (48, 32, 48)),
+            (dict(t=2048, d=128, window=None), (56, 16, 56)),
+        ],
+        ids=["laguna-xs.2-window", "laguna-xs.2-full", "kanana-2-30b-a3b",
+             "starcoder2-3b", "cerebras-gpt-1.3b"],
+    )
+    def test_subtile_census_of_the_cells_calls(self, call, expected):
+        t, window = call["t"], call["window"]
+        bq, bk = pick_blocks(
+            t, call["d"], jnp.bfloat16, windowed=window is not None)
+        assert fa.subtile_census(
+            t, t, bq, bk, causal=True, window=window) == expected
+        # The sub-blocks a triangle tile holds are its whole.
+        edge = tile_census(t, t, bq, bk, causal=True, window=window)[1]
+        assert sum(expected) == edge * (bk // 128) ** 2
+
+    def test_calls_that_run_no_triangle_tile(self):
+        """Each condition the sub-block form needs, broken once: the edge
+        tiles run whole and the census reads 0 / 0 / 0."""
+        for kwargs in (
+            dict(causal=False),
+            dict(causal=True, segmented=True),
+            dict(causal=True, window=512, sinks=64),
+            dict(causal=True, window=384),      # off the tile grid
+            dict(causal=True, q_offset=100),    # a hop off the tile grid
+        ):
+            assert fa.subtile_census(1024, 1024, 256, 256, **kwargs) == (
+                0, 0, 0), kwargs
+        assert fa.subtile_census(
+            1024, 1024, 256, 128, causal=True) == (0, 0, 0)  # unequal
+        assert fa.subtile_census(
+            1024, 1024, 128, 128, causal=True) == (0, 0, 0)  # one strip
+        assert fa.subtile_census(
+            1024, 1024, 256, 256, causal=True) == (4, 8, 4)
+
 
 class TestEveryTileClass:
     """Forward and the three gradients through skipped, full and edge tiles
@@ -1018,6 +1107,23 @@ class TestEveryTileClass:
            "window=T", "window>T", "tk>tq", "tk>tq-window", "q_offset",
            "q_offset-window", "dk!=dv", "sinks", "sinks-full-tiles",
            "segments", "segments-window"]
+    # Tiles of 256, so that a triangle tile runs as two strips of 128:
+    # the causal diagonal alone, the band's lower edge and the diagonal in
+    # neighbouring tiles (window = tile), with full tiles between them
+    # (window = 2 tiles), and a window off the tile grid (384), whose
+    # edge tiles run whole. ``subtiles``: the `subtile_census`.
+    TRIANGLES = [
+        dict(tile=256, window=window, dk=dk, dv=dv, no_full=no_full,
+             subtiles=subtiles)
+        for window, no_full, subtiles in (
+            (None, False, (4, 8, 4)), (256, True, (7, 14, 7)),
+            (512, False, (6, 12, 6)), (384, True, (0, 0, 0)))
+        for dk, dv in ((64, 64), (192, 128))
+    ]
+    TRIANGLE_IDS = [
+        f"tile256-{'causal' if c['window'] is None else c['window']}"
+        f"-D{c['dk']}" + ("" if c["dk"] == c["dv"] else f"-Dv{c['dv']}")
+        for c in TRIANGLES]
 
     def _call(self, case):
         """(q, k, v, tile, flash kwargs) of a case, its census checked: a
@@ -1025,8 +1131,8 @@ class TestEveryTileClass:
         ones where the case can have them."""
         segments = case.get("segments", False)
         # Segment ids need lane-aligned k blocks: 128² tiles there.
-        tile = 128 if segments else self.TILE
-        tq = 3 * tile
+        tile = case.get("tile", 128 if segments else self.TILE)
+        tq = 1024 if "tile" in case else 3 * tile
         tk = case.get("tk", tq)
         dk, dv = case.get("dk", 16), case.get("dv", case.get("dk", 16))
         q = self._rand((1, tq, 2, dk), 50)
@@ -1049,6 +1155,26 @@ class TestEveryTileClass:
 
     @pytest.mark.parametrize("case", CASES, ids=IDS)
     def test_forward_and_gradients(self, case):
+        self._check_against_dense(case)
+
+    @pytest.mark.parametrize("case", TRIANGLES, ids=TRIANGLE_IDS)
+    def test_triangle_tiles_forward_and_gradients(self, case):
+        """The sub-block form against the dense reference, and the gauge
+        ``hvt_flash_subtiles`` of the call it traced."""
+        from horovod_tpu import obs
+        from horovod_tpu.obs import core as obs_core
+        from horovod_tpu.obs import prom
+
+        obs_core.reset()
+        self._check_against_dense(case)
+        values = prom.parse_text(prom.render(obs.default_registry()))
+        assert tuple(
+            values[f'hvt_flash_subtiles{{kind="{kind}"}}']
+            for kind in ("run_full", "run_masked", "skipped")
+        ) == case["subtiles"]
+        obs_core.reset()
+
+    def _check_against_dense(self, case):
         q, k, v, tile, kwargs = self._call(case)
 
         def flash(q, k, v):
@@ -1073,8 +1199,9 @@ class TestEveryTileClass:
     @pytest.mark.parametrize("lse_cotangent", [False, True],
                              ids=["out", "out+lse"])
     @pytest.mark.parametrize(
-        "case", [c for c in CASES if "sinks" not in c],
-        ids=[i for i, c in zip(IDS, CASES) if "sinks" not in c])
+        "case", [c for c in CASES if "sinks" not in c] + TRIANGLES,
+        ids=[i for i, c in zip(IDS, CASES) if "sinks" not in c]
+        + TRIANGLE_IDS)
     def test_fused_backward_equals_the_two_kernels_to_the_bit(
             self, case, lse_cotangent):
         """dq, dk and dv of the one-kernel backward against the two-kernel
