@@ -5,7 +5,7 @@
 
 A helper, run by no cell and no test: nothing runs on a device, so nothing
 here is a time. It compiles ``jax.grad(flash_attention)`` for a DESCRIBED
-v5e (as `tests/test_chip_compile.py` does) in a child process with
+v5e (`horovod_tpu/testing/described_v5e.py`) in a child process with
 
     LIBTPU_INIT_ARGS="--xla_jf_dump_to=<dir> --xla_jf_dump_llo_text=true"
 
@@ -61,7 +61,7 @@ MASK_OPS = ("vlaneseq", "vcmp", "vsel")
 
 def compile_in_child(args, dump_dir: str) -> None:
     env = dict(
-        os.environ, JAX_PLATFORMS="cpu", TPU_LOG_DIR="disabled",
+        os.environ, JAX_PLATFORMS="cpu",
         LIBTPU_INIT_ARGS=(
             f"--xla_jf_dump_to={dump_dir} --xla_jf_dump_llo_text=true"),
     )
@@ -82,21 +82,11 @@ def compile_in_child(args, dump_dir: str) -> None:
 def child_main(args) -> None:
     import jax
     import jax.numpy as jnp
-    from jax.experimental import topologies
     from jax.sharding import SingleDeviceSharding
 
     from horovod_tpu.ops import flash_attention as fa
+    from horovod_tpu.testing import described_v5e
 
-    jax.config.update("jax_enable_compilation_cache", False)
-    topo = topologies.get_topology_desc(
-        platform="tpu", topology_name="v5e:2x2")
-    one_chip = SingleDeviceSharding(topo.devices[0])
-    qk = jax.ShapeDtypeStruct(
-        (args.b, args.t, args.heads, args.dk), jnp.bfloat16,
-        sharding=one_chip)
-    v = jax.ShapeDtypeStruct(
-        (args.b, args.t, args.heads, args.dv), jnp.bfloat16,
-        sharding=one_chip)
     blocks = {} if args.block is None else dict(
         block_q=args.block, block_k=args.block)
 
@@ -107,7 +97,15 @@ def child_main(args) -> None:
                 **blocks)
         return out.astype(jnp.float32).sum()
 
-    jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(qk, qk, v).compile()
+    with described_v5e.described() as topo:
+        one_chip = SingleDeviceSharding(topo.devices[0])
+        qk = jax.ShapeDtypeStruct(
+            (args.b, args.t, args.heads, args.dk), jnp.bfloat16,
+            sharding=one_chip)
+        v = jax.ShapeDtypeStruct(
+            (args.b, args.t, args.heads, args.dv), jnp.bfloat16,
+            sharding=one_chip)
+        jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(qk, qk, v).compile()
 
 
 BUNDLE = re.compile(r"\s*(0x[0-9a-f]+|\d+)\s+(?:([A-Z]{2}):)?\s*>?\s*:?\s*>?\s*\{")

@@ -6,7 +6,7 @@
 
 A helper, run by no cell and no test: nothing runs on a device, so nothing
 here is a time. It builds the cell's trainer as `chipbench/run.py` does, on
-the devices of a DESCRIBED v5e:2x2 (as `tests/test_chip_compile.py` does),
+the devices of a DESCRIBED v5e:2x2 (`horovod_tpu/testing/described_v5e.py`),
 lowers and compiles the step `Trainer.fit` runs (≈ 2 min for the dp4 cell,
 a 5 MB text; ``--dump`` keeps it, ``--text`` reads one back in a second)
 and prints what `horovod_tpu.analysis.hlo_audit.reduction_schedule` makes
@@ -26,47 +26,21 @@ import sys
 
 
 def compiled_text(cell_name: str) -> str:
-    os.environ.setdefault("TPU_LOG_DIR", "disabled")
     os.environ.setdefault("JAX_PLATFORMS", "cpu")
-    import jax
-    import jax.numpy as jnp
-    from jax.experimental import topologies
-
     from chipbench import run
     from horovod_tpu.ops import flash_attention as fa
-    from horovod_tpu.parallel import sharding as sharding_lib
-    from horovod_tpu.training.train_state import TrainState
+    from horovod_tpu.testing import described_v5e
 
-    # An executable compiled for a described device can be written to the
-    # compile cache but not read back.
-    jax.config.update("jax_enable_compilation_cache", False)
     # The program asks the attached devices (CPUs here) whether to
     # interpret its kernels: steered here, never by an option of its own.
     fa.default_interpret = lambda: False
     cell = run.load_cell(run.ROOT, cell_name)
-    topo = topologies.get_topology_desc(
-        platform="tpu", topology_name="v5e:2x2")
-    trainer = run.build_trainer(cell, topo.devices[:cell["chips"]], 0)
-    mesh, traffic = trainer.mesh, cell["traffic"]
-    sds = jax.ShapeDtypeStruct
-    rep = sharding_lib.replicated(mesh)
-    tokens = sds((traffic["global_batch"], traffic["seq_len"]), jnp.int32,
-                 sharding=sharding_lib.batch_sharding(mesh, 2))
-    x0 = jnp.zeros((trainer.dp_size, traffic["seq_len"]), jnp.int32)
-    key = jax.random.PRNGKey(0)
-    params = jax.eval_shape(lambda: trainer.module.init(
-        {"params": key, "dropout": key}, x0, train=False, labels=x0)
-    )["params"]
-    state = jax.tree.map(
-        lambda leaf: sds(leaf.shape, leaf.dtype, sharding=rep),
-        TrainState(step=sds((), jnp.int32), params=params,
-                   opt_state=jax.eval_shape(trainer.tx.init, params),
-                   rng=sds((2,), jnp.uint32), model_state=None))
-    scalar = sds((), jnp.float32, sharding=rep)
-    return trainer._train_step_donated.lower(
-        state, (tokens, tokens), scalar,
-        {name: scalar for name in trainer.metric_names},
-    ).compile().as_text()
+    with described_v5e.described() as topo:
+        trainer = run.build_trainer(cell, topo.devices[:cell["chips"]], 0)
+        traffic = cell["traffic"]
+        return trainer._train_step_donated.lower(*described_v5e.step_args(
+            trainer, traffic["seq_len"], traffic["global_batch"],
+        )).compile().as_text()
 
 
 def main() -> int:

@@ -95,3 +95,35 @@ def tmp_cache(tmp_path_factory):
     d = tmp_path_factory.mktemp("hvt_cache")
     os.environ["HVT_DATA_DIR"] = str(d)
     return d
+
+
+# --- the described chip of the compile tests, which alone request it -------
+# (tests/test_chip_compile.py, tests/test_kernel_compile.py)
+
+@pytest.fixture(scope="module")
+def topo():
+    """A described TPU v5e:2x2 (`horovod_tpu.testing.described_v5e`),
+    described inside a fixture and never at import, so every xdist worker
+    collects the same tests. Where libtpu cannot describe it (no libtpu, or
+    a second process without ``ALLOW_MULTIPLE_LIBTPU_LOAD=1``) the module's
+    tests skip with libtpu's reason."""
+    from horovod_tpu.testing import described_v5e
+
+    try:
+        chip = described_v5e.described()
+        desc = chip.__enter__()
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    try:
+        yield desc
+    finally:
+        chip.__exit__(None, None, None)
+
+
+@pytest.fixture
+def compiled_kernel(monkeypatch):
+    """Steer the program's one interpret decision to what it takes on the
+    chip. It asks the attached devices, which are CPUs here."""
+    from horovod_tpu.ops import flash_attention as fa
+
+    monkeypatch.setattr(fa, "default_interpret", lambda: False)

@@ -6,7 +6,7 @@ thread wrap their boundaries in it. Device side: `jax.named_scope`s around
 the optimizer update and the head + CE (forward AND backward rule), which
 the lowered step carries in its debug locations. The always-on counters
 move in the loop, exporter on or off. The flash kernels' instruction names
-need the chip's compiler and live in tests/test_chip_compile.py.
+need the chip's compiler and live in tests/test_kernel_compile.py.
 """
 
 import collections
